@@ -61,6 +61,8 @@ _PAIRS_RE = re.compile(r"source_target_pairs=\{(\{[0-9,{} ]*\})\}")
 # parens/quotes first).
 _PARAM_RE = re.compile(r"^[^()\"]*\bparameter\((\d+)\)")
 _OPERAND_NAME_RE = re.compile(r"%([\w.\-]+)")
+# The opcode's opening paren: everything before it is the result shape.
+_OPCODE_RE = re.compile(r"\s[a-z][\w\-]*\(")
 
 
 @dataclass
@@ -177,11 +179,20 @@ def _split_operands(rest: str, open_idx: int) -> Tuple[str, str]:
 
 def parse_hlo_collectives(text: str) -> HloCollectives:
     out = HloCollectives()
+    # name -> bytes of the value that instruction defines. Newer XLA
+    # prints operands by NAME only (``all-reduce(%wrapped_reduce)``, no
+    # inline ``f32[128]{0}``), so operand bytes come from the defining
+    # instruction's result shape; older text carries the shapes inline
+    # and never consults this map.
+    defined: Dict[str, int] = {}
     for line in text.splitlines():
         im = _INSTR_RE.match(line)
         if im is None:
             continue
         rest = im.group("rest")
+        op_open = _OPCODE_RE.search(rest)
+        defined[im.group("name")] = _shape_bytes(
+            rest[:op_open.start()] if op_open else rest)
         pm = _PARAM_RE.match(rest)
         if pm is not None:
             out.params[im.group("name")] = int(pm.group(1))
@@ -211,7 +222,9 @@ def parse_hlo_collectives(text: str) -> HloCollectives:
         out.collectives.append(Collective(
             kind=km.group("kind"),
             name=im.group("name"),
-            bytes_in=_shape_bytes(operands),
+            bytes_in=(_shape_bytes(operands) if _SHAPE_RE.search(operands)
+                      else sum(defined.get(n, 0) for n in
+                               _OPERAND_NAME_RE.findall(operands))),
             bytes_out=bytes_out,
             groups=parse_replica_groups(attrs),
             pairs=parse_permute_pairs(attrs),

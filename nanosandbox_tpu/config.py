@@ -86,8 +86,9 @@ class TrainConfig:
     # in-register — ~128x less stat HBM traffic (ops/attention.py).
     # Default compact: measured faster at the 124M bench shape once the
     # r5 backward-kernel changes removed the other overheads (110.8k vs
-    # 108.9k tok/s), and strictly less memory; the compile probe covers
-    # both layouts so 'auto' still degrades safely.
+    # 108.9k tok/s; July 2026, earlier tree, not re-measured), and
+    # strictly less memory; tests/test_chip_compile.py compiles both
+    # layouts for v5e.
     attention_stat_layout: str = "compact"
     remat: bool = False  # jax.checkpoint each block (HBM <-> FLOPs trade)
     # What remat saves: 'save_attention' keeps each block's attention
@@ -321,9 +322,9 @@ class GPTConfig:
     remat: bool = False
     remat_policy: str = "save_attention"
     # Cached-decode attention impl for the T=1 per-row hot path
-    # (ops/flash_decode.py ladder): 'auto' = Pallas flash-decode when the
-    # compile probe passes, XLA otherwise; 'pallas' / 'pallas_interpret'
-    # / 'xla' pin it. Training never reads this field.
+    # (ops/flash_decode.py): 'auto' = Pallas flash-decode on a tpu
+    # backend (a compile error propagates), XLA on any other; 'pallas' /
+    # 'pallas_interpret' / 'xla' pin it. Training never reads this field.
     decode_impl: str = "auto"
 
     def replace(self, **kw: Any) -> "GPTConfig":

@@ -547,7 +547,7 @@ class GPT(nn.Module):
         blocks. Without the anchor at the wte gather, SPMD has to invert a
         sharding transition through a gather whose table is fsdp-sharded —
         a move it only solves by involuntary full rematerialization
-        (replicate, then re-partition; MULTICHIP_r03.json tail warning).
+        (replicate, then re-partition — the SPMD partitioner warns).
         Free when the sharding already matches, which it does everywhere
         else, so this is an anchor, not a resharding."""
         if self.mesh is None or self.mesh.size == 1:
@@ -595,6 +595,17 @@ class GPT(nn.Module):
                 pos = cache_index[:, None] + jnp.arange(T)[None, :]
             else:
                 pos = cache_index + jnp.arange(T)[None, :]
+            # Lanes that overshoot a row's end ON PURPOSE (speculative
+            # verify lanes, a scan rung's trailing lane-steps, padded
+            # prefill buckets) carry positions >= block_size. nn.Embed's
+            # gather FILLS an out-of-range row with NaN, it does not
+            # clamp — and one NaN row poisons the logits the poison
+            # guard reads. Bound the lookup here: such a lane reads the
+            # last (finite) row, and the masks already discard it. (wte
+            # is deliberately NOT bounded: token ids are validated at
+            # submit, and the one out-of-vocab id in the system is the
+            # poison sentinel, whose row must stay poisoned.)
+            pos = jnp.clip(pos, 0, cfg.block_size - 1)
         else:
             pos = jnp.arange(T)[None, :]
         x = self._constrain_acts(wte(idx) + wpe(pos))
@@ -609,8 +620,9 @@ class GPT(nn.Module):
                     "the cached decode path always returns (logits, cache)")
             # Contract: cache_index + T must stay within the cache buffer.
             # An overrun would not error — dynamic_update_slice clamps the
-            # write offset and the wpe gather clamps positions — it would
-            # silently produce wrong logits. Checkable only when the index
+            # write offset and the wpe lookup above is bounded to the last
+            # position — it would silently produce wrong logits for the
+            # overrunning lanes. Checkable only when the index
             # is a Python int (jit callers pass a traced scalar and must
             # enforce the bound themselves, as sample.generate does by
             # falling back to the windowed path when total > block_size).
@@ -884,7 +896,8 @@ def cross_entropy_loss(logits: jax.Array, targets: jax.Array,
     log-probability tensor never materializes. At the 124M bench shape
     that tensor is 3.3 GB of f32 HBM writes+reads per step; the lse form
     reduces the head+CE fwd+bwd from ~38.6 to ~25.8 ms on v5e
-    (benchmarks/r5/roofline_124m.json, RTT-corrected)."""
+    (benchmarks/r5/roofline_124m.json; measured July 2026 on an earlier
+    tree, not re-measured)."""
     logits = logits.astype(jnp.float32)
     valid = targets != ignore_index
     safe_targets = jnp.where(valid, targets, 0)

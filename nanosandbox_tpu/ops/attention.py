@@ -29,7 +29,6 @@ Layouts: q, k, v are (B, H, T, D). D (head_dim) is padded to a multiple of
 from __future__ import annotations
 
 import functools
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -60,7 +59,7 @@ LANES = 128  # minor-dim register width; row stats are replicated across it
 __all__ = ["causal_attention", "xla_attention", "flash_attention",
            "flash_attention_dropout", "flash_attention_lse",
            "flash_attention_lse_dropout", "hash_dropout_keep_mask",
-           "pallas_compile_probe"]
+           "resolve_attention_impl"]
 
 
 # ---------------------------------------------------------------------------
@@ -686,8 +685,8 @@ def _flash_bwd_tiles_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
 # 'split' (q-parallel dQ kernel + key-parallel dKV walk). Both strategies
 # share _flash_bwd_tiles_kernel for the dk/dv math, so they cannot drift
 # there; tests/test_attention.py pins fused-vs-split gradient parity so
-# the split path stays exercised. NOT an automatic fallback: the compile
-# probe degrades auto -> XLA attention, never fused -> split.
+# the split path stays exercised. NOT an automatic fallback: nothing
+# degrades fused -> split (or pallas -> XLA) on a compile error.
 BWD_IMPL = "fused"
 
 
@@ -1149,15 +1148,13 @@ def _jax_tpu_flash(q, k, v, sm_scale):
     opt-in alternative: isolated fwd+bwd microbenchmarks on v5e slightly
     favor it, but in the full GPT-2 train step it measures ~15% SLOWER than
     this file's kernel (664 vs 563 ms/step at batch 32) and OOMs at batch
-    64 — its backward saves more residuals. Returns None when unavailable
-    so callers fall back to the custom kernel. Sequence lengths that are
-    not 128-aligned (e.g. the Trainer's tiny init dummy batch) are zero
+    64 — its backward saves more residuals (measured July 2026 on an
+    earlier tree, not re-measured). Sequence lengths that are not
+    128-aligned (e.g. the Trainer's tiny init dummy batch) are zero
     padded here; causal masking keeps real queries from seeing the pad."""
-    try:
-        from jax.experimental.pallas.ops.tpu.flash_attention import (
-            flash_attention as jflash)
-    except ImportError:
-        return None
+    from jax.experimental.pallas.ops.tpu.flash_attention import (
+        flash_attention as jflash)
+
     T = q.shape[2]
     pad_T = (-T) % 128
     if pad_T:
@@ -1167,106 +1164,15 @@ def _jax_tpu_flash(q, k, v, sm_scale):
     return out[:, :, :T, :] if pad_T else out
 
 
-_PALLAS_PROBE: dict[str, bool] = {}
-
-
-def pallas_compile_probe() -> bool:
-    """True iff the custom Pallas kernel (fwd AND bwd) compiles on the
-    current default backend. Compiled once per process per backend; the
-    result gates 'auto' dispatch so one kernel regression can never take
-    down default-config runs (it degrades to the XLA path with a warning).
-
-    Compile-only (AOT lower+compile on tiny shapes), so the probe is cheap
-    and safe to call while tracing an outer jit.
-
-    Multi-host note: with process_count > 1 the probe runs a cross-process
-    broadcast so all hosts agree on one verdict — every process that built
-    the distributed runtime MUST reach its first attention call, or the
-    barrier deadlocks. A single-process diagnostic tool running inside an
-    initialized multi-process runtime (e.g. a rank-0-only script) should
-    set NANOSANDBOX_ATTENTION_PROBE=local to skip the collective (or pin
-    --attention_impl explicitly, which never probes).
-    """
-    backend = jax.default_backend()
-    if backend in _PALLAS_PROBE:
-        return _PALLAS_PROBE[backend]
-    if backend != "tpu":
-        # Compiled Mosaic kernels only exist on TPU; interpret mode is a
-        # separate explicit impl.
-        _PALLAS_PROBE[backend] = False
-        return False
-    import os
-
-    if os.environ.get("NANOSANDBOX_ATTENTION_PROBE") == "local":
-        _PALLAS_PROBE[backend] = _probe_locally()
-        return _PALLAS_PROBE[backend]
-    if jax.process_count() > 1:
-        # Multi-host SPMD: a per-host probe could diverge (e.g. one host
-        # fails compile transiently) and hosts would then lower DIFFERENT
-        # programs — a silent hang at the first collective. All hosts
-        # follow process 0's verdict; if a host then genuinely cannot
-        # compile the kernel it fails loudly, which beats divergence.
-        from jax.experimental import multihost_utils
-
-        local = _probe_locally()
-        verdict = bool(multihost_utils.broadcast_one_to_all(
-            jnp.asarray(local)))
-        if verdict and not local:
-            raise RuntimeError(
-                "Pallas flash kernel compiled on process 0 but not on "
-                f"process {jax.process_index()} — refusing to diverge")
-        _PALLAS_PROBE[backend] = verdict
-        return verdict
-    _PALLAS_PROBE[backend] = _probe_locally()
-    return _PALLAS_PROBE[backend]
-
-
-def _probe_locally() -> bool:
-    try:
-        # T=1024 so _clamp_blocks selects the production DEFAULT_BLOCK
-        # config — probing a smaller shape would compile 128-row blocks
-        # and miss regressions specific to the block size real training
-        # runs (e.g. VMEM pressure of the 512x512 score tile).
-        x = jax.ShapeDtypeStruct((1, 1, 1024, 64), jnp.bfloat16)
-
-        def fwd(q, k, v):
-            return flash_attention(q, k, v, True, None, False)
-
-        def make_loss(layout):
-            def loss(q, k, v):
-                return flash_attention(
-                    q, k, v, True, None, False, layout
-                ).astype(jnp.float32).sum()
-            return loss
-
-        def make_loss_dropout(layout):
-            def loss_dropout(q, k, v, seed):
-                return flash_attention_dropout(
-                    q, k, v, seed, True, None, 0.1, False, layout
-                ).astype(jnp.float32).sum()
-            return loss_dropout
-
-        s = jax.ShapeDtypeStruct((1,), jnp.uint32)
-        jax.jit(fwd).lower(x, x, x).compile()
-        # BOTH stat layouts are part of the verdict: the config default is
-        # 'compact', and 'auto' must not promise a fallback it only
-        # checked for 'replicated' (round-4 ADVICE #2 — a Mosaic
-        # regression in the compact expansion path would otherwise crash
-        # the first backward instead of degrading to XLA). The dropout
-        # variant is part of the same verdict too, in both layouts:
-        # 'auto' promises that regularized (dropout>0) configs run the
-        # flash path under whichever layout the config selects.
-        for layout in ("replicated", "compact"):
-            jax.jit(jax.grad(make_loss(layout),
-                             argnums=(0, 1, 2))).lower(x, x, x).compile()
-            jax.jit(jax.grad(make_loss_dropout(layout),
-                             argnums=(0, 1, 2))).lower(x, x, x, s).compile()
-        return True
-    except Exception as e:  # Mosaic lowering / compile failure
-        warnings.warn(
-            "Pallas flash attention failed to compile on this TPU; "
-            f"falling back to XLA attention. Error: {e}")
-        return False
+def resolve_attention_impl(impl: str) -> str:
+    """'auto' means ONE thing per backend: this file's compiled Pallas
+    kernel on tpu, XLA attention everywhere else. Nothing is probed and
+    nothing is caught: a kernel the chip's compiler refuses fails the
+    train step's compile instead of silently training on the XLA path.
+    Explicit impls pass through."""
+    if impl != "auto":
+        return impl
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -1276,10 +1182,10 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                      stat_layout: str = "replicated") -> jax.Array:
     """Causal attention over (B, H, T, D) tensors.
 
-    impl: 'auto' (Pallas on TPU when it compiles, XLA otherwise — a probe
-    compiles the kernel once per process so a kernel regression degrades
-    to XLA instead of crashing), 'pallas', 'pallas_interpret' (for CPU
-    tests), 'pallas_jax' (jax's library kernel), or 'xla'.
+    impl: 'auto' (Pallas on a tpu backend — a compile error propagates —
+    and XLA on any other; see resolve_attention_impl), 'pallas',
+    'pallas_interpret' (for CPU tests), 'pallas_jax' (jax's library
+    kernel), or 'xla'.
 
     stat_layout ('replicated' | 'compact'): the flash backward's softmax-
     stat operand layout (--attention_stat_layout); ignored by the
@@ -1291,8 +1197,7 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     The pallas and XLA paths draw different (equally valid) masks from the
     same rng — identical regularization statistics, different bits.
     """
-    if impl == "auto":
-        impl = "pallas" if pallas_compile_probe() else "xla"
+    impl = resolve_attention_impl(impl)
     if dropout_rate > 0.0 and dropout_rng is not None:
         if impl in ("pallas", "pallas_interpret"):
             seed = jax.random.bits(dropout_rng, (1,), jnp.uint32)
@@ -1308,12 +1213,8 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if impl == "pallas":
         return flash_attention(q, k, v, True, sm_scale, False, stat_layout)
     if impl == "pallas_jax":
-        out = _jax_tpu_flash(q, k, v, sm_scale if sm_scale is not None
-                             else q.shape[-1] ** -0.5)
-        if out is None:
-            raise ValueError("jax library flash kernel unavailable "
-                             "(requires a TPU backend)")
-        return out
+        return _jax_tpu_flash(q, k, v, sm_scale if sm_scale is not None
+                              else q.shape[-1] ** -0.5)
     if impl == "pallas_interpret":
         return flash_attention(q, k, v, True, sm_scale, True, stat_layout)
     raise ValueError(f"unknown attention impl: {impl!r}")

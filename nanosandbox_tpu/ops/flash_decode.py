@@ -39,21 +39,20 @@ block skipping (not fetching past-frontier blocks at all) belongs to
 the ROADMAP-2 paged pool, whose block table this kernel is built to
 page over.
 
-Impl ladder (the training kernel's idiom, --decode_impl):
-  'auto'             — Pallas when the compile probe passes (TPU),
-                       warn_once + XLA otherwise;
+Impls (the training kernel's idiom, --decode_impl):
+  'auto'             — the compiled Pallas kernel on a tpu backend (a
+                       compile error propagates), XLA on any other;
   'pallas'           — pin the compiled Mosaic kernel;
   'pallas_interpret' — the same kernel through the Pallas interpreter,
                        so CPU CI exercises this file's exact math;
-  'xla'              — the masked-score reference (also the fallback
-                       models/gpt.py keeps inline for T > 1 verify
-                       blocks and scalar-index prefill).
+  'xla'              — the masked-score reference (also what
+                       models/gpt.py keeps inline for dense-pool T > 1
+                       verify blocks and scalar-index prefill).
 """
 
 from __future__ import annotations
 
 import functools
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -70,8 +69,7 @@ DEFAULT_BLOCK_K = 256
 
 __all__ = ["flash_decode", "flash_decode_paged", "flash_prefill_paged",
            "xla_decode_attention", "xla_decode_attention_paged",
-           "resolve_decode_impl", "decode_compile_probe",
-           "compile_probe_check", "quantize_kv_rows",
+           "resolve_decode_impl", "compile_check", "quantize_kv_rows",
            "quantize_kv_rows_int4", "unpack_int4", "DECODE_IMPLS"]
 
 DECODE_IMPLS = ("auto", "pallas", "pallas_interpret", "xla")
@@ -149,12 +147,40 @@ def quantize_kv_rows_int4(x: jax.Array, valid=None):
 def unpack_int4(packed: jax.Array) -> jax.Array:
     """Packed uint8 (..., D//2) -> int8 (..., D): the inverse of
     quantize_kv_rows_int4's nibble layout (low nibble first). Shared by
-    the XLA fallback and the test oracles; the Pallas kernels inline
-    the same two-op unpack per K/V tile."""
+    the XLA path and the test oracles; the Pallas kernels unpack the
+    same bytes per K/V tile into two contiguous halves instead
+    (_unpack_int4_halves) — the chip's compiler refuses this lane
+    interleave."""
     lo = jnp.bitwise_and(packed, 15).astype(jnp.int8) - 8
     hi = jnp.right_shift(packed, 4).astype(jnp.int8) - 8
     return jnp.stack([lo, hi], axis=-1).reshape(
         *packed.shape[:-1], packed.shape[-1] * 2)
+
+
+def _unpack_int4_halves(packed: jax.Array) -> jax.Array:
+    """In-kernel int4 unpack: (rows, D//2) packed bytes -> (rows, D) with
+    the EVEN head dims in lanes [0, D//2) and the ODD ones in [D//2, D)
+    — two contiguous halves, because Mosaic will not interleave along
+    lanes (unpack_int4's stack+reshape). The kernels' wrappers permute
+    the query into the same order (so q.k is unchanged, a dot being
+    permutation-invariant) and un-permute the small output. The nibble
+    arithmetic runs in int32: the v5e vector unit has no int8 subtract
+    (Mosaic: "failed to legalize arith.subi")."""
+    p32 = packed.astype(jnp.int32)
+    lo = jnp.bitwise_and(p32, 15) - 8
+    hi = jnp.right_shift(p32, 4) - 8
+    return jnp.concatenate([lo, hi], axis=-1)
+
+
+def _even_odd_halves(x: jax.Array) -> jax.Array:
+    """(..., D) -> (..., D) ordered [even dims | odd dims]."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+
+
+def _interleave_halves(x: jax.Array) -> jax.Array:
+    """Inverse of _even_odd_halves."""
+    h = x.shape[-1] // 2
+    return jnp.stack([x[..., :h], x[..., h:]], axis=-1).reshape(x.shape)
 
 
 # ---------------------------------------------------------------------------
@@ -269,7 +295,7 @@ def _flash_decode_kernel(len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
         acc, m, l = carry
         k = k_ref[0, pl.ds(j * block_k, block_k), :]
         if four_bit:
-            k = unpack_int4(k)
+            k = _unpack_int4_halves(k)
         # int8 K enters the dot WITHOUT its scale; the scale folds into
         # the (1, block_k) score row below — a lane-dim multiply, never
         # a dequantized K tile.
@@ -293,7 +319,7 @@ def _flash_decode_kernel(len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
             p = p * vs_ref[0, :, pl.ds(j * block_k, block_k)]
         v = v_ref[0, pl.ds(j * block_k, block_k), :]
         if four_bit:
-            v = unpack_int4(v)
+            v = _unpack_int4_halves(v)
         acc_new = acc * alpha + lax.dot_general(
             p.astype(dot_dt), v.astype(dot_dt), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -375,6 +401,8 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
         k, v = jnp.pad(k, pads), jnp.pad(v, pads)
     Dp = D + pad_D
     Dkp = Dk + pad_Dk
+    if four_bit:  # jaxlint: disable=tracer-leak -- four_bit is a static Python bool (dtype metadata, not data)
+        q = _even_odd_halves(q)
     qf = q.reshape(B * H, 1, Dp)
     kf = k.reshape(B * H, Lp, Dkp)
     vf = v.reshape(B * H, Lp, Dkp)
@@ -411,12 +439,29 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(jnp.asarray(lengths, jnp.int32), qf, kf, vf, ksf, vsf)
+    if four_bit:  # jaxlint: disable=tracer-leak -- four_bit is a static Python bool (dtype metadata, not data)
+        out = _interleave_halves(out)
     return out.reshape(B, H, Dp)[:, :, :D]
 
 
 # ---------------------------------------------------------------------------
 # Paged variant: the block-table indirection (ROADMAP-2 / ISSUE 9)
 # ---------------------------------------------------------------------------
+
+def _paged_scale_operands(k_scale, v_scale, page: int):
+    """The paged kernels' scale operands: the pool's (num_blocks, H,
+    page) f32 planes viewed as (num_blocks, H, 1, page), so the
+    (1, 1, 1, page) block's last two dims are WHOLE array dims — the TPU
+    lowering refuses a (1, page) block cut out of an (H, page) plane.
+    fp pools get a 1-block dummy the index_map pins to block 0, keeping
+    the operand list fixed across modes (flash_decode's idiom)."""
+    if k_scale is None:
+        ones = jnp.ones((1, 1, 1, page), jnp.float32)
+        return ones, ones
+    N, H, _ = k_scale.shape
+    return (k_scale.astype(jnp.float32).reshape(N, H, 1, page),
+            v_scale.astype(jnp.float32).reshape(N, H, 1, page))
+
 
 def _paged_decode_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, ks_ref,
                          vs_ref, o_ref, acc_ref, m_ref, l_ref, *,
@@ -452,11 +497,11 @@ def _paged_decode_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, ks_ref,
         q = q_ref[0].astype(dot_dt)                      # (1, D)
         k = k_ref[0, 0]                                  # (page, D)
         if four_bit:
-            k = unpack_int4(k)
+            k = _unpack_int4_halves(k)
         s = lax.dot_general(q, k.astype(dot_dt), (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (1, page)
         if quantized:
-            s = s * ks_ref[0, 0][None, :]
+            s = s * ks_ref[0, 0]
         s = s * sm_scale
         kpos = i * page + lax.broadcasted_iota(jnp.int32, (1, page), 1)
         s = jnp.where(kpos < length, s, NEG_INF)
@@ -466,10 +511,10 @@ def _paged_decode_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, ks_ref,
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
         if quantized:
-            p = p * vs_ref[0, 0][None, :]
+            p = p * vs_ref[0, 0]
         v = v_ref[0, 0]
         if four_bit:
-            v = unpack_int4(v)
+            v = _unpack_int4_halves(v)
         acc_ref[...] = acc_ref[...] * alpha + lax.dot_general(
             p.astype(dot_dt), v.astype(dot_dt), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -495,10 +540,9 @@ def flash_decode_paged(q: jax.Array, k: jax.Array, v: jax.Array,
     length); lengths (B,) valid positions per row. Returns (B, H, D).
 
     Unlike flash_decode there is no pool-wide pad path for the block
-    dim: ``page`` IS the DMA chunk, so the pool must be built with a
-    legal page (fp32 tiles at 8 sublanes, bf16 16, int8 32 — int8 pools
-    on real TPUs want page >= 32; the engine's paged_pad_copies warning
-    covers this). head_dim follows the same verified rule as
+    dim: ``page`` IS the DMA chunk (every kv mode compiles for v5e at
+    pages 4..256; tests/test_chip_compile.py holds 16 and 32). head_dim
+    follows the same verified rule as
     flash_decode (64 or 128-multiples unpadded; anything else pads
     q AND the pool — a per-call copy the engine warns about)."""
     if sm_scale is None:
@@ -511,7 +555,7 @@ def flash_decode_paged(q: jax.Array, k: jax.Array, v: jax.Array,
     quantized = k_scale is not None
     four_bit = quantized and k.dtype == jnp.uint8
     N, H, page, Dk = k.shape
-    D = Dk * 2 if four_bit else Dk  # jaxlint: disable=tracer-leak -- four_bit is a static Python bool (dtype metadata, not data)
+    D = Dk * 2 if four_bit else Dk
     B = q.shape[0]
     if q.shape != (B, H, D):
         raise ValueError(f"q shape {q.shape} != {(B, H, D)}")
@@ -520,21 +564,17 @@ def flash_decode_paged(q: jax.Array, k: jax.Array, v: jax.Array,
             f"block_table shape {block_table.shape} != ({B}, max_blocks)")
     nb = block_table.shape[1]
     pad_D = 0 if (D == 64 or D % 128 == 0) else (-D) % 128
-    pad_Dk = pad_D // 2 if four_bit else pad_D  # jaxlint: disable=tracer-leak -- four_bit is a static Python bool (dtype metadata, not data)
+    pad_Dk = pad_D // 2 if four_bit else pad_D
     if pad_D:
         q = jnp.pad(q, [(0, 0), (0, 0), (0, pad_D)])
         pads = [(0, 0), (0, 0), (0, 0), (0, pad_Dk)]
         k, v = jnp.pad(k, pads), jnp.pad(v, pads)
     Dp = D + pad_D
     Dkp = Dk + pad_Dk
+    if four_bit:
+        q = _even_odd_halves(q)
     qf = q.reshape(B * H, 1, Dp)
-    if k_scale is not None:
-        ksf = k_scale.astype(jnp.float32)
-        vsf = v_scale.astype(jnp.float32)
-    else:
-        # Fixed operand list across modes (flash_decode's idiom): a
-        # 1-block dummy the index_map pins to block 0.
-        ksf = vsf = jnp.ones((1, 1, page), jnp.float32)
+    ksf, vsf = _paged_scale_operands(k_scale, v_scale, page)
 
     def q_map(r, i, lens, tbl):
         return (r, 0, 0)
@@ -548,8 +588,8 @@ def flash_decode_paged(q: jax.Array, k: jax.Array, v: jax.Array,
 
     def scale_map(r, i, lens, tbl):
         if not quantized:
-            return (0, 0, 0)
-        return (jnp.minimum(tbl[r // H, i], N - 1), r % H, 0)
+            return (0, 0, 0, 0)
+        return (jnp.minimum(tbl[r // H, i], N - 1), r % H, 0, 0)
 
     kernel = functools.partial(
         _paged_decode_kernel, page=page, heads=H, sm_scale=sm_scale,
@@ -563,8 +603,8 @@ def flash_decode_paged(q: jax.Array, k: jax.Array, v: jax.Array,
                 pl.BlockSpec((1, 1, Dp), q_map),
                 pl.BlockSpec((1, 1, page, Dkp), kv_map),
                 pl.BlockSpec((1, 1, page, Dkp), kv_map),
-                pl.BlockSpec((1, 1, page), scale_map),
-                pl.BlockSpec((1, 1, page), scale_map),
+                pl.BlockSpec((1, 1, 1, page), scale_map),
+                pl.BlockSpec((1, 1, 1, page), scale_map),
             ],
             out_specs=pl.BlockSpec((1, 1, Dp), q_map),
             scratch_shapes=[
@@ -579,6 +619,8 @@ def flash_decode_paged(q: jax.Array, k: jax.Array, v: jax.Array,
         interpret=interpret,
     )(jnp.asarray(lengths, jnp.int32), jnp.asarray(block_table, jnp.int32),
       qf, k, v, ksf, vsf)
+    if four_bit:
+        out = _interleave_halves(out)
     return out.reshape(B, H, Dp)[:, :, :D]
 
 
@@ -618,11 +660,11 @@ def _paged_prefill_kernel(start_ref, tbl_ref, q_ref, k_ref, v_ref,
         q = q_ref[0].astype(dot_dt)                      # (T, D)
         k = k_ref[0, 0]                                  # (page, D)
         if four_bit:
-            k = unpack_int4(k)
+            k = _unpack_int4_halves(k)
         s = lax.dot_general(q, k.astype(dot_dt), (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (T, page)
         if quantized:
-            s = s * ks_ref[0, 0][None, :]
+            s = s * ks_ref[0, 0]
         s = s * sm_scale
 
         def _accumulate(s):
@@ -632,10 +674,10 @@ def _paged_prefill_kernel(start_ref, tbl_ref, q_ref, k_ref, v_ref,
             alpha = jnp.exp(m_prev - m_new)
             l_ref[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
             if quantized:
-                p = p * vs_ref[0, 0][None, :]
+                p = p * vs_ref[0, 0]
             v = v_ref[0, 0]
             if four_bit:
-                v = unpack_int4(v)
+                v = _unpack_int4_halves(v)
             acc_ref[...] = acc_ref[...] * alpha + lax.dot_general(
                 p.astype(dot_dt), v.astype(dot_dt),
                 (((1,), (0,)), ((), ())),
@@ -700,7 +742,7 @@ def flash_prefill_paged(q: jax.Array, k: jax.Array, v: jax.Array,
     quantized = k_scale is not None
     four_bit = quantized and k.dtype == jnp.uint8
     N, H, page, Dk = k.shape
-    D = Dk * 2 if four_bit else Dk  # jaxlint: disable=tracer-leak -- four_bit is a static Python bool (dtype metadata, not data)
+    D = Dk * 2 if four_bit else Dk
     B, _, T, _ = q.shape
     if q.shape != (B, H, T, D):
         raise ValueError(f"q shape {q.shape} != {(B, H, T, D)}")
@@ -709,7 +751,7 @@ def flash_prefill_paged(q: jax.Array, k: jax.Array, v: jax.Array,
             f"block_table shape {block_table.shape} != ({B}, max_blocks)")
     nb = block_table.shape[1]
     pad_D = 0 if (D == 64 or D % 128 == 0) else (-D) % 128
-    pad_Dk = pad_D // 2 if four_bit else pad_D  # jaxlint: disable=tracer-leak -- four_bit is a static Python bool (dtype metadata, not data)
+    pad_Dk = pad_D // 2 if four_bit else pad_D
     if pad_D:
         q = jnp.pad(q, [(0, 0), (0, 0), (0, 0), (0, pad_D)])
         pads = [(0, 0), (0, 0), (0, 0), (0, pad_Dk)]
@@ -718,12 +760,10 @@ def flash_prefill_paged(q: jax.Array, k: jax.Array, v: jax.Array,
     Dkp = Dk + pad_Dk
     # (B, H, T, Dp) -> (B*H, T, Dp): heads fold into the row dim, the
     # same flattening as the decode kernels.
+    if four_bit:
+        q = _even_odd_halves(q)
     qf = q.reshape(B * H, T, Dp)
-    if k_scale is not None:
-        ksf = k_scale.astype(jnp.float32)
-        vsf = v_scale.astype(jnp.float32)
-    else:
-        ksf = vsf = jnp.ones((1, 1, page), jnp.float32)
+    ksf, vsf = _paged_scale_operands(k_scale, v_scale, page)
 
     def q_map(r, i, start, tbl):
         return (r, 0, 0)
@@ -733,8 +773,8 @@ def flash_prefill_paged(q: jax.Array, k: jax.Array, v: jax.Array,
 
     def scale_map(r, i, start, tbl):
         if not quantized:
-            return (0, 0, 0)
-        return (jnp.minimum(tbl[r // H, i], N - 1), r % H, 0)
+            return (0, 0, 0, 0)
+        return (jnp.minimum(tbl[r // H, i], N - 1), r % H, 0, 0)
 
     kernel = functools.partial(
         _paged_prefill_kernel, page=page, heads=H, sm_scale=sm_scale,
@@ -748,8 +788,8 @@ def flash_prefill_paged(q: jax.Array, k: jax.Array, v: jax.Array,
                 pl.BlockSpec((1, T, Dp), q_map),
                 pl.BlockSpec((1, 1, page, Dkp), kv_map),
                 pl.BlockSpec((1, 1, page, Dkp), kv_map),
-                pl.BlockSpec((1, 1, page), scale_map),
-                pl.BlockSpec((1, 1, page), scale_map),
+                pl.BlockSpec((1, 1, 1, page), scale_map),
+                pl.BlockSpec((1, 1, 1, page), scale_map),
             ],
             out_specs=pl.BlockSpec((1, T, Dp), q_map),
             scratch_shapes=[
@@ -764,36 +804,35 @@ def flash_prefill_paged(q: jax.Array, k: jax.Array, v: jax.Array,
         interpret=interpret,
     )(jnp.asarray(start, jnp.int32), jnp.asarray(block_table, jnp.int32),
       qf, k, v, ksf, vsf)
+    if four_bit:
+        out = _interleave_halves(out)
     return out.reshape(B, H, T, Dp)[:, :, :, :D]
 
 
 def paged_pad_copies(page: int, head_dim: int) -> bool:
     """True when flash_decode_paged must pad — copy — the POOL on every
-    call: head_dim outside the verified-unpadded set. (A page off the
-    int8 32-sublane quantum shows up as a compile-probe failure, not a
-    pad: the page is the DMA chunk and cannot be padded in place.)"""
+    call: head_dim outside the verified-unpadded set. (A page the chip's
+    compiler refuses is a compile error, not a pad: the page is the DMA
+    chunk and cannot be padded in place.)"""
     return not (head_dim == 64 or head_dim % 128 == 0)
 
 
 # ---------------------------------------------------------------------------
-# Dispatch: probe + impl ladder
+# Dispatch
 # ---------------------------------------------------------------------------
-
-_PROBE: dict[str, bool] = {}
-
 
 def _backend() -> str:
     return jax.default_backend()
 
 
-def compile_probe_check(*, interpret: bool = False) -> None:
-    """AOT lower+compile the kernels on tiny shapes in EVERY kv mode
+def compile_check(*, interpret: bool = False) -> None:
+    """AOT lower+compile the kernels on small shapes in EVERY kv mode
     (fp, int8-with-scales, packed int4), BOTH pool layouts (contiguous
     slot rows and the block-paged table) and BOTH query shapes (the T=1
-    decode walk and the T>1 paged prefill), raising on failure. The ONE
-    probe harness — decode_compile_probe (the 'auto' gate) and
-    bench.py's preflight_decode_impls both call it, so the shapes the
-    ladder is judged on can never drift between the two."""
+    decode walk and the T>1 paged prefill), raising on failure. A
+    DIAGNOSTIC for bench.py's per-impl preflight report — it decides
+    nothing: 'auto' never consults it, and tests/test_chip_compile.py
+    is what holds the kernels to the chip's compiler at real widths."""
     dt = jnp.float32 if interpret else jnp.bfloat16
     q = jax.ShapeDtypeStruct((2, 2, 64), dt)
     kv = jax.ShapeDtypeStruct((2, 2, 256, 64), dt)
@@ -841,48 +880,15 @@ def compile_probe_check(*, interpret: bool = False) -> None:
     jax.jit(preq8).lower(qT, pkv4, pkv4, tbl, ln, psc, psc).compile()
 
 
-def decode_compile_probe() -> bool:
-    """True iff the flash-decode kernel compiles on the current default
-    backend, in BOTH kv modes — 'auto' must not promise a fallback it
-    only checked for one mode. Compile-only AOT on tiny shapes, cached
-    per process per backend, exactly like ops/attention.py's
-    pallas_compile_probe."""
-    backend = _backend()
-    if backend in _PROBE:
-        return _PROBE[backend]
-    if backend != "tpu":
-        _PROBE[backend] = False
-        return False
-    try:
-        compile_probe_check()
-        _PROBE[backend] = True
-    except Exception as e:  # Mosaic lowering / compile failure
-        warnings.warn(
-            "Pallas flash-decode failed to compile on this TPU; decode "
-            f"attention falls back to the XLA path. Error: {e}")
-        _PROBE[backend] = False
-    return _PROBE[backend]
-
-
 def resolve_decode_impl(impl: str) -> str:
-    """'auto' -> 'pallas' when the probe passes, else 'xla' — with a
-    warn_once when a TPU lands on the fallback (a silent 2x decode
-    slowdown is exactly the failure mode that must not be silent).
-    Explicit impls pass through untouched (never probed)."""
+    """'auto' means ONE thing per backend: the compiled Pallas kernel on
+    tpu, the XLA path everywhere else. Nothing is probed and nothing is
+    caught — a kernel the chip's compiler refuses fails the program that
+    uses it, at its first compile, instead of being served (and
+    benchmarked) as the fallback. Explicit impls pass through."""
     if impl not in DECODE_IMPLS:
         raise ValueError(f"unknown decode impl: {impl!r} "
                          f"(expected one of {DECODE_IMPLS})")
     if impl != "auto":
         return impl
-    if decode_compile_probe():
-        return "pallas"
-    if _backend() == "tpu":
-        from nanosandbox_tpu.utils.metrics import warn_once
-
-        warn_once(
-            "flash-decode-xla-fallback",
-            "[serve] flash-decode Pallas kernel unavailable on this TPU "
-            "(compile probe failed) — decode attention is running on the "
-            "XLA fallback path, ~2x the HBM traffic per token. Pin "
-            "--decode_impl=xla to silence, or fix the kernel regression.")
-    return "xla"
+    return "pallas" if _backend() == "tpu" else "xla"

@@ -383,9 +383,10 @@ def clear_sharded_cache() -> None:
 
 def _resolve_block_impl(block_impl: str, chunk_len: int,
                         has_full_blocks: bool = True) -> str:
-    """'auto' -> 'pallas' when the Mosaic kernel compiles on this backend
-    AND the per-call chunk is 128-lane aligned (the flash path's full
-    [non-causal] blocks forbid T padding); 'xla' otherwise. A PINNED
+    """'auto' -> 'pallas' on a tpu backend (ops.attention's rule: a
+    compile error propagates) when the per-call chunk is 128-lane
+    aligned (the flash path's full [non-causal] blocks forbid T
+    padding); 'xla' otherwise. A PINNED
     pallas impl with an unaligned chunk fails here with a ring-level
     error — previously it surfaced as a block-divisibility ValueError
     deep inside _pad_qkv that never mentioned ring_block_impl (ADVICE r3).
@@ -404,9 +405,9 @@ def _resolve_block_impl(block_impl: str, chunk_len: int,
         return block_impl
     if unaligned:
         return "xla"
-    from nanosandbox_tpu.ops.attention import pallas_compile_probe
+    from nanosandbox_tpu.ops.attention import resolve_attention_impl
 
-    return "pallas" if pallas_compile_probe() else "xla"
+    return resolve_attention_impl("auto")
 
 
 def ring_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array, *,

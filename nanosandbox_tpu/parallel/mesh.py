@@ -144,18 +144,10 @@ def make_hybrid_mesh(mesh_dp: int = -1, mesh_fsdp: int = 1,
 
 
 def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across the jax versions this repo spans: the
-    top-level binding (with ``check_vma``) only exists from jax 0.5; on
-    older runtimes the same thing is ``jax.experimental.shard_map`` with
-    the pre-rename ``check_rep`` flag. Every shard_map in the package
-    goes through here so a version bump is a one-line audit."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    """``jax.shard_map``. Every shard_map in the package goes through
+    here, so the package has one place that names the binding."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def batch_sharding(mesh: Mesh) -> NamedSharding:

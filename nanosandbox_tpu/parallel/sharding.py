@@ -71,8 +71,8 @@ def spec_for_param(path: str, shape: tuple[int, ...], *, axis_sizes: dict,
         # dim: a feature-dim-sharded table turns every lookup into a gather
         # whose output is C-sharded, and SPMD can only move that back to
         # the C-replicated activation layout via involuntary full
-        # rematerialization (replicate-then-repartition; the
-        # MULTICHIP_r03.json spmd_partitioner.cc warning). Row-sharded
+        # rematerialization (replicate-then-repartition, which the SPMD
+        # partitioner warns about). Row-sharded
         # gathers lower to the clean masked-gather + psum pattern.
         allowed = ((0,) if path.endswith("wte/embedding")
                    or path.endswith("wpe/embedding") else range(ndim))

@@ -337,7 +337,9 @@ def main(argv: list[str] | None = None) -> list[str]:
     from nanosandbox_tpu.data.loader import BinDataset
     from nanosandbox_tpu.data.tokenizer import get_tokenizer
     from nanosandbox_tpu.train import restore_for_inference
+    from nanosandbox_tpu.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     trainer, state, _ = restore_for_inference(args.out_dir,
                                               data_dir=args.data_dir)
     cfg = trainer.cfg

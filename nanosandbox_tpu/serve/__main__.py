@@ -19,7 +19,7 @@ import contextlib
 import sys
 
 
-def main(argv: list[str] | None = None) -> None:
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser(prog="python -m nanosandbox_tpu.serve")
     ap.add_argument("--router", action="store_true",
                     help="run the FLEET ROUTER front tier instead of an "
@@ -151,10 +151,11 @@ def main(argv: list[str] | None = None) -> None:
                          "a coarser 4-bit quantization grid")
     ap.add_argument("--decode_impl", default=None,
                     choices=("auto", "pallas", "pallas_interpret", "xla"),
-                    help="cached-decode attention impl (flash-decode "
-                         "ladder, ops/flash_decode.py); 'auto' probes "
-                         "the Pallas kernel and warn_once-falls back to "
-                         "xla. The resolved impl is exported on /metrics")
+                    help="cached-decode attention impl "
+                         "(ops/flash_decode.py); 'auto' is the Pallas "
+                         "kernel on a tpu backend (a compile error "
+                         "fails the warm-up) and xla on any other. The "
+                         "resolved impl is exported on /metrics")
     ap.add_argument("--spec", default="off",
                     help="speculative decoding: 'ngram' (prompt-lookup "
                          "drafting) or 'model:<out_dir>' (smaller "
@@ -212,8 +213,37 @@ def main(argv: list[str] | None = None) -> None:
                          "/healthz readiness contract); 'buckets' "
                          "compiles one single-request prefill per bucket "
                          "and leaves larger waves to compile lazily")
-    args = ap.parse_args(argv if argv is not None else sys.argv[1:])
+    return ap.parse_args(argv if argv is not None else sys.argv[1:])
 
+
+class Served:
+    """What ``python -m nanosandbox_tpu.serve`` builds before it listens:
+    the warmed engine behind its loop and HTTP server. ``main`` calls
+    serve_forever() on it; chip_smoke.py drives the same object from a
+    thread, so the smoke serves through exactly the stack users get."""
+
+    def __init__(self, server, loop, engine, freeze, tokenizer):
+        self.server, self.loop, self.engine = server, loop, engine
+        self.freeze, self.tokenizer = freeze, tokenizer
+
+    @property
+    def port(self) -> int:
+        return self.server.server_address[1]
+
+    def serve_forever(self) -> None:
+        """Blocks until KeyboardInterrupt or server.shutdown()."""
+        try:
+            with self.freeze:
+                self.server.serve_forever()
+        except KeyboardInterrupt:
+            pass
+        finally:
+            self.loop.stop()
+            self.server.server_close()
+
+
+def main(argv: list[str] | None = None) -> None:
+    args = parse_args(argv)
     if args.router:
         # Front-tier mode: no checkpoint, no jax — just the router
         # proxy over the replica fleet.
@@ -244,13 +274,20 @@ def main(argv: list[str] | None = None) -> None:
         finally:
             fe.stop()
         return
+    build_server(args).serve_forever()
 
+
+def build_server(args: argparse.Namespace) -> Served:
+    """Restore the checkpoint, build the Engine with the flags' values,
+    warm its whole compile set and bind the port (``--port=0`` binds a
+    free one) — everything short of serving."""
     from nanosandbox_tpu.data.loader import BinDataset
     from nanosandbox_tpu.data.tokenizer import get_tokenizer
     from nanosandbox_tpu.sample import cast_params_for_serving
     from nanosandbox_tpu.serve.engine import Engine
     from nanosandbox_tpu.serve.http import EngineLoop, make_server
     from nanosandbox_tpu.train import restore_for_inference
+    from nanosandbox_tpu.utils.compile_cache import enable_compile_cache
 
     # Load the shardcheck budget BEFORE the restore + warmup compiles:
     # a typo'd path or corrupt file must fail in milliseconds, not
@@ -310,6 +347,7 @@ def main(argv: list[str] | None = None) -> None:
             raise SystemExit(f"--faults: {e}")
         fault_plan.enabled = False
 
+    enable_compile_cache()
     trainer, state, step = restore_for_inference(
         args.out_dir, data_dir=args.data_dir, device=args.device)
     params = cast_params_for_serving(state["params"],
@@ -485,7 +523,7 @@ def main(argv: list[str] | None = None) -> None:
           f"{'on' if engine.brownout is not None else 'off'}); "
           f"prefill buckets "
           f"{engine.sched.buckets}; listening on "
-          f"{args.host}:{args.port} (POST /generate /drain /profile, "
+          f"{args.host}:{server.server_address[1]} (POST /generate /drain /profile, "
           "GET /healthz[?ready=1] /stats /metrics /trace "
           "/debug/requests /debug/slots /debug/kvpool "
           "/debug/scheduler)",
@@ -498,14 +536,7 @@ def main(argv: list[str] | None = None) -> None:
     # deliberately leaves lazy wave compiles, so no freeze there.
     freeze = (engine.tracecheck.frozen() if args.warmup == "full"
               else contextlib.nullcontext())
-    try:
-        with freeze:
-            server.serve_forever()
-    except KeyboardInterrupt:
-        pass
-    finally:
-        loop.stop()
-        server.server_close()
+    return Served(server, loop, engine, freeze, tok)
 
 
 if __name__ == "__main__":

@@ -145,10 +145,13 @@ class ModelDrafter:
         free (its blocks for those ids still hold that prefix's K/V)."""
         import jax
 
-        if decode_impl is not None and decode_impl != self.model.cfg.decode_impl:
-            self.model = type(self.model)(
-                cfg=self.model.cfg.replace(decode_impl=decode_impl),
-                mesh=getattr(self.model, "mesh", None))
+        from nanosandbox_tpu.serve.engine import single_device_params
+
+        # Like the tp == 1 engine it drafts for, the drafter owns no
+        # mesh: neither in its weights' type nor bound onto its model.
+        self.params = single_device_params(self.params)
+        self.model = type(self.model)(cfg=self.model.cfg.replace(
+            decode_impl=decode_impl or self.model.cfg.decode_impl))
 
         from nanosandbox_tpu.models.gpt import init_cache, init_paged_cache
 

@@ -104,6 +104,27 @@ DEFAULT_PRIORITY = 1
 POISON_STRIKE_LIMIT = 3
 
 
+def single_device_params(params):
+    """Weights for a single-chip (tp == 1) engine: committed to ONE
+    device, with no mesh. Params restored from a checkpoint arrive on
+    the Trainer's (data, fsdp, seq, model) mesh, and under the installed
+    JAX that mesh is part of every array's TYPE: each program's outputs
+    inherit it, so the mesh-free pool and slot state the engine builds
+    would come back from their first dispatch re-typed and retrace every
+    program once more — `python -m nanosandbox_tpu.serve` then died in
+    warm-up with CompileBudgetExceeded. Mesh-free params (model.init)
+    pass through untouched."""
+    import jax
+    from jax.sharding import NamedSharding, SingleDeviceSharding
+
+    leaves = jax.tree.leaves(params)
+    if not leaves or not isinstance(getattr(leaves[0], "sharding", None),
+                                    NamedSharding):
+        return params
+    dev = min(leaves[0].sharding.device_set, key=lambda d: d.id)
+    return jax.device_put(params, SingleDeviceSharding(dev))
+
+
 class EngineFailedError(RuntimeError):
     """The engine escalated to permanent failure (recovery exhausted its
     attempts) and drained; submissions are refused until a restart. The
@@ -326,9 +347,9 @@ class Engine:
     kv_page_size : positions per KV block (paged only; must divide
         max_len). Small pages waste less memory on final-block
         fragmentation and shorten shareable-prefix granularity; large
-        pages cut table overhead and DMA count. On real TPUs int8
-        pools want >= 32 (the sublane tiling quantum — the compile
-        probe rejects smaller and decode falls back to XLA).
+        pages cut table overhead and DMA count. Every kv mode's
+        kernels compile for v5e at pages 4..256 (head_dim 64 and 128;
+        tests/test_chip_compile.py holds 16 and 32).
     kv_pool_blocks : pool size in blocks (paged only; default
         num_slots * max_len / page — byte-identical to the dense
         pool, so paged-vs-dense comparisons hold pool HBM constant
@@ -500,11 +521,19 @@ class Engine:
                 params,
                 param_shardings(mesh, jax.eval_shape(lambda: params),
                                 shard_params=False, tp=True))
+        else:
+            # The single-chip engine owns no mesh: not in its weights'
+            # type, and not bound onto the model either (a Trainer's
+            # model carries the training mesh, whose activation anchors
+            # would pin a multi-device mesh into a one-device program).
+            params = single_device_params(params)
+            if getattr(model, "mesh", None) is not None:
+                model = type(model)(cfg=cfg)
         self.kv_dtype = normalize_kv_dtype(kv_dtype) or (
             "bf16" if cfg.compute_dtype == "bfloat16" else "fp32")
-        # Resolve ONCE at construction (the probe caches per backend):
-        # 'auto' degrading to xla on a TPU fires the warn_once here, at
-        # startup, not silently inside the first traced decode step.
+        # Resolve ONCE at construction: 'auto' is the Pallas kernel on a
+        # tpu backend and XLA elsewhere — never a probed fallback; a
+        # kernel the compiler refuses fails the warm-up's first compile.
         self.decode_impl = resolve_decode_impl(cfg.decode_impl)
         self.model = model
         self.params = params

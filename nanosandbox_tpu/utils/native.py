@@ -1,43 +1,73 @@
 """ctypes loader for the native batch-gather library (csrc/batchgen.cpp).
 
-Compiles the shared library on first use with g++ (cached next to the
-source); every entry point has a pure-numpy fallback so the framework works
-on machines without a toolchain. pybind11 is not in the image, so the
-binding is plain ctypes over an ``extern "C"`` surface.
+Compiles the shared library on first use with g++, next to the source,
+under a name that carries the hash of the source and of the build flags
+— so a library is only ever loaded if it was built from THIS
+batchgen.cpp with THESE flags, whatever the mtimes say and whichever
+machine the tree was copied from. The flags name no CPU (-march): a copy
+of the tree, .so included, runs on another host. Every entry point has a
+pure-numpy path for machines without a toolchain; it draws DIFFERENT
+batches from the same seed, so which path is active is said once on
+stderr and recorded by the loader (BatchLoader.native). pybind11 is not
+in the image, so the binding is plain ctypes over an ``extern "C"``
+surface.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import sys
 import threading
 
 import numpy as np
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO_ROOT, "csrc", "batchgen.cpp")
-_LIB_PATH = os.path.join(_REPO_ROOT, "csrc", "libbatchgen.so")
+_FLAGS = ("-O3", "-fPIC", "-shared", "-fopenmp")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _load_failed = False
 
 
-def _build() -> bool:
-    cmd = ["g++", "-O3", "-march=native", "-fPIC", "-shared", "-fopenmp",
-           _SRC, "-o", _LIB_PATH]
+def _lib_path() -> str:
+    """csrc/libbatchgen-<hash of source + flags>.so (csrc/*.so is
+    gitignored: the library is always built by the run, never committed)."""
+    h = hashlib.sha256(" ".join(_FLAGS).encode())
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    return os.path.join(_REPO_ROOT, "csrc",
+                        f"libbatchgen-{h.hexdigest()[:16]}.so")
+
+
+def _build(path: str) -> None:
+    # Build to a private name, then rename: a concurrent process never
+    # loads a half-written library.
+    tmp = f"{path}.{os.getpid()}.tmp"
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return True
-    except Exception:
-        try:  # retry without -march/-fopenmp for maximum portability
-            subprocess.run(["g++", "-O3", "-fPIC", "-shared", _SRC,
-                            "-o", _LIB_PATH],
-                           check=True, capture_output=True, timeout=120)
-            return True
-        except Exception:
-            return False
+        subprocess.run(["g++", *_FLAGS, _SRC, "-o", tmp], check=True,
+                       capture_output=True, text=True, timeout=120)
+        os.replace(tmp, path)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+
+
+def _load() -> ctypes.CDLL:
+    path = _lib_path()
+    if not os.path.exists(path):
+        _build(path)
+    lib = ctypes.CDLL(path)
+    lib.gather_windows_u16.argtypes = [
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
+        ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p]
+    lib.sample_offsets.argtypes = [
+        ctypes.c_uint64, ctypes.c_uint64, ctypes.c_int64,
+        ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p]
+    return lib
 
 
 def get_lib() -> ctypes.CDLL | None:
@@ -47,28 +77,22 @@ def get_lib() -> ctypes.CDLL | None:
     with _lock:
         if _lib is not None or _load_failed:
             return _lib
-        if not os.path.exists(_LIB_PATH) or (
-                os.path.exists(_SRC)
-                and os.path.getmtime(_SRC) > os.path.getmtime(_LIB_PATH)):
+        try:
             # lockcheck: disable=blocking-under-lock -- build-once by
             # design: the double-checked _lock exists precisely so ONE
             # thread compiles the .so while every other caller waits
             # rather than racing g++ over the same output file; cold
             # path, runs at most once per process.
-            if not os.path.exists(_SRC) or not _build():
-                _load_failed = True
-                return None
-        try:
-            lib = ctypes.CDLL(_LIB_PATH)
-            lib.gather_windows_u16.argtypes = [
-                ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
-                ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p]
-            lib.sample_offsets.argtypes = [
-                ctypes.c_uint64, ctypes.c_uint64, ctypes.c_int64,
-                ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p]
-            _lib = lib
-        except OSError:
+            _lib = _load()
+            print(f"[native] batch gather: csrc/{os.path.basename(_lib._name)}"
+                  " (xorshift128+ offsets)", file=sys.stderr)
+        except (OSError, subprocess.SubprocessError) as e:
             _load_failed = True
+            why = (getattr(e, "stderr", None) or str(e)).strip()
+            print("[native] batch gather: numpy path (Philox offsets — NOT "
+                  "the batches the native path draws from the same seed); "
+                  f"csrc/batchgen.cpp did not build or load: "
+                  f"{type(e).__name__}: {why[-500:]}", file=sys.stderr)
     return _lib
 
 

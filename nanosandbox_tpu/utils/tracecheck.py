@@ -212,9 +212,8 @@ _sync_counts: Dict[str, int] = {}
 
 def host_sync(name: str, value=None) -> Optional[float]:
     """The BLESSED deliberate device->host readback: reads ``value``
-    back as a Python float (the hard sync some PJRT transports need
-    where block_until_ready is a no-op — see utils/benchmarking.py) and
-    counts the sync under ``name`` so windows can be audited. jaxlint's
+    back as a Python float (a hard sync: it waits for everything queued
+    before it) and counts the sync under ``name`` so windows can be audited. jaxlint's
     host-sync rule recognizes this call and does not flag it; a raw
     float()/np.asarray in a hot path does get flagged."""
     with _sync_lock:

@@ -1,5 +1,5 @@
 #!/usr/bin/env bash
-# Idempotent GitHub project sync: label taxonomy + issue backlog.
+# Idempotent GitHub project sync: label scheme + issue backlog.
 #
 # Bash port of the reference's scripts/gh_sync.ps1 (structure:
 # Get-RepoSlug :5-15, Ensure-Label GET->PATCH/POST :17-35, Ensure-Issue
@@ -50,7 +50,7 @@ if [[ -z "$REPO" ]]; then
 fi
 echo "Using repo: $REPO"
 
-# --- label taxonomy (24 labels; ps1:63-97 adapted to the TPU stack) ---------
+# --- label scheme (24 labels; ps1:63-97 adapted to the TPU stack) ---------
 # format: name|color|description
 LABELS=(
   "type:bug|d73a4a|Something isn't working"

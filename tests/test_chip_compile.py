@@ -1,0 +1,159 @@
+"""The main path's kernels, compiled for a DESCRIBED v5e at GPT-2 124M widths.
+
+Interpret mode (every other kernel test in this suite) checks the math
+and none of the chip compiler's rules: block shapes, vector layouts,
+what the vector unit can do to an int8. The TPU compiler is installed
+here and compiles for a chip that is described, not attached — so these
+tests hold every later PR to what the chip accepts, at no chip time:
+
+  * the training kernel ``flash_attention``, forward and backward in
+    both stat layouts (and the in-kernel dropout variant), at
+    (16, 12, 1024, 64) bf16;
+  * all nine serving variants — ``flash_decode`` / ``flash_decode_paged``
+    / ``flash_prefill_paged`` x fp / int8 / int4 — at B=8, H=12, D=64,
+    the engine's default page 16 and page 32, prefill T = a page and
+    T = 128.
+
+A compile that passes is not a chip run: nothing executes here.
+
+ONE file, by design. Only one process may load the TPU library; the
+topology is described inside a module-scoped fixture (never at import,
+in a skipif, in parametrize arguments or in conftest.py), so under
+pytest-xdist every worker collects the same tests and only the worker
+that is handed this file loads the library. Compiles run in the test's
+own process, with the persistent compilation cache off (an entry written
+for a described chip cannot be read back without one).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from nanosandbox_tpu.ops import flash_decode as fd
+from nanosandbox_tpu.ops.attention import (flash_attention,
+                                           flash_attention_dropout)
+
+B, H, D, L = 8, 12, 64, 1024          # serving widths (GPT-2 124M heads)
+TRAIN_SHAPE = (16, 12, 1024, 64)       # the 124M train step's q/k/v
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here, or its lock is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def sds(topo):
+    """ShapeDtypeStruct factory placing every operand on one described chip."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def make(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return make
+
+
+def compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+# ------------------------------------------------------------- training
+
+def test_flash_attention_forward(sds):
+    x = sds(TRAIN_SHAPE, jnp.bfloat16)
+    txt = compiled_text(
+        lambda q, k, v: flash_attention(q, k, v, True, None, False), x, x, x)
+    assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("dropout", [False, True], ids=["plain", "dropout"])
+@pytest.mark.parametrize("stat_layout", ["replicated", "compact"])
+def test_flash_attention_backward(sds, stat_layout, dropout):
+    x = sds(TRAIN_SHAPE, jnp.bfloat16)
+    if dropout:
+        def loss(q, k, v, seed):
+            return flash_attention_dropout(
+                q, k, v, seed, True, None, 0.1, False,
+                stat_layout).astype(jnp.float32).sum()
+        args = (x, x, x, sds((1,), jnp.uint32))
+    else:
+        def loss(q, k, v):
+            return flash_attention(
+                q, k, v, True, None, False,
+                stat_layout).astype(jnp.float32).sum()
+        args = (x, x, x)
+    txt = compiled_text(jax.grad(loss, argnums=(0, 1, 2)), *args)
+    assert "tpu_custom_call" in txt
+
+
+# -------------------------------------------------------------- serving
+
+KV_MODES = {"fp": (jnp.bfloat16, 1), "int8": (jnp.int8, 1),
+            "int4": (jnp.uint8, 2)}     # (stored dtype, head dims per byte)
+
+
+def _scaled(kernel, mode):
+    """The kernel with its scale planes as trailing positional operands
+    for the quantised modes (fp takes none)."""
+    if mode == "fp":
+        return kernel
+    return lambda *a: kernel(*a[:-2], k_scale=a[-2], v_scale=a[-1])
+
+
+@pytest.mark.parametrize("mode", list(KV_MODES))
+def test_flash_decode_contiguous(sds, mode):
+    dt, pack = KV_MODES[mode]
+    q = sds((B, H, D), jnp.bfloat16)
+    kv = sds((B, H, L, D // pack), dt)
+    args = [q, kv, kv, sds((B,), jnp.int32)]
+    if mode != "fp":
+        args += [sds((B, H, L), jnp.float32)] * 2
+    assert "tpu_custom_call" in compiled_text(
+        _scaled(fd.flash_decode, mode), *args)
+
+
+@pytest.mark.parametrize("page", [16, 32])
+@pytest.mark.parametrize("mode", list(KV_MODES))
+def test_flash_decode_paged(sds, mode, page):
+    dt, pack = KV_MODES[mode]
+    n_blocks = B * L // page
+    q = sds((B, H, D), jnp.bfloat16)
+    kv = sds((n_blocks, H, page, D // pack), dt)
+    args = [q, kv, kv, sds((B, L // page), jnp.int32), sds((B,), jnp.int32)]
+    if mode != "fp":
+        args += [sds((n_blocks, H, page), jnp.float32)] * 2
+    assert "tpu_custom_call" in compiled_text(
+        _scaled(fd.flash_decode_paged, mode), *args)
+
+
+@pytest.mark.parametrize("T", ["page", 128])
+@pytest.mark.parametrize("page", [16, 32])
+@pytest.mark.parametrize("mode", list(KV_MODES))
+def test_flash_prefill_paged(sds, mode, page, T):
+    dt, pack = KV_MODES[mode]
+    T = page if T == "page" else T
+    n_blocks = B * L // page
+    q = sds((B, H, T, D), jnp.bfloat16)
+    kv = sds((n_blocks, H, page, D // pack), dt)
+    args = [q, kv, kv, sds((B, L // page), jnp.int32), sds((B,), jnp.int32)]
+    if mode != "fp":
+        args += [sds((n_blocks, H, page), jnp.float32)] * 2
+    assert "tpu_custom_call" in compiled_text(
+        _scaled(fd.flash_prefill_paged, mode), *args)

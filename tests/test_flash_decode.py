@@ -13,7 +13,8 @@ The contract under test:
   * the scalar-index (prefill) attention path is BOUNDED to the known
     frontier — no dot in the jaxpr touches the full max_len buffer;
   * the resolved decode impl + kv mode are exported (stats + /metrics
-    gauges) and the auto->xla degrade on TPU warns once.
+    gauges); 'auto' is xla on cpu and the compiled kernel on tpu, and
+    a compile error there propagates.
 """
 
 import jax
@@ -319,16 +320,31 @@ def test_scalar_prefill_attention_bounded_to_frontier(served_model):
 def test_resolve_decode_impl_ladder(monkeypatch):
     assert fd.resolve_decode_impl("xla") == "xla"
     assert fd.resolve_decode_impl("pallas_interpret") == "pallas_interpret"
-    # CPU: auto degrades to xla silently (no TPU to warn about).
+    with pytest.raises(ValueError, match="unknown decode impl"):
+        fd.resolve_decode_impl("mosaic")
+    # 'auto' is one thing per backend: xla on cpu ...
     assert fd.resolve_decode_impl("auto") == "xla"
-    # TPU whose probe fails: the degrade must warn_once.
-    from nanosandbox_tpu.utils import metrics as um
-    um.reset_for_tests()
+    # ... and the compiled kernel where the backend says tpu, with no
+    # probe in between and no way back to xla.
     monkeypatch.setattr(fd, "_backend", lambda: "tpu")
-    monkeypatch.setattr(fd, "decode_compile_probe", lambda: False)
-    assert fd.resolve_decode_impl("auto") == "xla"
-    assert "flash-decode-xla-fallback" in um._WARNED_ONCE
-    um.reset_for_tests()
+    assert fd.resolve_decode_impl("auto") == "pallas"
+    assert not hasattr(fd, "decode_compile_probe")
+
+    # A compile error there PROPAGATES out of the program that uses the
+    # kernel: nothing catches it and carries on with the reference.
+    def refuses(*a, **kw):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(fd, "flash_decode", refuses)
+    cfg = GPTConfig(n_layer=1, n_head=2, n_embd=32, block_size=32,
+                    vocab_size=64, decode_impl="auto")
+    model = GPT(cfg)
+    params = model.init(jax.random.key(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    with pytest.raises(RuntimeError, match="Mosaic failed to compile"):
+        model.apply({"params": params}, jnp.zeros((2, 1), jnp.int32),
+                    cache=init_cache(cfg, 2, 32),
+                    cache_index=jnp.zeros((2,), jnp.int32))
 
 
 def test_model_drafter_follows_engine_decode_impl(served_model):

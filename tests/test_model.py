@@ -230,3 +230,34 @@ def test_save_attention_policy_elides_kernel_recompute():
     assert count_calls(False, "full") == 2 * tiny().n_layer
     assert count_calls(True, "full") == 3 * tiny().n_layer
     assert count_calls(True, "save_attention") == 2 * tiny().n_layer
+
+
+def test_cached_positions_past_block_size_stay_finite(model_and_params):
+    """Lanes that overshoot a row's end on purpose (speculative verify
+    lanes, a scan rung's trailing steps, padded prefill buckets) look up
+    positions >= block_size. nn.Embed FILLS an out-of-range row with
+    NaN; the wpe lookup is bounded to the last position instead, so the
+    logits the poison guard reads stay finite — and in-range lanes are
+    untouched."""
+    from nanosandbox_tpu.models.gpt import init_cache
+
+    model, params, cfg = model_and_params
+    T = 4
+    tok = jnp.ones((2, T), jnp.int32)
+    # Row 0 sits well inside the buffer; row 1 starts at the last
+    # position, so its lanes 1..3 are positions block_size..block_size+2.
+    index = jnp.asarray([3, cfg.block_size - 1], jnp.int32)
+
+    @jax.jit
+    def step(params, tok, index):
+        logits, _ = model.apply(
+            {"params": params}, tok, deterministic=True,
+            cache=init_cache(cfg, 2, cfg.block_size), cache_index=index)
+        return logits
+
+    logits = np.asarray(step(params, tok, index))
+    assert np.isfinite(logits).all()
+    # Bounding changes nothing for in-range positions: row 0 equals the
+    # same row run alone, far from the end.
+    alone = np.asarray(step(params, tok, jnp.asarray([3, 3], jnp.int32)))
+    np.testing.assert_array_equal(logits[0], alone[0])

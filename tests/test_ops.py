@@ -53,7 +53,7 @@ def test_task_template_requires_acceptance_criteria():
     assert acc["validations"]["required"] is True
 
 
-def test_feature_template_area_taxonomy():
+def test_feature_template_area_labels():
     doc = _load(".github/ISSUE_TEMPLATE/feature_request.yml")
     area = next(b for b in doc["body"] if b.get("id") == "area")
     opts = area["attributes"]["options"]

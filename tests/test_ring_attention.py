@@ -262,19 +262,22 @@ def test_ring_flash_blocks_gradients():
                                    atol=5e-4, rtol=5e-4)
 
 
-def test_ring_block_impl_auto_resolution():
-    """'auto' resolves per backend: the einsum body wherever the Mosaic
-    kernel can't compile (CPU), the flash body where it can (TPU);
-    unaligned chunks force einsum regardless of backend."""
-    from nanosandbox_tpu.ops.attention import pallas_compile_probe
+def test_ring_block_impl_auto_resolution(monkeypatch):
+    """'auto' resolves per backend, with no probe in between: the einsum
+    body on cpu, the flash body where the backend says tpu; unaligned
+    chunks force einsum regardless of backend."""
+    from nanosandbox_tpu.ops import attention
     from nanosandbox_tpu.ops.ring_attention import _resolve_block_impl
 
     assert _resolve_block_impl("xla", 128) == "xla"
     with pytest.raises(ValueError, match="ring_block_impl"):
         _resolve_block_impl("pallas", 77)  # pinned + unaligned: loud error
     assert _resolve_block_impl("auto", 64) == "xla"       # unaligned
-    expected = "pallas" if pallas_compile_probe() else "xla"
-    assert _resolve_block_impl("auto", 128) == expected
+    assert _resolve_block_impl("auto", 128) == "xla"      # cpu
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+    assert _resolve_block_impl("auto", 128) == "pallas"
+    assert _resolve_block_impl("auto", 64) == "xla"       # still unaligned
+    assert not hasattr(attention, "pallas_compile_probe")
 
 
 def test_model_ring_attention_dropout_trains_directly():

@@ -532,3 +532,33 @@ def test_bench_decode_mixed_mode():
                                 quick=True, on_tpu=False)
     assert result["extra"]["mixed"] is True
     assert 0 < result["extra"]["tokens_generated"] <= 16
+
+
+def test_engine_from_restored_checkpoint_keeps_compile_budget(tiny_cfg):
+    """`python -m nanosandbox_tpu.serve`'s own path: params restored from
+    a checkpoint arrive on the Trainer's mesh, and that mesh is part of
+    their TYPE. A single-chip engine must shed it, or every program's
+    outputs come back mesh-typed, retrace against the mesh-free pool
+    and slot state it was first traced with, and the warm-up dies with
+    CompileBudgetExceeded (it did, on every restored checkpoint)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from nanosandbox_tpu.sample import cast_params_for_serving
+    from nanosandbox_tpu.train import Trainer, restore_for_inference
+
+    cfg = tiny_cfg.replace(max_iters=2, eval_interval=0)
+    Trainer(cfg).run()
+    trainer, state, _ = restore_for_inference(
+        cfg.out_dir, data_dir=cfg.data_dir, device="cpu")
+    params = cast_params_for_serving(state["params"],
+                                     trainer.cfg.compute_dtype)
+    eng = Engine(trainer.model, params, num_slots=2, max_len=32)
+    assert all(isinstance(leaf.sharding, SingleDeviceSharding)
+               for leaf in jax.tree.leaves(eng.params))
+    # The same shapes three times over: from the second round on, every
+    # program is fed its own (or another program's) outputs.
+    for _ in range(3):
+        eng.submit([1, 2, 3], 3)
+        eng.submit([4, 5], 3)
+        assert all(len(r.tokens) == 3 for r in eng.drain())
+    _assert_compile_budget(eng)

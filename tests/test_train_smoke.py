@@ -168,3 +168,56 @@ print("RBG_OK", result["final_loss"])
                           capture_output=True, text=True, timeout=300)
     assert proc.returncode == 0 and "RBG_OK" in proc.stdout, (
         proc.stdout + proc.stderr)
+
+
+def test_device_tpu_without_a_chip_raises(tiny_cfg):
+    """--device=tpu is a demand, not a hint: on a host whose backend is
+    the CPU it fails instead of training there and exiting 0."""
+    import pytest
+
+    from nanosandbox_tpu.train import _select_platform, main
+
+    with pytest.raises(RuntimeError, match="--device=tpu"):
+        _select_platform("tpu")
+    with pytest.raises(RuntimeError, match="--device=tpu"):
+        Trainer(tiny_cfg.replace(device="tpu"))
+    with pytest.raises(RuntimeError, match="--device=tpu"):
+        main([f"--data_dir={tiny_cfg.data_dir}", "--device=tpu",
+              f"--out_dir={tiny_cfg.out_dir}"])
+    with pytest.raises(ValueError, match="expected one of"):
+        _select_platform("cuda")
+    _select_platform("auto")
+    _select_platform("cpu")
+
+
+def test_no_mfu_without_a_peak(tiny_cfg, capsys):
+    """peak_flops knows published peaks only: an unknown device_kind
+    (the CPU here) raises, and the CPU path prints and logs no MFU."""
+    import pytest
+
+    trainer = Trainer(tiny_cfg.replace(max_iters=2, log_interval=1))
+    with pytest.raises(ValueError, match="no peak FLOP/s for device_kind"):
+        trainer.peak_flops()
+    assert trainer.mfu(0.1) is None
+    trainer.run()
+    out = capsys.readouterr().out
+    assert "tok/s" in out and "mfu" not in out
+
+
+def test_compile_cache_is_placed_from_outside(monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set the repo sets no path in code;
+    without it the cache goes to one fixed directory in the checkout."""
+    import jax
+
+    from nanosandbox_tpu.utils import compile_cache
+
+    seen = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: seen.append((k, v)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    assert compile_cache.enable_compile_cache() == "/somewhere/else"
+    assert seen == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = compile_cache.enable_compile_cache()
+    assert path == compile_cache.DEFAULT_DIR and path.endswith(".jax_cache")
+    assert seen == [("jax_compilation_cache_dir", path)]

@@ -1,26 +1,24 @@
-"""Real-TPU test tier (run manually on a chip; NOT part of the CPU suite).
+"""Real-TPU test tier (run on a chip; NOT part of the CPU suite).
 
-The CPU suite (tests/) can only exercise Pallas kernels in interpret mode,
-which skips Mosaic layout checks — exactly how round 1 shipped a kernel
-that failed lowering on hardware with a green suite (VERDICT.md weak #5).
-This tier compiles the real kernels. Usage, on a machine with a TPU:
+The CPU suite (tests/) exercises Pallas kernels in interpret mode and
+compiles them for a described chip (tests/test_chip_compile.py); neither
+executes anything on a TPU. This tier does. Usage, on a machine with a
+chip (one pytest process — a chip belongs to one process at a time):
 
     python -m pytest tests_tpu/ -q
 
-Skips everything (collection-time) when no TPU backend is available, so
-accidentally running it on CI is a no-op, not a failure.
+Every test here needs the chip, so one session fixture asks JAX for its
+backend — when the first test starts, never while a module is imported
+or collected — and skips the tier where there is no TPU.
 """
 
-import jax
 import pytest
 
 
-def pytest_collection_modifyitems(config, items):
-    try:
-        on_tpu = jax.default_backend() == "tpu"
-    except Exception:
-        on_tpu = False
-    if not on_tpu:
-        skip = pytest.mark.skip(reason="requires a real TPU backend")
-        for item in items:
-            item.add_marker(skip)
+@pytest.fixture(scope="session", autouse=True)
+def require_tpu():
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "tpu":
+        pytest.skip(f"requires a TPU backend (found {backend!r})")

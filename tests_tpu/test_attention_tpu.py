@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from nanosandbox_tpu.ops.attention import (causal_attention, flash_attention,
-                                           pallas_compile_probe,
+                                           resolve_attention_impl,
                                            xla_attention)
 
 
@@ -29,9 +29,19 @@ def rand_qkv(rng, B=2, H=4, T=1024, D=64, dtype=jnp.bfloat16):
     return q, k, v
 
 
-def test_probe_compiles():
-    assert pallas_compile_probe(), (
-        "custom Pallas flash kernel must lower on TPU")
+def test_auto_is_the_compiled_kernel():
+    """On the chip 'auto' IS the Pallas kernel — no probe, no fallback —
+    and that kernel compiles (fwd + bwd) at the production block size."""
+    assert resolve_attention_impl("auto") == "pallas"
+    x = jax.ShapeDtypeStruct((1, 1, 1024, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return causal_attention(q, k, v, impl="auto").astype(
+            jnp.float32).sum()
+
+    txt = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    assert "tpu_custom_call" in txt
 
 
 @pytest.mark.parametrize("T,D,dtype", [
