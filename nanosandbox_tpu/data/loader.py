@@ -22,7 +22,11 @@ import threading
 
 import numpy as np
 
+from nanosandbox_tpu.obs import process_tracer
 from nanosandbox_tpu.utils import native
+
+# The prefetch thread's spans get a track of their own in an export.
+_PREFETCH_TRACK = "loader_prefetch"
 
 
 class BinDataset:
@@ -97,6 +101,7 @@ class BatchLoader:
         self.native = native.get_lib() is not None
         self._queue: queue.Queue | None = None
         self._worker_exc: BaseException | None = None
+        self._tracer = process_tracer()
         if prefetch:
             self._queue = queue.Queue(maxsize=2)
             self._stop = threading.Event()
@@ -110,19 +115,29 @@ class BatchLoader:
 
     def _put(self, item) -> None:
         """Blocking put that still honors close() (bounded queue: a dead
-        consumer must not wedge the worker forever)."""
-        while not self._stop.is_set():
-            try:
-                self._queue.put(item, timeout=0.1)
-                return
-            except queue.Full:
-                continue
+        consumer must not wedge the worker forever). A put that finds
+        the queue full is the producer's slack: span ``loader_full``."""
+        try:
+            self._queue.put_nowait(item)
+            return
+        except queue.Full:
+            pass
+        with self._tracer.span("loader_full", cat="loader",
+                               track=_PREFETCH_TRACK):
+            while not self._stop.is_set():
+                try:
+                    self._queue.put(item, timeout=0.1)
+                    return
+                except queue.Full:
+                    continue
 
     def _worker(self) -> None:
         step = self.step
         try:
             while not self._stop.is_set():
-                batch = self._load(step)
+                with self._tracer.span("loader_fill", cat="loader",
+                                       step=step, track=_PREFETCH_TRACK):
+                    batch = self._load(step)
                 self._put((step, batch))
                 step += 1
         except Exception as e:
@@ -139,7 +154,12 @@ class BatchLoader:
 
     def __next__(self) -> tuple[np.ndarray, np.ndarray]:
         if self._queue is not None:
-            item = self._queue.get()
+            # depth: batches found waiting; 0 means the loop waits for the
+            # worker (it is starved).
+            with self._tracer.span("loader_wait", cat="loader",
+                                   step=self.step,
+                                   args={"depth": self._queue.qsize()}):
+                item = self._queue.get()
             if item is self._FAILED:
                 # Re-queue the sentinel: the worker is dead (nothing else
                 # will ever be enqueued), so every subsequent __next__
@@ -152,7 +172,8 @@ class BatchLoader:
             step, batch = item
             self.step = step + 1
             return batch
-        batch = self._load(self.step)
+        with self._tracer.span("loader_fill", cat="loader", step=self.step):
+            batch = self._load(self.step)
         self.step += 1
         return batch
 

@@ -11,6 +11,13 @@ device readback, nothing for jaxlint to flag:
                 host-resident dispatch-time state, bounded ring,
                 request-id correlation, Chrome trace-event JSON export
                 (Perfetto-loadable) per request or per time window.
+                ``process_tracer()`` is the training path's one tracer
+                (trainer, loader, host_sync, compile-cache listeners).
+  opscopes.py — which part of the model (attn, mlp, ln, embed,
+                lm_head_loss, optimizer, grad_norm) each instruction of
+                a compiled step belongs to, from the scope path XLA
+                keeps as ``op_name``; how a device trace's op times are
+                named.
 
 Two more halves (ISSUE 10), same contract:
 
@@ -40,11 +47,12 @@ from nanosandbox_tpu.obs.registry import (DEFAULT_BUCKETS, MetricFamily,
                                           MetricRegistry, global_registry,
                                           render_prometheus)
 from nanosandbox_tpu.obs.slo import SLOLedger, validate_slo_class
-from nanosandbox_tpu.obs.tracer import ENGINE_TRACK, Span, SpanTracer
+from nanosandbox_tpu.obs.tracer import (ENGINE_TRACK, Span, SpanTracer,
+                                        process_tracer)
 from nanosandbox_tpu.obs.vitals import register_process_vitals
 
 __all__ = ["MetricRegistry", "MetricFamily", "SpanTracer", "Span",
            "global_registry", "render_prometheus", "DEFAULT_BUCKETS",
            "ENGINE_TRACK", "FlightRecorder", "WatchdogPanel",
            "TERMINAL_EVENTS", "SLOLedger", "validate_slo_class",
-           "register_process_vitals"]
+           "register_process_vitals", "process_tracer"]

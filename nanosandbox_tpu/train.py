@@ -30,7 +30,7 @@ from typing import Any
 import numpy as np
 
 from nanosandbox_tpu.config import GPTConfig, TrainConfig, load_config
-from nanosandbox_tpu.obs import MetricRegistry, SpanTracer
+from nanosandbox_tpu.obs import opscopes, process_tracer
 from nanosandbox_tpu.utils import tracecheck
 
 # Peak bf16 FLOP/s per chip for MFU reporting (public spec-sheet numbers),
@@ -154,6 +154,27 @@ def restore_for_inference(out_dir: str, *, step: int | None = None,
     return trainer, state, step
 
 
+def _lower_step(train_step, operands: tuple, budgets):
+    """The jitted train step lowered on abstract operands, off the hot path
+    (memory_report, step_op_parts). The extra trace this may cost is allowed
+    for by name: the budget of the live loop's one trace is raised by one
+    for the lowering and put back if it traced nothing."""
+    name = "train_step"
+    budget, traced = budgets.budgets()[name], budgets.counts()[name]
+    budgets.register(name, budget + 1)
+    try:
+        return train_step.lower(*operands)
+    finally:
+        if budgets.counts()[name] == traced:
+            budgets.register(name, budget)
+
+
+def _step_op_parts(train_step, operands: tuple, budgets) -> dict:
+    """Trainer.step_op_parts, over what a lowering needs and no more."""
+    lowered = _lower_step(train_step, operands, budgets)
+    return opscopes.op_parts(lowered.compile().as_text())
+
+
 class Trainer:
     """Owns model/optimizer/state/mesh and the compiled step functions.
 
@@ -167,6 +188,22 @@ class Trainer:
 
     def __init__(self, cfg: TrainConfig, mesh_devices: list | None = None):
         _select_platform(cfg.device)
+        import jax
+
+        # The training path's one tracer (obs.process_tracer): this
+        # object, the loader, tracecheck.host_sync and the compile-cache
+        # listeners record into it. With the profiler's annotation as its
+        # hook every span is also a TraceMe: in any xplane recorded over
+        # this process (--profile_steps, a benchmark's window) the spans
+        # lie on the host's `python` line under their own names, on the
+        # device's clock. Recording is a dict build and a deque append;
+        # nothing is read back from the device.
+        self.tracer = process_tracer()
+        self.tracer.annotate = jax.profiler.TraceAnnotation
+        with self.tracer.span("trainer_init", cat="train"):
+            self._init(cfg, mesh_devices)
+
+    def _init(self, cfg: TrainConfig, mesh_devices: list | None) -> None:
         import jax
 
         from nanosandbox_tpu.data.loader import BinDataset
@@ -184,7 +221,9 @@ class Trainer:
         self.process_count = jax.process_count()
         self.is_main = self.process_index == 0
 
-        self.dataset = BinDataset(cfg.data_dir, cfg.dataset)
+        span = partial(self.tracer.span, cat="train")
+        with span("dataset_open"):
+            self.dataset = BinDataset(cfg.data_dir, cfg.dataset)
         from nanosandbox_tpu.models.convert import HF_GPT2_NAMES
         meta_kind = self.dataset.meta.get("kind")
         if cfg.init_from in HF_GPT2_NAMES and meta_kind not in ("gpt2", None):
@@ -236,15 +275,16 @@ class Trainer:
         vocab = cfg.vocab_size or self.dataset.vocab_size
         self.model_cfg = GPTConfig.from_train_config(cfg, vocab)
 
-        if cfg.mesh_slices:
-            from nanosandbox_tpu.parallel.mesh import make_hybrid_mesh
-            self.mesh = make_hybrid_mesh(cfg.mesh_dp, cfg.mesh_fsdp,
-                                         cfg.mesh_tp, cfg.mesh_sp,
-                                         num_slices=cfg.mesh_slices,
-                                         devices=mesh_devices)
-        else:
-            self.mesh = make_mesh(cfg.mesh_dp, cfg.mesh_fsdp, cfg.mesh_tp,
-                                  cfg.mesh_sp, devices=mesh_devices)
+        with span("make_mesh"):
+            if cfg.mesh_slices:
+                from nanosandbox_tpu.parallel.mesh import make_hybrid_mesh
+                self.mesh = make_hybrid_mesh(cfg.mesh_dp, cfg.mesh_fsdp,
+                                             cfg.mesh_tp, cfg.mesh_sp,
+                                             num_slices=cfg.mesh_slices,
+                                             devices=mesh_devices)
+            else:
+                self.mesh = make_mesh(cfg.mesh_dp, cfg.mesh_fsdp, cfg.mesh_tp,
+                                      cfg.mesh_sp, devices=mesh_devices)
         set_current_mesh(self.mesh)
         # The mesh is bound to the model explicitly (ring attention needs
         # it); the global above is only a fallback for standalone model use.
@@ -289,24 +329,28 @@ class Trainer:
             raise ValueError(
                 f"attention_impl='ring' shards heads over model: n_head "
                 f"{cfg.n_head} must be divisible by mesh_tp {cfg.mesh_tp}")
-        self.tx, self.lr_schedule = make_optimizer(cfg)
+        with span("make_optimizer"):
+            self.tx, self.lr_schedule = make_optimizer(cfg)
 
         # Abstract state -> shardings -> sharded init.
-        abstract = jax.eval_shape(self._init_state, jax.random.key(cfg.seed))
-        self.state_shardings = {
-            "params": param_shardings(
-                self.mesh, abstract["params"],
-                shard_params=cfg.shard_params, tp=cfg.mesh_tp > 1),
-            "opt_state": param_shardings(
-                self.mesh, abstract["opt_state"],
-                shard_params=cfg.shard_params, tp=cfg.mesh_tp > 1),
-            "step": jax.sharding.NamedSharding(
-                self.mesh, jax.sharding.PartitionSpec()),
-        }
-        self.abstract_state = jax.tree.map(
-            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
-            abstract, self.state_shardings,
-            is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
+        with span("abstract_state"):
+            abstract = jax.eval_shape(self._init_state,
+                                      jax.random.key(cfg.seed))
+            self.state_shardings = {
+                "params": param_shardings(
+                    self.mesh, abstract["params"],
+                    shard_params=cfg.shard_params, tp=cfg.mesh_tp > 1),
+                "opt_state": param_shardings(
+                    self.mesh, abstract["opt_state"],
+                    shard_params=cfg.shard_params, tp=cfg.mesh_tp > 1),
+                "step": jax.sharding.NamedSharding(
+                    self.mesh, jax.sharding.PartitionSpec()),
+            }
+            self.abstract_state = jax.tree.map(
+                lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                  sharding=s),
+                abstract, self.state_shardings,
+                is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
 
         self._train_step = None
         self._eval_step = None
@@ -316,32 +360,6 @@ class Trainer:
         # step (the failure mode jaxlint's nonstatic-shape rule hunts
         # statically) and raises instead of silently recompiling.
         self.tracecheck = tracecheck.TraceBudgetRegistry()
-        # Telemetry spine (nanosandbox_tpu/obs): the loss/MFU/tok-s
-        # scalars land on the same registry kind the serve engine
-        # publishes (MetricsWriter keeps owning the JSONL/TB artifact
-        # contract — the registry is the live snapshot view), and the
-        # tracer records eval windows / checkpoint saves / profiler
-        # windows as spans. Only updated at log/eval points, never
-        # inside the compiled step.
-        self.metrics = MetricRegistry()
-        self.tracer = SpanTracer(capacity=2048)
-        m = self.metrics
-        self._m_loss = m.gauge("train_loss",
-                               "Training loss at the last log step.")
-        self._m_grad_norm = m.gauge("train_grad_norm",
-                                    "Global grad norm at the last log step.")
-        self._m_lr = m.gauge("train_lr", "Learning rate at the last "
-                             "log step.")
-        self._m_toks = m.gauge("train_tokens_per_sec",
-                               "Window-averaged training tokens/sec.")
-        self._m_mfu = m.gauge("train_mfu",
-                              "Model FLOPs utilization (0..1).")
-        self._m_iters = m.counter("train_iters_total",
-                                  "Optimizer steps completed.")
-        self._m_eval = m.gauge("eval_loss", "Last estimate_loss value, "
-                               "by split.", labelnames=("split",))
-        self._m_ckpt = m.counter("checkpoint_saves_total",
-                                 "Checkpoints written.")
 
     # -- state ---------------------------------------------------------------
 
@@ -405,12 +423,20 @@ class Trainer:
     # -- compiled steps ------------------------------------------------------
 
     def _loss_fn(self, params, x, y, rng):
+        import jax
+
         from nanosandbox_tpu.models.gpt import (
             chunked_cross_entropy_loss, cross_entropy_loss,
             sharded_chunked_cross_entropy_loss)
 
         deterministic = self.cfg.dropout == 0.0 or rng is None
         kwargs = {} if deterministic else {"rngs": {"dropout": rng}}
+        # The head matmul and the cross entropy are no flax module's, so
+        # they get a scope of their own (obs.opscopes reads it back from
+        # the compiled step): round them alone, not round this whole
+        # function, whose scope would sit inside the jvp(...) wrapper of
+        # every op of the model.
+        head_scope = jax.named_scope("lm_head_loss")
         # Chunked head+loss keeps (B, T, vocab) logits out of HBM. Under
         # sequence parallelism the scan runs per-shard inside shard_map
         # (a scan over the T-sharded dim would otherwise force gathers,
@@ -419,18 +445,22 @@ class Trainer:
             hidden = self.model.apply({"params": params}, x,
                                       deterministic=deterministic,
                                       return_hidden=True, **kwargs)
-            if self.mesh.shape["seq"] == 1:
-                return chunked_cross_entropy_loss(
-                    hidden, params["wte"]["embedding"], y,
+            with head_scope:
+                if self.mesh.shape["seq"] == 1:
+                    return chunked_cross_entropy_loss(
+                        hidden, params["wte"]["embedding"], y,
+                        chunk_size=self.loss_chunk_size,
+                        compute_dtype=self.cfg.compute_dtype)
+                return sharded_chunked_cross_entropy_loss(
+                    hidden, params["wte"]["embedding"], y, mesh=self.mesh,
                     chunk_size=self.loss_chunk_size,
                     compute_dtype=self.cfg.compute_dtype)
-            return sharded_chunked_cross_entropy_loss(
-                hidden, params["wte"]["embedding"], y, mesh=self.mesh,
-                chunk_size=self.loss_chunk_size,
-                compute_dtype=self.cfg.compute_dtype)
+        # Full logits: the tied head's matmul is the model's own
+        # (`wte.attend`, which opscopes also counts as the head).
         logits = self.model.apply({"params": params}, x,
                                   deterministic=deterministic, **kwargs)
-        return cross_entropy_loss(logits, y)
+        with head_scope:
+            return cross_entropy_loss(logits, y)
 
     def train_rng(self, seed: int):
         """Root key of the TRAINING rng stream (dropout masks), honoring
@@ -467,58 +497,95 @@ class Trainer:
                 return (loss_acc + l,
                         jax.tree.map(jnp.add, grad_acc, g)), None
 
-            zero = jax.tree.map(jnp.zeros_like, params)
-            (loss, grads), _ = lax.scan(
-                body, (jnp.zeros(()), zero),
-                (xs, ys, jnp.arange(accum)))
-            loss = loss / accum
-            grads = jax.tree.map(lambda g: g / accum, grads)
+            with jax.named_scope("accum"):
+                zero = jax.tree.map(jnp.zeros_like, params)
+                (loss, grads), _ = lax.scan(
+                    body, (jnp.zeros(()), zero),
+                    (xs, ys, jnp.arange(accum)))
+                loss = loss / accum
+                grads = jax.tree.map(lambda g: g / accum, grads)
 
-        updates, opt_state = self.tx.update(grads, state["opt_state"], params)
         import optax
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = self.tx.update(grads, state["opt_state"],
+                                                params)
+            params = optax.apply_updates(params, updates)
         new_state = {"params": params, "opt_state": opt_state,
                      "step": state["step"] + 1}
-        grad_norm = optax.global_norm(grads)
+        with jax.named_scope("grad_norm"):
+            grad_norm = optax.global_norm(grads)
         return new_state, {"loss": loss, "grad_norm": grad_norm}
 
     def _eval_step_fn(self, state, x, y):
         return self._loss_fn(state["params"], x, y, None)
 
     def compiled_steps(self):
+        if self._train_step is None:
+            self._build_steps()
+            if self.cfg.compile:
+                # The map from the step's instructions to parts of the
+                # model is made only when somebody asks
+                # (obs.opscopes.step_parts). What the provider holds is
+                # what a lowering needs, so it still answers after the
+                # trainer's owner has dropped its state and loader.
+                self._op_parts = partial(
+                    _step_op_parts, self._train_step, self._step_operands(),
+                    self.tracecheck)
+                opscopes.set_provider(self._op_parts)
+        return self._train_step, self._eval_step
+
+    def _build_steps(self) -> None:
         import jax
 
-        if self._train_step is None:
-            step = partial(self._train_step_fn)
-            if self.cfg.compile:
-                # CPU jit ignores donation (and warns every compile);
-                # donate the train state only on accelerators, the same
-                # gate the serve engine applies to its pool/state.
-                on_accel = jax.default_backend() != "cpu"
-                # Budget 2 under --memory_report: its AOT .lower() on
-                # abstract operands traces once on top of the live step.
-                train_budget = 2 if self.cfg.memory_report else 1
-                step = self.tracecheck.guard("train_step",
-                                             train_budget)(step)
-                eval_fn = self.tracecheck.guard("eval_step",
-                                                1)(self._eval_step_fn)
-                self._train_step = jax.jit(
-                    step,
-                    in_shardings=(self.state_shardings, self.batch_sharding,
-                                  self.batch_sharding, None),
-                    out_shardings=(self.state_shardings, None),
-                    donate_argnums=(0,) if on_accel else ())
-                # jaxlint: disable=unconstrained-output -- scalar loss output: nothing mesh-sized to constrain
-                self._eval_step = jax.jit(
-                    eval_fn,
-                    in_shardings=(self.state_shardings, self.batch_sharding,
-                                  self.batch_sharding))
-            else:
-                # Uncompiled steps run the body EVERY call — a call
-                # counter would not be a trace counter, so no guard.
-                self._train_step = step
-                self._eval_step = self._eval_step_fn
-        return self._train_step, self._eval_step
+        step = partial(self._train_step_fn)
+        if not self.cfg.compile:
+            # Uncompiled steps run the body EVERY call — a call counter
+            # would not be a trace counter, so no guard.
+            self._train_step = step
+            self._eval_step = self._eval_step_fn
+            return
+        # CPU jit ignores donation (and warns every compile); donate the
+        # train state only on accelerators, the same gate the serve
+        # engine applies to its pool/state.
+        on_accel = jax.default_backend() != "cpu"
+        step = self.tracecheck.guard("train_step", 1)(step)
+        eval_fn = self.tracecheck.guard("eval_step", 1)(self._eval_step_fn)
+        self._train_step = jax.jit(
+            step,
+            in_shardings=(self.state_shardings, self.batch_sharding,
+                          self.batch_sharding, None),
+            out_shardings=(self.state_shardings, None),
+            donate_argnums=(0,) if on_accel else ())
+        # jaxlint: disable=unconstrained-output -- scalar loss output: nothing mesh-sized to constrain
+        self._eval_step = jax.jit(
+            eval_fn,
+            in_shardings=(self.state_shardings, self.batch_sharding,
+                          self.batch_sharding))
+
+    def _step_operands(self) -> tuple:
+        """The train step's operands in the abstract: no array but a key."""
+        import jax
+        import jax.numpy as jnp
+
+        batch_sds = jax.ShapeDtypeStruct(
+            (self.cfg.sequences_per_iter, self.cfg.block_size), jnp.int32,
+            sharding=self.batch_sharding)
+        return (self.abstract_state, batch_sds, batch_sds, self.train_rng(0))
+
+    def step_op_parts(self) -> dict:
+        """{instruction name: part of the model} for the train step's
+        executable (obs.opscopes), by lowering the same jitted step on the
+        abstract state and compiling. Where the step has already run in
+        this process that finds the executable in memory (0.3-0.7 s at
+        124M / 350M on a v5e, PERF.md); before its first run it is a
+        load out of the persistent cache or a compile. So on demand only:
+        never in the loop, and in set-up only if asked. The names are
+        those of the code that compiled the executable: see
+        utils/compile_cache.py on a cache that older code filled."""
+        if not self.cfg.compile:
+            raise ValueError("step_op_parts requires compile=True")
+        self.compiled_steps()
+        return self._op_parts()
 
     def memory_report(self) -> dict:
         """XLA's compile-time memory analysis of the train step — the
@@ -528,18 +595,13 @@ class Trainer:
         AOT-lowers on abstract inputs; costs one extra compile, which is
         why it sits behind --memory_report instead of running always.
         Keys are bytes, per device."""
-        import jax
         import jax.numpy as jnp
 
         if not self.cfg.compile:
             raise ValueError("memory_report requires compile=True")
         train_step, _ = self.compiled_steps()
-        rows = self.cfg.sequences_per_iter
-        batch_sds = jax.ShapeDtypeStruct((rows, self.cfg.block_size),
-                                         jnp.int32,
-                                         sharding=self.batch_sharding)
-        ma = train_step.lower(self.abstract_state, batch_sds, batch_sds,
-                              self.train_rng(0)).compile().memory_analysis()
+        ma = _lower_step(train_step, self._step_operands(),
+                         self.tracecheck).compile().memory_analysis()
         if ma is None:  # backend without memory analysis
             return {}
         self.flops_per_iter()  # populates self._n_params
@@ -630,8 +692,25 @@ class Trainer:
 
         global_batch = local.shape[0] * self.process_count
         global_shape = (global_batch,) + local.shape[1:]
-        return jax.make_array_from_process_local_data(
-            self.batch_sharding, local, global_shape)
+        with self.tracer.span("to_global", cat="train",
+                              args={"bytes": local.nbytes}):
+            return jax.make_array_from_process_local_data(
+                self.batch_sharding, local, global_shape)
+
+    def train_iter(self, state, loader, rng, iter_num: int):
+        """One iteration of the step path, as run() makes it and as any
+        other caller may drive it: the next batch, its two host-to-device
+        assemblies, the step's key and the step. Returns (state, metrics)
+        as the compiled step does; nothing is read back."""
+        import jax
+
+        train_step, _ = self.compiled_steps()
+        with self.tracer.span("train_iter", cat="train", step=iter_num):
+            xb, yb = next(loader)
+            xg, yg = self.to_global(xb), self.to_global(yb)
+            with self.tracer.span("dispatch", cat="train"):
+                return train_step(state, xg, yg,
+                                  jax.random.fold_in(rng, iter_num))
 
     # -- evaluation (nanoGPT estimate_loss) ----------------------------------
 
@@ -668,7 +747,6 @@ class Trainer:
             # so profiler windows can report their sync count.
             out[split] = tracecheck.host_sync("eval-readback",
                                               jnp.stack(losses).mean())
-            self._m_eval.labels(split=split).set(out[split])
         self.tracer.end(sid, {f"{k}_loss": round(v, 6)
                               for k, v in out.items()})
         return out
@@ -712,6 +790,22 @@ class Trainer:
 
     # -- main loop -----------------------------------------------------------
 
+    def _write_window_spans(self) -> None:
+        """The profiler window's spans, kept in memory until now: as
+        Chrome JSON beside the xplane, and in seconds by name."""
+        import json
+
+        last_s = (time.perf_counter_ns() - self._profile_t0_ns) / 1e9
+        path = os.path.join(self.profile_dir, "spans.json")
+        with open(path, "w", encoding="utf-8") as f:
+            json.dump(self.tracer.export_chrome(last_s=last_s), f)
+        by_name: dict[str, float] = {}
+        for s in self.tracer.spans(last_s=last_s):
+            by_name[s.name] = by_name.get(s.name, 0.0) + s.dur
+        print(f"spans of the window -> {path}; seconds by span: "
+              + ", ".join(f"{k} {v:.4f}" for k, v in
+                          sorted(by_name.items(), key=lambda kv: -kv[1])))
+
     def run(self) -> dict:
         import jax
 
@@ -733,7 +827,7 @@ class Trainer:
                          else "scratch")
         if init_from == "resume":
             state, extra = ckpt.restore(self.abstract_state)
-            # jaxlint: disable=host-sync -- one-time resume readback
+            # One-time resume readback.
             iter_num = int(extra.get("iter_num", int(state["step"])))
             best_val_loss = float(extra.get("best_val_loss", 1e9))
             if self.is_main:
@@ -744,7 +838,7 @@ class Trainer:
         else:
             state = self.init_state()
 
-        train_step, _ = self.compiled_steps()
+        self.compiled_steps()
         writer = MetricsWriter(cfg.resolved_log_dir, cfg.run_name,
                                enabled=self.is_main,
                                tensorboard=cfg.tensorboard)
@@ -826,7 +920,6 @@ class Trainer:
                                    "best_val_loss": best_val_loss,
                                    "config": cfg.to_dict()})
                         self.tracer.end(sid)
-                        self._m_ckpt.inc()
                     if cfg.eval_only:
                         break
                     # Eval + checkpoint time is reported on its own lines;
@@ -842,18 +935,17 @@ class Trainer:
                     jax.profiler.start_trace(self.profile_dir)
                     self._profiling = True
                     self._profile_span = self.tracer.begin(
-                        "profiler_window", cat="train",
+                        "profiler_window", cat="train", step=iter_num,
                         args={"start": prof_range[0],
                               "stop": prof_range[1]})
+                    self._profile_t0_ns = time.perf_counter_ns()
                     # Snapshot the sync ledger so the window report
                     # below describes the TRACED REGION's syncs, not the
                     # process-lifetime totals.
                     self._profile_sync_mark = tracecheck.sync_counts()
 
-                xb, yb = next(loader)
-                step_rng = jax.random.fold_in(rng, iter_num)
-                state, metrics = train_step(state, self.to_global(xb),
-                                            self.to_global(yb), step_rng)
+                state, metrics = self.train_iter(state, loader, rng,
+                                                 iter_num)
 
                 if self._profiling and iter_num == prof_range[1] - 1:
                     # Drain the async queue so the traced window contains
@@ -875,6 +967,7 @@ class Trainer:
                               f"({sum(by_kind.values())} logged host "
                               f"sync(s) in the window; by kind: "
                               f"{by_kind})")
+                        self._write_window_spans()
 
                 if cfg.log_interval > 0 and iter_num % cfg.log_interval == 0:
                     # The log-step sync point, through the audited
@@ -901,7 +994,9 @@ class Trainer:
                               f"time {dt * 1000:.2f}ms, tok/s {toks:,.0f}"
                               + ("" if mfu is None
                                  else f", mfu {mfu * 100:.2f}%"))
-                    # jaxlint: disable=host-sync -- free after loss sync
+                    # Free after the loss sync above: the step that made
+                    # both has finished. (jaxlint does not follow
+                    # train_iter's return value, so no suppression here.)
                     grad_norm = float(metrics["grad_norm"])
                     lr = (float(self.lr_schedule(iter_num))
                           if callable(self.lr_schedule)
@@ -913,17 +1008,6 @@ class Trainer:
                         "perf/tokens_per_sec": toks,
                         **({} if mfu is None else {"perf/mfu": mfu}),
                     })
-                    # The live-snapshot view of the same scalars: the
-                    # registry answers "what is this trainer doing NOW"
-                    # (tests, notebooks, a future scrape) without
-                    # tailing the JSONL artifact.
-                    self._m_loss.set(loss)
-                    self._m_grad_norm.set(grad_norm)
-                    self._m_lr.set(lr)
-                    self._m_toks.set(toks)
-                    if mfu is not None:
-                        self._m_mfu.set(mfu)
-                    self._m_iters._set_total(iter_num + 1)
                 iter_num += 1
         finally:
             if self._profiling:
@@ -946,7 +1030,6 @@ class Trainer:
                                             losses.get("val", 1e9)),
                        "config": cfg.to_dict()}, wait=True)
             self.tracer.end(sid)
-            self._m_ckpt.inc()
         ckpt.close()
         from nanosandbox_tpu.ops.attention import resolve_attention_impl
 

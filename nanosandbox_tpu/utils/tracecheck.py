@@ -228,7 +228,13 @@ def host_sync(name: str, value=None) -> Optional[float]:
                  name).inc()
     if value is None:
         return None
-    return float(value)
+    # The wait itself, as a span of the process tracer named by the
+    # ledger kind: where a device trace shows the chip idle after a
+    # readback, this says which readback it was.
+    from nanosandbox_tpu.obs.tracer import process_tracer
+
+    with process_tracer().span(name, cat="host_sync"):
+        return float(value)
 
 
 def sync_counts() -> Dict[str, int]:
