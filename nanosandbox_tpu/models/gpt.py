@@ -23,7 +23,9 @@ import jax
 import jax.numpy as jnp
 
 from nanosandbox_tpu.config import GPTConfig
-from nanosandbox_tpu.ops.attention import causal_attention
+from nanosandbox_tpu.ops.attention import (attention_layout,
+                                           causal_attention,
+                                           causal_attention_qkv)
 
 
 def _dense_init(std: float = 0.02):
@@ -36,6 +38,17 @@ def _layer_norm(cfg: GPTConfig, name: str) -> nn.LayerNorm:
     (models/convert.py) relies on the match."""
     return nn.LayerNorm(use_bias=cfg.bias, dtype=jnp.float32, epsilon=1e-5,
                         param_dtype=cfg.param_dtype, name=name)
+
+
+def attn_layout(cfg: GPTConfig, mesh: Any, T: int) -> str:
+    """'btc' or 'bhtd': the HBM interface the no-cache attention of this
+    model takes at sequence length T (ops/attention.attention_layout).
+    CausalSelfAttention asks it while tracing; the Trainer asks it for
+    its block_size and records the answer on ``trainer_init``. 'ring'
+    resolves to no Pallas impl of its own, so it keeps (B, H, T, D)."""
+    return attention_layout(
+        cfg.n_head, cfg.n_embd // cfg.n_head, T, impl=cfg.attention_impl,
+        stat_layout=cfg.attention_stat_layout, mesh=mesh)
 
 
 class CausalSelfAttention(nn.Module):
@@ -55,14 +68,30 @@ class CausalSelfAttention(nn.Module):
         qkv = nn.Dense(3 * C, use_bias=cfg.bias, dtype=dtype,
                        param_dtype=cfg.param_dtype,
                        kernel_init=_dense_init(), name="c_attn")(x)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        # (B, T, C) -> (B, H, T, D)
-        q = q.reshape(B, T, cfg.n_head, head_dim).transpose(0, 2, 1, 3)
-        k = k.reshape(B, T, cfg.n_head, head_dim).transpose(0, 2, 1, 3)
-        v = v.reshape(B, T, cfg.n_head, head_dim).transpose(0, 2, 1, 3)
+        # Training / eval on one device with the Pallas kernels: they read
+        # qkv and write c_proj's input where they lie (attn_layout). Every
+        # other path — the cache branches, ring, a mesh, XLA, head counts
+        # that do not tile 128 lanes — works on (B, H, T, D) and pays the
+        # transposes there.
+        layout = "bhtd" if cache is not None else attn_layout(
+            cfg, self.mesh, T)
+        if layout == "bhtd":
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            # (B, T, C) -> (B, H, T, D)
+            q = q.reshape(B, T, cfg.n_head, head_dim).transpose(0, 2, 1, 3)
+            k = k.reshape(B, T, cfg.n_head, head_dim).transpose(0, 2, 1, 3)
+            v = v.reshape(B, T, cfg.n_head, head_dim).transpose(0, 2, 1, 3)
 
         new_cache = None
-        if cache is not None:
+        if layout == "btc":
+            attn_rng = None
+            if cfg.dropout > 0.0 and not deterministic:
+                attn_rng = self.make_rng("dropout")
+            y = causal_attention_qkv(
+                qkv, cfg.n_head, impl=cfg.attention_impl,
+                dropout_rate=0.0 if deterministic else cfg.dropout,
+                dropout_rng=attn_rng)
+        elif cache is not None:
             # Incremental decode: write this call's K/V into the cache
             # buffer at cache_index and attend q against the buffer.
             # The T=1 per-row hot path dispatches to the fused flash-
@@ -476,7 +505,8 @@ class CausalSelfAttention(nn.Module):
                     dropout_rate=0.0 if deterministic else cfg.dropout,
                     dropout_rng=attn_rng,
                     stat_layout=cfg.attention_stat_layout)
-        y = y.transpose(0, 2, 1, 3).reshape(B, T, C)
+        if layout == "bhtd":
+            y = y.transpose(0, 2, 1, 3).reshape(B, T, C)
         if cache is not None and tp_mesh is not None:
             # The merged (H, D) -> C dim keeps the head split: this is
             # the Megatron row-parallel input layout for c_proj (kernel

@@ -14,16 +14,33 @@ other dK/dV (parallel over key blocks); both recompute P = exp(S - L)
 block-by-block instead of saving the (T, T) probability matrix, and both
 skip fully-masked blocks at the causal frontier.
 
-Mosaic layout note: per-row softmax stats (L, Drow) are stored
-lane-REPLICATED as (..., T, 128) arrays — Mosaic requires the last two
-block dims of every operand to tile onto (8, 128) sublane×lane registers,
-so a (1, block_q) row-vector block cannot lower; broadcasting each row
-stat across the 128-lane minor dim (the same layout jax's own
-pallas.ops.tpu.flash_attention uses) makes every BlockSpec legal at the
-cost of a 128x blowup on two tiny T-length vectors.
+Two HBM interfaces, one set of tile functions (_fwd_tile, _bwd_tile,
+_expand_stat_tile):
 
-Layouts: q, k, v are (B, H, T, D). D (head_dim) is padded to a multiple of
-128 lanes and T to a multiple of the 128-row block inside the Pallas path.
+  * flash_attention_qkv: the MODEL'S OWN layout. In qkv (B, T, 3C) as
+    c_attn emits it, out o (B, T, C) as c_proj consumes it, gradient one
+    dqkv (B, T, 3C); the logsumexp goes from forward to backward compact,
+    (B, H, 1, T) float32. A 128-lane block of the last dimension is a
+    group of heads (two at D = 64), so no transpose, pad, slice or cast
+    surrounds the kernels. Taken by training / eval on one device when
+    the heads tile the lanes (attention_layout decides, from shapes and
+    mesh); see the section heading further down.
+  * flash_attention / flash_attention_dropout / flash_attention_lse*:
+    q, k, v are (B, H, T, D), flattened to (B*H, T, D). D (head_dim) is
+    padded to a multiple of 128 lanes (64 runs unpadded) and T to a
+    multiple of the 128-row block inside the Pallas path. The entry ring
+    attention composes, and the one for every shape the first cannot take
+    (GPT-2 XL's 25 heads, D = 32, T off the 128 grid, a mesh).
+
+Mosaic layout note, (B, H, T, D) entry: per-row softmax stats (L, Drow)
+leave its forward lane-REPLICATED as (..., T, 128) arrays — Mosaic
+requires the last two block dims of every operand to tile onto (8, 128)
+sublane x lane registers, so a (block_q, 1) column block cannot lower;
+broadcasting each row stat across the 128-lane minor dim (the layout
+jax's own pallas.ops.tpu.flash_attention uses) makes every BlockSpec
+legal at the cost of a 128x blowup in HBM. The (B, T, 3C) entry turns the
+column into a lane-dense (1, block_q) row in-register instead
+(_stat_column_to_row) and never writes the replicated form.
 """
 
 from __future__ import annotations
@@ -56,10 +73,11 @@ def _tpu_params(*semantics: str):
 NEG_INF = -1e30
 LANES = 128  # minor-dim register width; row stats are replicated across it
 
-__all__ = ["causal_attention", "xla_attention", "flash_attention",
-           "flash_attention_dropout", "flash_attention_lse",
-           "flash_attention_lse_dropout", "hash_dropout_keep_mask",
-           "resolve_attention_impl"]
+__all__ = ["causal_attention", "causal_attention_qkv", "attention_layout",
+           "xla_attention", "flash_attention", "flash_attention_dropout",
+           "flash_attention_lse", "flash_attention_lse_dropout",
+           "flash_attention_qkv", "hash_dropout_keep_mask",
+           "qkv_layout_supported", "resolve_attention_impl"]
 
 
 # ---------------------------------------------------------------------------
@@ -176,6 +194,54 @@ def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 # Pallas flash forward
 # ---------------------------------------------------------------------------
 
+def _fwd_tile(q, k, v, carry, *, sm_scale: float, mask=None, keep=None,
+              dropout_rate: float = 0.0):
+    """One (block_q, block_k) step of the online softmax for ONE head: the
+    tile mathematics both forward kernels share (the (B*H, T, D) kernel
+    and the (B, T, heads*D) head-group kernel).
+
+    q (bq, W), k / v (bk, W) in their storage dtype; carry = (acc (bq, W)
+    f32, m (bq, 1), l (bq, 1)); mask / keep are (bq, bk) booleans (causal
+    frontier, dropout keep-mask) or None. W is the head size for the
+    per-head kernel; the head-group kernel passes 128-lane tiles with the
+    other head's q lanes zeroed, which contracts to the same scores.
+
+    MXU inputs stay in their storage dtype (bf16 on TPU) with float32
+    ACCUMULATION: pre-casting to f32 would run the matmuls at the MXU's
+    f32 rate, ~8x slower. Scores are scaled in f32 after the dot instead
+    of scaling q (same math, better bf16 numerics)."""
+    acc, m, l = carry
+    s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)  # (bq, bk)
+    s = s * sm_scale
+    if mask is not None:
+        s = jnp.where(mask, s, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))  # (bq, 1)
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m - m_new)
+    # The softmax normalizer l accumulates UNMASKED p: dropout applies
+    # to the normalized probabilities (o = dropout(softmax(s)) @ v), and
+    # masking commutes with the final per-row division by l, so masking
+    # only the p@v accumulation implements exactly that.
+    l_new = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+    p_v = p if keep is None else _apply_dropout(p, keep, dropout_rate)
+    acc_new = acc * alpha + lax.dot_general(
+        p_v.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return acc_new, m_new, l_new
+
+
+def _causal_kb_range(qi, block_q: int, block_k: int):
+    """(fully-unmasked, total) key-block counts for causal q block qi.
+    Only k blocks at or before the q block's frontier are walked, and the
+    walk is split at the diagonal: blocks strictly below it need no
+    causal mask, so the iota/compare/select VPU work (a real cost: the
+    per-tile matmuls are tiny at head_dim 64, leaving the kernel
+    VPU-bound) only runs on the block(s) the frontier crosses."""
+    return (lax.div(qi * block_q, block_k),
+            lax.div((qi + 1) * block_q + block_k - 1, block_k))
+
+
 def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
                       block_q: int, block_k: int, sm_scale: float,
                       causal: bool, dropout_rate: float = 0.0,
@@ -187,22 +253,12 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
                                  local_heads, hash_heads)
         q_off = seed_ref[3].astype(jnp.int32)
         k_off = seed_ref[4].astype(jnp.int32)
-    # Keep MXU inputs in their storage dtype (bf16 on TPU) with float32
-    # ACCUMULATION — pre-casting to f32 would run the matmuls at the MXU's
-    # f32 rate, ~8x slower. Scores are scaled in f32 after the dot instead
-    # of scaling q (same math, better bf16 numerics).
     q = q_ref[0]                                           # (block_q, D)
     seq_len = k_ref.shape[1]
     head_dim = q_ref.shape[2]
 
     if causal:
-        # Only iterate k blocks at or before this q block's frontier, and
-        # split the walk at the diagonal: blocks strictly below it need no
-        # causal mask, so the iota/compare/select VPU work (a real cost —
-        # the per-tile matmuls are tiny at head_dim 64, leaving the kernel
-        # VPU-bound) only runs on the block(s) the frontier crosses.
-        num_kb = lax.div((qi + 1) * block_q + block_k - 1, block_k)
-        num_kb_inner = lax.div(qi * block_q, block_k)  # fully-unmasked
+        num_kb_inner, num_kb = _causal_kb_range(qi, block_q, block_k)
     else:
         num_kb = seq_len // block_k
         num_kb_inner = num_kb
@@ -210,36 +266,20 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     q_pos = qi * block_q + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
 
     def body(j, carry, *, masked: bool):
-        acc, m, l = carry
         k = k_ref[0, pl.ds(j * block_k, block_k), :]
         v = v_ref[0, pl.ds(j * block_k, block_k), :]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (bq, bk)
-        s = s * sm_scale
+        mask = keep = None
         if masked:
             k_pos = j * block_k + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))  # (bq, 1)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        # The softmax normalizer l accumulates UNMASKED p — dropout applies
-        # to the normalized probabilities (o = dropout(softmax(s)) @ v), and
-        # masking commutes with the final per-row division by l, so masking
-        # only the p@v accumulation implements exactly that.
-        l_new = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+            mask = q_pos >= k_pos
         if dropout_rate > 0.0:
             keep = _dropout_keep(mix, q_off + qi * block_q,
                                  k_off + j * block_k,
                                  (block_q, block_k), hash_seq_len,
                                  dropout_rate)
-            p_v = _apply_dropout(p, keep, dropout_rate)
-        else:
-            p_v = p
-        acc_new = acc * alpha + lax.dot_general(
-            p_v.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return acc_new, m_new, l_new
+        return _fwd_tile(q, k, v, carry, sm_scale=sm_scale, mask=mask,
+                         keep=keep, dropout_rate=dropout_rate)
 
     init = (
         jnp.zeros((block_q, head_dim), jnp.float32),
@@ -471,6 +511,34 @@ def _expand_stat_tile(tile: jax.Array, row_offset, block_q: int) -> jax.Array:
     return jnp.sum(jnp.where(own_lane, spread, 0.0), axis=1, keepdims=True)
 
 
+def _bwd_tile(q, k, v, do, lse, drow, *, sm_scale: float, mask=None,
+              keep=None, dropout_rate: float = 0.0):
+    """One (block_q, block_k) tile of the flash backward for ONE head, up
+    to the three gradient matmuls: returns (p~, ds), both (bq, bk) f32,
+    with p = exp(s - L) recomputed, dp = dO V^T, ds = p (dp - Drow) and
+    p~ the probabilities that multiplied v in the forward. Shared by the
+    dQ kernel, the key-parallel walk and the (B, T, heads*D) head-group
+    walk, so the three cannot drift.
+
+    With dropout, p~ = keep * p / (1-r) is what multiplied v, so the mask
+    (and its 1/(1-r) rescale) lands on dp too; the row term drow =
+    rowsum(do*o) already equals rowsum(dp_masked * p) and needs no
+    correction. The head-group walk passes 128-lane tiles with the other
+    head's k and v lanes zeroed: the contractions give the same s, dp."""
+    s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * sm_scale
+    if mask is not None:
+        s = jnp.where(mask, s, NEG_INF)
+    p = jnp.exp(s - lse)                              # (bq, bk) f32
+    dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                         preferred_element_type=jnp.float32)
+    p_v = p
+    if keep is not None:
+        p_v = _apply_dropout(p, keep, dropout_rate)
+        dp = _apply_dropout(dp, keep, dropout_rate)
+    return p_v, p * (dp - drow)
+
+
 def _flash_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
                          lse_ref, dq_ref, *, block_q: int, block_k: int,
                          sm_scale: float, causal: bool, has_dlse: bool,
@@ -510,8 +578,7 @@ def _flash_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
         lse = lse_ref[0][:, :1]                      # (bq, 1) f32
     seq_len = k_ref.shape[1]
     if causal:
-        num_kb = lax.div((qi + 1) * block_q + block_k - 1, block_k)
-        num_kb_inner = lax.div(qi * block_q, block_k)  # fully-unmasked
+        num_kb_inner, num_kb = _causal_kb_range(qi, block_q, block_k)
     else:
         num_kb = seq_len // block_k
         num_kb_inner = num_kb
@@ -521,26 +588,18 @@ def _flash_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
     def body(j, dq_acc, *, masked: bool):
         k = k_ref[0, pl.ds(j * block_k, block_k), :]
         v = v_ref[0, pl.ds(j * block_k, block_k), :]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
+        mask = keep = None
         if masked:
             k_pos = j * block_k + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        p = jnp.exp(s - lse)                          # (bq, bk) f32
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+            mask = q_pos >= k_pos
         if dropout_rate > 0.0:
-            # p~ = keep * p / (1-r) is what multiplied v in the forward, so
-            # the mask (and its 1/(1-r) rescale) lands on dp; the row term
-            # drow = rowsum(do*o) already equals rowsum(dp_masked * p) and
-            # needs no correction.
             keep = _dropout_keep(mix, q_off + qi * block_q,
                                  k_off + j * block_k,
                                  (block_q, block_k), hash_seq_len,
                                  dropout_rate)
-            dp = _apply_dropout(dp, keep, dropout_rate)
-        ds = p * (dp - drow)
+        _, ds = _bwd_tile(q, k, v, do, lse, drow, sm_scale=sm_scale,
+                          mask=mask, keep=keep, dropout_rate=dropout_rate)
         return dq_acc + lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -629,32 +688,22 @@ def _flash_bwd_tiles_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
             if has_dlse:
                 drow = drow - stats[:, LANES:LANES + 1]
             lse = stats[:, :1]                        # (bq, 1) f32
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
+        mask = keep = None
         if masked:
             q_pos = i * block_q + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        p = jnp.exp(s - lse)                          # (bq, bk) f32
+            mask = q_pos >= k_pos
         if dropout_rate > 0.0:
-            # Same positional mask as fwd/dq; dv sums the MASKED p~ = the
-            # probabilities that actually multiplied v in the forward.
             keep = _dropout_keep(mix, q_off + i * block_q,
                                  k_off + ki * block_k,
                                  (block_q, block_k), hash_seq_len,
                                  dropout_rate)
-            p_v = _apply_dropout(p, keep, dropout_rate)
-        else:
-            p_v = p
-        pb = p_v.astype(do.dtype)
+        p_v, ds = _bwd_tile(q, k, v, do, lse, drow, sm_scale=sm_scale,
+                            mask=mask, keep=keep, dropout_rate=dropout_rate)
         dv_acc = dv_acc + lax.dot_general(
-            pb, do, (((0,), (0,)), ((), ())),
+            p_v.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)       # (bk, D)
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        if dropout_rate > 0.0:
-            dp = _apply_dropout(dp, keep, dropout_rate)
-        ds = (p * (dp - drow)).astype(q.dtype)
+        ds = ds.astype(q.dtype)
         dk_acc = dk_acc + lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)       # (bk, D)
@@ -1107,6 +1156,450 @@ flash_attention_lse_dropout.defvjp(_flash_lse_dropout_fwd_rule,
                                    _flash_lse_dropout_bwd_rule)
 
 
+# ---------------------------------------------------------------------------
+# The (B, T, heads*D) entry: the kernels read c_attn's output and write
+# c_proj's input
+# ---------------------------------------------------------------------------
+#
+# The model's own activation layout is (B, T, heads*D): c_attn emits
+# qkv (B, T, 3C), c_proj consumes o (B, T, C). The (B*H, T, D) kernels
+# above need every operand transposed to heads-major first and every
+# result transposed back, forward and backward: at the 124M training shape
+# 168 activation-sized copies a step, plus a slice of the lane-replicated
+# logsumexp and a scale+cast pass over dq. The kernels below take the
+# SAME tile functions (_fwd_tile, _bwd_tile, _expand_stat_tile) to the
+# data where it lies:
+#
+#   * a 128-lane block of the last dimension is a GROUP of 128 // D heads
+#     (two at GPT-2's D = 64; one head when D % 128 == 0), so the grid
+#     walks (batch, head groups, blocks) and the BlockSpecs index the q, k
+#     and v thirds of qkv directly;
+#   * inside a program the heads of a group are separated without moving
+#     a lane: a 64-deep contraction half-fills the 128-deep MXU anyway, so
+#     q (forward) or k and v (backward) are zeroed outside the head's
+#     lanes and contracted at full width — the same MXU passes, the same
+#     scores. Products that come out 128 lanes wide (p @ v, p^T @ dO,
+#     ds^T @ q) are right in the head's own lanes and are picked by one
+#     select when the program ends; ds @ k against the zeroed k is exactly
+#     zero in the other head's lanes, so dq needs no select at all;
+#   * the logsumexp leaves the forward compact, (B, H, 1, T) float32, one
+#     row per head, and the backward reads it through the compact stat
+#     layout's (T // 128, 128) tiles (_expand_stat_tile);
+#   * the backward writes dq (scaled, in the compute type), dk and dv
+#     into the thirds of ONE dqkv (B, T, 3C) array, which c_attn's two
+#     backward matmuls read as it is. One pallas output has one BlockSpec,
+#     so the three thirds are written by the kernel's own DMAs from VMEM
+#     staging buffers; a program waits for its predecessor's DMAs only
+#     when it is about to refill the buffers, so they overlap the next
+#     program's compute.
+
+# The two calls below are jitted: a model's layers then share ONE trace and
+# ONE lowering of each kernel (24 / 48 kernel bodies a step otherwise, each
+# twice the (B*H, T, D) kernel's size: +2.4 s of tracing and +1.5 s of
+# lowering in the 124M set-up, measured on the chip's host). XLA names a
+# custom call after the innermost scope, which would be the jit's; the
+# scope below keeps the name the model's own 'attn' scope gave it, which
+# the trace readers look for (%attn.N).
+KERNEL_SCOPE = "attn"
+
+
+def _head_lane_masks(width: int, head_dim: int):
+    """(1, width) boolean lane masks, one per head of a lane group; [None]
+    when the group is a single head (nothing to separate)."""
+    group = width // head_dim
+    if group == 1:
+        return [None]
+    lane_head = lax.broadcasted_iota(jnp.int32, (1, width), 1) // head_dim
+    return [lane_head == h for h in range(group)]
+
+
+def _only_lanes(x: jax.Array, lanes) -> jax.Array:
+    """x with every lane outside the head's zeroed (x itself for a
+    single-head group). Through float32: the v5e's vector unit has no
+    bfloat16 select, and the round trip is exact."""
+    if lanes is None:
+        return x
+    return jnp.where(lanes, x.astype(jnp.float32), 0.0).astype(x.dtype)
+
+
+def _pick_lanes(per_head: list, lane_masks: list) -> jax.Array:
+    """Assemble one 128-lane array from per-head arrays that are each
+    right in their own head's lanes."""
+    out = per_head[-1]
+    for x, lanes in zip(per_head[-2::-1], lane_masks[-2::-1]):
+        out = jnp.where(lanes, x, out)
+    return out
+
+
+def _stat_column_to_row(col: jax.Array) -> jax.Array:
+    """(block_q, 1) per-row statistic -> the (1, block_q) lane-dense row
+    the compact logsumexp output stores: the sublane -> lane relayout,
+    built 128 rows at a time from a select against the identity and a
+    sublane sum (ops Mosaic always lowers; 128 x 128 per chunk, noise
+    against a (block_q, block_k) score tile)."""
+    n = col.shape[0]
+    eye = (lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+           == lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1))
+    rows = [jnp.sum(jnp.where(eye, col[c:c + LANES], 0.0), axis=0,
+                    keepdims=True) for c in range(0, n, LANES)]
+    return rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=1)
+
+
+def _flash_fwd_qkv_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
+                          block_q: int, block_k: int, sm_scale: float,
+                          n_head: int, head_dim: int,
+                          dropout_rate: float = 0.0):
+    """Causal flash forward for one (batch row, head group, q block).
+
+    q_ref (1, block_q, W), k_ref / v_ref (1, T, W): 128-lane column blocks
+    of qkv's thirds; o_ref (1, block_q, W) the same columns of o;
+    lse_ref (1, group, 1, block_q). Walks the same causal k-block range as
+    _flash_fwd_kernel, loading each k / v tile once for the group."""
+    b, g, qi = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    width = q_ref.shape[2]
+    seq_len = k_ref.shape[1]
+    lane_masks = _head_lane_masks(width, head_dim)
+    group = len(lane_masks)
+    q = q_ref[0]
+    q_heads = [_only_lanes(q, lanes) for lanes in lane_masks]
+    if dropout_rate > 0.0:
+        mixes = [_dropout_tile_seed(seed_ref, b * n_head + g * group + h,
+                                    n_head, n_head) for h in range(group)]
+        q_off = seed_ref[3].astype(jnp.int32)
+        k_off = seed_ref[4].astype(jnp.int32)
+    num_kb_inner, num_kb = _causal_kb_range(qi, block_q, block_k)
+    q_pos = qi * block_q + lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0)
+
+    def body(j, carries, *, masked: bool):
+        k = k_ref[0, pl.ds(j * block_k, block_k), :]
+        v = v_ref[0, pl.ds(j * block_k, block_k), :]
+        mask = None
+        if masked:
+            k_pos = j * block_k + lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            mask = q_pos >= k_pos
+        out = []
+        for h in range(group):
+            keep = None
+            if dropout_rate > 0.0:
+                keep = _dropout_keep(mixes[h], q_off + qi * block_q,
+                                     k_off + j * block_k,
+                                     (block_q, block_k), seq_len,
+                                     dropout_rate)
+            out.append(_fwd_tile(q_heads[h], k, v, carries[h],
+                                 sm_scale=sm_scale, mask=mask, keep=keep,
+                                 dropout_rate=dropout_rate))
+        return tuple(out)
+
+    init = tuple((jnp.zeros((block_q, width), jnp.float32),
+                  jnp.full((block_q, 1), NEG_INF, jnp.float32),
+                  jnp.zeros((block_q, 1), jnp.float32))
+                 for _ in range(group))
+    carries = lax.fori_loop(0, num_kb_inner,
+                            functools.partial(body, masked=False), init)
+    carries = lax.fori_loop(num_kb_inner, num_kb,
+                            functools.partial(body, masked=True), carries)
+    o_ref[0] = _pick_lanes([acc / l for acc, _, l in carries],
+                           lane_masks).astype(o_ref.dtype)
+    for h, (_, m, l) in enumerate(carries):
+        lse_ref[0, h] = _stat_column_to_row(m + jnp.log(l))
+
+
+def _qkv_geometry(qkv_shape, n_head: int):
+    """(B, T, C, head_dim, lane-block width, lane blocks per third)."""
+    B, T, C3 = qkv_shape
+    C = C3 // 3
+    head_dim = C // n_head
+    width = max(head_dim, LANES)
+    if not qkv_layout_supported(n_head, head_dim, T):
+        raise ValueError(
+            f"flash_attention_qkv needs T % {LANES} == 0 and heads that "
+            f"tile the 128-lane blocks of (B, T, heads*D) (D == 64 with an "
+            f"even head count, or D % 128 == 0); got T={T}, "
+            f"n_head={n_head}, head_dim={head_dim}: use the (B, H, T, D) "
+            "entry (causal_attention)")
+    return B, T, C, head_dim, width, C // width
+
+
+def qkv_layout_supported(n_head: int, head_dim: int, T: int) -> bool:
+    """Whether (B, T, n_head * head_dim) splits into whole 128-lane head
+    groups the kernels above can walk with no padding and no copy."""
+    return (T % LANES == 0 and (n_head * head_dim) % LANES == 0
+            and ((head_dim == 64 and n_head % 2 == 0)
+                 or head_dim % LANES == 0))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n_head", "interpret", "dropout_rate"))
+def _pallas_flash_fwd_qkv(qkv: jax.Array, n_head: int, *,
+                          interpret: bool = False,
+                          dropout_rate: float = 0.0, seed=None):
+    """qkv (B, T, 3C) -> (o (B, T, C), lse (B, H, 1, T) f32). qkv is
+    handed to the kernel three times, each BlockSpec indexing its third."""
+    B, T, C, head_dim, width, nw = _qkv_geometry(qkv.shape, n_head)
+    group = width // head_dim
+    block_q, block_k = _clamp_blocks(T, DEFAULT_BLOCK, DEFAULT_BLOCK)
+    _check_dropout_seq_len(dropout_rate, T)
+    kernel = functools.partial(
+        _flash_fwd_qkv_kernel, block_q=block_q, block_k=block_k,
+        sm_scale=head_dim ** -0.5, n_head=n_head, head_dim=head_dim,
+        dropout_rate=dropout_rate)
+    call = pl.pallas_call(
+        kernel,
+        grid=(B, n_head // group, T // block_q),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, block_q, width), lambda b, g, i: (b, i, g)),
+            pl.BlockSpec((1, T, width), lambda b, g, i: (b, 0, nw + g)),
+            pl.BlockSpec((1, T, width), lambda b, g, i: (b, 0, 2 * nw + g)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block_q, width), lambda b, g, i: (b, i, g)),
+            pl.BlockSpec((1, group, 1, block_q),
+                         lambda b, g, i: (b, g, 0, i)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, T, C), qkv.dtype),
+            jax.ShapeDtypeStruct((B, n_head, 1, T), jnp.float32),
+        ],
+        compiler_params=None if interpret else _tpu_params(
+            "parallel", "parallel", "parallel"),
+        interpret=interpret,
+    )
+    with jax.named_scope(KERNEL_SCOPE):
+        return call(_dropout_seed_arg(seed, dropout_rate), qkv, qkv, qkv)
+
+
+def _flash_bwd_qkv_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
+                          lse_ref, dqkv_ref, dq_acc, dq_buf, dk_buf, dv_buf,
+                          sems, *, block_q: int, block_k: int,
+                          sm_scale: float, n_head: int, head_dim: int,
+                          dropout_rate: float = 0.0):
+    """The fused one-pass backward (see _flash_bwd_tiles_kernel,
+    with_dq=True) for one (batch row, head group, key block).
+
+    q_ref / o_ref / do_ref (1, T, W), k_ref / v_ref (1, block_k, W),
+    lse_ref (1, group, T // 128, 128); dqkv_ref is the whole (B, T, 3C)
+    output in HBM. dq_acc (T, W) f32 accumulates dq across the key blocks
+    of the group; dq_buf / dk_buf / dv_buf stage the finished blocks, in
+    the compute type, for the DMAs into dqkv's thirds (sems: dq, dk, dv).
+    The grid runs in order ('arbitrary'), so "the previous program" is
+    well defined: its DMAs are waited for right before the buffers are
+    written again, and the last program waits for its own."""
+    b, g, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    nb, ng, nk = pl.num_programs(0), pl.num_programs(1), pl.num_programs(2)
+    width = k_ref.shape[2]
+    seq_len = q_ref.shape[1]
+    num_qb = seq_len // block_q
+    C = dqkv_ref.shape[2] // 3
+    lane_masks = _head_lane_masks(width, head_dim)
+    group = len(lane_masks)
+    k = k_ref[0]
+    v = v_ref[0]
+    k_heads = [_only_lanes(k, lanes) for lanes in lane_masks]
+    v_heads = [_only_lanes(v, lanes) for lanes in lane_masks]
+    if dropout_rate > 0.0:
+        mixes = [_dropout_tile_seed(seed_ref, b * n_head + g * group + h,
+                                    n_head, n_head) for h in range(group)]
+        q_off = seed_ref[3].astype(jnp.int32)
+        k_off = seed_ref[4].astype(jnp.int32)
+    start_qb = lax.div(ki * block_k, block_q)
+    diag_end = lax.div((ki + 1) * block_k + block_q - 1, block_q)
+    k_pos = ki * block_k + lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1)
+
+    @pl.when(ki == 0)
+    def _zero_dq():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def body(i, carry, *, masked: bool):
+        rows = pl.ds(i * block_q, block_q)
+        q = q_ref[0, rows, :]
+        do = do_ref[0, rows, :]
+        # Drow = rowsum(dO * O) per head, from the group's one product.
+        do_o = do.astype(jnp.float32) * o_ref[0, rows, :].astype(jnp.float32)
+        mask = None
+        if masked:
+            q_pos = i * block_q + lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0)
+            mask = q_pos >= k_pos
+        dq_blk = dq_acc[rows, :]
+        out = []
+        for h, (dk_acc, dv_acc) in enumerate(carry):
+            lanes = lane_masks[h]
+            drow = jnp.sum(do_o if lanes is None
+                           else jnp.where(lanes, do_o, 0.0),
+                           axis=1, keepdims=True)     # (bq, 1) f32
+            lse = _expand_stat_tile(lse_ref[0, h], i * (block_q // LANES),
+                                    block_q)
+            keep = None
+            if dropout_rate > 0.0:
+                keep = _dropout_keep(mixes[h], q_off + i * block_q,
+                                     k_off + ki * block_k,
+                                     (block_q, block_k), seq_len,
+                                     dropout_rate)
+            p_v, ds = _bwd_tile(q, k_heads[h], v_heads[h], do, lse, drow,
+                                sm_scale=sm_scale, mask=mask, keep=keep,
+                                dropout_rate=dropout_rate)
+            # Right in head h's lanes, picked when the program ends.
+            dv_acc = dv_acc + lax.dot_general(
+                p_v.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)   # (bk, W)
+            ds = ds.astype(q.dtype)
+            dk_acc = dk_acc + lax.dot_general(
+                ds, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)   # (bk, W)
+            # Exactly zero outside head h's lanes (k is zeroed there).
+            dq_blk = dq_blk + lax.dot_general(
+                ds, k_heads[h], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)   # (bq, W)
+            out.append((dk_acc, dv_acc))
+        dq_acc[rows, :] = dq_blk
+        return tuple(out)
+
+    init = tuple((jnp.zeros((block_k, width), jnp.float32),
+                  jnp.zeros((block_k, width), jnp.float32))
+                 for _ in range(group))
+    carry = lax.fori_loop(start_qb, diag_end,
+                          functools.partial(body, masked=True), init)
+    carry = lax.fori_loop(diag_end, num_qb,
+                          functools.partial(body, masked=False), carry)
+    dk = _pick_lanes([dk_acc for dk_acc, _ in carry], lane_masks)
+    dv = _pick_lanes([dv_acc for _, dv_acc in carry], lane_masks)
+
+    col = pl.multiple_of(g * width, LANES)
+    row = pl.multiple_of(ki * block_k, LANES)
+
+    def copy_out(buf, sem, rows, third):
+        return pltpu.make_async_copy(
+            buf, dqkv_ref.at[b, rows, pl.ds(third * C + col, width)],
+            sems.at[sem])
+
+    dq_copy = copy_out(dq_buf, 0, pl.ds(0, seq_len), 0)
+    dk_copy = copy_out(dk_buf, 1, pl.ds(row, block_k), 1)
+    dv_copy = copy_out(dv_buf, 2, pl.ds(row, block_k), 2)
+    first_group = jnp.logical_and(b == 0, g == 0)
+    last_group = jnp.logical_and(b == nb - 1, g == ng - 1)
+
+    # A wait needs only the semaphore and the byte count, which are those
+    # of the predecessor's copy of the same buffer.
+    @pl.when(jnp.logical_not(jnp.logical_and(first_group, ki == 0)))
+    def _wait_previous_dk_dv():
+        dk_copy.wait()
+        dv_copy.wait()
+
+    dk_buf[...] = (dk * sm_scale).astype(dk_buf.dtype)
+    dv_buf[...] = dv.astype(dv_buf.dtype)
+    dk_copy.start()
+    dv_copy.start()
+
+    @pl.when(ki == nk - 1)
+    def _flush_dq():
+        @pl.when(jnp.logical_not(first_group))
+        def _wait_previous_dq():
+            dq_copy.wait()
+
+        dq_buf[...] = (dq_acc[...] * sm_scale).astype(dq_buf.dtype)
+        dq_copy.start()
+
+    @pl.when(jnp.logical_and(last_group, ki == nk - 1))
+    def _drain():
+        dq_copy.wait()
+        dk_copy.wait()
+        dv_copy.wait()
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n_head", "interpret", "dropout_rate"))
+def _pallas_flash_bwd_qkv(qkv, o, lse, do, n_head: int, *,
+                          interpret: bool = False,
+                          dropout_rate: float = 0.0, seed=None):
+    """(qkv (B, T, 3C), o, lse (B, H, 1, T), do (B, T, C)) -> dqkv
+    (B, T, 3C) in qkv's dtype, dq already scaled by head_dim ** -0.5."""
+    B, T, C, head_dim, width, nw = _qkv_geometry(qkv.shape, n_head)
+    group = width // head_dim
+    block_q, block_k = _clamp_blocks(T, DEFAULT_BLOCK, DEFAULT_BLOCK)
+    _check_dropout_seq_len(dropout_rate, T)
+    kernel = functools.partial(
+        _flash_bwd_qkv_kernel, block_q=block_q, block_k=block_k,
+        sm_scale=head_dim ** -0.5, n_head=n_head, head_dim=head_dim,
+        dropout_rate=dropout_rate)
+    full = lambda b, g, j: (b, 0, g)
+    call = pl.pallas_call(
+        kernel,
+        grid=(B, n_head // group, T // block_k),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, T, width), full),
+            pl.BlockSpec((1, block_k, width),
+                         lambda b, g, j: (b, j, nw + g)),
+            pl.BlockSpec((1, block_k, width),
+                         lambda b, g, j: (b, j, 2 * nw + g)),
+            pl.BlockSpec((1, T, width), full),
+            pl.BlockSpec((1, T, width), full),
+            pl.BlockSpec((1, group, T // LANES, LANES),
+                         lambda b, g, j: (b, g, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct(qkv.shape, qkv.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((T, width), jnp.float32),
+            pltpu.VMEM((T, width), qkv.dtype),
+            pltpu.VMEM((block_k, width), qkv.dtype),
+            pltpu.VMEM((block_k, width), qkv.dtype),
+            pltpu.SemaphoreType.DMA((3,)),
+        ],
+        # In grid order throughout: dq_acc is revisited across the key
+        # blocks, and the staging buffers' DMAs are waited for by the
+        # program that runs next.
+        compiler_params=None if interpret else _tpu_params(
+            "arbitrary", "arbitrary", "arbitrary"),
+        interpret=interpret,
+    )
+    with jax.named_scope(KERNEL_SCOPE):
+        return call(_dropout_seed_arg(seed, dropout_rate), qkv, qkv, qkv, o,
+                    do, lse.reshape(B, n_head, T // LANES, LANES))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def flash_attention_qkv(qkv, seed, n_head: int, dropout_rate: float = 0.0,
+                        interpret: bool = False):
+    """Causal flash attention on the model's own layout: qkv (B, T, 3C) as
+    c_attn emits it -> o (B, T, C) as c_proj consumes it, heads of size
+    C // n_head side by side in the last dimension, scores scaled by
+    head_dim ** -0.5. The gradient is one dqkv (B, T, 3C). No transpose,
+    pad, slice or cast around the kernels.
+
+    seed: (1,) uint32 for in-kernel attention dropout (None when
+    dropout_rate == 0); the keep-mask is hash_dropout_keep_mask's, bit for
+    bit, as in flash_attention_dropout. Shapes must satisfy
+    qkv_layout_supported; everything else goes through causal_attention.
+    """
+    return _pallas_flash_fwd_qkv(qkv, n_head, interpret=interpret,
+                                 dropout_rate=dropout_rate, seed=seed)[0]
+
+
+def _flash_qkv_fwd_rule(qkv, seed, n_head, dropout_rate, interpret):
+    from jax.ad_checkpoint import checkpoint_name
+
+    o, lse = _pallas_flash_fwd_qkv(qkv, n_head, interpret=interpret,
+                                   dropout_rate=dropout_rate, seed=seed)
+    o = checkpoint_name(o, "attn_out")  # see _flash_fwd_rule
+    return o, (qkv, o, checkpoint_name(lse, "attn_lse"), seed)
+
+
+def _flash_qkv_bwd_rule(n_head, dropout_rate, interpret, res, do):
+    qkv, o, lse, seed = res
+    dqkv = _pallas_flash_bwd_qkv(qkv, o, lse, do, n_head,
+                                 interpret=interpret,
+                                 dropout_rate=dropout_rate, seed=seed)
+    return dqkv, None
+
+
+flash_attention_qkv.defvjp(_flash_qkv_fwd_rule, _flash_qkv_bwd_rule)
+
+
 def hash_dropout_keep_mask(seed, B: int, H: int, Tq: int, Tk: int, *,
                            q_off=0, k_off=0, b_off=0, h_off=0,
                            hash_heads: int | None = None,
@@ -1173,6 +1666,47 @@ def resolve_attention_impl(impl: str) -> str:
     if impl != "auto":
         return impl
     return "pallas" if jax.default_backend() == "tpu" else "xla"
+
+
+def attention_layout(n_head: int, head_dim: int, T: int, *,
+                     impl: str = "auto", stat_layout: str = "compact",
+                     mesh=None) -> str:
+    """Which HBM interface the training / eval attention of these shapes
+    takes: 'btc' (flash_attention_qkv: the kernels read qkv (B, T, 3C) and
+    write o (B, T, C), no layout copy) or 'bhtd' (causal_attention over
+    (B, H, T, D) after the transposes). Decided once, at trace time, from
+    what the code can observe: the resolved impl, the shapes and the bound
+    mesh. 'btc' needs this file's Pallas kernels, the compact statistic
+    layout (the only one the new kernels have), head groups that tile the
+    128 lanes (qkv_layout_supported: not GPT-2 XL's 25 heads) and one
+    device: on a mesh the kernels run inside ring_attention_sharded's
+    shard_map shell, which composes the (B, H, T, D) entry."""
+    if (resolve_attention_impl(impl) in ("pallas", "pallas_interpret")
+            and stat_layout == "compact"
+            and (mesh is None or mesh.size == 1)
+            and qkv_layout_supported(n_head, head_dim, T)):
+        return "btc"
+    return "bhtd"
+
+
+def causal_attention_qkv(qkv: jax.Array, n_head: int, *, impl: str = "auto",
+                         dropout_rate: float = 0.0,
+                         dropout_rng: jax.Array | None = None) -> jax.Array:
+    """Causal attention from qkv (B, T, 3C) to o (B, T, C) through
+    flash_attention_qkv: the 'btc' side of attention_layout, which the
+    caller has asked first. Dropout draws its seed from dropout_rng as
+    causal_attention does."""
+    impl = resolve_attention_impl(impl)
+    if impl not in ("pallas", "pallas_interpret"):
+        raise ValueError(
+            f"causal_attention_qkv is the Pallas (B, T, heads*D) entry; "
+            f"impl {impl!r} goes through causal_attention")
+    seed = None
+    if dropout_rate > 0.0 and dropout_rng is not None:
+        seed = jax.random.bits(dropout_rng, (1,), jnp.uint32)
+    return flash_attention_qkv(
+        qkv, seed, n_head, float(dropout_rate) if seed is not None else 0.0,
+        impl == "pallas_interpret")
 
 
 def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,

@@ -200,14 +200,21 @@ class Trainer:
         # nothing is read back from the device.
         self.tracer = process_tracer()
         self.tracer.annotate = jax.profiler.TraceAnnotation
-        with self.tracer.span("trainer_init", cat="train"):
+        sid = self.tracer.begin("trainer_init", cat="train")
+        try:
             self._init(cfg, mesh_devices)
+        finally:
+            # Which HBM interface the step's attention takes ('btc': the
+            # kernels read qkv (B, T, 3C) where it lies; 'bhtd': after the
+            # transposes), decided from shapes and mesh at trace time.
+            self.tracer.end(sid, args={
+                "attn_layout": getattr(self, "attn_layout", None)})
 
     def _init(self, cfg: TrainConfig, mesh_devices: list | None) -> None:
         import jax
 
         from nanosandbox_tpu.data.loader import BinDataset
-        from nanosandbox_tpu.models.gpt import GPT
+        from nanosandbox_tpu.models.gpt import GPT, attn_layout
         from nanosandbox_tpu.parallel.distributed import (
             maybe_initialize_distributed)
         from nanosandbox_tpu.parallel.mesh import (batch_sharding, make_mesh,
@@ -289,6 +296,8 @@ class Trainer:
         # The mesh is bound to the model explicitly (ring attention needs
         # it); the global above is only a fallback for standalone model use.
         self.model = GPT(self.model_cfg, mesh=self.mesh)
+        self.attn_layout = attn_layout(self.model_cfg, self.mesh,
+                                       cfg.block_size)
         self.batch_sharding = batch_sharding(self.mesh)
         # Fail fast on batch/mesh mismatches instead of surfacing them later
         # as opaque pjit sharding errors (docs/playbook.md pitfalls).
@@ -1050,7 +1059,8 @@ def main(argv: list[str] | None = None) -> dict:
     enable_compile_cache()
     trainer = Trainer(cfg)
     if trainer.is_main:
-        print(f"tokens per iteration: {cfg.tokens_per_iter:,}")
+        print(f"tokens per iteration: {cfg.tokens_per_iter:,}; "
+              f"attn_layout: {trainer.attn_layout}")
         print(f"mesh: {trainer.mesh}")
     return trainer.run()
 

@@ -465,3 +465,244 @@ def test_fused_and_split_backward_agree_dropout_dlse():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-4, rtol=1e-4,
                                    err_msg=f"d{name} diverged")
+
+
+# -- the (B, T, heads*D) entry: kernels on the model's own layout ----------
+
+def _heads_major(x, H):
+    B, T, C = x.shape
+    return x.reshape(B, T, H, C // H).transpose(0, 2, 1, 3)
+
+
+def _xla_on_qkv(qkv, H):
+    """xla_attention on the same (B, T, 3C) input, back to (B, T, C)."""
+    B, T, C3 = qkv.shape
+    q, k, v = (_heads_major(x, H) for x in jnp.split(qkv, 3, axis=-1))
+    o = xla_attention(q, k, v, causal=True)
+    return o.transpose(0, 2, 1, 3).reshape(B, T, C3 // 3)
+
+
+@pytest.mark.parametrize("T", [256, 640, 1024])
+@pytest.mark.parametrize("H,D", [(12, 64), (16, 64), (2, 128)])
+def test_qkv_entry_matches_xla(H, D, T):
+    """Forward and the gradient with respect to qkv, head pairs (D = 64)
+    and whole-head blocks (D = 128), at T that clamp the blocks to 256,
+    128 and 512."""
+    from nanosandbox_tpu.ops.attention import flash_attention_qkv
+
+    rng = np.random.default_rng(30)
+    qkv = jnp.asarray(rng.normal(size=(1, T, 3 * H * D)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(1, T, H * D)), jnp.float32)
+    out = flash_attention_qkv(qkv, None, H, 0.0, True)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_xla_on_qkv(qkv, H)),
+                               atol=2e-5, rtol=2e-5)
+    g = jax.grad(lambda x: (flash_attention_qkv(
+        x, None, H, 0.0, True) * w).sum())(qkv)
+    g_ref = jax.grad(lambda x: (_xla_on_qkv(x, H) * w).sum())(qkv)
+    np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
+                               atol=2e-4, rtol=2e-4)
+
+
+def test_qkv_entry_dropout_mask_and_gradients():
+    """In the head-pair programs the keep-mask is hash_dropout_keep_mask's
+    bit for bit (read off the forward: uniform scores, v one-hot over one
+    64-key block at a time), and output and gradients equal the
+    (B, H, T, D) entry's for the same seed."""
+    from nanosandbox_tpu.ops.attention import (flash_attention_dropout,
+                                               flash_attention_qkv,
+                                               hash_dropout_keep_mask)
+
+    B, H, D, T, rate = 2, 2, 64, 256, 0.1
+    seed = jnp.array([4242], jnp.uint32)
+    blocks = []
+    for c in range(T // D):
+        v = jnp.zeros((T, D), jnp.float32).at[c * D:(c + 1) * D].set(
+            jnp.eye(D))
+        qkv = jnp.concatenate(
+            [jnp.zeros((B, T, 2 * H * D), jnp.float32),
+             jnp.broadcast_to(jnp.tile(v, (1, H)), (B, T, H * D))], axis=-1)
+        o = flash_attention_qkv(qkv, seed, H, rate, True)
+        blocks.append(_heads_major(o, H))              # (B, H, T, 64 keys)
+    seen = np.asarray(jnp.concatenate(blocks, axis=-1)) != 0.0
+    want = np.asarray(hash_dropout_keep_mask(seed, B, H, T, T, rate=rate))
+    tril = np.tril(np.ones((T, T), bool))
+    np.testing.assert_array_equal(seen[:, :, tril], want[:, :, tril])
+    assert 0.05 < 1.0 - want[:, :, tril].mean() < 0.15
+
+    rng = np.random.default_rng(31)
+    qkv = jnp.asarray(rng.normal(size=(B, T, 3 * H * D)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(B, T, H * D)), jnp.float32)
+
+    def old(x):
+        q, k, v = (_heads_major(t, H) for t in jnp.split(x, 3, axis=-1))
+        o = flash_attention_dropout(q, k, v, seed, True, None, rate, True,
+                                    "compact")
+        return o.transpose(0, 2, 1, 3).reshape(B, T, H * D)
+
+    new = lambda x: flash_attention_qkv(x, seed, H, rate, True)
+    np.testing.assert_allclose(np.asarray(new(qkv)), np.asarray(old(qkv)),
+                               atol=1e-6, rtol=1e-6)
+    g_new = jax.grad(lambda x: (new(x) * w).sum())(qkv)
+    g_old = jax.grad(lambda x: (old(x) * w).sum())(qkv)
+    np.testing.assert_allclose(np.asarray(g_new), np.asarray(g_old),
+                               atol=1e-5, rtol=1e-5)
+
+
+def _attn_module_jaxprs(n_head, n_embd, T, *, mesh=None, cached=False,
+                        grad=False, **cfg_kw):
+    """The jaxpr of CausalSelfAttention.apply (or of its gradient with
+    respect to the input) on abstract operands: nothing runs."""
+    from nanosandbox_tpu.config import GPTConfig
+    from nanosandbox_tpu.models.gpt import CausalSelfAttention
+
+    cfg = GPTConfig(n_layer=1, n_head=n_head, n_embd=n_embd, block_size=T,
+                    vocab_size=64, dropout=0.0, bias=False,
+                    attention_impl="pallas_interpret",
+                    compute_dtype="float32", **cfg_kw)
+    mod = CausalSelfAttention(cfg, mesh=mesh)
+    B = 2
+    x = jnp.zeros((B, T, n_embd), jnp.float32)
+    params = jax.eval_shape(
+        lambda: mod.init(jax.random.PRNGKey(0), x[:, :8], True))
+    if cached:
+        kv = jnp.zeros((B, n_head, 2 * T, n_embd // n_head), jnp.float32)
+
+        def fn(p, x):
+            return mod.apply(p, x, True, (kv, kv), 0)[0]
+    else:
+        def fn(p, x):
+            return mod.apply(p, x, True)
+    if grad:
+        return jax.make_jaxpr(jax.grad(
+            lambda p, x: fn(p, x).sum(), argnums=(0, 1)))(params, x), B
+    return jax.make_jaxpr(fn)(params, x), B
+
+
+def _walk_eqns(jaxpr, *, into_kernels=False):
+    """Every equation of a jaxpr and of the jaxprs its equations carry
+    (custom_vjp bodies, pjit, shard_map); Pallas kernel bodies only on
+    request: they work on tiles in VMEM, not on arrays in HBM."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call" and not into_kernels:
+            continue
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _walk_eqns(inner, into_kernels=into_kernels)
+
+
+def _activation_transposes(closed, floor):
+    return [e for e in _walk_eqns(closed.jaxpr)
+            if e.primitive.name == "transpose"
+            and e.outvars[0].aval.size >= floor]
+
+
+def _kernel_operand_ranks(closed):
+    return sorted({len(v.aval.shape) for e in _walk_eqns(closed.jaxpr)
+                   if e.primitive.name == "pallas_call"
+                   for v in e.invars if v.aval.size > 8})
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
+def test_new_path_has_no_activation_sized_transpose(grad):
+    """CausalSelfAttention on the (B, T, heads*D) entry, forward and
+    jax.grad: no transpose of an activation-sized array in the jaxpr (a
+    weight gradient's (3C, C) -> (C, 3C) is smaller and allowed). The
+    'replicated' stat layout, which keeps the (B, H, T, D) entry, is the
+    control that the detector sees the old path's transposes."""
+    T, C = 256, 128
+    new, B = _attn_module_jaxprs(2, C, T, grad=grad)
+    assert _kernel_operand_ranks(new)[:1] == [3]           # (B, T, 3C)
+    assert _activation_transposes(new, B * T * C) == []
+    old, _ = _attn_module_jaxprs(2, C, T, grad=grad,
+                                 attention_stat_layout="replicated")
+    assert len(_activation_transposes(old, B * T * C)) >= 4
+
+
+@pytest.mark.parametrize("case", ["25x64", "12x32", "model2", "cache",
+                                  "replicated", "12x64"])
+def test_layout_dispatch(case):
+    """What lands on the old (B, H, T, D) entry: head counts that do not
+    tile 128 lanes (GPT-2 XL's 25 x 64; 12 x 32), a bound mesh with
+    model = 2, a cache, the lane-replicated stat layout. 12 x 64 on no
+    mesh is the control that takes the new entry."""
+    from nanosandbox_tpu.config import GPTConfig
+    from nanosandbox_tpu.models.gpt import attn_layout
+    from nanosandbox_tpu.ops.attention import attention_layout
+
+    T = 256
+    H, D = {"25x64": (25, 64), "12x32": (12, 32)}.get(case, (12, 64))
+    mesh = None
+    if case == "model2":
+        from nanosandbox_tpu.parallel.mesh import make_mesh
+
+        mesh = make_mesh(mesh_dp=1, mesh_tp=2, devices=jax.devices()[:2])
+    kw = ({"attention_stat_layout": "replicated"}
+          if case == "replicated" else {})
+    closed, B = _attn_module_jaxprs(H, H * D, T, mesh=mesh,
+                                    cached=case == "cache", **kw)
+    cfg = GPTConfig(n_layer=1, n_head=H, n_embd=H * D, block_size=T,
+                    vocab_size=64, attention_impl="pallas_interpret", **kw)
+    want = "btc" if case == "12x64" else "bhtd"
+    if case != "cache":      # a cache is the module's own branch
+        assert attn_layout(cfg, mesh, T) == want
+    kernels = [e for e in _walk_eqns(closed.jaxpr)
+               if e.primitive.name == "pallas_call"]
+    ranks = _kernel_operand_ranks(closed)
+    moved = _activation_transposes(closed, B * T * H * D)
+    if want == "btc":
+        assert ranks[:1] == [3] and not moved, (ranks, moved)
+    else:
+        # heads-major operands ((B*H, T, D) kernels, or the cache
+        # branch's XLA scores) behind activation-sized transposes
+        assert moved and 3 * H * D not in {
+            v.aval.shape[-1] for e in kernels for v in e.invars}
+    # shapes alone: T off the 128 grid and CPU 'auto' keep the old entry
+    assert attention_layout(12, 64, 200, impl="pallas_interpret") == "bhtd"
+    assert attention_layout(12, 64, 256, impl="auto") == "bhtd"
+    assert attention_layout(12, 64, 256, impl="xla") == "bhtd"
+    assert attention_layout(12, 64, 256, impl="ring") == "bhtd"
+
+
+@pytest.mark.parametrize("dropout", [0.0, 0.1])
+def test_gpt_loss_and_gradients_equal_across_layouts(dropout):
+    """The whole model, both HBM interfaces: same parameters, same batch,
+    same dropout rng -> the same loss and the same gradient for every
+    parameter ('replicated' keeps the (B, H, T, D) entry; the keep-masks
+    agree because both key them on the global (b, h, q, k))."""
+    from nanosandbox_tpu.config import GPTConfig
+    from nanosandbox_tpu.models.gpt import GPT, attn_layout
+
+    T = 128
+    rng = np.random.default_rng(40)
+    idx = jnp.asarray(rng.integers(0, 64, size=(2, T)), jnp.int32)
+    tgt = jnp.asarray(rng.integers(0, 64, size=(2, T)), jnp.int32)
+
+    def loss_and_grads(stat_layout, want):
+        cfg = GPTConfig(n_layer=2, n_head=2, n_embd=128, block_size=T,
+                        vocab_size=64, dropout=dropout, bias=False,
+                        attention_impl="pallas_interpret",
+                        attention_stat_layout=stat_layout,
+                        compute_dtype="float32")
+        assert attn_layout(cfg, None, T) == want
+        model = GPT(cfg)
+        params = model.init(jax.random.PRNGKey(0), idx, deterministic=True)
+
+        def loss(p):
+            logits = model.apply(p, idx, deterministic=dropout == 0.0,
+                                 rngs={"dropout": jax.random.PRNGKey(7)})
+            logits = logits[0] if isinstance(logits, tuple) else logits
+            logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+            return -jnp.take_along_axis(logp, tgt[..., None], -1).mean()
+
+        return jax.value_and_grad(loss)(params)
+
+    l_new, g_new = loss_and_grads("compact", "btc")
+    l_old, g_old = loss_and_grads("replicated", "bhtd")
+    np.testing.assert_allclose(float(l_new), float(l_old), rtol=1e-6)
+    for a, b in zip(jax.tree.leaves(g_new), jax.tree.leaves(g_old)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-6, rtol=1e-4)

@@ -9,6 +9,10 @@ tests hold every later PR to what the chip accepts, at no chip time:
   * the training kernel ``flash_attention``, forward and backward in
     both stat layouts (and the in-kernel dropout variant), at
     (16, 12, 1024, 64) bf16;
+  * ``flash_attention_qkv``, the same kernels on the model's own
+    (B, T, 3C) layout, forward and backward, with and without dropout,
+    at both benchmark cells' shapes: (16, 1024, 2304) (12 heads) and
+    (8, 1024, 3072) (16 heads);
   * all nine serving variants — ``flash_decode`` / ``flash_decode_paged``
     / ``flash_prefill_paged`` x fp / int8 / int4 — at B=8, H=12, D=64,
     the engine's default page 16 and page 32, prefill T = a page and
@@ -26,6 +30,7 @@ for a described chip cannot be read back without one).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -34,10 +39,13 @@ from jax.sharding import SingleDeviceSharding
 
 from nanosandbox_tpu.ops import flash_decode as fd
 from nanosandbox_tpu.ops.attention import (flash_attention,
-                                           flash_attention_dropout)
+                                           flash_attention_dropout,
+                                           flash_attention_qkv)
 
 B, H, D, L = 8, 12, 64, 1024          # serving widths (GPT-2 124M heads)
 TRAIN_SHAPE = (16, 12, 1024, 64)       # the 124M train step's q/k/v
+# c_attn's output in the two benchmark cells: (qkv shape, heads)
+QKV_SHAPES = {"124m": ((16, 1024, 2304), 12), "medium": ((8, 1024, 3072), 16)}
 
 
 @pytest.fixture(scope="module")
@@ -101,6 +109,45 @@ def test_flash_attention_backward(sds, stat_layout, dropout):
         args = (x, x, x)
     txt = compiled_text(jax.grad(loss, argnums=(0, 1, 2)), *args)
     assert "tpu_custom_call" in txt
+
+
+# an HLO copy or transpose of a floating-point array (the dropout seed's
+# u32[1] move into scalar memory is not one)
+MOVES_AN_ACTIVATION = re.compile(r"= (?:bf16|f32)\[[^ ]* (?:copy|transpose)\(")
+
+
+def _qkv_call(shape, dropout, sds):
+    qkv_shape, n_head = QKV_SHAPES[shape]
+    rate = 0.1 if dropout else 0.0
+
+    def attend(qkv, seed):
+        return flash_attention_qkv(qkv, seed if dropout else None, n_head,
+                                   rate, False)
+
+    return attend, (sds(qkv_shape, jnp.bfloat16), sds((1,), jnp.uint32))
+
+
+@pytest.mark.parametrize("dropout", [False, True], ids=["plain", "dropout"])
+@pytest.mark.parametrize("shape", list(QKV_SHAPES))
+def test_flash_attention_qkv_forward(sds, shape, dropout):
+    attend, args = _qkv_call(shape, dropout, sds)
+    txt = compiled_text(attend, *args)
+    assert "tpu_custom_call" in txt
+    assert not MOVES_AN_ACTIVATION.search(txt)
+
+
+@pytest.mark.parametrize("dropout", [False, True], ids=["plain", "dropout"])
+@pytest.mark.parametrize("shape", list(QKV_SHAPES))
+def test_flash_attention_qkv_backward(sds, shape, dropout):
+    """Forward + backward kernels and nothing else: the gradient program
+    holds no transpose and no copy of any array, and the logsumexp goes
+    from one kernel to the other through a bitcast."""
+    attend, args = _qkv_call(shape, dropout, sds)
+    txt = compiled_text(
+        jax.grad(lambda qkv, seed: attend(qkv, seed).astype(
+            jnp.float32).sum()), *args)
+    assert txt.count("custom-call(") == 2
+    assert not MOVES_AN_ACTIVATION.search(txt)
 
 
 # -------------------------------------------------------------- serving

@@ -515,6 +515,18 @@ def test_http_router_tier_end_to_end(served_model):
         warm = body["replica"]
         assert body["prefix_digest"] == prefix_digests(
             list(range(40)), 16)
+        # A health poll that fetched the warm replica's summary BEFORE the
+        # request finished may land after the result report and replace
+        # the index with its stale (empty) set; the next poll restores it.
+        # Send the follower once the index has held the chain over a
+        # whole interval, or it is routed by load (a race in this test,
+        # seen about one run in ten on a loaded host).
+        deadline, held = time.time() + 5, 0
+        while held < 2:
+            assert time.time() < deadline, fe.router.stats()
+            held = held + 1 if fe.router.match_tokens(
+                warm, body["prefix_digest"]) else 0
+            time.sleep(0.15)
         st, body2, _ = _post(fe.port, "/generate",
                              {"prompt_tokens": list(range(32)) + [45],
                               "max_new_tokens": 2})

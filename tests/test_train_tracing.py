@@ -423,3 +423,24 @@ def test_per_step_span_overhead_pinned():
     runs.sort()
     assert runs[2] < 250e-6, f"five spans an iteration {runs[2] * 1e6:.1f}us"
     assert tr.open_count() == 0
+
+
+@pytest.mark.parametrize("case,want", [
+    ("cpu-auto", "bhtd"),            # 'auto' off the chip is the XLA path
+    ("kernels-one-device", "btc"),   # what a one-chip run resolves to
+    ("kernels-on-a-mesh", "bhtd"),   # shard_map shell: (B, H, T, D) entry
+])
+def test_trainer_init_records_attn_layout(tiny_cfg, case, want):
+    """Which HBM interface the step's attention takes is a set-up fact:
+    an argument of the ``trainer_init`` span, decided from impl, shapes
+    and mesh before anything is traced."""
+    tracer = process_tracer()
+    tracer.clear()
+    cfg = tiny_cfg.replace(n_embd=128, block_size=128)
+    if case != "cpu-auto":
+        cfg = cfg.replace(attention_impl="pallas_interpret")
+    one = case == "kernels-one-device"
+    trainer = Trainer(cfg, mesh_devices=jax.devices()[:1] if one else None)
+    assert trainer.mesh.size == (1 if one else len(jax.devices()))
+    (init,) = [s for s in tracer.spans() if s.name == "trainer_init"]
+    assert init.args["attn_layout"] == trainer.attn_layout == want

@@ -442,3 +442,70 @@ def test_flash_bwd_compiles_at_long_context(T):
                                "compact").astype(jnp.float32).sum()
 
     jax.jit(jax.grad(loss)).lower(x).compile()
+
+
+# -- the (B, T, heads*D) entry, compiled --------------------------------
+
+def _heads_major(x, H):
+    B, T, C = x.shape
+    return x.reshape(B, T, H, C // H).transpose(0, 2, 1, 3)
+
+
+def _xla_on_qkv(qkv, H):
+    B, T, C3 = qkv.shape
+    q, k, v = (_heads_major(x, H) for x in jnp.split(qkv, 3, axis=-1))
+    o = xla_attention(q, k, v, causal=True)
+    return o.transpose(0, 2, 1, 3).reshape(B, T, C3 // 3)
+
+
+@pytest.mark.parametrize("T", [256, 640, 1024])
+@pytest.mark.parametrize("H,D", [(12, 64), (16, 64), (2, 128)])
+def test_qkv_entry_matches_xla_compiled(H, D, T):
+    """tests/test_attention.py::test_qkv_entry_matches_xla on the chip:
+    forward, and the gradient with respect to qkv, in bfloat16."""
+    from nanosandbox_tpu.ops.attention import flash_attention_qkv
+
+    rng = np.random.default_rng(30)
+    qkv = jnp.asarray(rng.normal(size=(2, T, 3 * H * D)) * 0.5, jnp.bfloat16)
+    w = jnp.asarray(rng.normal(size=(2, T, H * D)), jnp.float32)
+    new = lambda x: flash_attention_qkv(x, None, H, 0.0, False)
+    out = jax.jit(new)(qkv)
+    ref = _xla_on_qkv(qkv, H)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), atol=1e-2)
+    g = jax.jit(jax.grad(lambda x: (new(x).astype(jnp.float32) * w).sum()))(
+        qkv)
+    g_ref = jax.jit(jax.grad(
+        lambda x: (_xla_on_qkv(x, H).astype(jnp.float32) * w).sum()))(qkv)
+    g32, r32 = np.asarray(g, np.float32), np.asarray(g_ref, np.float32)
+    assert np.abs(g32 - r32).max() / max(np.abs(r32).max(), 1e-8) < 1e-2
+
+
+@pytest.mark.parametrize("H,D", [(12, 64), (2, 128)])
+def test_qkv_entry_dropout_equals_bhtd_entry_compiled(H, D):
+    """Same seed, same keep-mask (hash_dropout_keep_mask's, keyed on the
+    global (b, h, q, k)): output and gradients of the head-group kernels
+    equal the (B, H, T, D) entry's on the chip."""
+    from nanosandbox_tpu.ops.attention import (flash_attention_dropout,
+                                               flash_attention_qkv)
+
+    B, T, rate = 2, 1024, 0.1
+    rng = np.random.default_rng(31)
+    qkv = jnp.asarray(rng.normal(size=(B, T, 3 * H * D)) * 0.5, jnp.bfloat16)
+    w = jnp.asarray(rng.normal(size=(B, T, H * D)), jnp.float32)
+    seed = jnp.array([4242], jnp.uint32)
+
+    def old(x):
+        q, k, v = (_heads_major(t, H) for t in jnp.split(x, 3, axis=-1))
+        o = flash_attention_dropout(q, k, v, seed, True, None, rate, False,
+                                    "compact")
+        return o.transpose(0, 2, 1, 3).reshape(B, T, H * D)
+
+    new = lambda x: flash_attention_qkv(x, seed, H, rate, False)
+    np.testing.assert_allclose(np.asarray(jax.jit(new)(qkv), np.float32),
+                               np.asarray(jax.jit(old)(qkv), np.float32),
+                               atol=2e-2)
+    grad = lambda f: jax.jit(jax.grad(
+        lambda x: (f(x).astype(jnp.float32) * w).sum()))(qkv)
+    g_new, g_old = (np.asarray(grad(f), np.float32) for f in (new, old))
+    assert np.abs(g_new - g_old).max() / np.abs(g_old).max() < 1e-2
