@@ -93,6 +93,29 @@ def write_bins(ids: np.ndarray, out_dir: str, meta: dict,
             "vocab_size": meta["vocab_size"]}
 
 
+def folded_name(dataset: str, vocab: int) -> str:
+    return f"{dataset}_mod{vocab}"
+
+
+def fold_vocab(src_dir: str, out_dir: str, vocab: int) -> dict:
+    """A prepared set with every id folded into [0, vocab) (``id mod
+    vocab``), same splits: the corpus for a model that holds a SLICE of a
+    vocabulary (one rank of a vocabulary-parallel job: its embedding and
+    head have ``vocab`` rows, and logits and loss are over the slice)."""
+    os.makedirs(out_dir, exist_ok=True)
+    stats = {"vocab_size": vocab}
+    for split in ("train", "val"):
+        ids = np.fromfile(os.path.join(src_dir, f"{split}.bin"),
+                          dtype=np.uint16)
+        (ids % vocab).astype(np.uint16).tofile(
+            os.path.join(out_dir, f"{split}.bin"))
+        stats[f"{split}_tokens"] = len(ids)
+    with open(os.path.join(out_dir, "meta.pkl"), "wb") as f:
+        pickle.dump({"vocab_size": vocab, "kind": "folded",
+                     "source": os.path.basename(src_dir)}, f)
+    return stats
+
+
 def prepare_char_dataset(out_dir: str, source_file: str | None = None,
                          url: str = TINY_SHAKESPEARE_URL,
                          allow_synthetic: bool = True) -> dict:
@@ -252,6 +275,9 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--num_chars", type=int,
                     default=int(os.environ.get("DATASET_NUM_CHARS", "0")) or None)
     ap.add_argument("--tokenizer", default="gpt2")
+    ap.add_argument("--fold_vocab", type=int, default=0,
+                    help="also write <dataset>_mod<N>: the same tokens with "
+                         "every id folded into [0, N) (fold_vocab)")
     # shakespeare_char is the smoke-test dataset: synthetic fallback stays on
     # by default (reference scale-down philosophy). openwebtext is a REAL
     # training corpus: silent synthetic data would invalidate runs, so it
@@ -289,6 +315,11 @@ def main(argv: list[str] | None = None) -> None:
             allow_synthetic=allow_synth,
             allow_byte_fallback=args.allow_byte_fallback)
     print(f"prepared {args.dataset} -> {out_dir}: {stats}")
+    if args.fold_vocab:
+        folded = os.path.join(args.data_dir,
+                              folded_name(args.dataset, args.fold_vocab))
+        print(f"folded -> {folded}: "
+              f"{fold_vocab(out_dir, folded, args.fold_vocab)}")
 
 
 if __name__ == "__main__":

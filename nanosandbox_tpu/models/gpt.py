@@ -51,6 +51,49 @@ def attn_layout(cfg: GPTConfig, mesh: Any, T: int) -> str:
         stat_layout=cfg.attention_stat_layout, mesh=mesh)
 
 
+def constrain_acts(mesh: Any, x: jax.Array) -> jax.Array:
+    """Pin (B, T, C) activations to batch-over-(data, fsdp) /
+    seq-over-seq / C-replicated at the embedding lookup and between
+    blocks. Without the anchor at the wte gather, SPMD has to invert a
+    sharding transition through a gather whose table is fsdp-sharded —
+    a move it only solves by involuntary full rematerialization
+    (replicate, then re-partition — the SPMD partitioner warns).
+    Free when the sharding already matches, which it does everywhere
+    else, so this is an anchor, not a resharding. Shared by every model
+    family (models/afmoe.py)."""
+    if mesh is None or mesh.size == 1:
+        return x
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(mesh, P(("data", "fsdp"), "seq", None)))
+
+
+def remat_block(block_cls, remat_policy: str, static_argnums=(2,)):
+    """``block_cls`` under jax.checkpoint, by ``remat_policy``.
+
+    'save_attention': save each block's attention output + the flash
+    kernel's logsumexp residual (tagged with checkpoint_name inside
+    ops/attention.py) so the backward never re-runs the O(T^2) forward
+    kernel — a remat region discards custom_vjp residuals, so without
+    the tags the flash forward would execute twice in the backward. The
+    saved bytes are O(B*T*C) per block; everything else (qkv dense, MLP)
+    recomputes cheaply. models/afmoe.py also tags its routed experts'
+    weighted sum ("moe_routed", as large as the block's output): its
+    recompute is k row gathers a token. 'full' is the classic save-nothing
+    trade."""
+    if remat_policy == "save_attention":
+        policy = jax.checkpoint_policies.save_only_these_names(
+            "attn_out", "attn_lse", "moe_routed")
+    elif remat_policy == "full":
+        policy = None
+    else:
+        raise ValueError(
+            f"unknown remat_policy: {remat_policy!r} "
+            "(expected 'save_attention' or 'full')")
+    return nn.remat(block_cls, static_argnums=static_argnums, policy=policy)
+
+
 class CausalSelfAttention(nn.Module):
     cfg: GPTConfig
     mesh: Any = None  # required for attention_impl='ring' (sequence parallel)
@@ -572,20 +615,7 @@ class GPT(nn.Module):
     mesh: Any = None  # bound by Trainer; needed for attention_impl='ring'
 
     def _constrain_acts(self, x: jax.Array) -> jax.Array:
-        """Pin (B, T, C) activations to batch-over-(data, fsdp) /
-        seq-over-seq / C-replicated at the embedding lookup and between
-        blocks. Without the anchor at the wte gather, SPMD has to invert a
-        sharding transition through a gather whose table is fsdp-sharded —
-        a move it only solves by involuntary full rematerialization
-        (replicate, then re-partition — the SPMD partitioner warns).
-        Free when the sharding already matches, which it does everywhere
-        else, so this is an anchor, not a resharding."""
-        if self.mesh is None or self.mesh.size == 1:
-            return x
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(self.mesh, P(("data", "fsdp"), "seq", None)))
+        return constrain_acts(self.mesh, x)
 
     @nn.compact
     def __call__(self, idx: jax.Array, *, deterministic: bool = True,
@@ -674,25 +704,7 @@ class GPT(nn.Module):
 
         block_cls = Block
         if cfg.remat:
-            # 'save_attention': save each block's attention output + the
-            # flash kernel's logsumexp residual (tagged with
-            # checkpoint_name inside ops/attention.py) so the backward
-            # never re-runs the O(T^2) forward kernel — a remat region
-            # discards custom_vjp residuals, so without the tags the
-            # flash forward would execute twice in the backward. The
-            # saved bytes are O(B*T*C) per block; everything else (qkv
-            # dense, MLP) recomputes cheaply. 'full' is the classic
-            # save-nothing trade.
-            if cfg.remat_policy == "save_attention":
-                policy = jax.checkpoint_policies.save_only_these_names(
-                    "attn_out", "attn_lse")
-            elif cfg.remat_policy == "full":
-                policy = None
-            else:
-                raise ValueError(
-                    f"unknown remat_policy: {cfg.remat_policy!r} "
-                    "(expected 'save_attention' or 'full')")
-            block_cls = nn.remat(Block, static_argnums=(2,), policy=policy)
+            block_cls = remat_block(Block, cfg.remat_policy)
         for i in range(cfg.n_layer):
             x = self._constrain_acts(
                 block_cls(cfg, mesh=self.mesh, name=f"h_{i}")(x, deterministic))

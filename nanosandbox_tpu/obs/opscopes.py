@@ -3,7 +3,9 @@
 XLA keeps, on every instruction of a compiled program, the scope path JAX
 traced it under (``metadata={op_name="jit(traced)/transpose(jvp(GPT))/h_3/
 mlp/c_fc/dot_general"}``), backward pass included. Flax names the modules
-(``h_3/attn/c_attn``, ``mlp``, ``ln_1``, ``ln_f``, ``wte``, ``wpe``) and
+(``h_3/attn/c_attn``, ``mlp``, ``ln_1``, ``ln_f``, ``wte``, ``wpe``; for
+models/afmoe.py ``attn_sliding`` / ``attn_full``, ``moe_shared``, ``ln_in``
+...) and
 the trainer adds ``jax.named_scope`` where no module names the work
 (``lm_head_loss``, ``optimizer``, ``grad_norm``, ``accum``). A device trace
 names its events by instruction (``%fusion.24 = ...``), a name the compiler
@@ -31,7 +33,11 @@ from typing import Callable, Dict, Optional
 # (parameters, copies the compiler added, the accumulation's own adds) are
 # ``UNSCOPED``.
 PARTS = ("attn", "mlp", "ln", "embed", "lm_head_loss", "optimizer",
-         "grad_norm")
+         "grad_norm",
+         # models/afmoe.py: its attention modules are named by their kind
+         # (nothing of it falls under "attn"), its expert layer by stage.
+         "attn_sliding", "attn_full", "moe_route", "moe_experts",
+         "moe_shared")
 UNSCOPED = "unscoped"
 
 # Path component -> part. ``wte.attend`` is the tied head's matmul where the
@@ -43,6 +49,13 @@ _COMPONENT = {
     "wte": "embed", "wpe": "embed",
     "wte.attend": "lm_head_loss", "lm_head_loss": "lm_head_loss",
     "optimizer": "optimizer", "grad_norm": "grad_norm",
+    # models/afmoe.py. Its q_norm / k_norm, rotary positions and output gate
+    # name no part of their own and ride with the attention module's.
+    "attn_sliding": "attn_sliding", "attn_full": "attn_full",
+    "moe_route": "moe_route", "moe_experts": "moe_experts",
+    "moe_shared": "moe_shared",
+    "ln_in": "ln", "ln_post_attn": "ln", "ln_pre_mlp": "ln",
+    "ln_post_mlp": "ln", "lm_head": "lm_head_loss",
 }
 
 # `%fusion.24 = f32[...] fusion(...), ..., metadata={... op_name="..." ...}`;

@@ -74,10 +74,12 @@ NEG_INF = -1e30
 LANES = 128  # minor-dim register width; row stats are replicated across it
 
 __all__ = ["causal_attention", "causal_attention_qkv", "attention_layout",
-           "xla_attention", "flash_attention", "flash_attention_dropout",
-           "flash_attention_lse", "flash_attention_lse_dropout",
-           "flash_attention_qkv", "hash_dropout_keep_mask",
-           "qkv_layout_supported", "resolve_attention_impl"]
+           "causal_attention_gqa", "xla_attention", "flash_attention",
+           "flash_attention_dropout", "flash_attention_lse",
+           "flash_attention_lse_dropout", "flash_attention_qkv",
+           "flash_attention_gqa", "gqa_layout_supported",
+           "hash_dropout_keep_mask", "qkv_layout_supported",
+           "resolve_attention_impl"]
 
 
 # ---------------------------------------------------------------------------
@@ -161,8 +163,12 @@ def _apply_dropout(x: jax.Array, keep: jax.Array, rate: float) -> jax.Array:
 def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                   *, causal: bool = True, sm_scale: float | None = None,
                   dropout_rate: float = 0.0,
-                  dropout_rng: jax.Array | None = None) -> jax.Array:
+                  dropout_rng: jax.Array | None = None,
+                  window: int | None = None) -> jax.Array:
     """Plain attention; XLA fuses this adequately for short-T and CPU tests.
+
+    window (causal only): key j is visible to query i iff j <= i and
+    i - j < window; None is full causal attention.
 
     dropout_rate/dropout_rng apply inverted dropout to the softmax weights
     (nanoGPT's attn_dropout; the reference model regularizes attention
@@ -175,6 +181,8 @@ def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if causal:
         T = q.shape[2]
         mask = jnp.tril(jnp.ones((T, T), dtype=bool))
+        if window is not None:
+            mask = mask & ~jnp.tril(jnp.ones((T, T), dtype=bool), -window)
         s = jnp.where(mask[None, None, :, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     if dropout_rate > 0.0 and dropout_rng is not None:
@@ -1600,6 +1608,339 @@ def _flash_qkv_bwd_rule(n_head, dropout_rate, interpret, res, do):
 flash_attention_qkv.defvjp(_flash_qkv_fwd_rule, _flash_qkv_bwd_rule)
 
 
+# ---------------------------------------------------------------------------
+# The grouped-query, windowed entry: q (B, T, H*D), k / v (B, T, G*D)
+# ---------------------------------------------------------------------------
+#
+# A model whose query heads share KV heads (H // G query heads read one KV
+# head) and whose layers bound how far back a query looks (key j visible to
+# query i iff j <= i and i - j < window). Head size D % 128 == 0, so one
+# 128-lane-aligned column block of the last dimension IS one head: no lane
+# pairing, no zeroed lanes. The same tile functions (_fwd_tile, _bwd_tile,
+# _expand_stat_tile, _stat_column_to_row) and the compact statistic layout:
+#
+#   * forward and dQ: grid (B, H, q blocks); k and v arrive as whole-T
+#     blocks of KV head h // (H // G), so the 8 query heads of a KV head and
+#     all their q blocks reuse one fetch. The key-block walk has a LOWER
+#     bound beside _causal_kb_range's upper one (_window_kb_range); blocks
+#     wholly inside the window and below the diagonal run unmasked.
+#   * dK/dV: grid (B, G, key blocks, query heads of the KV head, q blocks
+#     walked), the last two in order: dk and dv of one (KV head, key block)
+#     accumulate in float32 scratch over the H // G query heads and the q
+#     blocks that can see the key block, and are written once. Only the q
+#     blocks inside causal + window reach are fetched (five of sixteen at
+#     T = 8192, window 2048, blocks of 512), one (block_q, D) tile a step.
+#
+# The backward is the split strategy (dQ and dK/dV in two kernels, the
+# score tile recomputed in each): a fused one-pass walk would keep a
+# (T, (H // G) * D) float32 dq resident, 32 MB at T = 8192.
+
+def gqa_layout_supported(head_dim: int, T: int) -> bool:
+    """Whether the grouped-query kernels can walk these shapes: whole
+    128-lane heads and whole 128-row blocks."""
+    return head_dim % LANES == 0 and T % LANES == 0
+
+
+def _window_kb_range(qi, block_q: int, block_k: int, window):
+    """(first, first-unmasked) key blocks of q block qi under ``window``:
+    the first block holding a key the block's first query still sees, and
+    the first block every query of the block sees whole. (0, 0) without a
+    window."""
+    if window is None:
+        return 0, 0
+    first = lax.div(jnp.maximum(qi * block_q - window + 1, 0), block_k)
+    whole = lax.div(jnp.maximum((qi + 1) * block_q - window, 0)
+                    + block_k - 1, block_k)
+    return first, whole
+
+
+def _visible(q_pos, k_pos, window):
+    mask = q_pos >= k_pos
+    if window is not None:
+        mask = jnp.logical_and(mask, q_pos - k_pos < window)
+    return mask
+
+
+def _walk_key_blocks(body, init, qi, block_q: int, block_k: int, window):
+    """fori over the key blocks q block qi sees: masked at the window's
+    edge, unmasked inside, masked at the diagonal."""
+    first, whole = _window_kb_range(qi, block_q, block_k, window)
+    inner_end, end = _causal_kb_range(qi, block_q, block_k)
+    carry = init
+    if window is not None:
+        whole = jnp.minimum(jnp.maximum(whole, first), inner_end)
+        carry = lax.fori_loop(first, whole,
+                              functools.partial(body, masked=True), carry)
+    carry = lax.fori_loop(whole, inner_end,
+                          functools.partial(body, masked=False), carry)
+    return lax.fori_loop(inner_end, end,
+                         functools.partial(body, masked=True), carry)
+
+
+def _flash_fwd_gqa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
+                          block_q: int, block_k: int, sm_scale: float,
+                          window):
+    """q_ref / o_ref (1, block_q, D) of query head h; k_ref / v_ref
+    (1, T, D) of its KV head; lse_ref (1, 1, 1, block_q)."""
+    qi = pl.program_id(2)
+    q = q_ref[0]
+    q_pos = qi * block_q + lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0)
+
+    def body(j, carry, *, masked: bool):
+        k = k_ref[0, pl.ds(j * block_k, block_k), :]
+        v = v_ref[0, pl.ds(j * block_k, block_k), :]
+        mask = None
+        if masked:
+            mask = _visible(q_pos, j * block_k + lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1), window)
+        return _fwd_tile(q, k, v, carry, sm_scale=sm_scale, mask=mask)
+
+    init = (jnp.zeros(q.shape, jnp.float32),
+            jnp.full((block_q, 1), NEG_INF, jnp.float32),
+            jnp.zeros((block_q, 1), jnp.float32))
+    acc, m, l = _walk_key_blocks(body, init, qi, block_q, block_k, window)
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+    lse_ref[0, 0] = _stat_column_to_row(m + jnp.log(l))
+
+
+def _flash_bwd_gqa_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                             dq_ref, *, block_q: int, block_k: int,
+                             sm_scale: float, window):
+    """dQ of one (query head, q block): the forward's walk with _bwd_tile.
+    lse_ref (1, 1, T // 128, 128), the head's compact statistic."""
+    qi = pl.program_id(2)
+    q = q_ref[0]
+    do = do_ref[0]
+    drow = jnp.sum(do.astype(jnp.float32) * o_ref[0].astype(jnp.float32),
+                   axis=1, keepdims=True)
+    lse = _expand_stat_tile(lse_ref[0, 0], qi * (block_q // LANES), block_q)
+    q_pos = qi * block_q + lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0)
+
+    def body(j, dq_acc, *, masked: bool):
+        k = k_ref[0, pl.ds(j * block_k, block_k), :]
+        v = v_ref[0, pl.ds(j * block_k, block_k), :]
+        mask = None
+        if masked:
+            mask = _visible(q_pos, j * block_k + lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1), window)
+        _, ds = _bwd_tile(q, k, v, do, lse, drow, sm_scale=sm_scale,
+                          mask=mask)
+        return dq_acc + lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    dq = _walk_key_blocks(body, jnp.zeros(q.shape, jnp.float32), qi,
+                          block_q, block_k, window)
+    dq_ref[0] = (dq * sm_scale).astype(dq_ref.dtype)
+
+
+def _gqa_q_steps(T: int, block_q: int, block_k: int, window) -> int:
+    """How many q blocks a key block's walk may have to visit."""
+    num_qb = T // block_q
+    if window is None:
+        return num_qb
+    return min(num_qb, (block_k + window - 2) // block_q + 2)
+
+
+def _flash_bwd_gqa_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                              dk_ref, dv_ref, dk_acc, dv_acc, *,
+                              block_q: int, block_k: int, sm_scale: float,
+                              window, num_qb: int):
+    """dK / dV of one (KV head, key block), summed over the query heads
+    that read the KV head (grid axis 3) and the q blocks that see the key
+    block (grid axis 4: q block start + step; steps past the sequence's
+    end, or past the window's reach, do nothing)."""
+    ki, r, step = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+    last = jnp.logical_and(r == pl.num_programs(3) - 1,
+                           step == pl.num_programs(4) - 1)
+    i = lax.div(ki * block_k, block_q) + step
+
+    @pl.when(jnp.logical_and(r == 0, step == 0))
+    def _zero():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    reaches = i < num_qb
+    whole = i * block_q >= (ki + 1) * block_k - 1
+    if window is not None:
+        reaches = jnp.logical_and(
+            reaches, i * block_q - ((ki + 1) * block_k - 1) < window)
+        whole = jnp.logical_and(
+            whole, (i + 1) * block_q - 1 - ki * block_k < window)
+
+    def tile(masked: bool):
+        q = q_ref[0]
+        do = do_ref[0]
+        k = k_ref[0]
+        drow = jnp.sum(do.astype(jnp.float32) * o_ref[0].astype(jnp.float32),
+                       axis=1, keepdims=True)
+        lse = _expand_stat_tile(lse_ref[0, 0], i * (block_q // LANES),
+                                block_q)
+        mask = None
+        if masked:
+            mask = _visible(
+                i * block_q + lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 0),
+                ki * block_k + lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 1), window)
+        p, ds = _bwd_tile(q, k, v_ref[0], do, lse, drow, sm_scale=sm_scale,
+                          mask=mask)
+        dv_acc[...] += lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_acc[...] += lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    pl.when(jnp.logical_and(reaches, whole))(
+        functools.partial(tile, False))
+    pl.when(jnp.logical_and(reaches, jnp.logical_not(whole)))(
+        functools.partial(tile, True))
+
+    @pl.when(last)
+    def _write():
+        dk_ref[0] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _gqa_geometry(q_shape, k_shape, n_head: int, n_kv_head: int):
+    B, T, HD = q_shape
+    D = HD // n_head
+    if (n_head % n_kv_head or k_shape != (B, T, n_kv_head * D)
+            or not gqa_layout_supported(D, T)):
+        raise ValueError(
+            f"flash_attention_gqa needs q (B, T, H*D), k / v (B, T, G*D) "
+            f"with G dividing H, D % {LANES} == 0 and T % {LANES} == 0; got "
+            f"q {q_shape}, k {k_shape}, H={n_head}, G={n_kv_head}")
+    return B, T, D, n_head // n_kv_head
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n_head", "n_kv_head", "window", "interpret", "scope"))
+def _pallas_flash_fwd_gqa(q, k, v, *, n_head: int, n_kv_head: int, window,
+                          interpret: bool = False, scope: str = KERNEL_SCOPE):
+    """-> (o (B, T, H*D), lse (B, H, 1, T) f32). Jitted like the (B, T, 3C)
+    calls: layers of one kind share one trace and one lowering; ``scope``
+    names the custom call (%<scope>.N) and its part in obs.opscopes."""
+    B, T, D, rep = _gqa_geometry(q.shape, k.shape, n_head, n_kv_head)
+    block_q, block_k = _clamp_blocks(T, DEFAULT_BLOCK, DEFAULT_BLOCK)
+    kernel = functools.partial(
+        _flash_fwd_gqa_kernel, block_q=block_q, block_k=block_k,
+        sm_scale=D ** -0.5, window=window)
+    kv_spec = pl.BlockSpec((1, T, D), lambda b, h, i: (b, 0, h // rep))
+    call = pl.pallas_call(
+        kernel,
+        grid=(B, n_head, T // block_q),
+        in_specs=[pl.BlockSpec((1, block_q, D), lambda b, h, i: (b, i, h)),
+                  kv_spec, kv_spec],
+        out_specs=[
+            pl.BlockSpec((1, block_q, D), lambda b, h, i: (b, i, h)),
+            pl.BlockSpec((1, 1, 1, block_q), lambda b, h, i: (b, h, 0, i)),
+        ],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct((B, n_head, 1, T), jnp.float32)],
+        compiler_params=None if interpret else _tpu_params(
+            "parallel", "parallel", "parallel"),
+        interpret=interpret,
+    )
+    with jax.named_scope(scope):
+        return call(q, k, v)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n_head", "n_kv_head", "window", "interpret", "scope"))
+def _pallas_flash_bwd_gqa(q, k, v, o, lse, do, *, n_head: int,
+                          n_kv_head: int, window, interpret: bool = False,
+                          scope: str = KERNEL_SCOPE):
+    """-> (dq, dk, dv) in the operands' shapes and dtypes."""
+    B, T, D, rep = _gqa_geometry(q.shape, k.shape, n_head, n_kv_head)
+    block_q, block_k = _clamp_blocks(T, DEFAULT_BLOCK, DEFAULT_BLOCK)
+    num_qb = T // block_q
+    stats = lse.reshape(B, n_head, T // LANES, LANES)
+    common = dict(block_q=block_q, block_k=block_k, sm_scale=D ** -0.5,
+                  window=window)
+    params = lambda *sem: None if interpret else _tpu_params(*sem)
+
+    q_blk = pl.BlockSpec((1, block_q, D), lambda b, h, i: (b, i, h))
+    kv_all = pl.BlockSpec((1, T, D), lambda b, h, i: (b, 0, h // rep))
+    dq_call = pl.pallas_call(
+        functools.partial(_flash_bwd_gqa_dq_kernel, **common),
+        grid=(B, n_head, num_qb),
+        in_specs=[q_blk, kv_all, kv_all, q_blk, q_blk,
+                  pl.BlockSpec((1, 1, T // LANES, LANES),
+                               lambda b, h, i: (b, h, 0, 0))],
+        out_specs=q_blk,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=params("parallel", "parallel", "parallel"),
+        interpret=interpret,
+    )
+
+    def q_rows(b, g, j, r, s):
+        i = jnp.minimum(lax.div(j * block_k, block_q) + s, num_qb - 1)
+        return (b, i, g * rep + r)
+
+    q_step = pl.BlockSpec((1, block_q, D), q_rows)
+    kv_blk = pl.BlockSpec((1, block_k, D), lambda b, g, j, r, s: (b, j, g))
+    dkv_call = pl.pallas_call(
+        functools.partial(_flash_bwd_gqa_dkv_kernel, num_qb=num_qb,
+                          **common),
+        grid=(B, n_kv_head, T // block_k, rep,
+              _gqa_q_steps(T, block_q, block_k, window)),
+        in_specs=[q_step, kv_blk, kv_blk, q_step, q_step,
+                  pl.BlockSpec((1, 1, T // LANES, LANES),
+                               lambda b, g, j, r, s: (b, g * rep + r, 0, 0))],
+        out_specs=[kv_blk, kv_blk],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
+                        pltpu.VMEM((block_k, D), jnp.float32)],
+        compiler_params=params("parallel", "parallel", "parallel",
+                               "arbitrary", "arbitrary"),
+        interpret=interpret,
+    )
+    with jax.named_scope(scope):
+        dq = dq_call(q, k, v, o, do, stats)
+        dk, dv = dkv_call(q, k, v, o, do, stats)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def flash_attention_gqa(q, k, v, n_head: int, n_kv_head: int,
+                        window: int | None = None, interpret: bool = False,
+                        scope: str = KERNEL_SCOPE):
+    """Causal flash attention with grouped KV heads and an optional window,
+    on the projections' own layout: q (B, T, H*D), k / v (B, T, G*D) ->
+    o (B, T, H*D); query head h reads KV head h // (H // G); key j is
+    visible to query i iff j <= i and (window is None or i - j < window).
+    Scores are scaled by D ** -0.5. Shapes must satisfy
+    gqa_layout_supported."""
+    return _pallas_flash_fwd_gqa(q, k, v, n_head=n_head, n_kv_head=n_kv_head,
+                                 window=window, interpret=interpret,
+                                 scope=scope)[0]
+
+
+def _flash_gqa_fwd_rule(q, k, v, n_head, n_kv_head, window, interpret, scope):
+    from jax.ad_checkpoint import checkpoint_name
+
+    o, lse = _pallas_flash_fwd_gqa(q, k, v, n_head=n_head,
+                                   n_kv_head=n_kv_head, window=window,
+                                   interpret=interpret, scope=scope)
+    o = checkpoint_name(o, "attn_out")  # see _flash_fwd_rule
+    return o, (q, k, v, o, checkpoint_name(lse, "attn_lse"))
+
+
+def _flash_gqa_bwd_rule(n_head, n_kv_head, window, interpret, scope, res, do):
+    q, k, v, o, lse = res
+    return _pallas_flash_bwd_gqa(q, k, v, o, lse, do, n_head=n_head,
+                                 n_kv_head=n_kv_head, window=window,
+                                 interpret=interpret, scope=scope)
+
+
+flash_attention_gqa.defvjp(_flash_gqa_fwd_rule, _flash_gqa_bwd_rule)
+
+
 def hash_dropout_keep_mask(seed, B: int, H: int, Tq: int, Tk: int, *,
                            q_off=0, k_off=0, b_off=0, h_off=0,
                            hash_heads: int | None = None,
@@ -1752,3 +2093,32 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if impl == "pallas_interpret":
         return flash_attention(q, k, v, True, sm_scale, True, stat_layout)
     raise ValueError(f"unknown attention impl: {impl!r}")
+
+
+def causal_attention_gqa(q: jax.Array, k: jax.Array, v: jax.Array,
+                         n_head: int, n_kv_head: int, *,
+                         window: int | None = None, impl: str = "auto",
+                         scope: str = KERNEL_SCOPE) -> jax.Array:
+    """Causal attention with grouped KV heads and an optional window from
+    q (B, T, H*D), k / v (B, T, G*D) to o (B, T, H*D). The Pallas impls take
+    flash_attention_gqa where the shapes allow (gqa_layout_supported:
+    decided from shapes at trace time, like attention_layout; a trainer's
+    8-token init batch is what does not) and 'xla' everything:
+    xla_attention over (B, H, T, D) with the KV heads repeated."""
+    impl = resolve_attention_impl(impl)
+    if impl not in ("pallas", "pallas_interpret", "xla"):
+        raise ValueError(
+            f"grouped-query attention has impls 'pallas', "
+            f"'pallas_interpret' and 'xla'; got {impl!r}")
+    B, T, HD = q.shape
+    D = HD // n_head
+    if impl != "xla" and gqa_layout_supported(D, T):
+        return flash_attention_gqa(q, k, v, n_head, n_kv_head, window,
+                                   impl == "pallas_interpret", scope)
+    heads = lambda x, n: x.reshape(B, T, n, D).transpose(0, 2, 1, 3)
+    rep = n_head // n_kv_head
+    o = xla_attention(heads(q, n_head),
+                      jnp.repeat(heads(k, n_kv_head), rep, axis=1),
+                      jnp.repeat(heads(v, n_kv_head), rep, axis=1),
+                      window=window)
+    return o.transpose(0, 2, 1, 3).reshape(B, T, HD)

@@ -10,6 +10,14 @@ collectives).
 
 A dim is only sharded when divisible by the axis size, so tiny test models
 fall back to replication rather than erroring.
+
+The ``afmoe`` family's leaves (models/afmoe.py: ``q_proj`` / ``k_proj`` /
+``v_proj`` / ``gate_proj`` / ``o_proj``, ``router``, the ``(experts, in,
+out)`` matrices ``w_gate`` / ``w_up`` / ``w_down``, ``lm_head``) take the
+fsdp rule like any other leaf (largest divisible dim; ``wte`` its rows).
+They have NO ``model`` rule and there is no expert axis: ``_tp_dim`` knows
+GPT-2's names only, and ``Trainer`` refuses ``mesh_tp`` > 1 and ``mesh_sp``
+> 1 for the family by name instead of replicating in silence.
 """
 
 from __future__ import annotations

@@ -29,7 +29,8 @@ from typing import Any
 
 import numpy as np
 
-from nanosandbox_tpu.config import GPTConfig, TrainConfig, load_config
+from nanosandbox_tpu.config import (MODEL_FAMILIES, AfmoeConfig, GPTConfig,
+                                    TrainConfig, load_config)
 from nanosandbox_tpu.obs import opscopes, process_tracer
 from nanosandbox_tpu.utils import tracecheck
 
@@ -137,6 +138,16 @@ def restore_for_inference(out_dir: str, *, step: int | None = None,
         step, args=ocp.args.Composite(extra=ocp.args.JsonRestore()))
     cfg = TrainConfig(**{**restored["extra"]["config"], "device": device,
                          "init_from": "resume", "out_dir": out_dir})
+    if cfg.model_family != "gpt2":
+        # sample.py, serve/ and models/convert.py all come through here.
+        raise NotImplementedError(
+            f"checkpoint {out_dir} holds a model of family "
+            f"{cfg.model_family!r}: inference (sample.py, serve/, convert) "
+            "runs models/gpt.py's cached decode only. Missing for this "
+            "family: a cache branch in its attention (grouped KV heads, "
+            "rotary positions at the cached offset, a window bound on the "
+            "keys read), paged pools for two kinds of layer, and a decode "
+            "path through the routed experts")
     # Unconditional pure-DP normalization (idempotent for already-pure-DP
     # configs): a saved EXPLICIT mesh_dp (e.g. 8 from a v4-8 run) must not
     # survive onto a host with a different device count any more than
@@ -208,13 +219,16 @@ class Trainer:
             # kernels read qkv (B, T, 3C) where it lies; 'bhtd': after the
             # transposes), decided from shapes and mesh at trace time.
             self.tracer.end(sid, args={
-                "attn_layout": getattr(self, "attn_layout", None)})
+                "attn_layout": getattr(self, "attn_layout", None),
+                "model_family": cfg.model_family,
+                **({"layer_types": cfg.layer_types,
+                    "experts_held": list(cfg.experts_held)}
+                   if cfg.model_family == "afmoe" else {})})
 
     def _init(self, cfg: TrainConfig, mesh_devices: list | None) -> None:
         import jax
 
         from nanosandbox_tpu.data.loader import BinDataset
-        from nanosandbox_tpu.models.gpt import GPT, attn_layout
         from nanosandbox_tpu.parallel.distributed import (
             maybe_initialize_distributed)
         from nanosandbox_tpu.parallel.mesh import (batch_sharding, make_mesh,
@@ -222,6 +236,9 @@ class Trainer:
         from nanosandbox_tpu.parallel.sharding import param_shardings
 
         self.cfg = cfg
+        if cfg.model_family not in MODEL_FAMILIES:
+            raise ValueError(f"unknown model_family {cfg.model_family!r} "
+                             f"(expected one of {MODEL_FAMILIES})")
         self.multi_host = maybe_initialize_distributed(
             cfg.coordinator_address, cfg.num_processes, cfg.process_id)
         self.process_index = jax.process_index()
@@ -280,7 +297,23 @@ class Trainer:
                       f"{hf_cfg.n_embd}d, vocab {hf_cfg.vocab_size}")
 
         vocab = cfg.vocab_size or self.dataset.vocab_size
-        self.model_cfg = GPTConfig.from_train_config(cfg, vocab)
+        if cfg.model_family == "afmoe":
+            if self._pretrained:
+                raise ValueError("init_from loads GPT-2 weights; "
+                                 "model_family='afmoe' starts from scratch")
+            if cfg.mesh_sp > 1 or cfg.mesh_tp > 1:
+                raise NotImplementedError(
+                    "model_family='afmoe' runs on the data and fsdp axes "
+                    f"only (got seq={cfg.mesh_sp}, model={cfg.mesh_tp}). "
+                    "Missing for seq: ring attention with grouped KV heads "
+                    "and a window (ops/ring_attention.py walks one KV head "
+                    "a query head, all keys). Missing for model: a rule in "
+                    "parallel/sharding.py for q/k/v/gate/o projections and "
+                    "expert matrices, and an expert axis with its exchange "
+                    "in parallel/mesh.py")
+            self.model_cfg = AfmoeConfig.from_train_config(cfg, vocab)
+        else:
+            self.model_cfg = GPTConfig.from_train_config(cfg, vocab)
 
         with span("make_mesh"):
             if cfg.mesh_slices:
@@ -295,9 +328,24 @@ class Trainer:
         set_current_mesh(self.mesh)
         # The mesh is bound to the model explicitly (ring attention needs
         # it); the global above is only a fallback for standalone model use.
-        self.model = GPT(self.model_cfg, mesh=self.mesh)
-        self.attn_layout = attn_layout(self.model_cfg, self.mesh,
-                                       cfg.block_size)
+        if cfg.model_family == "afmoe":
+            from nanosandbox_tpu.models.afmoe import Afmoe
+            from nanosandbox_tpu.ops.attention import (
+                gqa_layout_supported, resolve_attention_impl)
+
+            self.model = Afmoe(self.model_cfg, mesh=self.mesh)
+            # 'btc-gqa': the grouped-query kernels on the projections' own
+            # (B, T, heads*D) layout; 'bhtd': xla_attention.
+            self.attn_layout = "btc-gqa" if (
+                resolve_attention_impl(cfg.attention_impl) != "xla"
+                and gqa_layout_supported(cfg.head_dim, cfg.block_size)
+            ) else "bhtd"
+        else:
+            from nanosandbox_tpu.models.gpt import GPT, attn_layout
+
+            self.model = GPT(self.model_cfg, mesh=self.mesh)
+            self.attn_layout = attn_layout(self.model_cfg, self.mesh,
+                                           cfg.block_size)
         self.batch_sharding = batch_sharding(self.mesh)
         # Fail fast on batch/mesh mismatches instead of surfacing them later
         # as opaque pjit sharding errors (docs/playbook.md pitfalls).
@@ -432,6 +480,9 @@ class Trainer:
     # -- compiled steps ------------------------------------------------------
 
     def _loss_fn(self, params, x, y, rng):
+        """(loss, aux): aux is what the model reports of a step beside its
+        loss ({} for GPT-2; the expert layers' rows held, fullest expert
+        and dropped slots for afmoe)."""
         import jax
 
         from nanosandbox_tpu.models.gpt import (
@@ -450,26 +501,33 @@ class Trainer:
         # sequence parallelism the scan runs per-shard inside shard_map
         # (a scan over the T-sharded dim would otherwise force gathers,
         # and full logits at long context defeat the ring's memory story).
+        afmoe = self.cfg.model_family == "afmoe"
+
+        def apply(**kw):
+            out = self.model.apply({"params": params}, x,
+                                   deterministic=deterministic, **kw,
+                                   **kwargs)
+            return out if afmoe else (out, {})
+
         if self.loss_chunk_size > 0:
-            hidden = self.model.apply({"params": params}, x,
-                                      deterministic=deterministic,
-                                      return_hidden=True, **kwargs)
+            hidden, aux = apply(return_hidden=True)
+            # (vocab, C) either way: GPT-2's tied table, afmoe's own head.
+            head = params["lm_head"] if afmoe else params["wte"]["embedding"]
             with head_scope:
                 if self.mesh.shape["seq"] == 1:
                     return chunked_cross_entropy_loss(
-                        hidden, params["wte"]["embedding"], y,
+                        hidden, head, y,
                         chunk_size=self.loss_chunk_size,
-                        compute_dtype=self.cfg.compute_dtype)
+                        compute_dtype=self.cfg.compute_dtype), aux
                 return sharded_chunked_cross_entropy_loss(
-                    hidden, params["wte"]["embedding"], y, mesh=self.mesh,
+                    hidden, head, y, mesh=self.mesh,
                     chunk_size=self.loss_chunk_size,
-                    compute_dtype=self.cfg.compute_dtype)
-        # Full logits: the tied head's matmul is the model's own
-        # (`wte.attend`, which opscopes also counts as the head).
-        logits = self.model.apply({"params": params}, x,
-                                  deterministic=deterministic, **kwargs)
+                    compute_dtype=self.cfg.compute_dtype), aux
+        # Full logits: the head's matmul is the model's own (GPT-2's
+        # `wte.attend`, which opscopes also counts as the head).
+        logits, aux = apply()
         with head_scope:
-            return cross_entropy_loss(logits, y)
+            return cross_entropy_loss(logits, y), aux
 
     def train_rng(self, seed: int):
         """Root key of the TRAINING rng stream (dropout masks), honoring
@@ -488,8 +546,9 @@ class Trainer:
         accum = self.cfg.gradient_accumulation_steps
         params = state["params"]
 
+        loss_and_grad = jax.value_and_grad(self._loss_fn, has_aux=True)
         if accum == 1:
-            loss, grads = jax.value_and_grad(self._loss_fn)(params, x, y, rng)
+            (loss, aux), grads = loss_and_grad(params, x, y, rng)
         else:
             # x is (accum * batch_size, T): nanoGPT semantics — accumulation
             # multiplies the data per optimizer step, micro-batch stays
@@ -502,15 +561,17 @@ class Trainer:
                 loss_acc, grad_acc = carry
                 xm, ym, i = xy
                 r = None if rng is None else jax.random.fold_in(rng, i)
-                l, g = jax.value_and_grad(self._loss_fn)(params, xm, ym, r)
+                (l, aux), g = loss_and_grad(params, xm, ym, r)
                 return (loss_acc + l,
-                        jax.tree.map(jnp.add, grad_acc, g)), None
+                        jax.tree.map(jnp.add, grad_acc, g)), aux
 
             with jax.named_scope("accum"):
                 zero = jax.tree.map(jnp.zeros_like, params)
-                (loss, grads), _ = lax.scan(
+                (loss, grads), aux = lax.scan(
                     body, (jnp.zeros(()), zero),
                     (xs, ys, jnp.arange(accum)))
+                # Counters of the micro-steps: the fullest one of each.
+                aux = jax.tree.map(lambda a: a.max(axis=0), aux)
                 loss = loss / accum
                 grads = jax.tree.map(lambda g: g / accum, grads)
 
@@ -523,10 +584,10 @@ class Trainer:
                      "step": state["step"] + 1}
         with jax.named_scope("grad_norm"):
             grad_norm = optax.global_norm(grads)
-        return new_state, {"loss": loss, "grad_norm": grad_norm}
+        return new_state, {"loss": loss, "grad_norm": grad_norm, **aux}
 
     def _eval_step_fn(self, state, x, y):
-        return self._loss_fn(state["params"], x, y, None)
+        return self._loss_fn(state["params"], x, y, None)[0]
 
     def compiled_steps(self):
         if self._train_step is None:
@@ -721,6 +782,25 @@ class Trainer:
                 return train_step(state, xg, yg,
                                   jax.random.fold_in(rng, iter_num))
 
+    def _count_expert_rows(self, metrics: dict, iter_num: int) -> None:
+        """The expert layers' counters of a step whose loss has been read
+        (so the step is done and this waits for nothing): an instant in the
+        process tracer, rows held by layer, the fullest expert's rows and
+        the held (token, slot) pairs no chunk of the sorted walk covered
+        (ops/moe.py: 0 by construction). A dropped pair is a step computed
+        wrong: said loudly, once a log step."""
+        if "moe_dropped" not in metrics:
+            return
+        got = {k: np.asarray(metrics[k]).tolist()
+               for k in ("moe_held", "moe_max_rows", "moe_dropped")}
+        self.tracer.instant("moe_rows", cat="train",
+                            args={"iter": iter_num, **got})
+        if sum(got["moe_dropped"]) and self.is_main:
+            print(f"iter {iter_num}: {sum(got['moe_dropped'])} routed "
+                  f"(token, slot) pairs DROPPED (rows held by layer "
+                  f"{got['moe_held']}): ops/moe.py's chunks no longer "
+                  "cover its own bound", file=sys.stderr)
+
     # -- evaluation (nanoGPT estimate_loss) ----------------------------------
 
     def estimate_loss(self, state, eval_iters: int | None = None) -> dict:
@@ -771,6 +851,10 @@ class Trainer:
             abstract = jax.eval_shape(self._init_state,
                                       jax.random.key(0))
             self._n_params = count_params(abstract["params"])
+        if cfg.model_family == "afmoe":
+            from nanosandbox_tpu.models.afmoe import flops_per_token
+
+            return flops_per_token(m, cfg.block_size) * cfg.tokens_per_iter
         N = self._n_params - m.block_size * m.n_embd  # exclude wpe (nanoGPT)
         L, H, Q, T = m.n_layer, m.n_head, m.n_embd // m.n_head, cfg.block_size
         flops_per_token = 6 * N + 12 * L * H * Q * T
@@ -1007,6 +1091,7 @@ class Trainer:
                     # both has finished. (jaxlint does not follow
                     # train_iter's return value, so no suppression here.)
                     grad_norm = float(metrics["grad_norm"])
+                    self._count_expert_rows(metrics, iter_num)
                     lr = (float(self.lr_schedule(iter_num))
                           if callable(self.lr_schedule)
                           else self.lr_schedule)
@@ -1047,6 +1132,7 @@ class Trainer:
                 # not take a fallback for the real thing (chip_smoke.py).
                 "attention_impl": resolve_attention_impl(
                     self.model_cfg.attention_impl),
+                "model_family": cfg.model_family,
                 "loader_native": loader.native,
                 **{f"final_{k}_loss": v for k, v in losses.items()}}
 

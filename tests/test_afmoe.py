@@ -1,0 +1,435 @@
+"""The ``afmoe`` family (models/afmoe.py, ops/moe.py, the grouped-query
+windowed kernels of ops/attention.py) against the plain reference
+``chipbench/reference/afmoe.py``, which imports nothing of the program.
+Small sizes, CPU."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench import weights_afmoe
+from chipbench.reference import afmoe as ref
+from nanosandbox_tpu.config import AfmoeConfig, TrainConfig
+from nanosandbox_tpu.models import afmoe
+from nanosandbox_tpu.ops import attention as A
+from nanosandbox_tpu.ops import moe
+
+SIZES = {
+    "n_layer": 3, "n_head": 4, "n_kv_head": 2, "head_dim": 16, "n_embd": 32,
+    "vocab_size": 96, "block_size": 64,
+    "layer_types": ("sliding", "full", "sliding"), "sliding_window": 16,
+    "num_dense_layers": 1, "intermediate_size": 48,
+    "moe_intermediate_size": 24, "num_experts": 8, "num_experts_per_tok": 2,
+    "experts_held": (2, 4), "route_scale": 2.0, "route_norm": True,
+    "mup_enabled": True, "rope_theta": 10000.0, "rms_norm_eps": 1e-5,
+}
+
+
+def train_cfg(**kw) -> TrainConfig:
+    s = SIZES
+    base = dict(
+        model_family="afmoe", layer_types=",".join(s["layer_types"]),
+        compute_dtype="float32",
+        **{k: s[k] for k in s if k not in ("layer_types", "mup_enabled")})
+    return TrainConfig(**{**base, **kw})
+
+
+def model_cfg(**kw) -> AfmoeConfig:
+    return AfmoeConfig.from_train_config(train_cfg(**kw), SIZES["vocab_size"])
+
+
+@pytest.fixture(scope="module")
+def seeded():
+    params = weights_afmoe.make_params(SIZES, weights_afmoe.seed_key(3))
+    x = jax.random.randint(jax.random.key(1), (2, 65), 0, SIZES["vocab_size"])
+    return params, x[:, :-1], x[:, 1:]
+
+
+@pytest.fixture(scope="module")
+def ref_loss_and_grad(seeded):
+    params, x, y = seeded
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(lambda p: ref.loss_and_grad(p, x, y, SIZES))(params)
+
+
+def program_loss_and_grad(cfg, params, x, y):
+    return jax.jit(jax.value_and_grad(
+        lambda p: program_loss(cfg, p, x, y), has_aux=True))(params)
+
+
+def program_loss(cfg, params, x, y):
+    from nanosandbox_tpu.models.gpt import chunked_cross_entropy_loss
+
+    hidden, aux = afmoe.Afmoe(cfg).apply({"params": params}, x,
+                                         return_hidden=True)
+    return chunked_cross_entropy_loss(
+        hidden, params["lm_head"], y, chunk_size=32,
+        compute_dtype=cfg.compute_dtype), aux
+
+
+def flat(tree):
+    return weights_afmoe.flatten(tree)
+
+
+# -- the program against the plain reference ----------------------------------
+
+def test_weights_file_has_the_programs_layout(seeded):
+    params, x, _ = seeded
+    own = jax.eval_shape(afmoe.Afmoe(model_cfg()).init, jax.random.key(0),
+                         x)["params"]
+    assert (jax.tree.map(lambda a: (a.shape, a.dtype), own)
+            == jax.tree.map(lambda a: (a.shape, a.dtype), params))
+
+
+def test_logits_equal_the_reference_in_float32(seeded):
+    params, x, _ = seeded
+    with jax.default_matmul_precision("highest"):
+        got, aux = jax.jit(afmoe.Afmoe(model_cfg()).apply)(
+            {"params": params}, x)
+        want = jax.jit(lambda p: ref.logits_fn(p, x, SIZES))(params)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    assert aux["moe_dropped"].tolist() == [0, 0]
+
+
+@pytest.mark.parametrize("variant", ["plain", "remat", "megablox_interpret"])
+def test_loss_and_every_gradient_leaf_equal_the_reference(
+        seeded, ref_loss_and_grad, variant, monkeypatch):
+    params, x, y = seeded
+    cfg = model_cfg(remat=variant == "remat")
+    if variant == "megablox_interpret":  # what 'auto' is on a tpu backend
+        monkeypatch.setattr(moe, "resolve_gmm_impl", lambda impl: variant)
+    with jax.default_matmul_precision("highest"):
+        (loss, _), grads = program_loss_and_grad(cfg, params, x, y)
+    want_loss, want = ref_loss_and_grad
+    assert abs(float(loss) - float(want_loss)) < 2e-6
+    got, want = flat(grads), flat(want)
+    assert got.keys() == want.keys()
+    for name in want:
+        scale = float(jnp.abs(want[name]).max()) + 1e-8
+        assert float(jnp.abs(got[name] - want[name]).max()) < 2e-4 * scale + 1e-7, name
+
+
+def test_bfloat16_compute_stays_near_the_reference(seeded, ref_loss_and_grad):
+    """bfloat16 matmul inputs: the loss within 2e-2 and every gradient
+    leaf's norm within 5 % of the float32 reference's (or of the median
+    leaf's where the leaf's own is smaller): rounding of 8-bit mantissas
+    through three layers, not another computation. A routing flip moves one
+    token's weight between experts; at this size none does."""
+    params, x, y = seeded
+    cfg = model_cfg(compute_dtype="bfloat16")
+    (loss, _), grads = program_loss_and_grad(cfg, params, x, y)
+    want_loss, want = ref_loss_and_grad
+    assert abs(float(loss) - float(want_loss)) < 2e-2
+    norm = lambda t: {k: float(jnp.linalg.norm(v)) for k, v in flat(t).items()}
+    got, want = norm(grads), norm(want)
+    floor = float(np.median(list(want.values())))
+    for name in want:
+        assert abs(got[name] - want[name]) < 0.05 * max(want[name], floor), name
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """Four shares of two experts each (eight of sixteen in the cell): every share's routed
+    part, plus the shared expert ONCE, is the reference's uncut layer."""
+    E, count = SIZES["num_experts"], 2
+    d, F = SIZES["n_embd"], SIZES["moe_intermediate_size"]
+    keys = jax.random.split(jax.random.key(5), 8)
+    normal = lambda k, *s: 0.2 * jax.random.normal(k, s, jnp.float32)
+    full = {"router": normal(keys[0], d, E),
+            "expert_bias": normal(keys[0], E),     # moves the selection too
+            "w_gate": normal(keys[1], E, d, F), "w_up": normal(keys[2], E, d, F),
+            "w_down": normal(keys[3], E, F, d),
+            "moe_shared": {
+                "gate_proj": {"kernel": normal(keys[4], d, F)},
+                "up_proj": {"kernel": normal(keys[5], d, F)},
+                "down_proj": {"kernel": normal(keys[6], F, d)}}}
+    m = jax.random.normal(keys[7], (2, 32, d), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        whole = ref._moe(full, m, {**SIZES, "experts_held": (0, E)},
+                         ref._ident, frozenset())
+        sh = full["moe_shared"]
+        shared = ref._swiglu(m, sh["gate_proj"]["kernel"],
+                             sh["up_proj"]["kernel"],
+                             sh["down_proj"]["kernel"], ref._ident)
+        total = shared
+        held = 0
+        for first in range(0, E, count):
+            cfg = model_cfg(experts_held=(first, count))
+            share = {**full, **{k: full[k][first:first + count]
+                                for k in ("w_gate", "w_up", "w_down")}}
+            out, stats = jax.jit(afmoe.Moe(cfg).apply)({"params": share}, m)
+            assert int(stats[2]) == 0
+            held += int(stats[0])
+            total = total + (out - shared)
+    assert held == m.shape[0] * m.shape[1] * SIZES["num_experts_per_tok"]
+    np.testing.assert_allclose(total, whole, atol=2e-5, rtol=2e-5)
+
+
+def test_selection_bias_moves_the_selection_and_not_the_weights(seeded):
+    params, x, y = seeded
+    cfg = model_cfg()
+    xs = jax.random.normal(jax.random.key(4), (64, SIZES["n_embd"]))
+    router = params["h_1"]["moe"]["router"]
+    sel0, w0 = afmoe.route(xs, router, jnp.zeros(8), cfg)
+    bias = jnp.zeros(8).at[5].set(10.0)         # expert 5 wins every token
+    sel1, w1 = afmoe.route(xs, router, bias, cfg)
+    assert bool((sel1 == 5).any(axis=1).all()) and not bool(
+        (sel0 == 5).any(axis=1).all())
+    # a pair both selections hold weighs by its own score, not score + bias
+    s = jax.nn.sigmoid(xs @ router)
+    got = jnp.take_along_axis(s, sel1, axis=1)
+    np.testing.assert_allclose(
+        w1, 2.0 * got / got.sum(-1, keepdims=True), rtol=1e-6)
+    # ... and no gradient reaches it
+    biased = weights_afmoe.make_params(
+        SIZES, weights_afmoe.seed_key(3),
+        0.3 * jax.random.normal(jax.random.key(6), (2, 8)))
+    _, grads = program_loss_and_grad(cfg, biased, x, y)
+    assert float(jnp.abs(grads["h_1"]["moe"]["expert_bias"]).max()) == 0.0
+    with jax.default_matmul_precision("highest"):
+        want, _ = jax.jit(lambda p: ref.loss_and_grad(p, x, y, SIZES))(biased)
+        got, _ = program_loss(cfg, biased, x, y)
+    assert abs(float(got) - float(want)) < 2e-6
+
+
+def test_the_references_balanced_bias_evens_the_load(seeded):
+    """The benchmark's selection bias, fitted by the reference alone: on
+    its own rows every expert of every expert layer draws within a tenth of
+    the even share where zeros leave the fullest at twice it or more, the
+    held experts' share is the even one, the values do not depend on the
+    bias the weights came with, and the program routes the same rows as
+    evenly with them."""
+    params, _, _ = seeded
+    E, k = SIZES["num_experts"], SIZES["num_experts_per_tok"]
+    first, count = SIZES["experts_held"]
+    rows = jax.random.randint(jax.random.key(8), (16, 64), 0,
+                              SIZES["vocab_size"])
+    with jax.default_matmul_precision("highest"):
+        fit = jax.jit(lambda p: ref.balanced_bias(p, rows, SIZES, 4))
+        bias, load = fit(params)
+        again, _ = fit(weights_afmoe.make_params(
+            SIZES, weights_afmoe.seed_key(3), jnp.ones((2, E))))
+    assert bias.shape == load.shape == (2, E)
+    np.testing.assert_array_equal(bias, again)
+    assert float(load.max()) < 1.1 and float(load.min()) > 0.9
+    np.testing.assert_allclose(load[:, first:first + count].mean(axis=1), 1.0,
+                               atol=0.03)
+
+    def held_rows(p):
+        _, aux = jax.jit(afmoe.Afmoe(model_cfg()).apply)({"params": p}, rows)
+        return np.asarray(aux["moe_held"]) / (rows.size * k * count / E)
+
+    plain = held_rows(params)
+    fitted = held_rows(weights_afmoe.make_params(
+        SIZES, weights_afmoe.seed_key(3), bias))
+    assert np.abs(fitted - 1).max() < 0.03 < np.abs(plain - 1).max()
+
+
+# -- attention -----------------------------------------------------------------
+
+def _dense_mask_attention(q, k, v, window):
+    T, D = q.shape[2], q.shape[3]
+    i, j = np.arange(T)[:, None], np.arange(T)[None, :]
+    visible = (j <= i) if window is None else (j <= i) & (i - j < window)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(D)
+    p = jax.nn.softmax(jnp.where(visible, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+@pytest.mark.parametrize("window", [None, 16, 24, 100])
+def test_window_mask_against_a_dense_mask(window):
+    """T = 64 > W: xla_attention's window is the dense mask's."""
+    q, k, v = (jax.random.normal(kk, (2, 3, 64, 8), jnp.float32)
+               for kk in jax.random.split(jax.random.key(2), 3))
+    with jax.default_matmul_precision("highest"):
+        got = A.xla_attention(q, k, v, window=window)
+        want = _dense_mask_attention(q, k, v, window)
+    np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-6)
+
+
+@pytest.mark.parametrize("window", [None, 256, 200, 64, 1000],
+                         ids=lambda w: f"window-{w}")
+def test_gqa_window_kernels_equal_xla_attention(monkeypatch, window):
+    """The Pallas kernels in interpret mode (blocks of 128 at T = 512:
+    window aligned to the blocks, across them, inside one, wider than T)
+    against xla_attention with the same mask: output and all three
+    gradients."""
+    monkeypatch.setattr(A, "DEFAULT_BLOCK", 128)
+    B, T, H, G, D = 1, 512, 4, 2, 128
+    ks = jax.random.split(jax.random.key(0), 4)
+    q = jax.random.normal(ks[0], (B, T, H * D), jnp.float32)
+    k = jax.random.normal(ks[1], (B, T, G * D), jnp.float32)
+    v = jax.random.normal(ks[2], (B, T, G * D), jnp.float32)
+    w = jax.random.normal(ks[3], (B, T, H * D), jnp.float32)
+
+    def run(impl):
+        def loss(q, k, v):
+            o = A.causal_attention_gqa(q, k, v, H, G, window=window,
+                                       impl=impl, scope="attn_sliding")
+            return jnp.sum(o * w), o
+        return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+            q, k, v)
+
+    with jax.default_matmul_precision("highest"):
+        (_, o_x), g_x = run("xla")
+        (_, o_p), g_p = run("pallas_interpret")
+    np.testing.assert_allclose(o_p, o_x, atol=1e-5, rtol=1e-5)
+    for got, want in zip(g_p, g_x):
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+def test_gqa_entry_refuses_shapes_it_cannot_walk():
+    x = jnp.zeros((1, 128, 4 * 64))
+    with pytest.raises(ValueError, match="D % 128"):
+        A.flash_attention_gqa(x, x[..., :128], x[..., :128], 4, 2, None, True)
+    with pytest.raises(ValueError, match="impls"):
+        A.causal_attention_gqa(x, x, x, 4, 4, impl="ring")
+
+
+def test_rotary_positions_only_on_window_layers(monkeypatch, seeded):
+    params, x, _ = seeded
+    calls = []
+    real = afmoe.rotary
+    monkeypatch.setattr(afmoe, "rotary",
+                        lambda t, theta: calls.append(t.shape) or real(t, theta))
+    afmoe.Afmoe(model_cfg()).apply({"params": params}, x)
+    # q and k of the two sliding layers; the full layer none
+    assert len(calls) == 2 * SIZES["layer_types"].count("sliding")
+    # and positions matter there: a rotated head is not the head
+    t = jax.random.normal(jax.random.key(0), (1, 8, 2, 16))
+    assert float(jnp.abs(real(t, 1e4) - t)[:, 1:].max()) > 1e-2
+    np.testing.assert_allclose(real(t, 1e4)[:, 0], t[:, 0], atol=1e-6)
+
+
+# -- the sorted buffer ----------------------------------------------------------
+
+def test_chunks_walk_the_held_pairs_sorted_by_expert():
+    rng = np.random.default_rng(0)
+    N, k, E, first, count, rows, chunks = 40, 3, 10, 4, 3, 16, 8
+    sel = np.stack([rng.permutation(E)[:k] for _ in range(N)]).astype(np.int32)
+    pairs = moe.plan_pairs(jnp.asarray(sel), first, count, rows * chunks)
+    held = [(e - first, n, j) for n in range(N) for j in range(k)
+            for e in [sel[n, j]] if first <= e < first + count]
+    want = sorted(held)                     # by expert, then (token, slot)
+    assert int(pairs["total"]) == len(want) > rows
+    assert int(pairs["max_rows"]) == max(
+        sum(1 for h in want if h[0] == e) for e in range(count))
+    seen = 0
+    for c in range(chunks):
+        plan = jax.tree.map(np.asarray, moe.chunk_plan(pairs, c, rows, k))
+        mine = want[c * rows:(c + 1) * rows]
+        assert plan["row_valid"].sum() == len(mine)
+        assert plan["group_sizes"].tolist() == [
+            sum(1 for h in mine if h[0] == e) for e in range(count)]
+        assert plan["row_pair"][:len(mine)].tolist() == [
+            n * k + j for _, n, j in mine]
+        for r, (_, n, j) in enumerate(mine):
+            assert plan["dest"][n, j] == r and plan["row_token"][r] == n
+        assert (plan["dest"] < rows).sum() == len(mine)
+        seen += len(mine)
+    assert seen == len(want)
+
+
+@pytest.mark.parametrize("factor", [100.0, 0.5, moe.ROWS_FACTOR])
+def test_a_router_biased_to_one_expert_drops_nothing(factor):
+    """Every token sends a slot to held expert 2, four times the expected
+    load: one chunk of the whole bound (factor 100), chunks of half the
+    expected load (0.5: four of them run) and of twice it all give the
+    same sum as the model's layer, and none drops a pair."""
+    cfg = model_cfg(block_size=2048)
+    d, E = SIZES["n_embd"], SIZES["num_experts"]
+    first, count = SIZES["experts_held"]
+    init = afmoe.Moe(cfg).init(jax.random.key(0), jnp.zeros((1, 8, d)))
+    router = np.zeros((d, E), np.float32)
+    router[:, 2] = 1.0                       # the first held expert (2..5)
+    params = {**init["params"], "router": jnp.asarray(router)}
+    m = jnp.abs(jax.random.normal(jax.random.key(1), (2, 2048, d))) + 0.1
+    x = m.reshape(-1, d)
+    sel, w = afmoe.route(x, params["router"], params["expert_bias"], cfg)
+    out, stats = jax.jit(moe.routed_experts, static_argnums=(6, 7, 8, 9))(
+        x, sel, w, params["w_gate"], params["w_up"], params["w_down"],
+        first, count, E, factor)
+    held, fullest, dropped = (int(v) for v in stats)
+    assert fullest == 2 * 2048 and held == fullest and dropped == 0
+    layer, _ = jax.jit(afmoe.Moe(cfg).apply)({"params": params}, m)
+    sh = params["moe_shared"]
+    shared = ref._swiglu(x, sh["gate_proj"]["kernel"], sh["up_proj"]["kernel"],
+                         sh["down_proj"]["kernel"], ref._ident)
+    np.testing.assert_allclose(out + shared, layer.reshape(-1, d),
+                               atol=1e-5, rtol=1e-5)
+    assert float(jnp.abs(out).max()) > 0
+
+
+def test_chunk_rows():
+    assert moe.chunk_rows(16384, 8, 128, 16) == (32768, 4)
+    assert moe.chunk_rows(16384, 8, 128, 16, 3.0) == (49152, 3)
+    assert moe.chunk_rows(16384, 8, 128, 16, 100.0) == (16384 * 8, 1)
+    assert moe.chunk_rows(16, 2, 8, 4, 1.0) == (moe.ROW_TILE, 1)
+
+
+# -- the trainer's normal path ---------------------------------------------------
+
+@pytest.fixture()
+def afmoe_train_cfg(char_dataset, tmp_path):
+    return train_cfg(
+        out_dir=str(tmp_path / "out"), data_dir=char_dataset,
+        dataset="shakespeare_char", vocab_size=0, batch_size=8,
+        max_iters=2, lr_decay_iters=2, eval_interval=0, eval_iters=1,
+        log_interval=1, warmup_iters=1, learning_rate=1e-3, min_lr=1e-4,
+        tensorboard=False, seed=0, loss_chunk_size=32, remat=True)
+
+
+def test_trainer_two_steps_save_restore_same_loss(afmoe_train_cfg):
+    from nanosandbox_tpu.checkpoint import Checkpointer
+    from nanosandbox_tpu.obs import opscopes, process_tracer
+    from nanosandbox_tpu.train import Trainer, restore_for_inference
+
+    cfg = afmoe_train_cfg
+    trainer = Trainer(cfg)
+    out = trainer.run()
+    assert out["iter_num"] == 2 and out["model_family"] == "afmoe"
+    assert np.isfinite(out["final_loss"])
+    init = [s for s in process_tracer().spans() if s.name == "trainer_init"][-1]
+    assert init.args["model_family"] == "afmoe"
+    assert init.args["experts_held"] == [2, 4]
+    assert init.args["layer_types"] == "sliding,full,sliding"
+    rows = [s for s in process_tracer().spans() if s.name == "moe_rows"][-1]
+    assert rows.args["moe_dropped"] == [0, 0] and len(rows.args["moe_held"]) == 2
+    parts = set(opscopes.step_parts().values())
+    assert {"attn_sliding", "attn_full", "moe_route", "moe_experts",
+            "moe_shared"} <= parts and "attn" not in parts
+
+    ckpt = Checkpointer(cfg.out_dir)
+    state, extra = ckpt.restore(trainer.abstract_state)
+    ckpt.close()
+    assert extra["config"]["model_family"] == "afmoe"
+    again = Trainer(dataclasses.replace(cfg, init_from="resume"))
+    state2, _ = Checkpointer(cfg.out_dir).restore(again.abstract_state)
+    loss = trainer.estimate_loss(state, eval_iters=1)
+    loss2 = again.estimate_loss(state2, eval_iters=1)
+    assert loss == loss2
+
+    with pytest.raises(NotImplementedError, match="cache branch"):
+        restore_for_inference(cfg.out_dir)
+
+
+@pytest.mark.parametrize("axis", ["mesh_sp", "mesh_tp"])
+def test_seq_and_model_axes_are_refused_by_name(afmoe_train_cfg, axis):
+    from nanosandbox_tpu.train import Trainer
+
+    extra = {"attention_impl": "ring"} if axis == "mesh_sp" else {}
+    with pytest.raises(NotImplementedError, match="data and fsdp axes"):
+        Trainer(dataclasses.replace(afmoe_train_cfg, **{axis: 2}, **extra))
+
+
+def test_config_says_what_is_missing():
+    with pytest.raises(ValueError, match="layer_types needs 3"):
+        AfmoeConfig.from_train_config(train_cfg(layer_types="sliding"), 96)
+    with pytest.raises(ValueError, match="experts_held inside"):
+        AfmoeConfig.from_train_config(train_cfg(experts_held=(6, 4)), 96)
+    assert model_cfg(experts_held=(0, 0)).experts_held == (0, 8)
+    with pytest.raises(ValueError, match="unknown model_family"):
+        from nanosandbox_tpu.train import Trainer
+        Trainer(TrainConfig(model_family="llama"))

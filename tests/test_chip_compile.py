@@ -13,6 +13,12 @@ tests hold every later PR to what the chip accepts, at no chip time:
     (B, T, 3C) layout, forward and backward, with and without dropout,
     at both benchmark cells' shapes: (16, 1024, 2304) (12 heads) and
     (8, 1024, 3072) (16 heads);
+  * ``flash_attention_gqa``, the grouped-query windowed kernels of the
+    ``afmoe`` family, forward and backward, at the Trinity-Mini cell's
+    shapes (2 x 8192 tokens, 32 query heads on 4 KV heads of 128, window
+    2048 and full), and the routed experts' grouped matmul (megablox,
+    forward, dgrad and wgrad) at the cell's buffer (32,768 rows, 16
+    experts, 2048 x 1024);
   * all nine serving variants — ``flash_decode`` / ``flash_decode_paged``
     / ``flash_prefill_paged`` x fp / int8 / int4 — at B=8, H=12, D=64,
     the engine's default page 16 and page 32, prefill T = a page and
@@ -40,6 +46,7 @@ from jax.sharding import SingleDeviceSharding
 from nanosandbox_tpu.ops import flash_decode as fd
 from nanosandbox_tpu.ops.attention import (flash_attention,
                                            flash_attention_dropout,
+                                           flash_attention_gqa,
                                            flash_attention_qkv)
 
 B, H, D, L = 8, 12, 64, 1024          # serving widths (GPT-2 124M heads)
@@ -114,6 +121,43 @@ def test_flash_attention_backward(sds, stat_layout, dropout):
 # an HLO copy or transpose of a floating-point array (the dropout seed's
 # u32[1] move into scalar memory is not one)
 MOVES_AN_ACTIVATION = re.compile(r"= (?:bf16|f32)\[[^ ]* (?:copy|transpose)\(")
+
+
+# Trinity-Mini's cell: (B, T, H, G, D)
+GQA_SHAPE = (2, 8192, 32, 4, 128)
+
+
+@pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
+def test_flash_attention_gqa_forward_and_backward(sds, window):
+    B, T, H, G, D = GQA_SHAPE
+    scope = "attn_sliding" if window else "attn_full"
+
+    def loss(q, k, v):
+        return flash_attention_gqa(q, k, v, H, G, window, False,
+                                   scope).astype(jnp.float32).sum()
+
+    q = sds((B, T, H * D), jnp.bfloat16)
+    kv = sds((B, T, G * D), jnp.bfloat16)
+    txt = compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    # forward, dQ and dK/dV, each named after its scope
+    assert len(set(re.findall(rf"%({scope}[.0-9]*) = ", txt))) == 3
+    assert not MOVES_AN_ACTIVATION.search(txt)
+
+
+def test_megablox_grouped_matmul_backward(sds):
+    from nanosandbox_tpu.ops.moe import grouped_matmul
+
+    def loss(xs, w, sizes):
+        return grouped_matmul(xs, w, sizes,
+                              impl="megablox").astype(jnp.float32).sum()
+
+    txt = compiled_text(
+        jax.grad(loss, argnums=(0, 1)), sds((32768, 2048), jnp.bfloat16),
+        sds((16, 2048, 1024), jnp.bfloat16), sds((16,), jnp.int32))
+    # dgrad (the forward product against the transposed weights) and wgrad;
+    # the forward's own output is not needed for this loss's gradient
+    calls = re.findall(r"%([\w.]*gmm[\w.]*) = [^\n]*custom-call\(", txt)
+    assert len(calls) == 2 and sum("tgmm" in c for c in calls) == 1, calls
 
 
 def _qkv_call(shape, dropout, sds):
