@@ -40,14 +40,26 @@ float32 accumulation; the residual stream, the norms, rotary positions,
 the output gate, the router (matmul at full float32 precision, sigmoid,
 top-k, weights) and the weighted sum of expert outputs in float32.
 
+Between the projections and the attention kernels, q and k stay
+(B, T, heads * D) in ``compute_dtype``. Where the grouped-query kernels run
+(ops.attention.resolve_gqa_impl: a Pallas impl and whole 128-lane heads over
+whole 128-row blocks), RMSNorm_q / RMSNorm_k and the rotary positions are ONE
+kernel a tensor, forward and backward (ops.attention.qk_prep: float32 in
+registers from the projection's output as it lies, no float32 copy of q or k
+in HBM); everywhere else head_rms_norm and rotary below, in XLA.
+
 Scopes (obs/opscopes.py): modules ``attn_sliding`` / ``attn_full``, ``mlp``,
 ``moe_shared``, the norms ``ln_*``, ``wte``; named scopes ``moe_route``
 (router, top-k, sort, gather, combine) and, inside it, ``moe_experts`` (the
-grouped matmuls and the activation between them, ops/moe.expert_ffn).
+grouped matmuls and the activation between them, ops/moe.expert_ffn);
+``qk_prep`` inside the attention modules (its custom calls are
+``%qk_prep.N``, apart from the flash kernels' ``%attn_sliding.N`` /
+``%attn_full.N``; it names no part, so its time is the module's).
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -62,7 +74,8 @@ from nanosandbox_tpu.config import AfmoeConfig
 from nanosandbox_tpu.models.gpt import (_dense_init, constrain_acts,
                                         remat_block)
 from nanosandbox_tpu.ops import moe
-from nanosandbox_tpu.ops.attention import causal_attention_gqa
+from nanosandbox_tpu.ops.attention import (causal_attention_gqa, qk_prep,
+                                           resolve_gqa_impl, rotary_table)
 
 # What a step reports of its expert layers, one entry a layer.
 STAT_NAMES = ("moe_held", "moe_max_rows", "moe_dropped")
@@ -80,31 +93,24 @@ def _rms_norm(cfg: AfmoeConfig, name: str) -> nn.RMSNorm:
                       param_dtype=cfg.param_dtype, name=name)
 
 
-class HeadRMSNorm(nn.Module):
-    """RMSNorm over the last (head) dimension of (B, T, heads, D), float32,
-    one scale of D shared by the heads. The mean of squares over a head's
-    lanes is taken as a product with the constant 1/D matrix at full
-    float32 precision, which leaves it in every lane with no cross-lane
-    reduce and broadcast: 3.8 against 7.6 ms for a layer's q, forward and
-    backward, at (2, 8192, 32, 128) (PERF.md §6, PR 29)."""
-    eps: float
-    param_dtype: str
-
-    @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
-        D = x.shape[-1]
-        scale = self.param("scale", nn.initializers.ones, (D,),
-                           jnp.dtype(self.param_dtype))
-        x = x.astype(jnp.float32)
-        mean_sq = jnp.einsum("bthd,de->bthe", x * x,
-                             jnp.full((D, D), 1.0 / D, jnp.float32),
-                             precision=lax.Precision.HIGHEST)
-        return x * lax.rsqrt(mean_sq + self.eps) * scale
+def head_rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """RMSNorm over the last (head) dimension of x (B, T, heads, D),
+    float32, one scale of D shared by the heads: the XLA path. The mean of
+    squares over a head's lanes is taken as a product with the constant 1/D
+    matrix at full float32 precision, which leaves it in every lane with no
+    cross-lane reduce and broadcast: 3.8 against 7.6 ms for a layer's q,
+    forward and backward, at (2, 8192, 32, 128) (PERF.md §6, PR 29)."""
+    D = x.shape[-1]
+    x = x.astype(jnp.float32)
+    mean_sq = jnp.einsum("bthd,de->bthe", x * x,
+                         jnp.full((D, D), 1.0 / D, jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+    return x * lax.rsqrt(mean_sq + eps) * scale
 
 
 def rotary(x: jax.Array, theta: float) -> jax.Array:
     """Rotate-half rotary positions 0..T-1 over all of the last dimension
-    of x (B, T, heads, D), float32.
+    of x (B, T, heads, D), float32: the XLA path.
 
     rotate_half(x) = [-x2, x1] is taken as a product with the fixed signed
     permutation matrix that says so, at full float32 precision: the same
@@ -112,16 +118,46 @@ def rotary(x: jax.Array, theta: float) -> jax.Array:
     concatenate of the 128 lanes it cost 20.0 ms a layer's q (forward and
     backward, (2, 8192, 32, 128)) against 4.6 ms (PERF.md §6, PR 29)."""
     T, D = x.shape[1], x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
-    angle = jnp.arange(T, dtype=jnp.float32)[:, None] * inv_freq[None]
-    cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)[None, :, None, :]
-    sin = jnp.concatenate([jnp.sin(angle)] * 2, -1)[None, :, None, :]
+    cos, sin = (t[None, :, None, :] for t in rotary_table(T, D, theta))
     lane = np.arange(D)
     half_turn = np.zeros((D, D), np.float32)
     half_turn[(lane + D // 2) % D, lane] = np.where(lane < D // 2, -1.0, 1.0)
     rotated = jnp.einsum("bthd,de->bthe", x, jnp.asarray(half_turn),
                          precision=lax.Precision.HIGHEST)
     return x * cos + rotated * sin
+
+
+class HeadRMSNorm(nn.Module):
+    """The prologue of attention for q or k as its projection leaves it,
+    x (B, T, heads*D) -> the same shape and dtype for the kernels: RMSNorm
+    over each head's D lanes (one leaf, ``scale`` (D,), shared by the heads),
+    then rotary positions where ``theta`` is given; float32 inside.
+
+    ``impl`` (ops.attention.resolve_gqa_impl, the predicate that picks the
+    attention kernels) picks the form. 'pallas' / 'pallas_interpret':
+    ops.attention.qk_prep, ONE kernel over x where it lies, forward and
+    backward (custom call ``%qk_prep.N``; PERF.md §6, PR 30). 'xla':
+    head_rms_norm and rotary above, float32 (B, T, heads, D) arrays in HBM
+    between them: what the CPU, the trainer's 8-token init batch and the
+    kernel's tests run."""
+    heads: int
+    eps: float
+    param_dtype: str
+
+    @nn.compact
+    def __call__(self, x: jax.Array, theta: float | None,
+                 impl: str) -> jax.Array:
+        B, T, HD = x.shape
+        D = HD // self.heads
+        scale = self.param("scale", nn.initializers.ones, (D,),
+                           jnp.dtype(self.param_dtype))
+        if impl != "xla":
+            return qk_prep(x, scale, self.heads, self.eps, theta,
+                           impl == "pallas_interpret")
+        y = head_rms_norm(x.reshape(B, T, self.heads, D), scale, self.eps)
+        if theta is not None:
+            y = rotary(y, theta)
+        return y.reshape(B, T, HD).astype(x.dtype)
 
 
 class Attention(nn.Module):
@@ -136,18 +172,19 @@ class Attention(nn.Module):
         B, T, _ = a.shape
         H, G, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
         dtype = jnp.dtype(cfg.compute_dtype)
-        q = _dense(cfg, H * D, "q_proj")(a).reshape(B, T, H, D)
-        k = _dense(cfg, G * D, "k_proj")(a).reshape(B, T, G, D)
+        q = _dense(cfg, H * D, "q_proj")(a)
+        k = _dense(cfg, G * D, "k_proj")(a)
         v = _dense(cfg, G * D, "v_proj")(a)
         gate = _dense(cfg, H * D, "gate_proj")(a)
-        q = HeadRMSNorm(cfg.rms_norm_eps, cfg.param_dtype, name="q_norm")(q)
-        k = HeadRMSNorm(cfg.rms_norm_eps, cfg.param_dtype, name="k_norm")(k)
-        if self.window is not None:  # full layers carry no positions
-            q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
-        o = causal_attention_gqa(
-            q.reshape(B, T, H * D).astype(dtype),
-            k.reshape(B, T, G * D).astype(dtype), v, H, G,
-            window=self.window, impl=cfg.attention_impl, scope=self.name)
+        # full layers carry no positions
+        theta = cfg.rope_theta if self.window is not None else None
+        impl = resolve_gqa_impl(cfg.attention_impl, D, T)
+        norm = functools.partial(HeadRMSNorm, eps=cfg.rms_norm_eps,
+                                 param_dtype=cfg.param_dtype)
+        q = norm(H, name="q_norm")(q, theta, impl)
+        k = norm(G, name="k_norm")(k, theta, impl)
+        o = causal_attention_gqa(q, k, v, H, G, window=self.window,
+                                 impl=impl, scope=self.name)
         gated = o.astype(jnp.float32) * jax.nn.sigmoid(
             gate.astype(jnp.float32))
         return _dense(cfg, cfg.n_embd, "o_proj")(gated.astype(dtype))

@@ -49,7 +49,8 @@ _COMPONENT = {
     "wte": "embed", "wpe": "embed",
     "wte.attend": "lm_head_loss", "lm_head_loss": "lm_head_loss",
     "optimizer": "optimizer", "grad_norm": "grad_norm",
-    # models/afmoe.py. Its q_norm / k_norm, rotary positions and output gate
+    # models/afmoe.py. Its q_norm / k_norm with the rotary positions (the
+    # ``qk_prep`` kernel's scope, or the XLA path's ops) and its output gate
     # name no part of their own and ride with the attention module's.
     "attn_sliding": "attn_sliding", "attn_full": "attn_full",
     "moe_route": "moe_route", "moe_experts": "moe_experts",

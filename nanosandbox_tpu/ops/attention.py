@@ -78,8 +78,8 @@ __all__ = ["causal_attention", "causal_attention_qkv", "attention_layout",
            "flash_attention_dropout", "flash_attention_lse",
            "flash_attention_lse_dropout", "flash_attention_qkv",
            "flash_attention_gqa", "gqa_layout_supported",
-           "hash_dropout_keep_mask", "qkv_layout_supported",
-           "resolve_attention_impl"]
+           "hash_dropout_keep_mask", "qk_prep", "qkv_layout_supported",
+           "resolve_attention_impl", "resolve_gqa_impl", "rotary_table"]
 
 
 # ---------------------------------------------------------------------------
@@ -1941,6 +1941,171 @@ def _flash_gqa_bwd_rule(n_head, n_kv_head, window, interpret, scope, res, do):
 flash_attention_gqa.defvjp(_flash_gqa_fwd_rule, _flash_gqa_bwd_rule)
 
 
+# ---------------------------------------------------------------------------
+# The prologue of that entry: head RMSNorm, then rotary positions, in place
+# ---------------------------------------------------------------------------
+#
+# q and k leave their projections as (B, T, heads*D) in the compute dtype and
+# enter the kernels above in the same layout and dtype; between the two every
+# head's D lanes are normalised (one scale of D shared by the heads) and, in
+# layers that carry positions, rotated (rotate-half over all of D). Both
+# cross the lanes of a head, which XLA does as products with constant
+# (D, D) matrices at full float32 precision over float32 (B, T, heads, D)
+# arrays in HBM (models/afmoe.py's XLA path). Here a (rows, heads * D) block
+# is read once, in the dtype it has, and written once: float32 in registers,
+# the mean of squares a lane reduce and rotate-half a lane roll by D / 2.
+# The backward is one pass as well: it recomputes the norm from the saved
+# input, and leaves the scale's gradient as one (1, D) partial sum a program.
+
+QK_PREP_SCOPE = "qk_prep"   # names the custom calls: %qk_prep.N
+# A program's block, the largest of each that divides T and the heads. On a
+# v5e at (2, 8192, 32 * 128) the forward / backward take 0.44 / 0.68 ms at
+# 1024 rows x 4 heads against 0.33 / 0.49 ms of traffic at the memory's
+# peak; 512 x 4 is 0.49 / 0.73, 256 x 4 0.59 / 0.80, 512 x 2 0.60 / 0.84,
+# 2048 x 4 0.44 / 0.66 (PERF.md §6, PR 30).
+PREP_BLOCK_ROWS = (1024, 512, 256, LANES)
+PREP_BLOCK_HEADS = (4, 2, 1)
+
+
+def rotary_table(T: int, D: int, theta: float):
+    """(cos, sin), each (T, D) float32, of positions 0..T-1 for rotate-half
+    rotary over all D dimensions: both halves carry the same D / 2 angles."""
+    inv_freq = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+    angle = jnp.arange(T, dtype=jnp.float32)[:, None] * inv_freq[None]
+    return (jnp.concatenate([jnp.cos(angle)] * 2, -1),
+            jnp.concatenate([jnp.sin(angle)] * 2, -1))
+
+
+def _rotate_half_sin(T: int, D: int, theta: float):
+    """(cos, sin with the first half negated): rotate_half(y) * sin is
+    roll(y, D / 2) * that, the sign moved onto the table."""
+    cos, sin = rotary_table(T, D, theta)
+    sign = jnp.where(jnp.arange(D) < D // 2, -1.0, 1.0)
+    return cos, sin * sign
+
+
+def _qk_prep_fwd_kernel(x_ref, scale_ref, *refs, heads: int, D: int,
+                        eps: float):
+    """x_ref / o_ref (1, rows, heads * D); scale_ref (1, D) float32; with
+    positions, cos_ref / sin_ref (rows, D) before o_ref."""
+    *table, o_ref = refs
+    scale = scale_ref[...]
+    for h in range(heads):
+        lanes = slice(h * D, (h + 1) * D)
+        x = x_ref[0, :, lanes].astype(jnp.float32)
+        mean_sq = jnp.sum(x * x, axis=1, keepdims=True) * (1.0 / D)
+        y = x * lax.rsqrt(mean_sq + eps) * scale
+        if table:
+            cos_ref, sin_ref = table
+            y = y * cos_ref[...] + pltpu.roll(y, D // 2, 1) * sin_ref[...]
+        o_ref[0, :, lanes] = y.astype(o_ref.dtype)
+
+
+def _qk_prep_bwd_kernel(x_ref, scale_ref, dz_ref, *refs, heads: int, D: int,
+                        eps: float):
+    """dx_ref like x_ref; dscale_ref (1, 1, 1, 1, D): this program's sum
+    over its rows and heads of dy * normalised x."""
+    *table, dx_ref, dscale_ref = refs
+    scale = scale_ref[...]
+    dscale = jnp.zeros((1, D), jnp.float32)
+    for h in range(heads):
+        lanes = slice(h * D, (h + 1) * D)
+        x = x_ref[0, :, lanes].astype(jnp.float32)
+        mean_sq = jnp.sum(x * x, axis=1, keepdims=True) * (1.0 / D)
+        r = lax.rsqrt(mean_sq + eps)
+        n = x * r
+        dy = dz_ref[0, :, lanes].astype(jnp.float32)
+        if table:  # the transpose of rotate-half is its negative
+            cos_ref, sin_ref = table
+            dy = dy * cos_ref[...] - pltpu.roll(dy, D // 2, 1) * sin_ref[...]
+        dscale = dscale + jnp.sum(dy * n, axis=0, keepdims=True)
+        dn = dy * scale
+        proj = jnp.sum(dn * n, axis=1, keepdims=True) * (1.0 / D)
+        dx_ref[0, :, lanes] = (r * (dn - n * proj)).astype(dx_ref.dtype)
+    dscale_ref[0, 0, 0] = dscale
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n_head", "eps", "theta", "interpret"))
+def _pallas_qk_prep(x, scale, dz=None, *, n_head: int, eps: float, theta,
+                    interpret: bool = False):
+    """The forward (-> z like x) or, given the cotangent dz of z, the
+    backward (-> dx like x, dscale like scale). Grid (B, row blocks, head
+    groups), the head groups innermost so that a row block's table is
+    fetched once. Jitted like the kernel calls round it: one trace and one
+    lowering a (shape, pass, positions or not) variant, whatever the number
+    of layers."""
+    B, T, HD = x.shape
+    D = HD // n_head
+    if HD != n_head * D or scale.shape != (D,) or not gqa_layout_supported(
+            D, T):
+        raise ValueError(
+            f"qk_prep needs x (B, T, heads*D) and scale (D,) with "
+            f"D % {LANES} == 0 and T % {LANES} == 0; got x {x.shape}, "
+            f"scale {scale.shape}, heads={n_head}")
+    backward = dz is not None
+    rows = next(r for r in PREP_BLOCK_ROWS if T % r == 0)
+    heads = next(h for h in PREP_BLOCK_HEADS if n_head % h == 0)
+    grid = (B, T // rows, n_head // heads)
+    blk = pl.BlockSpec((1, rows, heads * D), lambda b, i, g: (b, i, g))
+    operands = [x, scale.astype(jnp.float32).reshape(1, D)]
+    in_specs = [blk, pl.BlockSpec((1, D), lambda b, i, g: (0, 0))]
+    if backward:
+        operands.append(dz)
+        in_specs.append(blk)
+    if theta is not None:
+        operands += _rotate_half_sin(T, D, theta)
+        in_specs += [pl.BlockSpec((rows, D), lambda b, i, g: (i, 0))] * 2
+    out_specs, out_shape = [blk], [jax.ShapeDtypeStruct(x.shape, x.dtype)]
+    if backward:
+        out_specs.append(pl.BlockSpec((1, 1, 1, 1, D),
+                                      lambda b, i, g: (b, i, g, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((*grid, 1, D), jnp.float32))
+    call = pl.pallas_call(
+        functools.partial(
+            _qk_prep_bwd_kernel if backward else _qk_prep_fwd_kernel,
+            heads=heads, D=D, eps=eps),
+        grid=grid, in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=None if interpret else _tpu_params(
+            "parallel", "parallel", "parallel"),
+        interpret=interpret,
+    )
+    with jax.named_scope(QK_PREP_SCOPE):
+        out = call(*operands)
+    if not backward:
+        return out[0]
+    return out[0], out[1].sum(axis=(0, 1, 2, 3)).astype(scale.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
+def qk_prep(x, scale, n_head: int, eps: float, theta: float | None = None,
+            interpret: bool = False):
+    """What stands between a projection and flash_attention_gqa, as one
+    pass: x (B, T, heads*D) -> the same shape and dtype, every head's D
+    lanes RMS-normalised (``x * rsqrt(mean(x^2) + eps) * scale``, scale (D,)
+    shared by the heads) and then, where ``theta`` is given, turned by
+    rotate-half rotary positions 0..T-1 (``y * cos + [-y2, y1] * sin``).
+    Computed in float32 from x as it lies; nothing of activation size is
+    kept for the backward but x. Shapes must satisfy gqa_layout_supported."""
+    return _pallas_qk_prep(x, scale, n_head=n_head, eps=eps, theta=theta,
+                           interpret=interpret)
+
+
+def _qk_prep_fwd_rule(x, scale, n_head, eps, theta, interpret):
+    z = _pallas_qk_prep(x, scale, n_head=n_head, eps=eps, theta=theta,
+                        interpret=interpret)
+    return z, (x, scale)
+
+
+def _qk_prep_bwd_rule(n_head, eps, theta, interpret, res, dz):
+    return _pallas_qk_prep(*res, dz, n_head=n_head, eps=eps, theta=theta,
+                           interpret=interpret)
+
+
+qk_prep.defvjp(_qk_prep_fwd_rule, _qk_prep_bwd_rule)
+
+
 def hash_dropout_keep_mask(seed, B: int, H: int, Tq: int, Tk: int, *,
                            q_off=0, k_off=0, b_off=0, h_off=0,
                            hash_heads: int | None = None,
@@ -2095,24 +2260,33 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     raise ValueError(f"unknown attention impl: {impl!r}")
 
 
+def resolve_gqa_impl(impl: str, head_dim: int, T: int) -> str:
+    """What the grouped-query entry and its prologue (qk_prep) run at these
+    shapes: 'pallas' / 'pallas_interpret' where the resolved impl is one and
+    the kernels can walk them (gqa_layout_supported: decided from shapes at
+    trace time, like attention_layout; a trainer's 8-token init batch is
+    what does not), 'xla' everywhere else."""
+    impl = resolve_attention_impl(impl)
+    if impl not in ("pallas", "pallas_interpret", "xla"):
+        raise ValueError(
+            f"grouped-query attention has impls 'pallas', "
+            f"'pallas_interpret' and 'xla'; got {impl!r}")
+    return impl if gqa_layout_supported(head_dim, T) else "xla"
+
+
 def causal_attention_gqa(q: jax.Array, k: jax.Array, v: jax.Array,
                          n_head: int, n_kv_head: int, *,
                          window: int | None = None, impl: str = "auto",
                          scope: str = KERNEL_SCOPE) -> jax.Array:
     """Causal attention with grouped KV heads and an optional window from
     q (B, T, H*D), k / v (B, T, G*D) to o (B, T, H*D). The Pallas impls take
-    flash_attention_gqa where the shapes allow (gqa_layout_supported:
-    decided from shapes at trace time, like attention_layout; a trainer's
-    8-token init batch is what does not) and 'xla' everything:
-    xla_attention over (B, H, T, D) with the KV heads repeated."""
-    impl = resolve_attention_impl(impl)
-    if impl not in ("pallas", "pallas_interpret", "xla"):
-        raise ValueError(
-            f"grouped-query attention has impls 'pallas', "
-            f"'pallas_interpret' and 'xla'; got {impl!r}")
+    flash_attention_gqa where the shapes allow (resolve_gqa_impl) and 'xla'
+    everything: xla_attention over (B, H, T, D) with the KV heads
+    repeated."""
     B, T, HD = q.shape
     D = HD // n_head
-    if impl != "xla" and gqa_layout_supported(D, T):
+    impl = resolve_gqa_impl(impl, D, T)
+    if impl != "xla":
         return flash_attention_gqa(q, k, v, n_head, n_kv_head, window,
                                    impl == "pallas_interpret", scope)
     heads = lambda x, n: x.reshape(B, T, n, D).transpose(0, 2, 1, 3)

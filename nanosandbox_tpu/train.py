@@ -222,7 +222,8 @@ class Trainer:
                 "attn_layout": getattr(self, "attn_layout", None),
                 "model_family": cfg.model_family,
                 **({"layer_types": cfg.layer_types,
-                    "experts_held": list(cfg.experts_held)}
+                    "experts_held": list(cfg.experts_held),
+                    "qk_prep": getattr(self, "qk_prep", None)}
                    if cfg.model_family == "afmoe" else {})})
 
     def _init(self, cfg: TrainConfig, mesh_devices: list | None) -> None:
@@ -330,16 +331,18 @@ class Trainer:
         # it); the global above is only a fallback for standalone model use.
         if cfg.model_family == "afmoe":
             from nanosandbox_tpu.models.afmoe import Afmoe
-            from nanosandbox_tpu.ops.attention import (
-                gqa_layout_supported, resolve_attention_impl)
+            from nanosandbox_tpu.ops.attention import resolve_gqa_impl
 
             self.model = Afmoe(self.model_cfg, mesh=self.mesh)
-            # 'btc-gqa': the grouped-query kernels on the projections' own
-            # (B, T, heads*D) layout; 'bhtd': xla_attention.
-            self.attn_layout = "btc-gqa" if (
-                resolve_attention_impl(cfg.attention_impl) != "xla"
-                and gqa_layout_supported(cfg.head_dim, cfg.block_size)
-            ) else "bhtd"
+            # What a full batch's attention resolves to, as the model will
+            # at trace time. 'pallas': q/k head norm + rotary as one kernel
+            # (ops.attention.qk_prep), then the grouped-query kernels, all
+            # on the projections' own (B, T, heads*D) layout ('btc-gqa');
+            # 'xla': head_rms_norm, rotary and xla_attention ('bhtd').
+            self.qk_prep = resolve_gqa_impl(cfg.attention_impl, cfg.head_dim,
+                                            cfg.block_size)
+            self.attn_layout = ("bhtd" if self.qk_prep == "xla"
+                                else "btc-gqa")
         else:
             from nanosandbox_tpu.models.gpt import GPT, attn_layout
 

@@ -94,13 +94,32 @@ def test_logits_equal_the_reference_in_float32(seeded):
     assert aux["moe_dropped"].tolist() == [0, 0]
 
 
-@pytest.mark.parametrize("variant", ["plain", "remat", "megablox_interpret"])
+# Heads of 128 over 128 positions: the smallest shapes the grouped-query
+# kernels and their prologue (ops.attention.qk_prep) take.
+KERNEL_SIZES = {**SIZES, "head_dim": 128, "block_size": 128}
+
+
+@pytest.mark.parametrize("variant", ["plain", "remat", "megablox_interpret",
+                                     "pallas_interpret"])
 def test_loss_and_every_gradient_leaf_equal_the_reference(
         seeded, ref_loss_and_grad, variant, monkeypatch):
     params, x, y = seeded
     cfg = model_cfg(remat=variant == "remat")
     if variant == "megablox_interpret":  # what 'auto' is on a tpu backend
         monkeypatch.setattr(moe, "resolve_gmm_impl", lambda impl: variant)
+    if variant == "pallas_interpret":    # ... and the attention kernels with
+        # their one-pass norm + rotary, under remat as the cell runs them
+        cfg = model_cfg(attention_impl=variant, remat=True, head_dim=128,
+                        block_size=128)
+        params = weights_afmoe.make_params(KERNEL_SIZES,
+                                           weights_afmoe.seed_key(3))
+        x = jax.random.randint(jax.random.key(1), (2, 129), 0,
+                               SIZES["vocab_size"])
+        x, y = x[:, :-1], x[:, 1:]
+        assert A.resolve_gqa_impl(cfg.attention_impl, 128, 128) == variant
+        with jax.default_matmul_precision("highest"):
+            ref_loss_and_grad = jax.jit(
+                lambda p: ref.loss_and_grad(p, x, y, KERNEL_SIZES))(params)
     with jax.default_matmul_precision("highest"):
         (loss, _), grads = program_loss_and_grad(cfg, params, x, y)
     want_loss, want = ref_loss_and_grad
@@ -288,6 +307,58 @@ def test_gqa_entry_refuses_shapes_it_cannot_walk():
         A.causal_attention_gqa(x, x, x, 4, 4, impl="ring")
 
 
+def _prologue(impl, x, scale, heads, theta):
+    return afmoe.HeadRMSNorm(heads, 1e-5, "float32").apply(
+        {"params": {"scale": scale}}, x, theta, impl)
+
+
+@pytest.mark.parametrize("T", [128, 384])
+@pytest.mark.parametrize("theta", [10000.0, None], ids=["rotary", "none"])
+@pytest.mark.parametrize("heads", [32, 4])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_qk_prep_kernel_equals_head_norm_then_rotary(dtype, heads, theta, T):
+    """The one-pass kernel in interpret mode against head_rms_norm + rotary
+    (the XLA path, products at full precision): output, input gradient and
+    the scale's gradient; float32 to rounding, bfloat16 to one bfloat16
+    step of the largest value (both round the same float32 numbers)."""
+    D = 128
+    ks = jax.random.split(jax.random.key(heads + T), 3)
+    x = (2.0 * jax.random.normal(ks[0], (2, T, heads * D))).astype(dtype)
+    w = jax.random.normal(ks[1], (2, T, heads * D)).astype(dtype)
+    scale = 1.0 + 0.2 * jax.random.normal(ks[2], (D,))
+
+    def run(impl):
+        def loss(x, scale):
+            z = _prologue(impl, x, scale, heads, theta)
+            return jnp.sum((z * w).astype(jnp.float32)), z
+        return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
+            x, scale)
+
+    with jax.default_matmul_precision("highest"):
+        (_, z_x), (dx_x, ds_x) = run("xla")
+    (_, z_p), (dx_p, ds_p) = run("pallas_interpret")
+    assert z_p.dtype == dx_p.dtype == x.dtype and ds_p.shape == (D,)
+    f32 = lambda a: np.asarray(a, np.float32)
+    step = 2.0 ** -8 if dtype == "bfloat16" else 2e-6
+    for got, want in ((z_p, z_x), (dx_p, dx_x)):
+        assert np.abs(f32(got) - f32(want)).max() <= step * np.abs(
+            f32(want)).max()
+    np.testing.assert_allclose(ds_p, ds_x, rtol=2e-5,
+                               atol=2e-6 * float(jnp.abs(ds_x).max()))
+
+
+def test_qk_prep_refuses_shapes_it_cannot_walk():
+    with pytest.raises(ValueError, match="D % 128"):
+        A.qk_prep(jnp.zeros((1, 128, 4 * 64)), jnp.ones((64,)), 4, 1e-5,
+                  None, True)
+    with pytest.raises(ValueError, match="T % 128"):
+        A.qk_prep(jnp.zeros((1, 8, 4 * 128)), jnp.ones((128,)), 4, 1e-5,
+                  None, True)
+    assert A.resolve_gqa_impl("pallas_interpret", 128, 8) == "xla"
+    assert A.resolve_gqa_impl("pallas", 128, 8192) == "pallas"
+    assert A.resolve_gqa_impl("xla", 128, 8192) == "xla"
+
+
 def test_rotary_positions_only_on_window_layers(monkeypatch, seeded):
     params, x, _ = seeded
     calls = []
@@ -395,6 +466,7 @@ def test_trainer_two_steps_save_restore_same_loss(afmoe_train_cfg):
     assert init.args["model_family"] == "afmoe"
     assert init.args["experts_held"] == [2, 4]
     assert init.args["layer_types"] == "sliding,full,sliding"
+    assert init.args["qk_prep"] == "xla"    # heads of 16: no kernel takes them
     rows = [s for s in process_tracer().spans() if s.name == "moe_rows"][-1]
     assert rows.args["moe_dropped"] == [0, 0] and len(rows.args["moe_held"]) == 2
     parts = set(opscopes.step_parts().values())

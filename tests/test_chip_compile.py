@@ -19,6 +19,9 @@ tests hold every later PR to what the chip accepts, at no chip time:
     2048 and full), and the routed experts' grouped matmul (megablox,
     forward, dgrad and wgrad) at the cell's buffer (32,768 rows, 16
     experts, 2048 x 1024);
+  * ``qk_prep``, the one-pass head RMSNorm + rotary positions in front of
+    those kernels, forward and backward, at the cell's q (2, 8192, 4096) /
+    32 heads and k (2, 8192, 512) / 4 heads, with and without positions;
   * all nine serving variants — ``flash_decode`` / ``flash_decode_paged``
     / ``flash_prefill_paged`` x fp / int8 / int4 — at B=8, H=12, D=64,
     the engine's default page 16 and page 32, prefill T = a page and
@@ -47,7 +50,7 @@ from nanosandbox_tpu.ops import flash_decode as fd
 from nanosandbox_tpu.ops.attention import (flash_attention,
                                            flash_attention_dropout,
                                            flash_attention_gqa,
-                                           flash_attention_qkv)
+                                           flash_attention_qkv, qk_prep)
 
 B, H, D, L = 8, 12, 64, 1024          # serving widths (GPT-2 124M heads)
 TRAIN_SHAPE = (16, 12, 1024, 64)       # the 124M train step's q/k/v
@@ -142,6 +145,30 @@ def test_flash_attention_gqa_forward_and_backward(sds, window):
     # forward, dQ and dK/dV, each named after its scope
     assert len(set(re.findall(rf"%({scope}[.0-9]*) = ", txt))) == 3
     assert not MOVES_AN_ACTIVATION.search(txt)
+
+
+@pytest.mark.parametrize("theta", [10000.0, None], ids=["rotary", "none"])
+@pytest.mark.parametrize("heads", [32, 4], ids=["q-32-heads", "k-4-heads"])
+def test_qk_prep_forward_and_backward(sds, heads, theta):
+    """One custom call a pass, named apart from the flash kernels'
+    (%qk_prep.N), and no other pass over the activation: the float32
+    (B, T, heads, D) arrays of the XLA path are gone."""
+    B, T, _, _, D = GQA_SHAPE
+
+    def loss(x, scale):
+        return qk_prep(x, scale, heads, 1e-5, theta).astype(jnp.float32).sum()
+
+    args = (sds((B, T, heads * D), jnp.bfloat16), sds((D,), jnp.float32))
+    # the backward alone (this loss's gradient does not need the output),
+    # then the forward
+    for fn in (jax.grad(loss, argnums=(0, 1)),
+               lambda x, scale: qk_prep(x, scale, heads, 1e-5, theta)):
+        txt = compiled_text(fn, *args)
+        assert len(re.findall(r"%qk_prep[.0-9]* = [^\n]*custom-call\(",
+                              txt)) == 1
+        assert txt.count("custom-call(") == 1
+        assert not MOVES_AN_ACTIVATION.search(txt)
+        assert not re.search(rf"f32\[{B},{T},", txt)
 
 
 def test_megablox_grouped_matmul_backward(sds):

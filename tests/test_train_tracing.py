@@ -215,6 +215,12 @@ def test_lowering_for_the_map_keeps_the_live_budget_of_one(tiny_cfg):
     ("state['params']['h_0']['attn']['c_attn']['kernel']", "unscoped"),
     # ops the compiler merged carry several paths: the first with a part
     ("jit(traced)/mul;jit(traced)/jvp(GPT)/h_1/mlp/add", "mlp"),
+    # afmoe's one-pass q/k norm + rotary: a scope of its own that names no
+    # part, so the attention module round it decides, forward and backward
+    ("jit(traced)/jvp(Afmoe)/h_0/attn_sliding/q_norm/jit(_pallas_qk_prep)"
+     "/qk_prep/pallas_call", "attn_sliding"),
+    ("jit(traced)/transpose(jvp(Afmoe))/h_3/attn_full/k_norm/"
+     "jit(_pallas_qk_prep)/qk_prep/pallas_call", "attn_full"),
 ])
 def test_part_of_a_scope_path(op_name, part):
     assert opscopes.part_of(op_name) == part
@@ -444,3 +450,27 @@ def test_trainer_init_records_attn_layout(tiny_cfg, case, want):
     assert trainer.mesh.size == (1 if one else len(jax.devices()))
     (init,) = [s for s in tracer.spans() if s.name == "trainer_init"]
     assert init.args["attn_layout"] == trainer.attn_layout == want
+
+
+@pytest.mark.parametrize("case,want", [
+    ("cpu-auto", ("xla", "bhtd")),        # 'auto' off the chip
+    ("kernels", ("pallas_interpret", "btc-gqa")),   # 'pallas' on the chip
+    ("kernels-heads-of-64", ("xla", "bhtd")),       # no whole-lane heads
+])
+def test_trainer_init_records_qk_prep(tiny_cfg, case, want):
+    """The ``afmoe`` family's q/k head norm + rotary runs as one kernel
+    exactly where its attention runs the grouped-query kernels; which, is
+    an argument of ``trainer_init`` beside ``attn_layout``."""
+    tracer = process_tracer()
+    tracer.clear()
+    cfg = tiny_cfg.replace(
+        model_family="afmoe", n_layer=2, n_head=2, n_kv_head=1,
+        head_dim=64 if case == "kernels-heads-of-64" else 128,
+        block_size=128, layer_types="sliding,full", sliding_window=32,
+        num_dense_layers=1, intermediate_size=48, moe_intermediate_size=24,
+        num_experts=4, num_experts_per_tok=2, experts_held=(0, 2),
+        attention_impl="auto" if case == "cpu-auto" else "pallas_interpret")
+    trainer = Trainer(cfg, mesh_devices=jax.devices()[:1])
+    (init,) = [s for s in tracer.spans() if s.name == "trainer_init"]
+    assert (init.args["qk_prep"], init.args["attn_layout"]) == want
+    assert trainer.qk_prep == want[0]
