@@ -100,7 +100,7 @@ def preflight_impls() -> dict[str, str]:
     from nanosandbox_tpu.ops.attention import causal_attention
 
     status = {}
-    impls = (["pallas", "pallas_jax", "xla"]
+    impls = (["pallas", "xla"]
              if jax.default_backend() == "tpu" else
              ["pallas_interpret", "xla"])
     x = jax.ShapeDtypeStruct((1, 2, 128, 64), jnp.bfloat16)

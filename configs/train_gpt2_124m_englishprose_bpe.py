@@ -32,6 +32,6 @@ compute_dtype = "bfloat16"
 attention_impl = "auto"
 # loss_chunk_size stays on the -1 auto default: at 16x1024x50304 the f32
 # logits fit the 4 GB budget, so it resolves to 0 (full logits) — the
-# measured-faster path. perf_sweep --mode=autoconfig pins this config's
-# unpinned surface at the bench headline (benchmarks/r4/sweep_autoconfig.json).
+# measured-faster path. A July sweep of this config's unpinned surface is
+# kept as a record (benchmarks/r4/sweep_autoconfig.json).
 profile_steps = "1000:1003"

@@ -1,3 +1,39 @@
-"""Model zoo: decoder-only GPT (the reference's single model family)."""
+"""Model families. ``FAMILIES`` is the one place that knows which exist:
+``TrainConfig.model_family`` -> the family's module, imported on first use (a
+GPT-2 run imports no other family's kernels). The MODULE is the interface:
+``Trainer`` and ``restore_for_inference`` ask it, by these module-level names,
+what they would otherwise ask by the family's name:
 
-from nanosandbox_tpu.models.gpt import GPT, count_params, cross_entropy_loss  # noqa: F401
+    model_config(cfg, vocab)   the model's own config from a TrainConfig
+    check(cfg, pretrained)     raise for what of cfg the family cannot run
+    build(model_cfg, mesh)     (model, what ``trainer_init`` records of it:
+                               'attn_layout' and the family's own keys)
+    apply(model, params, x, *, deterministic, return_hidden, rngs)
+                               (logits or hidden, what a step reports beside
+                               its loss: a dict of arrays)
+    head(params)               the head's (vocab, C) table
+    flops_per_token(model_cfg, T, n_params)   forward + backward, a token
+    inference                  None, or what inference misses for the family
+    pretrained(cfg, dataset_meta)   (cfg, params) where ``init_from`` can
+                               name weights; a family without it refuses them
+
+A new family is its module (and kernels), one line here, its ``TrainConfig``
+keys and model-config class (config.py), its rows in ``opscopes._COMPONENT``.
+"""
+
+from __future__ import annotations
+
+import importlib
+
+FAMILIES = {
+    "gpt2": "nanosandbox_tpu.models.gpt",
+    "afmoe": "nanosandbox_tpu.models.afmoe",
+}
+
+
+def family_of(cfg):
+    """The module of ``cfg.model_family``."""
+    if cfg.model_family not in FAMILIES:
+        raise ValueError(f"unknown model_family {cfg.model_family!r} "
+                         f"(expected one of {tuple(FAMILIES)})")
+    return importlib.import_module(FAMILIES[cfg.model_family])

@@ -71,14 +71,17 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from nanosandbox_tpu.config import AfmoeConfig
-from nanosandbox_tpu.models.gpt import (_dense_init, constrain_acts,
-                                        remat_block)
+from nanosandbox_tpu.models.common import (_dense_init, constrain_acts,
+                                           remat_block)
 from nanosandbox_tpu.ops import moe
 from nanosandbox_tpu.ops.attention import (causal_attention_gqa, qk_prep,
                                            resolve_gqa_impl, rotary_table)
 
 # What a step reports of its expert layers, one entry a layer.
 STAT_NAMES = ("moe_held", "moe_max_rows", "moe_dropped")
+# What a block under remat keeps: the attention kernels' output and
+# logsumexp (ops/attention.py) and the routed experts' weighted sum (Moe).
+SAVED_NAMES = ("attn_out", "attn_lse", "moe_routed")
 
 
 def _dense(cfg: AfmoeConfig, features: int, name: str) -> nn.Dense:
@@ -243,8 +246,9 @@ class Moe(nn.Module):
                 x.astype(dtype), sel, w, w_gate.astype(dtype),
                 w_up.astype(dtype), w_down.astype(dtype), first, count,
                 cfg.num_experts)
-            # Saved under remat (models/gpt.remat_block): the norm after
-            # the layer needs it, and recomputing it is k row gathers.
+            # Saved under remat (SAVED_NAMES): as large as the block's
+            # output; the norm after the layer needs it, and recomputing it
+            # is k row gathers a token.
             routed = checkpoint_name(routed, "moe_routed").reshape(B, T, d)
         shared = SwiGLU(cfg, F, name="moe_shared")(m.astype(dtype))
         return shared.astype(jnp.float32) + routed, stats
@@ -299,7 +303,8 @@ class Afmoe(nn.Module):
         if cfg.mup_enabled:
             h = h * math.sqrt(cfg.n_embd)
         h = constrain_acts(self.mesh, h)
-        block_cls = (remat_block(Block, cfg.remat_policy, static_argnums=())
+        block_cls = (remat_block(Block, cfg.remat_policy, SAVED_NAMES,
+                                 static_argnums=())
                      if cfg.remat else Block)
         stats = []
         for i in range(cfg.n_layer):
@@ -317,11 +322,68 @@ class Afmoe(nn.Module):
                           head), aux
 
 
-def flops_per_token(cfg: AfmoeConfig, T: int) -> float:
+# -- the family's answers to Trainer (models/__init__.py: FAMILIES) ----------
+
+model_config = AfmoeConfig.from_train_config
+
+# What restore_for_inference misses for this family.
+inference = (
+    "a cache branch in its attention (grouped KV heads, rotary positions at "
+    "the cached offset, a window bound on the keys read), paged pools for "
+    "two kinds of layer, and a decode path through the routed experts")
+
+
+def check(cfg, pretrained: bool) -> None:
+    """What of a TrainConfig this family cannot run yet, refused by name
+    instead of replicating or attending wrongly in silence."""
+    if pretrained:
+        raise ValueError("init_from loads GPT-2 weights; "
+                         "model_family='afmoe' starts from scratch")
+    if cfg.mesh_sp > 1 or cfg.mesh_tp > 1:
+        raise NotImplementedError(
+            "model_family='afmoe' runs on the data and fsdp axes "
+            f"only (got seq={cfg.mesh_sp}, model={cfg.mesh_tp}). "
+            "Missing for seq: ring attention with grouped KV heads "
+            "and a window (ops/ring_attention.py walks one KV head "
+            "a query head, all keys). Missing for model: a rule in "
+            "parallel/sharding.py for q/k/v/gate/o projections and "
+            "expert matrices, and an expert axis with its exchange "
+            "in parallel/mesh.py")
+
+
+def build(cfg: AfmoeConfig, mesh: Any):
+    """(the model, what ``trainer_init`` records of it)."""
+    # What a full batch's attention resolves to, as the model will at trace
+    # time. 'pallas': q/k head norm + rotary as one kernel (qk_prep), then
+    # the grouped-query kernels, all on the projections' own
+    # (B, T, heads*D) layout ('btc-gqa'); 'xla': head_rms_norm, rotary and
+    # xla_attention ('bhtd').
+    prep = resolve_gqa_impl(cfg.attention_impl, cfg.head_dim, cfg.block_size)
+    return Afmoe(cfg, mesh=mesh), {
+        "attn_layout": "bhtd" if prep == "xla" else "btc-gqa",
+        "qk_prep": prep, "layer_types": ",".join(cfg.layer_types),
+        "experts_held": list(cfg.experts_held)}
+
+
+def apply(model: Afmoe, params, x: jax.Array, *, deterministic: bool,
+          return_hidden: bool, rngs=None):
+    """(logits or hidden, the expert layers' counters), as the model
+    returns them."""
+    return model.apply({"params": params}, x, deterministic=deterministic,
+                       return_hidden=return_hidden, rngs=rngs)
+
+
+def head(params) -> jax.Array:
+    """The head's (vocab, d) table: the model's own, untied."""
+    return params["lm_head"]
+
+
+def flops_per_token(cfg: AfmoeConfig, T: int, n_params: int) -> float:
     """Forward + backward operations a trained token requires here: 6 per
     parameter that multiplies it (one routed expert for each of the
     k * count / E held slots a token has on average) plus attention over
-    the (query, key) pairs the masks leave, 12 * H * D a pair."""
+    the (query, key) pairs the masks leave, 12 * H * D a pair. Counted
+    from the config: ``n_params`` is the hook's and not read."""
     d, H, G, D = cfg.n_embd, cfg.n_head, cfg.n_kv_head, cfg.head_dim
     attn_params = d * (2 * H * D + 2 * G * D) + H * D * d
     expert = 3 * d * cfg.moe_intermediate_size

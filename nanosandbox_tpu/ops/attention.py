@@ -2142,27 +2142,6 @@ def hash_dropout_keep_mask(seed, B: int, H: int, Tq: int, Tk: int, *,
 # Dispatch
 # ---------------------------------------------------------------------------
 
-def _jax_tpu_flash(q, k, v, sm_scale):
-    """The jax-shipped Mosaic flash kernel (impl='pallas_jax'). Kept as an
-    opt-in alternative: isolated fwd+bwd microbenchmarks on v5e slightly
-    favor it, but in the full GPT-2 train step it measures ~15% SLOWER than
-    this file's kernel (664 vs 563 ms/step at batch 32) and OOMs at batch
-    64 — its backward saves more residuals (measured July 2026 on an
-    earlier tree, not re-measured). Sequence lengths that are not
-    128-aligned (e.g. the Trainer's tiny init dummy batch) are zero
-    padded here; causal masking keeps real queries from seeing the pad."""
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        flash_attention as jflash)
-
-    T = q.shape[2]
-    pad_T = (-T) % 128
-    if pad_T:
-        pads = [(0, 0), (0, 0), (0, pad_T), (0, 0)]
-        q, k, v = (jnp.pad(x, pads) for x in (q, k, v))
-    out = jflash(q, k, v, causal=True, sm_scale=sm_scale)
-    return out[:, :, :T, :] if pad_T else out
-
-
 def resolve_attention_impl(impl: str) -> str:
     """'auto' means ONE thing per backend: this file's compiled Pallas
     kernel on tpu, XLA attention everywhere else. Nothing is probed and
@@ -2224,16 +2203,14 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     impl: 'auto' (Pallas on a tpu backend — a compile error propagates —
     and XLA on any other; see resolve_attention_impl), 'pallas',
-    'pallas_interpret' (for CPU tests), 'pallas_jax' (jax's library
-    kernel), or 'xla'.
+    'pallas_interpret' (for CPU tests), or 'xla'.
 
     stat_layout ('replicated' | 'compact'): the flash backward's softmax-
-    stat operand layout (--attention_stat_layout); ignored by the
-    xla/pallas_jax paths.
+    stat operand layout (--attention_stat_layout); ignored by the xla
+    path.
 
     Attention-probability dropout runs INSIDE the flash kernels
-    (flash_attention_dropout) for the pallas impls; 'pallas_jax' has no
-    dropout hook and falls back to the XLA path when dropout is active.
+    (flash_attention_dropout) for the pallas impls.
     The pallas and XLA paths draw different (equally valid) masks from the
     same rng — identical regularization statistics, different bits.
     """
@@ -2252,9 +2229,6 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         return xla_attention(q, k, v, causal=True, sm_scale=sm_scale)
     if impl == "pallas":
         return flash_attention(q, k, v, True, sm_scale, False, stat_layout)
-    if impl == "pallas_jax":
-        return _jax_tpu_flash(q, k, v, sm_scale if sm_scale is not None
-                              else q.shape[-1] ** -0.5)
     if impl == "pallas_interpret":
         return flash_attention(q, k, v, True, sm_scale, True, stat_layout)
     raise ValueError(f"unknown attention impl: {impl!r}")

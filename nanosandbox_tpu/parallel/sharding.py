@@ -16,8 +16,8 @@ The ``afmoe`` family's leaves (models/afmoe.py: ``q_proj`` / ``k_proj`` /
 out)`` matrices ``w_gate`` / ``w_up`` / ``w_down``, ``lm_head``) take the
 fsdp rule like any other leaf (largest divisible dim; ``wte`` its rows).
 They have NO ``model`` rule and there is no expert axis: ``_tp_dim`` knows
-GPT-2's names only, and ``Trainer`` refuses ``mesh_tp`` > 1 and ``mesh_sp``
-> 1 for the family by name instead of replicating in silence.
+GPT-2's names only, and the family's own ``check`` (models/afmoe.py) refuses
+``mesh_tp`` > 1 and ``mesh_sp`` > 1 instead of replicating in silence.
 """
 
 from __future__ import annotations

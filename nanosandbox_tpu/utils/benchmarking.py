@@ -1,4 +1,4 @@
-"""Shared train-step throughput measurement for bench.py / perf_sweep.
+"""Train-step throughput measurement for bench.py.
 
 Pipelined timing: enqueue all timed iters, sync once at the end. This is
 what the real train loop achieves under JAX async dispatch (it only reads
@@ -59,6 +59,6 @@ def measure_train_throughput(cfg, warmup: int, iters: int) -> dict:
         "loss": round(loss, 4),
         # Provenance: the value the measured Trainer ACTUALLY resolved
         # (auto chunk depends on per-device batch/mesh — reporting it from
-        # the source keeps sweep artifacts honest, perf_sweep autoconfig).
+        # the source keeps the record honest).
         "resolved_loss_chunk_size": trainer.loss_chunk_size,
     }

@@ -61,7 +61,7 @@ def program_loss_and_grad(cfg, params, x, y):
 
 
 def program_loss(cfg, params, x, y):
-    from nanosandbox_tpu.models.gpt import chunked_cross_entropy_loss
+    from nanosandbox_tpu.models.loss import chunked_cross_entropy_loss
 
     hidden, aux = afmoe.Afmoe(cfg).apply({"params": params}, x,
                                          return_hidden=True)

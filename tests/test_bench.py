@@ -1,6 +1,6 @@
-"""bench.py / perf_sweep contract tests (round-2 VERDICT weak #4-#5,
-ADVICE r2): batch-size semantics are per-chip everywhere, and the
-measurement helper rejects configurations it would silently mis-time."""
+"""bench.py contract tests (round-2 VERDICT weak #4-#5): batch-size
+semantics are per-chip everywhere, and the measurement helper rejects
+configurations it would silently mis-time."""
 
 import sys
 

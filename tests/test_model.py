@@ -123,7 +123,7 @@ def _chunk_case(B=2, T=12, C=32, V=65, seed=0):
 
 @pytest.mark.parametrize("chunk_size", [5, 4, 128])  # 5 does not divide 12
 def test_chunked_loss_matches_full_f32(chunk_size):
-    from nanosandbox_tpu.models.gpt import chunked_cross_entropy_loss
+    from nanosandbox_tpu.models.loss import chunked_cross_entropy_loss
 
     hidden, emb, targets = _chunk_case()
     logits = hidden @ emb.T
@@ -136,7 +136,7 @@ def test_chunked_loss_matches_full_f32(chunk_size):
 
 
 def test_chunked_loss_grads_match_full_f32():
-    from nanosandbox_tpu.models.gpt import chunked_cross_entropy_loss
+    from nanosandbox_tpu.models.loss import chunked_cross_entropy_loss
 
     hidden, emb, targets = _chunk_case(seed=1)
 
@@ -158,7 +158,7 @@ def test_chunked_loss_grads_match_full_f32():
 def test_chunked_loss_bf16_within_rounding_of_full():
     """Documented tradeoff: chunked feeds the MXU bf16 inputs while the
     full path casts to f32 — under bf16 they agree to bf16 rounding."""
-    from nanosandbox_tpu.models.gpt import chunked_cross_entropy_loss
+    from nanosandbox_tpu.models.loss import chunked_cross_entropy_loss
 
     hidden, emb, targets = _chunk_case(seed=2)
     full = cross_entropy_loss(hidden @ emb.T, targets)
@@ -169,7 +169,7 @@ def test_chunked_loss_bf16_within_rounding_of_full():
 
 
 def test_chunked_loss_all_ignored_is_zero():
-    from nanosandbox_tpu.models.gpt import chunked_cross_entropy_loss
+    from nanosandbox_tpu.models.loss import chunked_cross_entropy_loss
 
     hidden, emb, _ = _chunk_case()
     targets = jnp.full((2, 12), -1, jnp.int32)

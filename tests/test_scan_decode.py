@@ -1,10 +1,7 @@
 """Multi-token decode scan + int4 KV + paged-prefill kernel (ISSUE 12).
 
-The lag-k contract under test:
-  * greedy token PARITY: a scan_k in {2, 4, 8} engine emits exactly the
-    scan_k=1 engine's tokens across paged/dense pools and
-    fp32/int8/int4 KV modes — chunks are dispatch boundaries, not
-    sampling state;
+The lag-k contract under test (its greedy token PARITY across pools and KV
+modes is tests/test_scan_parity.py's):
   * a mid-chunk eos truncates exactly where the single-step loop would
     have stopped, with no leaked slots or KV blocks;
   * a poisoned MID-SCAN chunk recovers through the supervisor and the
@@ -21,56 +18,13 @@ The lag-k contract under test:
     lanes (the per-(row, head, position) residual-scale format).
 """
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from nanosandbox_tpu.config import GPTConfig
-from nanosandbox_tpu.models.gpt import GPT
+from _scan_common import _mixed_reqs, _run, served_model  # noqa: F401
 from nanosandbox_tpu.serve import Engine, EngineSupervisor
 from nanosandbox_tpu.serve.faults import FaultPlan
-
-
-@pytest.fixture(scope="module")
-def served_model():
-    cfg = GPTConfig(n_layer=2, n_head=2, n_embd=32, block_size=64,
-                    vocab_size=50, dropout=0.0, compute_dtype="float32",
-                    attention_impl="xla")
-    model = GPT(cfg)
-    params = model.init(jax.random.key(0),
-                        jnp.zeros((1, 8), jnp.int32))["params"]
-    return cfg, model, params
-
-
-def _mixed_reqs(n=10, seed=0, vocab=50, eos=None):
-    rng = np.random.default_rng(seed)
-    return [(rng.integers(0, vocab, int(rng.integers(2, 40))).tolist(),
-             int(rng.integers(2, 12)), int(rng.integers(0, 99)), eos)
-            for _ in range(n)]
-
-
-def _run(model, params, reqs, **kw):
-    eng = Engine(model, params, num_slots=4, max_len=64, **kw)
-    for prompt, mnt, seed, eos in reqs:
-        eng.submit(prompt, mnt, seed=seed, eos_id=eos)
-    out = {r.rid: (r.tokens, r.finish_reason) for r in eng.drain()}
-    assert len(out) == len(reqs)
-    return eng, out
-
-
-@pytest.mark.parametrize("paged", [True, False])
-@pytest.mark.parametrize("kv_dtype", [None, "int8", "int4"])
-def test_scan_greedy_parity_all_modes(served_model, paged, kv_dtype):
-    """scan_k in {2, 4, 8} vs single-step: token-identical outputs on a
-    mixed continuous-batching workload, per pool layout and KV mode."""
-    _, model, params = served_model
-    reqs = _mixed_reqs(seed=3)
-    _, base = _run(model, params, reqs, paged=paged, kv_dtype=kv_dtype)
-    for k in (2, 4, 8):
-        _, out = _run(model, params, reqs, paged=paged,
-                      kv_dtype=kv_dtype, scan_k=k)
-        assert out == base, f"scan_k={k} diverged"
 
 
 def test_scan_parity_survives_sync_loop(served_model):
