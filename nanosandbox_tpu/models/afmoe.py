@@ -55,6 +55,10 @@ grouped matmuls and the activation between them, ops/moe.expert_ffn);
 ``qk_prep`` inside the attention modules (its custom calls are
 ``%qk_prep.N``, apart from the flash kernels' ``%attn_sliding.N`` /
 ``%attn_full.N``; it names no part, so its time is the module's).
+The family's custom calls in a device trace: ``%attn_sliding.N`` /
+``%attn_full.N``, ``%qk_prep.N``, ``%gmm.N`` / ``%tgmm.N`` (megablox) and
+``%moe_rows.N`` (ops/moe.py's row mover: the routed experts' rows summed
+back to their tokens, inside ``moe_route``, so its time is that part's).
 """
 
 from __future__ import annotations
@@ -359,9 +363,15 @@ def build(cfg: AfmoeConfig, mesh: Any):
     # (B, T, heads*D) layout ('btc-gqa'); 'xla': head_rms_norm, rotary and
     # xla_attention ('bhtd').
     prep = resolve_gqa_impl(cfg.attention_impl, cfg.head_dim, cfg.block_size)
+    # What brings the routed experts' rows back to their tokens, resolved as
+    # ops.moe.routed_experts will for a batch of whole sequences (a batch is
+    # a multiple of block_size tokens): 'pallas' (%moe_rows.N, only the rows
+    # that hold a pair are moved) or 'xla' (k gathers a token).
+    mover = moe.resolve_row_mover("auto", cfg.block_size, cfg.n_embd)
     return Afmoe(cfg, mesh=mesh), {
         "attn_layout": "bhtd" if prep == "xla" else "btc-gqa",
-        "qk_prep": prep, "layer_types": ",".join(cfg.layer_types),
+        "qk_prep": prep, "moe_row_mover": mover,
+        "layer_types": ",".join(cfg.layer_types),
         "experts_held": list(cfg.experts_held)}
 
 

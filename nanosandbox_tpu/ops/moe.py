@@ -25,7 +25,25 @@ the same: the trainer's counter and the benchmark's ``fault`` read it.
 
 Both directions of dispatch and combine are GATHERS (custom VJPs below): a
 row knows its token (``row_token``), a (token, slot) pair knows its row
-(``dest``), so neither pass needs a scatter-add with repeated indices.
+(``dest``), so neither pass needs a scatter-add with repeated indices. What
+runs where:
+
+  * rows from tokens (dispatch, its recompute, combine's backward:
+    ``x[row_token]``, ``dout[row_token]``, ``w[row_pair]``): XLA gathers of
+    ``rows`` indices on every backend (1.1 / 1.3 / 0.2 ms each in the
+    Trinity-Mini cell's step; a kernel copying only the rows held was no
+    faster: PERF.md §6, PR 32).
+  * tokens from rows (combine, dispatch's backward): sum_j of a token's k
+    slots, of which k * count / E hold a pair; and ``dw_row[dest]``, one
+    number a pair. The 'xla' mover is k gathers of (N, d), 0.6 ms each
+    whether a slot is held or not (_rows_of_pairs), and a gather of N * k
+    numbers (0.94 ms). The 'pallas' mover (_pallas_rows_to_tokens,
+    _pallas_row_scalars_to_pairs; custom calls %moe_rows.N) reads the plan
+    from scalar memory and touches ONLY the pairs held: rows come by
+    asynchronous copies, a set of tokens' in flight while the set before is
+    summed, 0.9 ms a pass in the step against 5.9, the same float32 sums bit
+    for bit; dw in 0.43 ms. ``resolve_row_mover`` picks from the grouped
+    matmul's resolved ``impl`` and the shapes; there is no option.
 
 Grouped matmul, ``impl``: 'ragged_dot' (``jax.lax.ragged_dot``: XLA's own,
 every backend), 'megablox' (the Pallas kernels shipped with JAX,
@@ -44,12 +62,15 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["chunk_rows", "plan_pairs", "chunk_plan", "dispatch", "combine",
            "grouped_matmul", "expert_ffn", "routed_experts",
-           "resolve_gmm_impl", "GMM_IMPLS"]
+           "resolve_gmm_impl", "resolve_row_mover", "GMM_IMPLS", "MOVERS"]
 
 GMM_IMPLS = ("auto", "ragged_dot", "megablox", "megablox_interpret")
+MOVERS = ("pallas", "pallas_interpret", "xla")
 # What 'auto' means on a tpu backend: measured, one expert layer's three
 # products at (16,384 rows, 16 groups, 2048 x 1024) on a v5e (PERF.md §6).
 TPU_GMM_IMPL = "megablox"
@@ -58,6 +79,16 @@ ROW_TILE = 512
 # Rows of ONE chunk of the sorted pairs, as a multiple of the expected load.
 ROWS_FACTOR = 2.0
 MEGABLOX_TILING = (512, 1024, 1024)
+LANES = 128
+MOVE_SCOPE = "moe_rows"   # names the row mover's custom calls: %moe_rows.N
+# The row mover's walk: a program sums MOVE_STEP tokens, MOVE_TOKENS at a
+# time with the next MOVE_TOKENS' rows already in flight; the plan reaches
+# scalar memory in blocks of SMEM_BLOCK tokens (XLA tiles a 1-D 32-bit array
+# by 1024, and a block is whole tiles).
+MOVE_STEP = 256
+MOVE_TOKENS = 64
+SMEM_BLOCK = 1024
+SCALAR_BYTES = 512 * 1024   # of a chip's 1 MiB of scalar memory: one array
 
 
 def resolve_gmm_impl(impl: str) -> str:
@@ -129,11 +160,27 @@ def chunk_plan(pairs: dict, c, rows: int, k: int) -> dict:
     }
 
 
+def resolve_row_mover(impl: str, n_tokens: int, d: int) -> str:
+    """What moves rows back to their tokens (combine, and dispatch's
+    backward) at these shapes. It follows the grouped matmul's resolved
+    ``impl`` and what the kernel can walk, as
+    ``ops.attention.resolve_gqa_impl`` does: 'pallas' under 'megablox',
+    'pallas_interpret' under 'megablox_interpret', where a row is whole
+    128-lane tiles and the tokens whole programs; 'xla' (k gathers a token)
+    under 'ragged_dot' and everywhere else: a trainer's 8-token init batch
+    is what does not."""
+    impl = resolve_gmm_impl(impl)
+    if impl == "ragged_dot" or d % LANES or n_tokens % MOVE_STEP:
+        return "xla"
+    return "pallas" if impl == "megablox" else "pallas_interpret"
+
+
 def _rows_of_pairs(y: jax.Array, dest: jax.Array, w=None) -> jax.Array:
-    """sum_j w[:, j] * y[dest[:, j]] in float32 (w None: ones): one gather
-    of (N, d) a slot, rows out of bounds reading 0. Unrolled, not scanned:
-    XLA then sums the k gathers without a float32 (N, d) round trip between
-    them (5.8 against 7.4 ms at N = 16,384, k = 8, d = 2048, PERF.md §6)."""
+    """sum_j w[:, j] * y[dest[:, j]] in float32 (w None: ones), the 'xla'
+    mover: one gather of (N, d) a slot, rows out of bounds reading 0: XLA
+    visits all N * k indices, held or not (0.6 ms a slot at N = 16,384,
+    d = 2048 on a v5e). Unrolled, not scanned: XLA then sums the k gathers
+    without a float32 (N, d) round trip between them (PERF.md §6, PR 29)."""
     acc = 0.0
     for j in range(dest.shape[1]):
         got = y.at[dest[:, j]].get(mode="fill", fill_value=0)
@@ -142,36 +189,246 @@ def _rows_of_pairs(y: jax.Array, dest: jax.Array, w=None) -> jax.Array:
     return acc
 
 
-@jax.custom_vjp
-def dispatch(x: jax.Array, plan: dict) -> jax.Array:
+def _held_ranks(dest: jax.Array, rows: int):
+    """Per slot j, over the tokens padded to whole SMEM_BLOCKs: the slot's
+    row, whether it holds a pair (``dest < rows``), and how many held slots
+    the token has before it; then the tokens' counts of held slots."""
+    N, k = dest.shape
+    cols = [jnp.pad(dest[:, j], (0, -N % SMEM_BLOCK), constant_values=rows)
+            for j in range(k)]
+    held = [c < rows for c in cols]
+    rank, cnt = [], jnp.zeros(cols[0].shape, jnp.int32)
+    for h in held:
+        rank.append(cnt)
+        cnt = cnt + h.astype(jnp.int32)
+    return cols, held, rank, cnt
+
+
+def _by_rank(cols, held, rank):
+    """k columns by slot -> by rank among the token's held slots, as the
+    kernels read them from scalar memory: (k * Np,), a block of SMEM_BLOCK
+    tokens laid out rank-major, rank r of token t at r * SMEM_BLOCK + t."""
+    k = len(cols)
+    out = []
+    for r in range(k):              # slot j can only be a rank <= j
+        v = jnp.zeros_like(cols[0])
+        for j in range(r, k):
+            v = jnp.where(jnp.logical_and(held[j], rank[j] == r), cols[j], v)
+        out.append(v)
+    return jnp.stack(out).reshape(k, -1, SMEM_BLOCK).transpose(
+        1, 0, 2).reshape(-1)
+
+
+def _by_slot(got, held, rank, n_tokens: int):
+    """_by_rank's way back: (k * Np,) by rank -> (N, k) by slot, 0 in the
+    slots that hold no pair (whatever the kernel left there)."""
+    k = len(held)
+    got = got.reshape(-1, k, SMEM_BLOCK)            # (block, rank, token)
+    out = []
+    for j in range(k):
+        v = jnp.zeros(held[0].shape, got.dtype)
+        for r in range(j + 1):
+            v = jnp.where(jnp.logical_and(held[j], rank[j] == r),
+                          got[:, r].reshape(-1), v)
+        out.append(v[:n_tokens])
+    return jnp.stack(out, axis=1)
+
+
+def _rows_to_tokens_kernel(cnt_ref, src_ref, *refs, k: int, weighted: bool):
+    """One program: MOVE_STEP tokens of out_ref (step, c, 128), MOVE_TOKENS
+    at a time. Of a set of tokens, first every held slot's row is asked for
+    (one asynchronous copy of a whole (c, 128) tile from y_ref in HBM into
+    ``buf``, in (token, slot) order, with the token and the weight noted in
+    scalar memory); the set before it is then waited for and summed: float32
+    in ``acc``, the rows of a token in slot order, cast on the way out."""
+    if weighted:
+        w_ref, y_ref, out_ref, buf, acc, token_of, weight_of, sem = refs
+    else:
+        y_ref, out_ref, buf, acc, token_of, sem = refs
+    step, tokens = out_ref.shape[0], acc.shape[0]
+    room = tokens * k                       # a set's rows, at the bound
+    first = (pl.program_id(0) % (SMEM_BLOCK // step)) * step
+
+    def ask(s, half):
+        def token(i, n):
+            t = first + s * tokens + i
+
+            def slot(r, n):
+                at = half * room + n
+                pltpu.make_async_copy(y_ref.at[src_ref[r * SMEM_BLOCK + t]],
+                                      buf.at[at], sem.at[half]).start()
+                token_of[at] = i
+                if weighted:
+                    weight_of[at] = w_ref[r * SMEM_BLOCK + t]
+                return n + 1
+
+            return lax.fori_loop(0, cnt_ref[t], slot, n)
+
+        return lax.fori_loop(0, tokens, token, 0)
+
+    def add(s, half, n):
+        def wait(times):      # every copy moves one row: n waits of that size
+            def body(_, carry):
+                for _ in range(times):
+                    pltpu.make_async_copy(y_ref.at[0], buf.at[0],
+                                          sem.at[half]).wait()
+                return carry
+            return body
+
+        lax.fori_loop(0, n // 8, wait(8), 0)
+        lax.fori_loop(0, n % 8, wait(1), 0)
+        acc[...] = jnp.zeros(acc.shape, jnp.float32)
+
+        def row(p, carry):
+            at = half * room + p
+            got = buf[at].astype(jnp.float32)
+            i = token_of[at]
+            acc[i] = acc[i] + (got * weight_of[at] if weighted else got)
+            return carry
+
+        lax.fori_loop(0, n, row, 0)
+        out_ref[pl.ds(s * tokens, tokens)] = acc[...].astype(out_ref.dtype)
+
+    n = ask(0, 0)
+    for s in range(step // tokens):
+        ahead = ask(s + 1, (s + 1) % 2) if s + 1 < step // tokens else None
+        add(s, s % 2, n)
+        n = ahead
+
+
+@functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
+def _pallas_rows_to_tokens(y, dest, w=None, *, out_dtype,
+                           interpret: bool = False):
+    """sum_j w[:, j] * y[dest[:, j]] (w None: ones) as ``out_dtype`` (N, d),
+    float32 inside: _rows_of_pairs's sum with only the rows that hold a pair
+    moved. An unheld slot adds nothing where _rows_of_pairs adds an exact 0,
+    and a token's held slots are added in slot order: the same float32 sum,
+    bit for bit. Jitted like the kernel calls of ops/attention.py: one trace
+    and one lowering a (shape, weighted or not) variant, whatever the number
+    of layers."""
+    rows, d = y.shape
+    N, k = dest.shape
+    if d % LANES or N % MOVE_STEP:
+        raise ValueError(
+            f"the row mover needs y (rows, d) with d % {LANES} == 0 and "
+            f"dest (N, k) with N % {MOVE_STEP} == 0; got y {y.shape}, "
+            f"dest {dest.shape}")
+    cols, held, rank, cnt = _held_ranks(dest, rows)
+    plan = [cnt, _by_rank(cols, held, rank)]
+    if w is not None:
+        plan.append(_by_rank([jnp.pad(w[:, j].astype(jnp.float32),
+                                      (0, cnt.shape[0] - N))
+                              for j in range(k)], held, rank))
+    share = SMEM_BLOCK // MOVE_STEP         # programs to a block of the plan
+    scalars = [pl.BlockSpec((SMEM_BLOCK * per,), lambda i: (i // share,),
+                            memory_space=pltpu.SMEM)
+               for per in (1,) + (k,) * (len(plan) - 1)]
+    c, room = d // LANES, 2 * MOVE_TOKENS * k
+    call = pl.pallas_call(
+        functools.partial(_rows_to_tokens_kernel, k=k, weighted=w is not None),
+        grid=(N // MOVE_STEP,),
+        in_specs=scalars + [pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((MOVE_STEP, c, LANES), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((N, c, LANES), out_dtype),
+        scratch_shapes=(
+            [pltpu.VMEM((room, c, LANES), y.dtype),
+             pltpu.VMEM((MOVE_TOKENS, c, LANES), jnp.float32),
+             pltpu.SMEM((room,), jnp.int32)]
+            + ([] if w is None else [pltpu.SMEM((room,), jnp.float32)])
+            + [pltpu.SemaphoreType.DMA((2,))]),
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret,
+    )
+    # A row as (d / 128, 128): whole tiles, so a row is one contiguous copy.
+    with jax.named_scope(MOVE_SCOPE):
+        out = call(*plan, y.reshape(rows, c, LANES))
+    return out.reshape(N, d)
+
+
+def _pairs_to_tokens(y, dest, w, out_dtype, mover: str) -> jax.Array:
+    """sum_j w[:, j] * y[dest[:, j]] (w None: ones), summed in float32 and
+    returned as ``out_dtype`` (N, d), by the resolved ``mover``."""
+    if mover == "xla":
+        return _rows_of_pairs(y, dest, w).astype(out_dtype)
+    return _pallas_rows_to_tokens(y, dest, w, out_dtype=out_dtype,
+                                  interpret=mover == "pallas_interpret")
+
+
+def _slot_scalars_kernel(val_ref, cnt_ref, src_ref, out_ref):
+    """out[r, t] = val[src[r, t]] for the held ranks r of a block's tokens;
+    what no held slot writes is left as it was."""
+    def token(t, carry):
+        def slot(r, carry):
+            out_ref[r * SMEM_BLOCK + t] = val_ref[src_ref[r * SMEM_BLOCK + t]]
+            return carry
+
+        return lax.fori_loop(0, cnt_ref[t], slot, carry)
+
+    lax.fori_loop(0, SMEM_BLOCK, token, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _pallas_row_scalars_to_pairs(val, dest, *, interpret: bool = False):
+    """val[dest] (N, k), 0 where ``dest`` is out of bounds: one number a row
+    back to its (token, slot) pair, ``val.at[dest].get(mode='fill')`` with
+    only the held pairs read. All in scalar memory: ``val`` (rows,) whole,
+    the plan and the result a block of tokens at a time."""
+    N, k = dest.shape
+    cols, held, rank, cnt = _held_ranks(dest, val.shape[0])
+    blocks = [pl.BlockSpec((SMEM_BLOCK * per,), lambda i, val: (i,),
+                           memory_space=pltpu.SMEM) for per in (1, k, k)]
+    call = pl.pallas_call(
+        _slot_scalars_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(cnt.shape[0] // SMEM_BLOCK,),
+            in_specs=blocks[:2], out_specs=blocks[2]),
+        out_shape=jax.ShapeDtypeStruct((k * cnt.shape[0],), val.dtype),
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )
+    with jax.named_scope(MOVE_SCOPE):
+        got = call(val, cnt, _by_rank(cols, held, rank))
+    return _by_slot(got, held, rank, N)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def dispatch(x: jax.Array, plan: dict, mover: str = "xla") -> jax.Array:
     """x (N, d) -> the sorted buffer (rows, d): row r is x[row_token[r]],
-    0 past the rows held."""
+    0 past the rows held. One XLA gather of ``rows`` rows, whatever the
+    mover (0.65 ms; a kernel copying the ~half that hold a pair took 0.69:
+    PERF.md §6, PR 32); ``mover`` is the backward's."""
     return jnp.where(plan["row_valid"][:, None], x[plan["row_token"]], 0)
 
 
-def _dispatch_fwd(x, plan):
-    return dispatch(x, plan), plan
+def _dispatch_fwd(x, plan, mover):
+    return dispatch(x, plan, mover), plan
 
 
-def _dispatch_bwd(plan, dxs):  # the buffer has x's dtype
-    return _rows_of_pairs(dxs, plan["dest"]).astype(dxs.dtype), None
+def _dispatch_bwd(mover, plan, dxs):  # the buffer has x's dtype
+    return _pairs_to_tokens(dxs, plan["dest"], None, dxs.dtype, mover), None
 
 
 dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@jax.custom_vjp
-def combine(y: jax.Array, w: jax.Array, plan: dict) -> jax.Array:
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def combine(y: jax.Array, w: jax.Array, plan: dict,
+            mover: str = "xla") -> jax.Array:
     """out[n] = sum_j w[n, j] * y[dest[n, j]], float32 (N, d): the weighted
     sum of what a token's HELD experts gave. y (rows, d), w (N, k) f32."""
-    return _rows_of_pairs(y, plan["dest"], w)
+    return _pairs_to_tokens(y, plan["dest"], w, jnp.float32, mover)
 
 
-def _combine_fwd(y, w, plan):
-    return combine(y, w, plan), (y, w, plan)
+def _combine_fwd(y, w, plan, mover):
+    return combine(y, w, plan, mover), (y, w, plan)
 
 
-def _combine_bwd(res, dout):
+def _combine_bwd(mover, res, dout):
+    # The rows from tokens are XLA gathers of ``rows`` indices under every
+    # mover; dw's way back to (N, k), N * k indices, is the mover's.
     y, w, plan = res
     rows = jnp.where(plan["row_valid"][:, None],
                      dout[plan["row_token"]], 0)          # (rows, d) f32
@@ -179,7 +436,11 @@ def _combine_bwd(res, dout):
                       w.reshape(-1)[plan["row_pair"]], 0)
     dy = (rows * w_row[:, None]).astype(y.dtype)
     dw_row = jnp.sum(rows * y.astype(jnp.float32), axis=1)
-    dw = dw_row.at[plan["dest"]].get(mode="fill", fill_value=0)
+    if mover == "xla" or 4 * dw_row.shape[0] > SCALAR_BYTES:
+        dw = dw_row.at[plan["dest"]].get(mode="fill", fill_value=0)
+    else:
+        dw = _pallas_row_scalars_to_pairs(
+            dw_row, plan["dest"], interpret=mover == "pallas_interpret")
     return dy, dw.astype(w.dtype), None
 
 
@@ -234,9 +495,10 @@ def _walk(x, sel, first, count, n_experts, factor):
 
 
 def _chunk_out(x, w, w_gate, w_up, w_down, plan, impl):
-    ys = expert_ffn(dispatch(x, plan), w_gate, w_up, w_down,
+    mover = resolve_row_mover(impl, *x.shape)
+    ys = expert_ffn(dispatch(x, plan, mover), w_gate, w_up, w_down,
                     plan["group_sizes"], impl=impl)
-    return combine(ys, w, plan)
+    return combine(ys, w, plan, mover)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))

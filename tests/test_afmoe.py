@@ -4,6 +4,7 @@ windowed kernels of ops/attention.py) against the plain reference
 Small sizes, CPU."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -431,6 +432,141 @@ def test_a_router_biased_to_one_expert_drops_nothing(factor):
     np.testing.assert_allclose(out + shared, layer.reshape(-1, d),
                                atol=1e-5, rtol=1e-5)
     assert float(jnp.abs(out).max()) > 0
+
+
+# -- the row mover (ops.moe: %moe_rows.N) ----------------------------------------
+
+def _plans(case):
+    """(N, k, d, rows, every chunk's plan) of a tiny layer whose held pairs
+    are the named case's."""
+    N, k, E, d = 256, 4, 16, 128
+    rng = np.random.default_rng(7)
+    sel = np.stack([rng.permutation(E)[:k] for _ in range(N)]).astype(np.int32)
+    first, count, factor = 2, 4, moe.ROWS_FACTOR
+    if case == "none_held":
+        first, count = E, 4              # every choice names an absent expert
+    elif case == "all_held":             # the bound: N * k rows, one chunk
+        first, count, factor = 0, E, 1.0
+    elif case == "two_chunks":           # 12 of 16 held: ~768 pairs, in
+        first, count, factor = 2, 12, 0.5     # chunks of ROW_TILE = 512 rows
+    elif case == "whole_tokens":         # a token holds all its k or none
+        sel = np.where(np.arange(N)[:, None] % 3 == 0, np.arange(2, 2 + k),
+                       np.arange(8, 8 + k)).astype(np.int32)
+    rows, chunks = moe.chunk_rows(N, k, E, count, factor)
+    pairs = moe.plan_pairs(jnp.asarray(sel), first, count, rows * chunks)
+    total = int(pairs["total"])
+    ran = [c for c in range(chunks) if total > c * rows] or [0]
+    return N, k, d, rows, total, [moe.chunk_plan(pairs, c, rows, k)
+                                  for c in ran]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["expected", "none_held", "all_held",
+                                  "two_chunks", "whole_tokens"])
+def test_row_mover_equals_the_xla_gathers_bit_for_bit(case, dtype):
+    """combine and dispatch under the kernel ('pallas_interpret') against
+    the k gathers a token ('xla'): the outputs and, through jax.vjp, dx, dy
+    and dw, every bit. The inputs lie on a grid (eighths, no negative zero;
+    weights of eight bits, positive as the router's) so that every PRODUCT
+    is exact in float32: whether a backend contracts a multiply and an add
+    into one rounding, or drops the sum's leading 0.0, is then not seen; the
+    ORDER of a token's float32 sum is (the sums are not exact)."""
+    N, k, d, rows, total, plans = _plans(case)
+    assert {"expected": 200 < total < 330 and len(plans) == 1,
+            "none_held": total == 0, "all_held": total == N * k == rows,
+            "two_chunks": len(plans) == 2 and rows < total < 2 * rows,
+            "whole_tokens": total == k * len(range(0, N, 3))}[case]
+    assert moe.resolve_row_mover("megablox_interpret", N, d) == "pallas_interpret"
+    keys = jax.random.split(jax.random.key(5), 5)
+
+    def grid(key, shape):
+        g = (jnp.round(8 * jax.random.normal(key, shape)) / 8).clip(-4, 4)
+        return jnp.where(g == 0, 0.0, g).astype(dtype)
+
+    x, y, dxs = grid(keys[0], (N, d)), grid(keys[1], (rows, d)), grid(
+        keys[2], (rows, d))
+    dout = grid(keys[3], (N, d)).astype(jnp.float32)
+    w = (jax.random.uniform(keys[4], (N, k)) + 0.1).astype(
+        jnp.bfloat16).astype(jnp.float32)
+
+    @functools.partial(jax.jit, static_argnums=0)
+    def both_ways(mover):
+        got = []
+        for plan in plans:
+            xs, dx_of = jax.vjp(lambda x: moe.dispatch(x, plan, mover), x)
+            out, dyw_of = jax.vjp(
+                lambda y, w: moe.combine(y, w, plan, mover), y, w)
+            got.append((xs, out, dx_of(dxs)[0], *dyw_of(dout)))
+        return got
+
+    want, got = both_ways("xla"), both_ways("pallas_interpret")
+    for c, (a, b) in enumerate(zip(want, got)):
+        for name, u, v in zip(("xs", "out", "dx", "dy", "dw"), a, b):
+            assert u.dtype == v.dtype and u.shape == v.shape, (c, name)
+            assert np.asarray(u).tobytes() == np.asarray(v).tobytes(), (
+                c, name, float(jnp.abs(u.astype(jnp.float32)
+                                       - v.astype(jnp.float32)).max()))
+    if total:
+        assert any(float(jnp.abs(a[1]).max()) > 0 for a in want)
+        assert any(float(jnp.abs(a[2].astype(jnp.float32)).max()) > 0
+                   for a in want)
+
+
+def test_row_mover_refuses_shapes_it_cannot_walk(monkeypatch):
+    """The mover follows the grouped matmul's impl and the shapes: rows that
+    are no whole 128-lane tiles, the trainer's 8-token init batch and
+    'ragged_dot' keep XLA's gathers; the kernel's entry says what it needs."""
+    resolve = moe.resolve_row_mover
+    assert resolve("megablox", 16384, 2048) == "pallas"
+    assert resolve("megablox_interpret", 256, 128) == "pallas_interpret"
+    assert resolve("megablox", 16384, 2048 + 64) == "xla"
+    assert resolve("megablox", 8, 2048) == "xla"
+    assert resolve("ragged_dot", 16384, 2048) == "xla"
+    assert resolve("auto", 16384, 2048) == "xla"          # off the chip
+    with pytest.raises(ValueError, match="row mover needs"):
+        moe._pallas_rows_to_tokens(
+            jnp.zeros((512, 96)), jnp.zeros((256, 2), jnp.int32),
+            out_dtype=jnp.float32, interpret=True)
+    with pytest.raises(ValueError, match="row mover needs"):
+        moe._pallas_rows_to_tokens(
+            jnp.zeros((512, 128)), jnp.zeros((8, 2), jnp.int32),
+            out_dtype=jnp.float32, interpret=True)
+    # ... and the family says which it will be, beside qk_prep
+    monkeypatch.setattr(moe, "resolve_gmm_impl", lambda impl: "megablox")
+    wide = model_cfg(n_embd=128, block_size=256)
+    assert afmoe.build(wide, None)[1]["moe_row_mover"] == "pallas"
+    assert afmoe.build(model_cfg(), None)[1]["moe_row_mover"] == "xla"
+
+
+def test_routed_experts_with_the_row_mover_equal_the_xla_walk():
+    """The whole layer through its own VJP, two chunks, the kernels inside
+    the walk's cond: 'megablox_interpret' (grouped matmul AND row mover in
+    the interpreter) against 'ragged_dot' (XLA for both)."""
+    N, k, E, d, F, first, count = 256, 4, 16, 128, 64, 2, 12
+    keys = jax.random.split(jax.random.key(11), 6)
+    x = jax.random.normal(keys[0], (N, d))
+    _, sel = jax.lax.top_k(jax.random.uniform(keys[1], (N, E)), k)
+    w = jax.random.uniform(keys[2], (N, k)) + 0.1
+    mats = [0.1 * jax.random.normal(kk, shape) for kk, shape in zip(
+        keys[3:], [(count, d, F), (count, d, F), (count, F, d)])]
+
+    def loss(impl, x, w, *mats):
+        out, stats = moe.routed_experts(x, sel.astype(jnp.int32), w, *mats,
+                                        first, count, E, 0.5, impl)
+        return jnp.sum(out * jnp.cos(out)), stats
+
+    with jax.default_matmul_precision("highest"):
+        run = lambda impl: jax.jit(jax.value_and_grad(
+            functools.partial(loss, impl), argnums=(0, 1, 2, 3, 4),
+            has_aux=True))(x, w, *mats)
+        (want, stats), dwant = run("ragged_dot")
+        (got, stats2), dgot = run("megablox_interpret")
+    assert stats.tolist() == stats2.tolist() and int(stats[2]) == 0
+    assert int(stats[0]) > moe.chunk_rows(N, k, E, count, 0.5)[0]   # 2 chunks
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for a, b in zip(dgot, dwant):
+        np.testing.assert_allclose(a, b, rtol=1e-4,
+                                   atol=1e-5 * float(jnp.abs(b).max()))
 
 
 def test_chunk_rows():

@@ -18,7 +18,9 @@ tests hold every later PR to what the chip accepts, at no chip time:
     shapes (2 x 8192 tokens, 32 query heads on 4 KV heads of 128, window
     2048 and full), and the routed experts' grouped matmul (megablox,
     forward, dgrad and wgrad) at the cell's buffer (32,768 rows, 16
-    experts, 2048 x 1024);
+    experts, 2048 x 1024), with the row mover that brings that buffer's
+    rows back to their 16,384 tokens (ops.moe: combine, and dispatch's
+    backward);
   * ``qk_prep``, the one-pass head RMSNorm + rotary positions in front of
     those kernels, forward and backward, at the cell's q (2, 8192, 4096) /
     32 heads and k (2, 8192, 512) / 4 heads, with and without positions;
@@ -185,6 +187,37 @@ def test_megablox_grouped_matmul_backward(sds):
     # the forward's own output is not needed for this loss's gradient
     calls = re.findall(r"%([\w.]*gmm[\w.]*) = [^\n]*custom-call\(", txt)
     assert len(calls) == 2 and sum("tgmm" in c for c in calls) == 1, calls
+
+
+@pytest.mark.parametrize("pass_", ["combine", "dispatch_backward",
+                                   "combine_backward"])
+def test_moe_row_mover(sds, pass_):
+    """The routed experts' rows back to their tokens at the cell's shapes
+    (16,384 tokens of 8 slots, a 32,768-row buffer of 2048): ONE kernel a
+    pass (%moe_rows.N), weighted into float32 (combine), plain into the
+    compute type (dispatch's backward), or one number a row (dw in
+    combine's backward, whose row gathers stay XLA's)."""
+    from nanosandbox_tpu.ops import moe
+
+    plan = {"dest": sds((16384, 8), jnp.int32),
+            "row_valid": sds((32768,), jnp.bool_),
+            "row_token": sds((32768,), jnp.int32),
+            "row_pair": sds((32768,), jnp.int32)}
+    y, w = sds((32768, 2048), jnp.bfloat16), sds((16384, 8), jnp.float32)
+    if pass_ == "combine":
+        txt = compiled_text(
+            lambda y, w, plan: moe.combine(y, w, plan, "pallas"), y, w, plan)
+    elif pass_ == "dispatch_backward":
+        txt = compiled_text(
+            lambda dxs, plan: moe._dispatch_bwd("pallas", plan, dxs)[0], y,
+            plan)
+    else:
+        txt = compiled_text(
+            lambda y, w, plan, dout: moe._combine_bwd(
+                "pallas", (y, w, plan), dout)[:2], y, w, plan,
+            sds((16384, 2048), jnp.float32))
+    assert len(re.findall(r"%moe_rows[.0-9]* = [^\n]*custom-call\(",
+                          txt)) == 1
 
 
 def _qkv_call(shape, dropout, sds):

@@ -32,7 +32,8 @@ TINY = {
                    moe_intermediate_size=24, num_experts=8,
                    num_experts_per_tok=2, experts_held=(2, 4),
                    route_scale=2.0),
-              {"attn_layout", "qk_prep", "layer_types", "experts_held"},
+              {"attn_layout", "qk_prep", "moe_row_mover", "layer_types",
+               "experts_held"},
               152862720.0),
 }
 

@@ -221,6 +221,15 @@ def test_lowering_for_the_map_keeps_the_live_budget_of_one(tiny_cfg):
      "/qk_prep/pallas_call", "attn_sliding"),
     ("jit(traced)/transpose(jvp(Afmoe))/h_3/attn_full/k_norm/"
      "jit(_pallas_qk_prep)/qk_prep/pallas_call", "attn_full"),
+    # ... and its row mover, inside the routing scope in both directions
+    # (the backward under ops.moe's own VJP, which opens the scope again)
+    ("jit(traced)/jvp(Afmoe)/h_1/moe/moe_route/while/body/closed_call/cond/"
+     "branch_1_fun/jit(_pallas_rows_to_tokens)/moe_rows/pallas_call",
+     "moe_route"),
+    ("jit(traced)/transpose(jvp(Afmoe))/jvp(Afmoe)/checkpoint/h_4/moe/"
+     "moe_route/moe_route/while/body/closed_call/cond/branch_1_fun/"
+     "transpose(jvp(jit(_pallas_rows_to_tokens)))/moe_rows/pallas_call",
+     "moe_route"),
 ])
 def test_part_of_a_scope_path(op_name, part):
     assert opscopes.part_of(op_name) == part
