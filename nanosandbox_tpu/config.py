@@ -51,16 +51,21 @@ class TrainConfig:
     vocab_size: int = 0  # 0 = take from dataset meta.pkl, else explicit
 
     # -- model family. 'gpt2' (models/gpt.py) reads the keys above; 'afmoe'
-    #    (models/afmoe.py: Arcee Trinity's block, HF model_type afmoe) reads
-    #    n_layer, n_head, n_embd, block_size, vocab_size above and the keys
-    #    below, named as the published config.json names them. --
+    #    (models/afmoe.py: Arcee Trinity's block, HF model_type afmoe) and
+    #    'lfm2' (models/lfm2.py: LiquidAI's LFM2 MoE block, HF model_type
+    #    lfm2_moe) read n_layer, n_head, n_embd, block_size, vocab_size above
+    #    and the keys below, named as the published config.json names them
+    #    (each family's model-config class further down says which). --
     model_family: str = "gpt2"
     n_kv_head: int = 0  # KV heads; n_head // n_kv_head query heads share one
     head_dim: int = 0  # not n_embd // n_head: 32 heads x 128 on a 2048 stream
-    # One entry a layer, 'sliding' | 'full', comma-separated: window layers
-    # carry rotary positions and see `sliding_window` keys back, full
-    # layers see everything and carry no positions.
+    # One entry a layer, comma-separated. afmoe: 'sliding' | 'full': window
+    # layers carry rotary positions and see `sliding_window` keys back, full
+    # layers see everything and carry no positions. lfm2: 'conv' | 'full':
+    # a gated short convolution of `conv_L_cache` taps, or full causal
+    # attention with rotary positions.
     layer_types: str = ""
+    conv_L_cache: int = 3
     sliding_window: int = 0
     rope_theta: float = 10000.0
     rms_norm_eps: float = 1e-5
@@ -381,7 +386,43 @@ class GPTConfig:
         )
 
 
-MODEL_FAMILIES = ("gpt2", "afmoe")
+MODEL_FAMILIES = ("gpt2", "afmoe", "lfm2")
+
+
+def _expert_family_keys(cfg: TrainConfig, family: str, kinds_allowed: tuple):
+    """(layer kinds, (first, count) of the experts held, problems): what
+    both expert families read of a TrainConfig the same way, and what they
+    both ask of it."""
+    kinds = tuple(k.strip() for k in cfg.layer_types.split(",") if k.strip())
+    first, count = cfg.experts_held
+    if count == 0:
+        first, count = 0, cfg.num_experts
+    problems = []
+    if len(kinds) != cfg.n_layer or set(kinds) - set(kinds_allowed):
+        problems.append(
+            f"layer_types needs {cfg.n_layer} entries of "
+            f"{' | '.join(map(repr, kinds_allowed))}, got "
+            f"{cfg.layer_types!r}")
+    if (min(cfg.n_kv_head, cfg.head_dim, cfg.moe_intermediate_size) <= 0
+            or cfg.n_head % max(cfg.n_kv_head, 1)):
+        problems.append(
+            "n_kv_head (dividing n_head), head_dim and "
+            "moe_intermediate_size must be set")
+    if cfg.head_dim % 2:
+        problems.append("rotary positions need an even head_dim")
+    if cfg.num_dense_layers and cfg.intermediate_size <= 0:
+        problems.append("dense layers need intermediate_size > 0")
+    if cfg.num_dense_layers < cfg.n_layer and not (
+            0 < cfg.num_experts_per_tok <= cfg.num_experts
+            and 0 <= first and first + count <= cfg.num_experts):
+        problems.append(
+            f"expert layers need 0 < num_experts_per_tok <= num_experts "
+            f"and experts_held inside them, got k="
+            f"{cfg.num_experts_per_tok}, E={cfg.num_experts}, "
+            f"held={cfg.experts_held}")
+    if cfg.dropout or cfg.bias:
+        problems.append(f"the {family} block has no dropout and no biases")
+    return kinds, (first, count), problems
 
 
 @dataclass
@@ -420,37 +461,10 @@ class AfmoeConfig:
     @classmethod
     def from_train_config(cls, cfg: TrainConfig,
                           vocab_size: int) -> "AfmoeConfig":
-        kinds = tuple(k.strip() for k in cfg.layer_types.split(",")
-                      if k.strip())
-        first, count = cfg.experts_held
-        if count == 0:
-            first, count = 0, cfg.num_experts
-        problems = []
-        if len(kinds) != cfg.n_layer or set(kinds) - {"sliding", "full"}:
-            problems.append(
-                f"layer_types needs {cfg.n_layer} entries of 'sliding' | "
-                f"'full', got {cfg.layer_types!r}")
+        kinds, (first, count), problems = _expert_family_keys(
+            cfg, "afmoe", ("sliding", "full"))
         if "sliding" in kinds and cfg.sliding_window <= 0:
             problems.append("sliding layers need sliding_window > 0")
-        if (min(cfg.n_kv_head, cfg.head_dim, cfg.moe_intermediate_size) <= 0
-                or cfg.n_head % max(cfg.n_kv_head, 1)):
-            problems.append(
-                "n_kv_head (dividing n_head), head_dim and "
-                "moe_intermediate_size must be set")
-        if cfg.head_dim % 2:
-            problems.append("rotary positions need an even head_dim")
-        if cfg.num_dense_layers and cfg.intermediate_size <= 0:
-            problems.append("dense layers need intermediate_size > 0")
-        if cfg.num_dense_layers < cfg.n_layer and not (
-                0 < cfg.num_experts_per_tok <= cfg.num_experts
-                and 0 <= first and first + count <= cfg.num_experts):
-            problems.append(
-                f"expert layers need 0 < num_experts_per_tok <= num_experts "
-                f"and experts_held inside them, got k="
-                f"{cfg.num_experts_per_tok}, E={cfg.num_experts}, "
-                f"held={cfg.experts_held}")
-        if cfg.dropout or cfg.bias:
-            problems.append("the afmoe block has no dropout and no biases")
         if problems:
             raise ValueError("model_family='afmoe': " + "; ".join(problems))
         return cls(
@@ -467,6 +481,65 @@ class AfmoeConfig:
             route_norm=cfg.route_norm, mup_enabled=cfg.mup_enabled,
             rope_theta=cfg.rope_theta, rms_norm_eps=cfg.rms_norm_eps,
             param_dtype=cfg.param_dtype, compute_dtype=cfg.compute_dtype,
+            attention_impl=cfg.attention_impl, remat=cfg.remat,
+            remat_policy=cfg.remat_policy)
+
+
+@dataclass
+class Lfm2Config:
+    """Model-only view of the config for models.lfm2.Lfm2."""
+
+    n_layer: int
+    n_head: int
+    n_kv_head: int
+    head_dim: int
+    n_embd: int
+    block_size: int
+    vocab_size: int
+    layer_types: tuple  # 'conv' | 'full' a layer
+    conv_L_cache: int
+    num_dense_layers: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    num_experts: int
+    num_experts_per_tok: int
+    experts_held: tuple  # (first, count), count > 0
+    route_scale: float = 1.0
+    route_norm: bool = True
+    rope_theta: float = 1000000.0
+    rms_norm_eps: float = 1e-5
+    param_dtype: str = "float32"
+    compute_dtype: str = "bfloat16"
+    attention_impl: str = "auto"
+    remat: bool = False
+    remat_policy: str = "save_attention"
+
+    def replace(self, **kw: Any) -> "Lfm2Config":
+        return dataclasses.replace(self, **kw)
+
+    @classmethod
+    def from_train_config(cls, cfg: TrainConfig,
+                          vocab_size: int) -> "Lfm2Config":
+        kinds, (first, count), problems = _expert_family_keys(
+            cfg, "lfm2", ("conv", "full"))
+        if cfg.conv_L_cache < 1:
+            problems.append("conv layers need conv_L_cache >= 1 taps")
+        if problems:
+            raise ValueError("model_family='lfm2': " + "; ".join(problems))
+        return cls(
+            n_layer=cfg.n_layer, n_head=cfg.n_head, n_kv_head=cfg.n_kv_head,
+            head_dim=cfg.head_dim, n_embd=cfg.n_embd,
+            block_size=cfg.block_size, vocab_size=vocab_size,
+            layer_types=kinds, conv_L_cache=cfg.conv_L_cache,
+            num_dense_layers=cfg.num_dense_layers,
+            intermediate_size=cfg.intermediate_size,
+            moe_intermediate_size=cfg.moe_intermediate_size,
+            num_experts=cfg.num_experts,
+            num_experts_per_tok=cfg.num_experts_per_tok,
+            experts_held=(first, count), route_scale=cfg.route_scale,
+            route_norm=cfg.route_norm, rope_theta=cfg.rope_theta,
+            rms_norm_eps=cfg.rms_norm_eps, param_dtype=cfg.param_dtype,
+            compute_dtype=cfg.compute_dtype,
             attention_impl=cfg.attention_impl, remat=cfg.remat,
             remat_policy=cfg.remat_policy)
 

@@ -19,6 +19,8 @@ what they would otherwise ask by the family's name:
 
 A new family is its module (and kernels), one line here, its ``TrainConfig``
 keys and model-config class (config.py), its rows in ``opscopes._COMPONENT``.
+What two families share and neither owns lies beside them: models/common.py
+(every family), models/experts.py (the expert families).
 """
 
 from __future__ import annotations
@@ -28,6 +30,7 @@ import importlib
 FAMILIES = {
     "gpt2": "nanosandbox_tpu.models.gpt",
     "afmoe": "nanosandbox_tpu.models.afmoe",
+    "lfm2": "nanosandbox_tpu.models.lfm2",
 }
 
 

@@ -46,7 +46,9 @@ Between the projections and the attention kernels, q and k stay
 whole 128-row blocks), RMSNorm_q / RMSNorm_k and the rotary positions are ONE
 kernel a tensor, forward and backward (ops.attention.qk_prep: float32 in
 registers from the projection's output as it lies, no float32 copy of q or k
-in HBM); everywhere else head_rms_norm and rotary below, in XLA.
+in HBM); everywhere else models/experts.py's head_rms_norm and rotary, in
+XLA. The router, SwiGLU, the head norm and the routed half of the expert
+layer are models/experts.py's, shared with the other expert family.
 
 Scopes (obs/opscopes.py): modules ``attn_sliding`` / ``attn_full``, ``mlp``,
 ``moe_shared``, the norms ``ln_*``, ``wte``; named scopes ``moe_route``
@@ -70,101 +72,18 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax import lax
-from jax.ad_checkpoint import checkpoint_name
 
 from nanosandbox_tpu.config import AfmoeConfig
-from nanosandbox_tpu.models.common import (_dense_init, constrain_acts,
-                                           remat_block)
+from nanosandbox_tpu.models import experts
+from nanosandbox_tpu.models.common import _dense_init, constrain_acts
+from nanosandbox_tpu.models.experts import (STAT_NAMES, HeadRMSNorm, SwiGLU,
+                                            dense as _dense,
+                                            rms_norm as _rms_norm)
 from nanosandbox_tpu.ops import moe
-from nanosandbox_tpu.ops.attention import (causal_attention_gqa, qk_prep,
-                                           resolve_gqa_impl, rotary_table)
+from nanosandbox_tpu.ops.attention import (causal_attention_gqa,
+                                           resolve_gqa_impl)
 
-# What a step reports of its expert layers, one entry a layer.
-STAT_NAMES = ("moe_held", "moe_max_rows", "moe_dropped")
-# What a block under remat keeps: the attention kernels' output and
-# logsumexp (ops/attention.py) and the routed experts' weighted sum (Moe).
-SAVED_NAMES = ("attn_out", "attn_lse", "moe_routed")
-
-
-def _dense(cfg: AfmoeConfig, features: int, name: str) -> nn.Dense:
-    return nn.Dense(features, use_bias=False,
-                    dtype=jnp.dtype(cfg.compute_dtype),
-                    param_dtype=cfg.param_dtype, kernel_init=_dense_init(),
-                    name=name)
-
-
-def _rms_norm(cfg: AfmoeConfig, name: str) -> nn.RMSNorm:
-    return nn.RMSNorm(epsilon=cfg.rms_norm_eps, dtype=jnp.float32,
-                      param_dtype=cfg.param_dtype, name=name)
-
-
-def head_rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
-    """RMSNorm over the last (head) dimension of x (B, T, heads, D),
-    float32, one scale of D shared by the heads: the XLA path. The mean of
-    squares over a head's lanes is taken as a product with the constant 1/D
-    matrix at full float32 precision, which leaves it in every lane with no
-    cross-lane reduce and broadcast: 3.8 against 7.6 ms for a layer's q,
-    forward and backward, at (2, 8192, 32, 128) (PERF.md §6, PR 29)."""
-    D = x.shape[-1]
-    x = x.astype(jnp.float32)
-    mean_sq = jnp.einsum("bthd,de->bthe", x * x,
-                         jnp.full((D, D), 1.0 / D, jnp.float32),
-                         precision=lax.Precision.HIGHEST)
-    return x * lax.rsqrt(mean_sq + eps) * scale
-
-
-def rotary(x: jax.Array, theta: float) -> jax.Array:
-    """Rotate-half rotary positions 0..T-1 over all of the last dimension
-    of x (B, T, heads, D), float32: the XLA path.
-
-    rotate_half(x) = [-x2, x1] is taken as a product with the fixed signed
-    permutation matrix that says so, at full float32 precision: the same
-    numbers to float32 rounding, on the MXU. Written as a split and a
-    concatenate of the 128 lanes it cost 20.0 ms a layer's q (forward and
-    backward, (2, 8192, 32, 128)) against 4.6 ms (PERF.md §6, PR 29)."""
-    T, D = x.shape[1], x.shape[-1]
-    cos, sin = (t[None, :, None, :] for t in rotary_table(T, D, theta))
-    lane = np.arange(D)
-    half_turn = np.zeros((D, D), np.float32)
-    half_turn[(lane + D // 2) % D, lane] = np.where(lane < D // 2, -1.0, 1.0)
-    rotated = jnp.einsum("bthd,de->bthe", x, jnp.asarray(half_turn),
-                         precision=lax.Precision.HIGHEST)
-    return x * cos + rotated * sin
-
-
-class HeadRMSNorm(nn.Module):
-    """The prologue of attention for q or k as its projection leaves it,
-    x (B, T, heads*D) -> the same shape and dtype for the kernels: RMSNorm
-    over each head's D lanes (one leaf, ``scale`` (D,), shared by the heads),
-    then rotary positions where ``theta`` is given; float32 inside.
-
-    ``impl`` (ops.attention.resolve_gqa_impl, the predicate that picks the
-    attention kernels) picks the form. 'pallas' / 'pallas_interpret':
-    ops.attention.qk_prep, ONE kernel over x where it lies, forward and
-    backward (custom call ``%qk_prep.N``; PERF.md §6, PR 30). 'xla':
-    head_rms_norm and rotary above, float32 (B, T, heads, D) arrays in HBM
-    between them: what the CPU, the trainer's 8-token init batch and the
-    kernel's tests run."""
-    heads: int
-    eps: float
-    param_dtype: str
-
-    @nn.compact
-    def __call__(self, x: jax.Array, theta: float | None,
-                 impl: str) -> jax.Array:
-        B, T, HD = x.shape
-        D = HD // self.heads
-        scale = self.param("scale", nn.initializers.ones, (D,),
-                           jnp.dtype(self.param_dtype))
-        if impl != "xla":
-            return qk_prep(x, scale, self.heads, self.eps, theta,
-                           impl == "pallas_interpret")
-        y = head_rms_norm(x.reshape(B, T, self.heads, D), scale, self.eps)
-        if theta is not None:
-            y = rotary(y, theta)
-        return y.reshape(B, T, HD).astype(x.dtype)
+ROUTE_EPS = 1e-20   # in the sum the selected scores are divided by
 
 
 class Attention(nn.Module):
@@ -197,64 +116,19 @@ class Attention(nn.Module):
         return _dense(cfg, cfg.n_embd, "o_proj")(gated.astype(dtype))
 
 
-class SwiGLU(nn.Module):
-    cfg: AfmoeConfig
-    width: int
-
-    @nn.compact
-    def __call__(self, m: jax.Array) -> jax.Array:
-        cfg = self.cfg
-        g = _dense(cfg, self.width, "gate_proj")(m).astype(jnp.float32)
-        u = _dense(cfg, self.width, "up_proj")(m).astype(jnp.float32)
-        return _dense(cfg, cfg.n_embd, "down_proj")(
-            (jax.nn.silu(g) * u).astype(cfg.compute_dtype))
-
-
-def route(x: jax.Array, w_router: jax.Array, bias: jax.Array,
-          cfg: AfmoeConfig):
-    """(sel (N, k) int32, w (N, k) float32) for tokens x (N, d) float32:
-    the k experts of the highest score + bias, weighted by their scores."""
-    s = jax.nn.sigmoid(jnp.dot(x, w_router.astype(jnp.float32),
-                               precision=lax.Precision.HIGHEST))
-    _, sel = lax.top_k(s + lax.stop_gradient(bias.astype(jnp.float32)),
-                       cfg.num_experts_per_tok)
-    w = jnp.take_along_axis(s, sel, axis=1)
-    if cfg.route_norm:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-    return sel.astype(jnp.int32), w * cfg.route_scale
-
-
 class Moe(nn.Module):
     cfg: AfmoeConfig
 
     @nn.compact
     def __call__(self, m: jax.Array):
         """m (B, T, d) float32 -> (f (B, T, d) float32, stats (3,) int32:
-        STAT_NAMES)."""
+        STAT_NAMES): the routed experts held (models/experts.py) plus this
+        family's addition, the shared expert."""
         cfg = self.cfg
-        B, T, d = m.shape
-        first, count = cfg.experts_held
-        F = cfg.moe_intermediate_size
-        dtype = jnp.dtype(cfg.compute_dtype)
-        init, pd = _dense_init(), jnp.dtype(cfg.param_dtype)
-        w_router = self.param("router", init, (d, cfg.num_experts), pd)
-        bias = self.param("expert_bias", nn.initializers.zeros,
-                          (cfg.num_experts,), pd)
-        w_gate = self.param("w_gate", init, (count, d, F), pd)
-        w_up = self.param("w_up", init, (count, d, F), pd)
-        w_down = self.param("w_down", init, (count, F, d), pd)
-        x = m.reshape(B * T, d)
-        with jax.named_scope("moe_route"):
-            sel, w = route(x, w_router, bias, cfg)
-            routed, stats = moe.routed_experts(
-                x.astype(dtype), sel, w, w_gate.astype(dtype),
-                w_up.astype(dtype), w_down.astype(dtype), first, count,
-                cfg.num_experts)
-            # Saved under remat (SAVED_NAMES): as large as the block's
-            # output; the norm after the layer needs it, and recomputing it
-            # is k row gathers a token.
-            routed = checkpoint_name(routed, "moe_routed").reshape(B, T, d)
-        shared = SwiGLU(cfg, F, name="moe_shared")(m.astype(dtype))
+        routed, stats = experts.routed_experts(self, m, cfg,
+                                               route_eps=ROUTE_EPS)
+        shared = SwiGLU(cfg, cfg.moe_intermediate_size, name="moe_shared")(
+            m.astype(jnp.dtype(cfg.compute_dtype)))
         return shared.astype(jnp.float32) + routed, stats
 
 
@@ -307,18 +181,7 @@ class Afmoe(nn.Module):
         if cfg.mup_enabled:
             h = h * math.sqrt(cfg.n_embd)
         h = constrain_acts(self.mesh, h)
-        block_cls = (remat_block(Block, cfg.remat_policy, SAVED_NAMES,
-                                 static_argnums=())
-                     if cfg.remat else Block)
-        stats = []
-        for i in range(cfg.n_layer):
-            h, st = block_cls(cfg, i, name=f"h_{i}")(h)
-            h = constrain_acts(self.mesh, h)
-            if i >= cfg.num_dense_layers:
-                stats.append(st)
-        stats = (jnp.stack(stats) if stats else jnp.zeros(
-            (0, len(STAT_NAMES)), jnp.int32))
-        aux = {name: stats[:, n] for n, name in enumerate(STAT_NAMES)}
+        h, aux = experts.decoder_layers(Block, cfg, self.mesh, h)
         h = _rms_norm(cfg, "ln_f")(h)
         if return_hidden:
             return h, aux

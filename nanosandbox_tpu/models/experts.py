@@ -1,0 +1,198 @@
+"""What the expert families (models/afmoe.py, models/lfm2.py) are both
+written with and neither owns: the bias-free projection and the RMSNorm of a
+float32 residual stream, the q/k head norm with its rotary positions, SwiGLU,
+the sigmoid router, the routed half of an expert layer and the loop over a
+decoder's blocks that gathers the expert layers' counters. Beside
+models/common.py because it imports ops/moe.py, which a GPT-2 run never does.
+
+``cfg`` is the family's own model config; read here are ``compute_dtype``,
+``param_dtype``, ``n_embd``, ``rms_norm_eps`` and, by ``routed_experts``,
+``num_experts``, ``num_experts_per_tok``, ``experts_held``,
+``moe_intermediate_size``, ``route_norm`` and ``route_scale``.
+"""
+
+from __future__ import annotations
+
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+from nanosandbox_tpu.models.common import (_dense_init, constrain_acts,
+                                           remat_block)
+from nanosandbox_tpu.ops import moe
+from nanosandbox_tpu.ops.attention import qk_prep, rotary_table
+
+# What a step reports of its expert layers, one entry a layer.
+STAT_NAMES = ("moe_held", "moe_max_rows", "moe_dropped")
+# What a block under remat keeps: the attention kernels' output and
+# logsumexp (ops/attention.py) and the routed experts' weighted sum
+# (routed_experts below).
+SAVED_NAMES = ("attn_out", "attn_lse", "moe_routed")
+
+
+def dense(cfg: Any, features: int, name: str) -> nn.Dense:
+    return nn.Dense(features, use_bias=False,
+                    dtype=jnp.dtype(cfg.compute_dtype),
+                    param_dtype=cfg.param_dtype, kernel_init=_dense_init(),
+                    name=name)
+
+
+def rms_norm(cfg: Any, name: str) -> nn.RMSNorm:
+    return nn.RMSNorm(epsilon=cfg.rms_norm_eps, dtype=jnp.float32,
+                      param_dtype=cfg.param_dtype, name=name)
+
+
+def head_rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """RMSNorm over the last (head) dimension of x (B, T, heads, D),
+    float32, one scale of D shared by the heads: the XLA path. The mean of
+    squares over a head's lanes is taken as a product with the constant 1/D
+    matrix at full float32 precision, which leaves it in every lane with no
+    cross-lane reduce and broadcast: 3.8 against 7.6 ms for a layer's q,
+    forward and backward, at (2, 8192, 32, 128) (PERF.md §6, PR 29)."""
+    D = x.shape[-1]
+    x = x.astype(jnp.float32)
+    mean_sq = jnp.einsum("bthd,de->bthe", x * x,
+                         jnp.full((D, D), 1.0 / D, jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+    return x * lax.rsqrt(mean_sq + eps) * scale
+
+
+def rotary(x: jax.Array, theta: float) -> jax.Array:
+    """Rotate-half rotary positions 0..T-1 over all of the last dimension
+    of x (B, T, heads, D), float32: the XLA path.
+
+    rotate_half(x) = [-x2, x1] is taken as a product with the fixed signed
+    permutation matrix that says so, at full float32 precision: the same
+    numbers to float32 rounding, on the MXU. Written as a split and a
+    concatenate of the 128 lanes it cost 20.0 ms a layer's q (forward and
+    backward, (2, 8192, 32, 128)) against 4.6 ms (PERF.md §6, PR 29)."""
+    T, D = x.shape[1], x.shape[-1]
+    cos, sin = (t[None, :, None, :] for t in rotary_table(T, D, theta))
+    lane = np.arange(D)
+    half_turn = np.zeros((D, D), np.float32)
+    half_turn[(lane + D // 2) % D, lane] = np.where(lane < D // 2, -1.0, 1.0)
+    rotated = jnp.einsum("bthd,de->bthe", x, jnp.asarray(half_turn),
+                         precision=lax.Precision.HIGHEST)
+    return x * cos + rotated * sin
+
+
+class HeadRMSNorm(nn.Module):
+    """The prologue of attention for q or k as its projection leaves it,
+    x (B, T, heads*D) -> the same shape and dtype for the kernels: RMSNorm
+    over each head's D lanes (one leaf, ``scale`` (D,), shared by the heads),
+    then rotary positions where ``theta`` is given; float32 inside.
+
+    ``impl`` (ops.attention.resolve_gqa_impl, the predicate that picks the
+    grouped-query kernels) picks the form. 'pallas' / 'pallas_interpret':
+    ops.attention.qk_prep, ONE kernel over x where it lies, forward and
+    backward (custom call ``%qk_prep.N``; PERF.md §6, PR 30). 'xla':
+    head_rms_norm and rotary above, float32 (B, T, heads, D) arrays in HBM
+    between them: what the CPU, the trainer's 8-token init batch, the
+    kernel's tests and heads of 64 lanes run."""
+    heads: int
+    eps: float
+    param_dtype: str
+
+    @nn.compact
+    def __call__(self, x: jax.Array, theta: float | None,
+                 impl: str) -> jax.Array:
+        B, T, HD = x.shape
+        D = HD // self.heads
+        scale = self.param("scale", nn.initializers.ones, (D,),
+                           jnp.dtype(self.param_dtype))
+        if impl != "xla":
+            return qk_prep(x, scale, self.heads, self.eps, theta,
+                           impl == "pallas_interpret")
+        y = head_rms_norm(x.reshape(B, T, self.heads, D), scale, self.eps)
+        if theta is not None:
+            y = rotary(y, theta)
+        return y.reshape(B, T, HD).astype(x.dtype)
+
+
+class SwiGLU(nn.Module):
+    cfg: Any
+    width: int
+
+    @nn.compact
+    def __call__(self, m: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        g = dense(cfg, self.width, "gate_proj")(m).astype(jnp.float32)
+        u = dense(cfg, self.width, "up_proj")(m).astype(jnp.float32)
+        return dense(cfg, cfg.n_embd, "down_proj")(
+            (jax.nn.silu(g) * u).astype(cfg.compute_dtype))
+
+
+def route(x: jax.Array, w_router: jax.Array, bias: jax.Array, k: int, *,
+          norm: bool, scale: float, eps: float):
+    """(sel (N, k) int32, w (N, k) float32) for tokens x (N, d) float32:
+    the k experts of the highest sigmoid score + bias, weighted by their
+    scores alone; ``norm``: divided by their sum + ``eps``; times
+    ``scale``."""
+    s = jax.nn.sigmoid(jnp.dot(x, w_router.astype(jnp.float32),
+                               precision=lax.Precision.HIGHEST))
+    _, sel = lax.top_k(s + lax.stop_gradient(bias.astype(jnp.float32)), k)
+    w = jnp.take_along_axis(s, sel, axis=1)
+    if norm:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
+    return sel.astype(jnp.int32), w * scale
+
+
+def routed_experts(module: nn.Module, m: jax.Array, cfg: Any, *,
+                   route_eps: float):
+    """The routed half of an expert layer, its leaves (``router``,
+    ``expert_bias``, ``w_gate``, ``w_up``, ``w_down``) declared on
+    ``module``, the family's expert-layer module, from inside its compact
+    call. m (B, T, d) float32 -> (sum_j w_j Expert_{sel_j}(m) over the
+    experts held (B, T, d) float32, stats (3,) int32: STAT_NAMES), all
+    under the scope ``moe_route``. ``expert_bias`` is a leaf no gradient
+    reaches."""
+    B, T, d = m.shape
+    first, count = cfg.experts_held
+    F = cfg.moe_intermediate_size
+    dtype = jnp.dtype(cfg.compute_dtype)
+    init, pd = _dense_init(), jnp.dtype(cfg.param_dtype)
+    w_router = module.param("router", init, (d, cfg.num_experts), pd)
+    bias = module.param("expert_bias", nn.initializers.zeros,
+                        (cfg.num_experts,), pd)
+    w_gate = module.param("w_gate", init, (count, d, F), pd)
+    w_up = module.param("w_up", init, (count, d, F), pd)
+    w_down = module.param("w_down", init, (count, F, d), pd)
+    x = m.reshape(B * T, d)
+    with jax.named_scope("moe_route"):
+        sel, w = route(x, w_router, bias, cfg.num_experts_per_tok,
+                       norm=cfg.route_norm, scale=cfg.route_scale,
+                       eps=route_eps)
+        routed, stats = moe.routed_experts(
+            x.astype(dtype), sel, w, w_gate.astype(dtype),
+            w_up.astype(dtype), w_down.astype(dtype), first, count,
+            cfg.num_experts)
+        # Saved under remat (the families' SAVED_NAMES): as large as the
+        # block's output; what follows the layer needs it, and recomputing
+        # it is k row gathers a token.
+        routed = checkpoint_name(routed, "moe_routed").reshape(B, T, d)
+    return routed, stats
+
+
+def decoder_layers(block_cls, cfg: Any, mesh: Any, h: jax.Array):
+    """h (B, T, d) float32 through the family's ``cfg.n_layer`` blocks
+    (``block_cls(cfg, layer, name=f"h_{layer}")(h) -> (h, stats)``, created
+    under the calling module; rematerialised by ``cfg.remat`` /
+    ``cfg.remat_policy`` keeping SAVED_NAMES) -> (h, {name: (expert layers,)
+    int32} for STAT_NAMES: the layers past ``cfg.num_dense_layers``)."""
+    if cfg.remat:
+        block_cls = remat_block(block_cls, cfg.remat_policy, SAVED_NAMES,
+                                static_argnums=())
+    stats = []
+    for i in range(cfg.n_layer):
+        h, st = block_cls(cfg, i, name=f"h_{i}")(h)
+        h = constrain_acts(mesh, h)
+        if i >= cfg.num_dense_layers:
+            stats.append(st)
+    stats = (jnp.stack(stats) if stats else jnp.zeros(
+        (0, len(STAT_NAMES)), jnp.int32))
+    return h, {name: stats[:, n] for n, name in enumerate(STAT_NAMES)}

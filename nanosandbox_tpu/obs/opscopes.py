@@ -5,7 +5,7 @@ traced it under (``metadata={op_name="jit(traced)/transpose(jvp(GPT))/h_3/
 mlp/c_fc/dot_general"}``), backward pass included. Flax names the modules
 (``h_3/attn/c_attn``, ``mlp``, ``ln_1``, ``ln_f``, ``wte``, ``wpe``; for
 models/afmoe.py ``attn_sliding`` / ``attn_full``, ``moe_shared``, ``ln_in``
-...) and
+...; for models/lfm2.py ``conv``, ``attn_full``, ``operator_norm`` ...) and
 the trainer adds ``jax.named_scope`` where no module names the work
 (``lm_head_loss``, ``optimizer``, ``grad_norm``, ``accum``). A device trace
 names its events by instruction (``%fusion.24 = ...``), a name the compiler
@@ -37,7 +37,11 @@ PARTS = ("attn", "mlp", "ln", "embed", "lm_head_loss", "optimizer",
          # models/afmoe.py: its attention modules are named by their kind
          # (nothing of it falls under "attn"), its expert layer by stage.
          "attn_sliding", "attn_full", "moe_route", "moe_experts",
-         "moe_shared")
+         "moe_shared",
+         # models/lfm2.py: the gated short convolution's two projections,
+         # and its gates and taps (ops/short_conv.py, whatever implements
+         # them); its attention is an "attn_full", its experts as above.
+         "conv", "conv_mix")
 UNSCOPED = "unscoped"
 
 # Path component -> part. ``wte.attend`` is the tied head's matmul where the
@@ -57,6 +61,10 @@ _COMPONENT = {
     "moe_shared": "moe_shared",
     "ln_in": "ln", "ln_post_attn": "ln", "ln_pre_mlp": "ln",
     "ln_post_mlp": "ln", "lm_head": "lm_head_loss",
+    # models/lfm2.py. The named scope ``conv_mix`` lies inside the module
+    # ``conv``, as ``moe_experts`` inside ``moe_route``.
+    "conv": "conv", "conv_mix": "conv_mix",
+    "operator_norm": "ln", "ffn_norm": "ln", "embedding_norm": "ln",
 }
 
 # `%fusion.24 = f32[...] fusion(...), ..., metadata={... op_name="..." ...}`;

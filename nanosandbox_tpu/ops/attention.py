@@ -77,7 +77,7 @@ __all__ = ["causal_attention", "causal_attention_qkv", "attention_layout",
            "causal_attention_gqa", "xla_attention", "flash_attention",
            "flash_attention_dropout", "flash_attention_lse",
            "flash_attention_lse_dropout", "flash_attention_qkv",
-           "flash_attention_gqa", "gqa_layout_supported",
+           "flash_attention_gqa", "gqa_layout_supported", "gqa_route",
            "hash_dropout_keep_mask", "qk_prep", "qkv_layout_supported",
            "resolve_attention_impl", "resolve_gqa_impl", "rotary_table"]
 
@@ -2235,11 +2235,11 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def resolve_gqa_impl(impl: str, head_dim: int, T: int) -> str:
-    """What the grouped-query entry and its prologue (qk_prep) run at these
-    shapes: 'pallas' / 'pallas_interpret' where the resolved impl is one and
-    the kernels can walk them (gqa_layout_supported: decided from shapes at
-    trace time, like attention_layout; a trainer's 8-token init batch is
-    what does not), 'xla' everywhere else."""
+    """What the grouped-query kernels and their prologue (qk_prep) run at
+    these shapes: 'pallas' / 'pallas_interpret' where the resolved impl is
+    one and the kernels can walk them (gqa_layout_supported: decided from
+    shapes at trace time, like attention_layout; a trainer's 8-token init
+    batch is what does not), 'xla' everywhere else."""
     impl = resolve_attention_impl(impl)
     if impl not in ("pallas", "pallas_interpret", "xla"):
         raise ValueError(
@@ -2248,25 +2248,51 @@ def resolve_gqa_impl(impl: str, head_dim: int, T: int) -> str:
     return impl if gqa_layout_supported(head_dim, T) else "xla"
 
 
+def gqa_route(impl: str, head_dim: int, T: int,
+              window: int | None = None) -> str:
+    """Which entry causal_attention_gqa takes at these shapes, from the
+    resolved impl and the shapes alone (no option): 'btc-gqa'
+    (flash_attention_gqa on the projections' (B, T, heads*D) layout: whole
+    128-lane heads, resolve_gqa_impl), 'bhtd-rep' (a Pallas impl at head
+    size 64 over whole 128-row blocks, no window: the (B, H, T, D) entry
+    flash_attention, which runs D = 64 unpadded and knows no window, fed
+    the KV heads repeated H // G times, dK / dV summed back over the group
+    by the repeat's transpose; no (B, H, T, T) array either) or 'xla'
+    (xla_attention with the KV heads repeated: the CPU, an init batch,
+    every other shape)."""
+    if resolve_gqa_impl(impl, head_dim, T) != "xla":
+        return "btc-gqa"
+    if (resolve_attention_impl(impl) in ("pallas", "pallas_interpret")
+            and head_dim == 64 and T % LANES == 0 and window is None):
+        return "bhtd-rep"
+    return "xla"
+
+
 def causal_attention_gqa(q: jax.Array, k: jax.Array, v: jax.Array,
                          n_head: int, n_kv_head: int, *,
                          window: int | None = None, impl: str = "auto",
                          scope: str = KERNEL_SCOPE) -> jax.Array:
     """Causal attention with grouped KV heads and an optional window from
-    q (B, T, H*D), k / v (B, T, G*D) to o (B, T, H*D). The Pallas impls take
-    flash_attention_gqa where the shapes allow (resolve_gqa_impl) and 'xla'
-    everything: xla_attention over (B, H, T, D) with the KV heads
-    repeated."""
+    q (B, T, H*D), k / v (B, T, G*D) to o (B, T, H*D), by gqa_route: the
+    grouped-query kernels where heads are whole 128 lanes, the (B, H, T, D)
+    kernels on repeated KV heads at head size 64 without a window,
+    xla_attention everywhere else. ``scope`` names the Pallas routes'
+    custom calls (%<scope>.N)."""
     B, T, HD = q.shape
     D = HD // n_head
-    impl = resolve_gqa_impl(impl, D, T)
-    if impl != "xla":
+    route = gqa_route(impl, D, T, window)
+    interpret = resolve_attention_impl(impl) == "pallas_interpret"
+    if route == "btc-gqa":
         return flash_attention_gqa(q, k, v, n_head, n_kv_head, window,
-                                   impl == "pallas_interpret", scope)
+                                   interpret, scope)
     heads = lambda x, n: x.reshape(B, T, n, D).transpose(0, 2, 1, 3)
     rep = n_head // n_kv_head
-    o = xla_attention(heads(q, n_head),
-                      jnp.repeat(heads(k, n_kv_head), rep, axis=1),
-                      jnp.repeat(heads(v, n_kv_head), rep, axis=1),
-                      window=window)
+    qh = heads(q, n_head)
+    kh = jnp.repeat(heads(k, n_kv_head), rep, axis=1)
+    vh = jnp.repeat(heads(v, n_kv_head), rep, axis=1)
+    if route == "bhtd-rep":
+        with jax.named_scope(scope):
+            o = flash_attention(qh, kh, vh, True, None, interpret, "compact")
+    else:
+        o = xla_attention(qh, kh, vh, window=window)
     return o.transpose(0, 2, 1, 3).reshape(B, T, HD)

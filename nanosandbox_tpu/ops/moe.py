@@ -66,7 +66,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["chunk_rows", "plan_pairs", "chunk_plan", "dispatch", "combine",
-           "grouped_matmul", "expert_ffn", "routed_experts",
+           "grouped_matmul", "expert_ffn", "routed_experts", "gmm_tiling",
            "resolve_gmm_impl", "resolve_row_mover", "GMM_IMPLS", "MOVERS"]
 
 GMM_IMPLS = ("auto", "ragged_dot", "megablox", "megablox_interpret")
@@ -447,6 +447,19 @@ def _combine_bwd(mover, res, dout):
 combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def gmm_tiling(rows: int, K: int, N: int) -> tuple[int, int, int]:
+    """megablox's (m, k, n) tiles for ``rows`` x (K, N) products: ONE tuple
+    serves a product's forward, its dgrad (the same kernel with K and N
+    exchanged) and its wgrad. MEGABLOX_TILING, no tile larger than its
+    dimension. Measured at both cells' expert shapes (16,384 rows, 8 / 16
+    groups, three products forward and nine with the backward): at
+    2048 x 1024 it is the best of those tried (PERF.md §6, PR 29), and at
+    2048 x 1792, where its 1024 leaves a ragged 768 that the kernel masks,
+    it still is: 7.29 ms against 8.48 for tiles of 896 and 12.77 for tiles of
+    256, the only size that divides both dimensions (PERF.md §6, PR 33)."""
+    return tuple(min(t, s) for t, s in zip(MEGABLOX_TILING, (rows, K, N)))
+
+
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
                    *, impl: str = "auto") -> jax.Array:
     """lhs (rows, K) sorted by group, rhs (groups, K, N): row r of group g
@@ -464,8 +477,7 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
         # %gmm.N (forward, dgrad) and %tgmm.N (wgrad) in a device trace.
         from jax.experimental.pallas.ops.tpu.megablox import ops
 
-        tiling = tuple(min(t, s) for t, s in zip(
-            MEGABLOX_TILING, (lhs.shape[0], lhs.shape[1], rhs.shape[2])))
+        tiling = gmm_tiling(lhs.shape[0], lhs.shape[1], rhs.shape[2])
         out = ops.gmm(lhs, rhs, group_sizes.astype(jnp.int32),
                       lhs.dtype, tiling, None, None, False,
                       impl == "megablox_interpret")

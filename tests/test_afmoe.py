@@ -14,7 +14,7 @@ import pytest
 from chipbench import weights_afmoe
 from chipbench.reference import afmoe as ref
 from nanosandbox_tpu.config import AfmoeConfig, TrainConfig
-from nanosandbox_tpu.models import afmoe
+from nanosandbox_tpu.models import afmoe, experts
 from nanosandbox_tpu.ops import attention as A
 from nanosandbox_tpu.ops import moe
 
@@ -73,6 +73,14 @@ def program_loss(cfg, params, x, y):
 
 def flat(tree):
     return weights_afmoe.flatten(tree)
+
+
+def route(cfg, x, w_router, bias):
+    """The shared router (models/experts.py) with this family's arguments,
+    as models/afmoe.Moe hands them over."""
+    return experts.route(x, w_router, bias, cfg.num_experts_per_tok,
+                         norm=cfg.route_norm, scale=cfg.route_scale,
+                         eps=afmoe.ROUTE_EPS)
 
 
 # -- the program against the plain reference ----------------------------------
@@ -192,9 +200,9 @@ def test_selection_bias_moves_the_selection_and_not_the_weights(seeded):
     cfg = model_cfg()
     xs = jax.random.normal(jax.random.key(4), (64, SIZES["n_embd"]))
     router = params["h_1"]["moe"]["router"]
-    sel0, w0 = afmoe.route(xs, router, jnp.zeros(8), cfg)
+    sel0, w0 = route(cfg, xs, router, jnp.zeros(8))
     bias = jnp.zeros(8).at[5].set(10.0)         # expert 5 wins every token
-    sel1, w1 = afmoe.route(xs, router, bias, cfg)
+    sel1, w1 = route(cfg, xs, router, bias)
     assert bool((sel1 == 5).any(axis=1).all()) and not bool(
         (sel0 == 5).any(axis=1).all())
     # a pair both selections hold weighs by its own score, not score + bias
@@ -309,7 +317,7 @@ def test_gqa_entry_refuses_shapes_it_cannot_walk():
 
 
 def _prologue(impl, x, scale, heads, theta):
-    return afmoe.HeadRMSNorm(heads, 1e-5, "float32").apply(
+    return experts.HeadRMSNorm(heads, 1e-5, "float32").apply(
         {"params": {"scale": scale}}, x, theta, impl)
 
 
@@ -363,8 +371,8 @@ def test_qk_prep_refuses_shapes_it_cannot_walk():
 def test_rotary_positions_only_on_window_layers(monkeypatch, seeded):
     params, x, _ = seeded
     calls = []
-    real = afmoe.rotary
-    monkeypatch.setattr(afmoe, "rotary",
+    real = experts.rotary
+    monkeypatch.setattr(experts, "rotary",
                         lambda t, theta: calls.append(t.shape) or real(t, theta))
     afmoe.Afmoe(model_cfg()).apply({"params": params}, x)
     # q and k of the two sliding layers; the full layer none
@@ -419,7 +427,7 @@ def test_a_router_biased_to_one_expert_drops_nothing(factor):
     params = {**init["params"], "router": jnp.asarray(router)}
     m = jnp.abs(jax.random.normal(jax.random.key(1), (2, 2048, d))) + 0.1
     x = m.reshape(-1, d)
-    sel, w = afmoe.route(x, params["router"], params["expert_bias"], cfg)
+    sel, w = route(cfg, x, params["router"], params["expert_bias"])
     out, stats = jax.jit(moe.routed_experts, static_argnums=(6, 7, 8, 9))(
         x, sel, w, params["w_gate"], params["w_up"], params["w_down"],
         first, count, E, factor)

@@ -24,6 +24,13 @@ tests hold every later PR to what the chip accepts, at no chip time:
   * ``qk_prep``, the one-pass head RMSNorm + rotary positions in front of
     those kernels, forward and backward, at the cell's q (2, 8192, 4096) /
     32 heads and k (2, 8192, 512) / 4 heads, with and without positions;
+  * the ``lfm2`` family's shapes through the same entries: grouped-query
+    attention at head size 64 (2 x 8192 tokens, 32 query heads on 8 KV
+    heads of 64: ``causal_attention_gqa``'s 'bhtd-rep' route, the
+    (B, H, T, D) kernels on repeated KV heads), the grouped matmul at its
+    buffer (32,768 rows, 8 experts, 2048 x 1792 and back) with the tiling
+    ``ops.moe.gmm_tiling`` chooses there, the row mover at 16,384 tokens
+    of 4 slots;
   * all nine serving variants — ``flash_decode`` / ``flash_decode_paged``
     / ``flash_prefill_paged`` x fp / int8 / int4 — at B=8, H=12, D=64,
     the engine's default page 16 and page 32, prefill T = a page and
@@ -149,6 +156,53 @@ def test_flash_attention_gqa_forward_and_backward(sds, window):
     assert not MOVES_AN_ACTIVATION.search(txt)
 
 
+def test_gqa_at_head_size_64_forward_and_backward(sds):
+    """32 query heads on 8 KV heads of 64 lanes, the lfm2 cell's one
+    attention layer: the (B, H, T, D) flash kernels on repeated KV heads
+    (ops.attention.gqa_route: 'bhtd-rep'), named after the layer's scope,
+    and no (B, H, T, T) array anywhere."""
+    from nanosandbox_tpu.ops.attention import causal_attention_gqa, gqa_route
+
+    B, T, H, G, D = 2, 8192, 32, 8, 64
+    assert gqa_route("pallas", D, T) == "bhtd-rep"
+
+    def loss(q, k, v):
+        with jax.named_scope("Model"):   # takes the jvp( ) wrappers, as the
+            # model's own outermost scope does in a step
+            return causal_attention_gqa(q, k, v, H, G, impl="pallas",
+                                        scope="attn_full"
+                                        ).astype(jnp.float32).sum()
+
+    q = sds((B, T, H * D), jnp.bfloat16)
+    kv = sds((B, T, G * D), jnp.bfloat16)
+    txt = compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    calls = re.findall(r"%(attn_full[.0-9]*) = [^\n]*custom-call\(", txt)
+    assert len(set(calls)) >= 2, calls        # forward, fused backward
+    assert not re.search(rf"\[{B},{H},{T},{T}\]|\[{B * H},{T},{T}\]", txt)
+
+
+def test_gated_short_conv_forward_and_backward(sds):
+    """The lfm2 cell's conv mixer between its projections, (2, 8192, 3 x
+    2048) bfloat16 with a (2048, 3) filter: ONE custom call a pass
+    (%conv_mix.N), and no float32 array of activation size beside it."""
+    from nanosandbox_tpu.ops.short_conv import (gated_short_conv,
+                                                resolve_conv_impl)
+
+    B, T, d, L = 2, 8192, 2048, 3
+    assert resolve_conv_impl("pallas", T, d) == "pallas"
+
+    def loss(bcx, w):
+        return gated_short_conv(bcx, w, "pallas").astype(jnp.float32).sum()
+
+    args = (sds((B, T, 3 * d), jnp.bfloat16), sds((d, L), jnp.float32))
+    for fn in (jax.grad(loss, argnums=(0, 1)),
+               lambda bcx, w: gated_short_conv(bcx, w, "pallas")):
+        txt = compiled_text(fn, *args)
+        assert len(re.findall(r"%conv_mix[.0-9]* = [^\n]*custom-call\(",
+                              txt)) == 1
+        assert not re.search(rf"f32\[{B},{T},", txt)
+
+
 @pytest.mark.parametrize("theta", [10000.0, None], ids=["rotary", "none"])
 @pytest.mark.parametrize("heads", [32, 4], ids=["q-32-heads", "k-4-heads"])
 def test_qk_prep_forward_and_backward(sds, heads, theta):
@@ -173,37 +227,45 @@ def test_qk_prep_forward_and_backward(sds, heads, theta):
         assert not re.search(rf"f32\[{B},{T},", txt)
 
 
-def test_megablox_grouped_matmul_backward(sds):
-    from nanosandbox_tpu.ops.moe import grouped_matmul
+@pytest.mark.parametrize("experts, K, N", [
+    (16, 2048, 1024), (8, 2048, 1792), (8, 1792, 2048)],
+    ids=["trinity-mini", "lfm2-gate-up", "lfm2-down"])
+def test_megablox_grouped_matmul_backward(sds, experts, K, N):
+    """At each cell's buffer and expert shapes, with the tiling
+    ops.moe.gmm_tiling gives there: the one measured at both."""
+    from nanosandbox_tpu.ops.moe import gmm_tiling, grouped_matmul
+
+    assert gmm_tiling(32768, K, N) == (512, 1024, 1024)
 
     def loss(xs, w, sizes):
         return grouped_matmul(xs, w, sizes,
                               impl="megablox").astype(jnp.float32).sum()
 
     txt = compiled_text(
-        jax.grad(loss, argnums=(0, 1)), sds((32768, 2048), jnp.bfloat16),
-        sds((16, 2048, 1024), jnp.bfloat16), sds((16,), jnp.int32))
+        jax.grad(loss, argnums=(0, 1)), sds((32768, K), jnp.bfloat16),
+        sds((experts, K, N), jnp.bfloat16), sds((experts,), jnp.int32))
     # dgrad (the forward product against the transposed weights) and wgrad;
     # the forward's own output is not needed for this loss's gradient
     calls = re.findall(r"%([\w.]*gmm[\w.]*) = [^\n]*custom-call\(", txt)
     assert len(calls) == 2 and sum("tgmm" in c for c in calls) == 1, calls
 
 
+@pytest.mark.parametrize("k", [8, 4], ids=["k8", "k4"])
 @pytest.mark.parametrize("pass_", ["combine", "dispatch_backward",
                                    "combine_backward"])
-def test_moe_row_mover(sds, pass_):
-    """The routed experts' rows back to their tokens at the cell's shapes
-    (16,384 tokens of 8 slots, a 32,768-row buffer of 2048): ONE kernel a
+def test_moe_row_mover(sds, pass_, k):
+    """The routed experts' rows back to their tokens at the cells' shapes
+    (16,384 tokens of 8 slots, or of 4, a 32,768-row buffer of 2048): ONE kernel a
     pass (%moe_rows.N), weighted into float32 (combine), plain into the
     compute type (dispatch's backward), or one number a row (dw in
     combine's backward, whose row gathers stay XLA's)."""
     from nanosandbox_tpu.ops import moe
 
-    plan = {"dest": sds((16384, 8), jnp.int32),
+    plan = {"dest": sds((16384, k), jnp.int32),
             "row_valid": sds((32768,), jnp.bool_),
             "row_token": sds((32768,), jnp.int32),
             "row_pair": sds((32768,), jnp.int32)}
-    y, w = sds((32768, 2048), jnp.bfloat16), sds((16384, 8), jnp.float32)
+    y, w = sds((32768, 2048), jnp.bfloat16), sds((16384, k), jnp.float32)
     if pass_ == "combine":
         txt = compiled_text(
             lambda y, w, plan: moe.combine(y, w, plan, "pallas"), y, w, plan)

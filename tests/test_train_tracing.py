@@ -175,6 +175,33 @@ def test_op_parts_of_the_tiny_step(tiny_cfg, loss_chunk_size):
     assert opscopes.step_parts() is None
 
 
+def test_op_parts_of_a_two_layer_lfm2_step(tiny_cfg):
+    """The ``lfm2`` family's step: a conv layer and an attention layer with
+    experts; its ops fall under conv (the projections) and conv_mix (the
+    gates and taps), attn_full, moe_route and moe_experts, every matmul is
+    somebody's, and the map names no part the table lacks."""
+    trainer = Trainer(tiny_cfg.replace(
+        model_family="lfm2", n_layer=2, n_head=2, n_kv_head=1, head_dim=16,
+        n_embd=32, layer_types="conv,full", num_dense_layers=0,
+        moe_intermediate_size=24, num_experts=4, num_experts_per_tok=2,
+        experts_held=(0, 2), loss_chunk_size=16))
+    train_step, _ = trainer.compiled_steps()
+    text = train_step.lower(*trainer._step_operands()).compile().as_text()
+    parts = opscopes.op_parts(text)
+    work = _working_instructions(text)
+    labels = set(opscopes.PARTS) | {opscopes.UNSCOPED}
+    assert {parts[n] for n, _ in work} <= labels      # none unmapped
+    by_part = {p: [n for n, _ in work if parts[n] == p] for p in labels}
+    for part in ("conv", "conv_mix", "attn_full", "moe_route", "moe_experts",
+                 "ln", "embed", "lm_head_loss", "optimizer"):
+        assert by_part[part], part
+    for part in ("attn", "mlp", "attn_sliding", "moe_shared"):
+        assert not by_part[part], part
+    dots = [n for n, line in work if re.search(r"\sdot\(", line)]
+    assert dots and all(parts[n] != opscopes.UNSCOPED for n in dots)
+    opscopes.set_provider(None)
+
+
 def test_lowering_for_the_map_keeps_the_live_budget_of_one(tiny_cfg):
     """step_op_parts() may trace the step once more, which is allowed for by
     name; the live loop's own budget stays one trace."""
@@ -230,6 +257,15 @@ def test_lowering_for_the_map_keeps_the_live_budget_of_one(tiny_cfg):
      "moe_route/moe_route/while/body/closed_call/cond/branch_1_fun/"
      "transpose(jvp(jit(_pallas_rows_to_tokens)))/moe_rows/pallas_call",
      "moe_route"),
+    # lfm2's conv module: its projections are the module's, the gates and
+    # taps a part of their own inside it; its three norms are norms
+    ("jit(traced)/jvp(Lfm2)/h_0/conv/in_proj/dot_general", "conv"),
+    ("jit(traced)/transpose(jvp(Lfm2))/h_3/conv/conv_mix/mul", "conv_mix"),
+    ("jit(traced)/jvp(Lfm2)/h_2/attn_full/attn_full/pallas_call",
+     "attn_full"),
+    ("jit(traced)/jvp(Lfm2)/h_1/operator_norm/mul", "ln"),
+    ("jit(traced)/transpose(jvp(Lfm2))/h_1/ffn_norm/mul", "ln"),
+    ("jit(traced)/jvp(Lfm2)/embedding_norm/reduce_sum", "ln"),
 ])
 def test_part_of_a_scope_path(op_name, part):
     assert opscopes.part_of(op_name) == part
