@@ -58,7 +58,9 @@ grouped matmuls and the activation between them, ops/moe.expert_ffn);
 ``%qk_prep.N``, apart from the flash kernels' ``%attn_sliding.N`` /
 ``%attn_full.N``; it names no part, so its time is the module's).
 The family's custom calls in a device trace: ``%attn_sliding.N`` /
-``%attn_full.N``, ``%qk_prep.N``, ``%gmm.N`` / ``%tgmm.N`` (megablox) and
+``%attn_full.N`` (two a layer: the forward and the one-pass backward that
+gives dQ, dK and dV; three where ``trainer_init``'s ``gqa_bwd`` reads
+'split'), ``%qk_prep.N``, ``%gmm.N`` / ``%tgmm.N`` (megablox) and
 ``%moe_rows.N`` (ops/moe.py's row mover: the routed experts' rows summed
 back to their tokens, inside ``moe_route``, so its time is that part's).
 """
@@ -81,7 +83,7 @@ from nanosandbox_tpu.models.experts import (STAT_NAMES, HeadRMSNorm, SwiGLU,
                                             rms_norm as _rms_norm)
 from nanosandbox_tpu.ops import moe
 from nanosandbox_tpu.ops.attention import (causal_attention_gqa,
-                                           resolve_gqa_impl)
+                                           resolve_gqa_bwd, resolve_gqa_impl)
 
 ROUTE_EPS = 1e-20   # in the sum the selected scores are divided by
 
@@ -226,6 +228,11 @@ def build(cfg: AfmoeConfig, mesh: Any):
     # (B, T, heads*D) layout ('btc-gqa'); 'xla': head_rms_norm, rotary and
     # xla_attention ('bhtd').
     prep = resolve_gqa_impl(cfg.attention_impl, cfg.head_dim, cfg.block_size)
+    # The grouped-query kernels' backward: 'fused' (dQ, dK and dV from one
+    # walk of the score tiles, dK / dV of a KV head's whole sequence in
+    # VMEM), 'split' (two kernels: a sequence too long for that) or 'xla'.
+    bwd = resolve_gqa_bwd(cfg.attention_impl, cfg.head_dim, cfg.block_size,
+                          jnp.dtype(cfg.compute_dtype).itemsize)
     # What brings the routed experts' rows back to their tokens, resolved as
     # ops.moe.routed_experts will for a batch of whole sequences (a batch is
     # a multiple of block_size tokens): 'pallas' (%moe_rows.N, only the rows
@@ -233,7 +240,7 @@ def build(cfg: AfmoeConfig, mesh: Any):
     mover = moe.resolve_row_mover("auto", cfg.block_size, cfg.n_embd)
     return Afmoe(cfg, mesh=mesh), {
         "attn_layout": "bhtd" if prep == "xla" else "btc-gqa",
-        "qk_prep": prep, "moe_row_mover": mover,
+        "qk_prep": prep, "gqa_bwd": bwd, "moe_row_mover": mover,
         "layer_types": ",".join(cfg.layer_types),
         "experts_held": list(cfg.experts_held)}
 

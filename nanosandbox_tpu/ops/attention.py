@@ -68,7 +68,10 @@ def _tpu_params(*semantics: str):
     kernels are unaffected (124M bench measured identical), and with it
     the single-shard envelope extends through T=32768 (r5, v5e)."""
     return pltpu.CompilerParams(dimension_semantics=semantics,
-                                vmem_limit_bytes=100 * 1024 * 1024)
+                                vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+VMEM_LIMIT_BYTES = 100 * 1024 * 1024
 
 NEG_INF = -1e30
 LANES = 128  # minor-dim register width; row stats are replicated across it
@@ -79,7 +82,8 @@ __all__ = ["causal_attention", "causal_attention_qkv", "attention_layout",
            "flash_attention_lse_dropout", "flash_attention_qkv",
            "flash_attention_gqa", "gqa_layout_supported", "gqa_route",
            "hash_dropout_keep_mask", "qk_prep", "qkv_layout_supported",
-           "resolve_attention_impl", "resolve_gqa_impl", "rotary_table"]
+           "resolve_attention_impl", "resolve_gqa_bwd", "resolve_gqa_impl",
+           "rotary_table"]
 
 
 # ---------------------------------------------------------------------------
@@ -1619,21 +1623,35 @@ flash_attention_qkv.defvjp(_flash_qkv_fwd_rule, _flash_qkv_bwd_rule)
 # pairing, no zeroed lanes. The same tile functions (_fwd_tile, _bwd_tile,
 # _expand_stat_tile, _stat_column_to_row) and the compact statistic layout:
 #
-#   * forward and dQ: grid (B, H, q blocks); k and v arrive as whole-T
-#     blocks of KV head h // (H // G), so the 8 query heads of a KV head and
-#     all their q blocks reuse one fetch. The key-block walk has a LOWER
-#     bound beside _causal_kb_range's upper one (_window_kb_range); blocks
-#     wholly inside the window and below the diagonal run unmasked.
-#   * dK/dV: grid (B, G, key blocks, query heads of the KV head, q blocks
-#     walked), the last two in order: dk and dv of one (KV head, key block)
-#     accumulate in float32 scratch over the H // G query heads and the q
-#     blocks that can see the key block, and are written once. Only the q
-#     blocks inside causal + window reach are fetched (five of sixteen at
-#     T = 8192, window 2048, blocks of 512), one (block_q, D) tile a step.
-#
-# The backward is the split strategy (dQ and dK/dV in two kernels, the
-# score tile recomputed in each): a fused one-pass walk would keep a
-# (T, (H // G) * D) float32 dq resident, 32 MB at T = 8192.
+#   * forward: grid (B, H, q blocks); k and v arrive as whole-T blocks of
+#     KV head h // (H // G), so the 8 query heads of a KV head and all their
+#     q blocks reuse one fetch. The key-block walk has a LOWER bound beside
+#     _causal_kb_range's upper one (_window_kb_range); blocks wholly inside
+#     the window and below the diagonal run unmasked.
+#   * backward, ONE pass (_flash_bwd_gqa_kernel): grid (B, G, query heads
+#     of the KV head, q blocks), the last two in order. A program is the
+#     forward's walk with _bwd_tile: every visible score tile is computed
+#     once and feeds all three gradients (five matmuls a tile). QUERY-major,
+#     so dq of the q block stays in registers and is written once, and what
+#     is resident is dk and dv of ONE KV head's whole sequence, float32 in
+#     VMEM scratch (2 * T * D * 4 bytes: 8 MB at T = 8192, D = 128), zeroed
+#     at a (b, g)'s first program and written out, scaled and cast, at its
+#     last; beside them the whole-T k / v blocks (fetched once a (b, g)) and
+#     the single-buffered dk / dv output blocks: 20 * T * D bytes in
+#     bfloat16. The key-major order would keep a (T, (H // G) * D) float32
+#     dq instead, 32 MB at T = 8192. Alone on a v5e at (2, 8192, 32 x 128)
+#     on 4 KV heads (PERF.md, PR 34): 10.65 ms against the split pair's
+#     18.66 with window 2048, 18.30 against 33.71 with none.
+#   * backward, split (_flash_bwd_gqa_dq_kernel, then
+#     _flash_bwd_gqa_dkv_kernel on a grid (B, G, key blocks, query heads of
+#     the KV head, q blocks walked)): the score tile recomputed in each,
+#     seven matmuls a tile, VMEM independent of T but for the whole-T k / v
+#     of the dQ kernel. It runs where the one-pass kernel's whole-T blocks
+#     do not fit VMEM: gqa_bwd_fused_fits, ONE predicate on (D, T, itemsize)
+#     decided at trace time (T > 36,864 at D = 128 in bfloat16), recorded by
+#     the trainer as trainer_init's ``gqa_bwd`` (resolve_gqa_bwd). At equal
+#     blocks the two give the same three arrays bit for bit: a key block's
+#     sums arrive query head outer, q block ascending, in both.
 
 def gqa_layout_supported(head_dim: int, T: int) -> bool:
     """Whether the grouped-query kernels can walk these shapes: whole
@@ -1805,6 +1823,77 @@ def _flash_bwd_gqa_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
+def _flash_bwd_gqa_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                          dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+                          block_q: int, block_k: int, sm_scale: float,
+                          window):
+    """dQ of one (query head, q block) and its share of dK / dV of the KV
+    head: the forward's walk with _bwd_tile, every visible score tile
+    computed ONCE. Grid (B, G, H // G, q blocks), the last two in order:
+    q / o / do / dq_ref (1, block_q, D) of query head g * rep + r;
+    k / v / dk / dv_ref (1, T, D) of KV head g, resident over the (r, q
+    block) programs of a (b, g); lse_ref (1, 1, T // 128, 128), the query
+    head's compact statistic; dk_acc / dv_acc (T, D) float32, zeroed at
+    the first program of a (b, g) and written out at the last. A key
+    block's sums arrive query head outer, q block ascending."""
+    r, qi = pl.program_id(2), pl.program_id(3)
+    num_kb = dk_acc.shape[0] // block_k
+
+    def key_rows(j):
+        return pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+
+    @pl.when(jnp.logical_and(r == 0, qi == 0))
+    def _zero():
+        def zero(j, _):
+            zeros = jnp.zeros((block_k, dk_acc.shape[1]), jnp.float32)
+            dk_acc[key_rows(j), :] = zeros
+            dv_acc[key_rows(j), :] = zeros
+        lax.fori_loop(0, num_kb, zero, None)
+
+    q = q_ref[0]
+    do = do_ref[0]
+    drow = jnp.sum(do.astype(jnp.float32) * o_ref[0].astype(jnp.float32),
+                   axis=1, keepdims=True)
+    lse = _expand_stat_tile(lse_ref[0, 0], qi * (block_q // LANES), block_q)
+    q_pos = qi * block_q + lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0)
+
+    def body(j, dq_acc, *, masked: bool):
+        rows = key_rows(j)
+        k = k_ref[0, rows, :]
+        v = v_ref[0, rows, :]
+        mask = None
+        if masked:
+            mask = _visible(q_pos, j * block_k + lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1), window)
+        p, ds = _bwd_tile(q, k, v, do, lse, drow, sm_scale=sm_scale,
+                          mask=mask)
+        ds = ds.astype(q.dtype)
+        dv_acc[rows, :] += lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_acc[rows, :] += lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return dq_acc + lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    dq = _walk_key_blocks(body, jnp.zeros(q.shape, jnp.float32), qi,
+                          block_q, block_k, window)
+    dq_ref[0] = (dq * sm_scale).astype(dq_ref.dtype)
+
+    @pl.when(jnp.logical_and(r == pl.num_programs(2) - 1,
+                             qi == pl.num_programs(3) - 1))
+    def _write():
+        def write(j, _):
+            rows = key_rows(j)
+            dk_ref[0, rows, :] = (dk_acc[rows, :] * sm_scale).astype(
+                dk_ref.dtype)
+            dv_ref[0, rows, :] = dv_acc[rows, :].astype(dv_ref.dtype)
+        lax.fori_loop(0, num_kb, write, None)
+
+
 def _gqa_geometry(q_shape, k_shape, n_head: int, n_kv_head: int):
     B, T, HD = q_shape
     D = HD // n_head
@@ -1849,16 +1938,64 @@ def _pallas_flash_fwd_gqa(q, k, v, *, n_head: int, n_kv_head: int, window,
         return call(q, k, v)
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "n_head", "n_kv_head", "window", "interpret", "scope"))
-def _pallas_flash_bwd_gqa(q, k, v, o, lse, do, *, n_head: int,
-                          n_kv_head: int, window, interpret: bool = False,
-                          scope: str = KERNEL_SCOPE):
-    """-> (dq, dk, dv) in the operands' shapes and dtypes."""
+# The one-pass backward's own blocks (the forward keeps DEFAULT_BLOCK), from
+# a probe of ten blockings on a v5e at the shapes above (PERF.md, PR 34):
+# 512 x 512 10.65 / 18.30 ms (window 2048 / none); 1024 x 1024 11.98 / 19.05;
+# 512 x 256 12.24 / 21.70; 256 x 512 12.54 / 21.88; 256 x 256 13.73 / 25.90:
+# a narrower block visits fewer part-masked tiles and loses more to the
+# shorter matmuls.
+GQA_BWD_BLOCK_Q = 512
+GQA_BWD_BLOCK_K = 512
+# What the one-pass backward may keep resident under _tpu_params' limit; the
+# 10 MiB left hold the q / o / dO / dQ blocks in flight and the score tiles.
+GQA_BWD_RESIDENT_BYTES = VMEM_LIMIT_BYTES - 10 * 1024 * 1024
+
+
+def gqa_bwd_fused_fits(head_dim: int, T: int, itemsize: int = 2) -> bool:
+    """Whether the one-pass backward's whole-T blocks of ONE KV head fit
+    VMEM: k and v double-buffered, dk and dv single (written once a KV
+    head), and the two float32 accumulators. From the shapes alone, at
+    trace time; itemsize is the operands' (2: bfloat16)."""
+    return T * head_dim * (6 * itemsize + 8) <= GQA_BWD_RESIDENT_BYTES
+
+
+def _gqa_bwd_fused(q, k, v, o, stats, do, *, n_head, n_kv_head, window,
+                   interpret):
+    """(dq, dk, dv) by the one-pass kernel; stats is the compact
+    (B, H, T // 128, 128) statistic."""
+    B, T, D, rep = _gqa_geometry(q.shape, k.shape, n_head, n_kv_head)
+    block_q, block_k = _clamp_blocks(T, GQA_BWD_BLOCK_Q, GQA_BWD_BLOCK_K)
+    q_blk = pl.BlockSpec((1, block_q, D),
+                         lambda b, g, r, i: (b, i, g * rep + r))
+    kv_all = pl.BlockSpec((1, T, D), lambda b, g, r, i: (b, 0, g))
+    kv_out = pl.BlockSpec((1, T, D), lambda b, g, r, i: (b, 0, g),
+                          pipeline_mode=pl.Buffered(1))
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_gqa_kernel, block_q=block_q,
+                          block_k=block_k, sm_scale=D ** -0.5,
+                          window=window),
+        grid=(B, n_kv_head, rep, T // block_q),
+        in_specs=[q_blk, kv_all, kv_all, q_blk, q_blk,
+                  pl.BlockSpec((1, 1, T // LANES, LANES),
+                               lambda b, g, r, i: (b, g * rep + r, 0, 0))],
+        out_specs=[q_blk, kv_out, kv_out],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((T, D), jnp.float32),
+                        pltpu.VMEM((T, D), jnp.float32)],
+        compiler_params=None if interpret else _tpu_params(
+            "parallel", "parallel", "arbitrary", "arbitrary"),
+        interpret=interpret,
+    )(q, k, v, o, do, stats)
+
+
+def _gqa_bwd_split(q, k, v, o, stats, do, *, n_head, n_kv_head, window,
+                   interpret):
+    """(dq, dk, dv) by the dQ kernel, then the dK/dV kernel."""
     B, T, D, rep = _gqa_geometry(q.shape, k.shape, n_head, n_kv_head)
     block_q, block_k = _clamp_blocks(T, DEFAULT_BLOCK, DEFAULT_BLOCK)
     num_qb = T // block_q
-    stats = lse.reshape(B, n_head, T // LANES, LANES)
     common = dict(block_q=block_q, block_k=block_k, sm_scale=D ** -0.5,
                   window=window)
     params = lambda *sem: None if interpret else _tpu_params(*sem)
@@ -1900,10 +2037,25 @@ def _pallas_flash_bwd_gqa(q, k, v, o, lse, do, *, n_head: int,
                                "arbitrary", "arbitrary"),
         interpret=interpret,
     )
-    with jax.named_scope(scope):
-        dq = dq_call(q, k, v, o, do, stats)
-        dk, dv = dkv_call(q, k, v, o, do, stats)
+    dq = dq_call(q, k, v, o, do, stats)
+    dk, dv = dkv_call(q, k, v, o, do, stats)
     return dq, dk, dv
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n_head", "n_kv_head", "window", "interpret", "scope"))
+def _pallas_flash_bwd_gqa(q, k, v, o, lse, do, *, n_head: int,
+                          n_kv_head: int, window, interpret: bool = False,
+                          scope: str = KERNEL_SCOPE):
+    """-> (dq, dk, dv) in the operands' shapes and dtypes: the one-pass
+    kernel where its whole-T blocks fit VMEM, the split pair elsewhere."""
+    B, T, D, _ = _gqa_geometry(q.shape, k.shape, n_head, n_kv_head)
+    stats = lse.reshape(B, n_head, T // LANES, LANES)
+    bwd = (_gqa_bwd_fused if gqa_bwd_fused_fits(D, T, k.dtype.itemsize)
+           else _gqa_bwd_split)
+    with jax.named_scope(scope):
+        return bwd(q, k, v, o, stats, do, n_head=n_head,
+                   n_kv_head=n_kv_head, window=window, interpret=interpret)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -2246,6 +2398,17 @@ def resolve_gqa_impl(impl: str, head_dim: int, T: int) -> str:
             f"grouped-query attention has impls 'pallas', "
             f"'pallas_interpret' and 'xla'; got {impl!r}")
     return impl if gqa_layout_supported(head_dim, T) else "xla"
+
+
+def resolve_gqa_bwd(impl: str, head_dim: int, T: int,
+                    itemsize: int = 2) -> str:
+    """What flash_attention_gqa's backward runs at these shapes: 'fused'
+    (dQ, dK and dV from one walk of the score tiles, gqa_bwd_fused_fits),
+    'split' (the dQ kernel, then the dK/dV kernel: a T whose whole-T
+    accumulators do not fit VMEM) or 'xla' (no kernel: resolve_gqa_impl)."""
+    if resolve_gqa_impl(impl, head_dim, T) == "xla":
+        return "xla"
+    return "fused" if gqa_bwd_fused_fits(head_dim, T, itemsize) else "split"
 
 
 def gqa_route(impl: str, head_dim: int, T: int,

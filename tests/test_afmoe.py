@@ -277,25 +277,50 @@ def test_window_mask_against_a_dense_mask(window):
     np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-6)
 
 
-@pytest.mark.parametrize("window", [None, 256, 200, 64, 1000],
-                         ids=lambda w: f"window-{w}")
-def test_gqa_window_kernels_equal_xla_attention(monkeypatch, window):
-    """The Pallas kernels in interpret mode (blocks of 128 at T = 512:
-    window aligned to the blocks, across them, inside one, wider than T)
-    against xla_attention with the same mask: output and all three
-    gradients."""
+def _gqa_operands(B, T, H, G, D, dtype=jnp.float32, seed=0):
+    """q, k, v and a fourth array of q's shape (weights of a loss, or dO)."""
+    ks = jax.random.split(jax.random.key(seed), 4)
+    return (jax.random.normal(ks[0], (B, T, H * D), dtype),
+            jax.random.normal(ks[1], (B, T, G * D), dtype),
+            jax.random.normal(ks[2], (B, T, G * D), dtype),
+            jax.random.normal(ks[3], (B, T, H * D), dtype))
+
+
+# (B, T, H, G, window, (block_q, block_k) of the one-pass backward; the
+# forward's blocks are 128). Five windows at T = 512: aligned to the blocks,
+# across them, inside one, wider than T. Then what the backward's walk keeps
+# between programs: two batch rows x two KV heads x three query heads a
+# group (the accumulators zeroed and written out at the right programs),
+# windows that are no multiple of a block, blocks that differ either way.
+GQA_CASES = {
+    **{f"window-{w}": (1, 512, 4, 2, w, (128, 128))
+       for w in (None, 256, 200, 64, 1000, 72, 333)},
+    "b2-g2-rep3-window-200": (2, 256, 6, 2, 200, (128, 128)),
+    "b2-g2-rep3-full": (2, 256, 6, 2, None, (128, 128)),
+    "bq256-bk128-window-200": (1, 512, 4, 2, 200, (256, 128)),
+    "bq128-bk256-window-200": (2, 512, 2, 1, 200, (128, 256)),
+    "bq256-bk128-full": (1, 512, 2, 2, None, (256, 128)),
+}
+
+
+@pytest.mark.parametrize("case", list(GQA_CASES))
+def test_gqa_window_kernels_equal_xla_attention(monkeypatch, case):
+    """The Pallas kernels in interpret mode (the forward, and the one-pass
+    backward: dQ, dK and dV from one walk of the score tiles) against
+    xla_attention with the same mask: output and all three gradients."""
+    B, T, H, G, window, blocks = GQA_CASES[case]
     monkeypatch.setattr(A, "DEFAULT_BLOCK", 128)
-    B, T, H, G, D = 1, 512, 4, 2, 128
-    ks = jax.random.split(jax.random.key(0), 4)
-    q = jax.random.normal(ks[0], (B, T, H * D), jnp.float32)
-    k = jax.random.normal(ks[1], (B, T, G * D), jnp.float32)
-    v = jax.random.normal(ks[2], (B, T, G * D), jnp.float32)
-    w = jax.random.normal(ks[3], (B, T, H * D), jnp.float32)
+    monkeypatch.setattr(A, "GQA_BWD_BLOCK_Q", blocks[0])
+    monkeypatch.setattr(A, "GQA_BWD_BLOCK_K", blocks[1])
+    assert A.resolve_gqa_bwd("pallas_interpret", 128, T, 4) == "fused"
+    q, k, v, w = _gqa_operands(B, T, H, G, 128)
 
     def run(impl):
         def loss(q, k, v):
+            # a scope a case: the jitted kernel calls are cached by their
+            # static arguments, which the patched blocks are not among
             o = A.causal_attention_gqa(q, k, v, H, G, window=window,
-                                       impl=impl, scope="attn_sliding")
+                                       impl=impl, scope=case)
             return jnp.sum(o * w), o
         return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
             q, k, v)
@@ -306,6 +331,67 @@ def test_gqa_window_kernels_equal_xla_attention(monkeypatch, window):
     np.testing.assert_allclose(o_p, o_x, atol=1e-5, rtol=1e-5)
     for got, want in zip(g_p, g_x):
         np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("window", [None, 200, 256],
+                         ids=lambda w: f"window-{w}")
+def test_gqa_fused_backward_equals_the_split_pair_bit_for_bit(
+        monkeypatch, window, dtype):
+    """At equal blocks a key block's sums arrive in the split dK/dV
+    kernel's order (query head outer, q block ascending) and a q block's
+    in the dQ kernel's: the same float32 sums, the same three arrays."""
+    for name in ("DEFAULT_BLOCK", "GQA_BWD_BLOCK_Q", "GQA_BWD_BLOCK_K"):
+        monkeypatch.setattr(A, name, 128)
+    B, T, H, G, D = 2, 384, 6, 2, 128
+    q, k, v, do = _gqa_operands(B, T, H, G, D, dtype, seed=1)
+    o, lse = A._pallas_flash_fwd_gqa(q, k, v, n_head=H, n_kv_head=G,
+                                     window=window, interpret=True)
+    stats = lse.reshape(B, H, T // 128, 128)
+    kw = dict(n_head=H, n_kv_head=G, window=window, interpret=True)
+    fused = A._gqa_bwd_fused(q, k, v, o, stats, do, **kw)
+    split = A._gqa_bwd_split(q, k, v, o, stats, do, **kw)
+    for got, want in zip(fused, split):
+        assert got.dtype == want.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+
+
+def test_gqa_backward_is_chosen_from_the_shapes_alone(monkeypatch):
+    """One predicate: the one-pass kernel while a KV head's whole-T k, v,
+    dk, dv and float32 accumulators fit VMEM, the split pair beyond, XLA
+    where no kernel walks the shapes; and the entry runs what it says."""
+    assert A.resolve_gqa_bwd("pallas", 128, 8192) == "fused"
+    assert A.resolve_gqa_bwd("pallas", 128, 36864) == "fused"
+    assert A.resolve_gqa_bwd("pallas", 128, 40960) == "split"
+    assert A.resolve_gqa_bwd("pallas", 256, 16384) == "fused"
+    assert A.resolve_gqa_bwd("pallas", 256, 20480) == "split"
+    assert A.resolve_gqa_bwd("pallas_interpret", 128, 16384, 4) == "fused"
+    assert A.resolve_gqa_bwd("pallas_interpret", 128, 32768, 4) == "split"
+    assert A.resolve_gqa_bwd("pallas", 64, 8192) == "xla"
+    assert A.resolve_gqa_bwd("xla", 128, 8192) == "xla"
+
+    ran = []
+
+    def spy(name):
+        real = getattr(A, name)
+
+        def wrapped(*args, **kw):
+            ran.append(name)
+            return real(*args, **kw)
+        monkeypatch.setattr(A, name, wrapped)
+
+    spy("_gqa_bwd_fused")
+    spy("_gqa_bwd_split")
+    monkeypatch.setattr(A, "DEFAULT_BLOCK", 128)
+    q, k, v, w = _gqa_operands(1, 256, 2, 1, 128)
+    grad = lambda scope: jax.grad(lambda q: jnp.sum(w * A.flash_attention_gqa(
+        q, k, v, 2, 1, 64, True, scope)))(q)
+    here = grad("fits")
+    monkeypatch.setattr(A, "GQA_BWD_RESIDENT_BYTES", 0)
+    np.testing.assert_allclose(grad("does-not-fit"), here, atol=1e-6)
+    assert ran == ["_gqa_bwd_fused", "_gqa_bwd_split"]
 
 
 def test_gqa_entry_refuses_shapes_it_cannot_walk():
@@ -611,6 +697,7 @@ def test_trainer_two_steps_save_restore_same_loss(afmoe_train_cfg):
     assert init.args["experts_held"] == [2, 4]
     assert init.args["layer_types"] == "sliding,full,sliding"
     assert init.args["qk_prep"] == "xla"    # heads of 16: no kernel takes them
+    assert init.args["gqa_bwd"] == "xla"
     rows = [s for s in process_tracer().spans() if s.name == "moe_rows"][-1]
     assert rows.args["moe_dropped"] == [0, 0] and len(rows.args["moe_held"]) == 2
     parts = set(opscopes.step_parts().values())

@@ -16,7 +16,9 @@ tests hold every later PR to what the chip accepts, at no chip time:
   * ``flash_attention_gqa``, the grouped-query windowed kernels of the
     ``afmoe`` family, forward and backward, at the Trinity-Mini cell's
     shapes (2 x 8192 tokens, 32 query heads on 4 KV heads of 128, window
-    2048 and full), and the routed experts' grouped matmul (megablox,
+    2048 and full: the forward and the one-pass backward), at the longest
+    sequence that backward takes (T = 36,864) and the first it leaves to
+    the split pair (40,960), and the routed experts' grouped matmul (megablox,
     forward, dgrad and wgrad) at the cell's buffer (32,768 rows, 16
     experts, 2048 x 1024), with the row mover that brings that buffer's
     rows back to their 16,384 tokens (ops.moe: combine, and dispatch's
@@ -59,7 +61,8 @@ from nanosandbox_tpu.ops import flash_decode as fd
 from nanosandbox_tpu.ops.attention import (flash_attention,
                                            flash_attention_dropout,
                                            flash_attention_gqa,
-                                           flash_attention_qkv, qk_prep)
+                                           flash_attention_qkv, qk_prep,
+                                           resolve_gqa_bwd)
 
 B, H, D, L = 8, 12, 64, 1024          # serving widths (GPT-2 124M heads)
 TRAIN_SHAPE = (16, 12, 1024, 64)       # the 124M train step's q/k/v
@@ -139,10 +142,11 @@ MOVES_AN_ACTIVATION = re.compile(r"= (?:bf16|f32)\[[^ ]* (?:copy|transpose)\(")
 GQA_SHAPE = (2, 8192, 32, 4, 128)
 
 
-@pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
-def test_flash_attention_gqa_forward_and_backward(sds, window):
-    B, T, H, G, D = GQA_SHAPE
-    scope = "attn_sliding" if window else "attn_full"
+def _gqa_custom_calls(sds, shape, window, scope):
+    """The custom calls of flash_attention_gqa's forward + backward at
+    ``shape``, by name; no copy or transpose of an activation beside
+    them."""
+    B, T, H, G, D = shape
 
     def loss(q, k, v):
         return flash_attention_gqa(q, k, v, H, G, window, False,
@@ -151,9 +155,31 @@ def test_flash_attention_gqa_forward_and_backward(sds, window):
     q = sds((B, T, H * D), jnp.bfloat16)
     kv = sds((B, T, G * D), jnp.bfloat16)
     txt = compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
-    # forward, dQ and dK/dV, each named after its scope
-    assert len(set(re.findall(rf"%({scope}[.0-9]*) = ", txt))) == 3
     assert not MOVES_AN_ACTIVATION.search(txt)
+    return set(re.findall(rf"%({scope}[.0-9]*) = [^\n]*custom-call\(", txt))
+
+
+@pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
+def test_flash_attention_gqa_forward_and_backward(sds, window):
+    """The forward and ONE backward kernel (dQ, dK and dV from one walk of
+    the score tiles), each named after its scope."""
+    scope = "attn_sliding" if window else "attn_full"
+    assert resolve_gqa_bwd("pallas", GQA_SHAPE[4], GQA_SHAPE[1]) == "fused"
+    assert len(_gqa_custom_calls(sds, GQA_SHAPE, window, scope)) == 2
+
+
+@pytest.mark.parametrize("T,bwd", [(36864, "fused"), (40960, "split")])
+@pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
+def test_flash_attention_gqa_backward_at_the_predicates_edge(sds, window, T,
+                                                             bwd):
+    """The longest sequence ops.attention.gqa_bwd_fused_fits lets through
+    (a KV head's whole-T k, v, dk, dv and float32 accumulators: 90 MiB of
+    VMEM) compiles as the one-pass kernel, and the first it turns away as
+    the split pair (forward, dQ, dK/dV)."""
+    _, _, H, G, D = GQA_SHAPE
+    assert resolve_gqa_bwd("pallas", D, T) == bwd
+    calls = _gqa_custom_calls(sds, (1, T, H, G, D), window, "attn_long")
+    assert len(calls) == {"fused": 2, "split": 3}[bwd]
 
 
 def test_gqa_at_head_size_64_forward_and_backward(sds):

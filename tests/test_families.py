@@ -33,8 +33,8 @@ TINY = {
                    moe_intermediate_size=24, num_experts=8,
                    num_experts_per_tok=2, experts_held=(2, 4),
                    route_scale=2.0),
-              {"attn_layout", "qk_prep", "moe_row_mover", "layer_types",
-               "experts_held"},
+              {"attn_layout", "qk_prep", "gqa_bwd", "moe_row_mover",
+               "layer_types", "experts_held"},
               152862720.0),
     "lfm2": (dict(n_layer=3, n_head=4, n_kv_head=2, head_dim=8, n_embd=32,
                   layer_types="conv,full,conv", num_dense_layers=1,

@@ -498,14 +498,16 @@ def test_trainer_init_records_attn_layout(tiny_cfg, case, want):
 
 
 @pytest.mark.parametrize("case,want", [
-    ("cpu-auto", ("xla", "bhtd")),        # 'auto' off the chip
-    ("kernels", ("pallas_interpret", "btc-gqa")),   # 'pallas' on the chip
-    ("kernels-heads-of-64", ("xla", "bhtd")),       # no whole-lane heads
+    ("cpu-auto", ("xla", "bhtd", "xla")),        # 'auto' off the chip
+    ("kernels", ("pallas_interpret", "btc-gqa", "fused")),  # on the chip
+    ("kernels-heads-of-64", ("xla", "bhtd", "xla")),   # no whole-lane heads
 ])
 def test_trainer_init_records_qk_prep(tiny_cfg, case, want):
     """The ``afmoe`` family's q/k head norm + rotary runs as one kernel
     exactly where its attention runs the grouped-query kernels; which, is
-    an argument of ``trainer_init`` beside ``attn_layout``."""
+    an argument of ``trainer_init`` beside ``attn_layout``, and beside
+    them ``gqa_bwd``, what those kernels' backward runs at the model's
+    sequence length (ops.attention.resolve_gqa_bwd)."""
     tracer = process_tracer()
     tracer.clear()
     cfg = tiny_cfg.replace(
@@ -517,5 +519,6 @@ def test_trainer_init_records_qk_prep(tiny_cfg, case, want):
         attention_impl="auto" if case == "cpu-auto" else "pallas_interpret")
     trainer = Trainer(cfg, mesh_devices=jax.devices()[:1])
     (init,) = [s for s in tracer.spans() if s.name == "trainer_init"]
-    assert (init.args["qk_prep"], init.args["attn_layout"]) == want
+    assert (init.args["qk_prep"], init.args["attn_layout"],
+            init.args["gqa_bwd"]) == want
     assert trainer.qk_prep == want[0]
