@@ -51,11 +51,13 @@ class TrainConfig:
     vocab_size: int = 0  # 0 = take from dataset meta.pkl, else explicit
 
     # -- model family. 'gpt2' (models/gpt.py) reads the keys above; 'afmoe'
-    #    (models/afmoe.py: Arcee Trinity's block, HF model_type afmoe) and
+    #    (models/afmoe.py: Arcee Trinity's block, HF model_type afmoe),
     #    'lfm2' (models/lfm2.py: LiquidAI's LFM2 MoE block, HF model_type
-    #    lfm2_moe) read n_layer, n_head, n_embd, block_size, vocab_size above
-    #    and the keys below, named as the published config.json names them
-    #    (each family's model-config class further down says which). --
+    #    lfm2_moe) and 'deepseek_v3' (models/deepseek_v3.py: latent attention
+    #    beside routed and shared experts, HF model_type deepseek_v3) read
+    #    n_layer, n_head, n_embd, block_size, vocab_size above and the keys
+    #    below, named as the published config.json names them (each family's
+    #    model-config class further down says which). --
     model_family: str = "gpt2"
     n_kv_head: int = 0  # KV heads; n_head // n_kv_head query heads share one
     head_dim: int = 0  # not n_embd // n_head: 32 heads x 128 on a 2048 stream
@@ -63,7 +65,8 @@ class TrainConfig:
     # layers carry rotary positions and see `sliding_window` keys back, full
     # layers see everything and carry no positions. lfm2: 'conv' | 'full':
     # a gated short convolution of `conv_L_cache` taps, or full causal
-    # attention with rotary positions.
+    # attention with rotary positions. deepseek_v3: 'mla' every layer (left
+    # empty, that is what it means).
     layer_types: str = ""
     conv_L_cache: int = 3
     sliding_window: int = 0
@@ -82,6 +85,20 @@ class TrainConfig:
     # expert are computed (one chip's share of an expert-parallel job, with
     # no exchange). (0, 0) = all of them.
     experts_held: tuple = (0, 0)
+    # deepseek_v3's latent attention: keys and values come up from one latent
+    # of kv_lora_rank a token; a query / key head is qk_nope_head_dim content
+    # dims beside qk_rope_head_dim rotary dims whose key all heads share; a
+    # value head has v_head_dim. q_lora_rank > 0 (a query latent) and n_group
+    # / topk_group > 1 (group-limited expert selection) are what the
+    # published family also has and this program refuses by name.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    q_lora_rank: int = 0
+    n_shared_experts: int = 0  # the shared expert is this many experts wide
+    n_group: int = 1
+    topk_group: int = 1
 
     # -- optimizer / schedule (nanoGPT contract: cosine decay, AdamW, clip) --
     learning_rate: float = 6e-4
@@ -386,13 +403,15 @@ class GPTConfig:
         )
 
 
-MODEL_FAMILIES = ("gpt2", "afmoe", "lfm2")
+MODEL_FAMILIES = ("gpt2", "afmoe", "lfm2", "deepseek_v3")
 
 
-def _expert_family_keys(cfg: TrainConfig, family: str, kinds_allowed: tuple):
+def _expert_family_keys(cfg: TrainConfig, family: str, kinds_allowed: tuple,
+                        one_head_size: bool = True):
     """(layer kinds, (first, count) of the experts held, problems): what
-    both expert families read of a TrainConfig the same way, and what they
-    both ask of it."""
+    the expert families read of a TrainConfig the same way, and what they
+    all ask of it; ``one_head_size``: n_kv_head and head_dim too (a family
+    whose heads have one size for q, k and v)."""
     kinds = tuple(k.strip() for k in cfg.layer_types.split(",") if k.strip())
     first, count = cfg.experts_held
     if count == 0:
@@ -403,12 +422,13 @@ def _expert_family_keys(cfg: TrainConfig, family: str, kinds_allowed: tuple):
             f"layer_types needs {cfg.n_layer} entries of "
             f"{' | '.join(map(repr, kinds_allowed))}, got "
             f"{cfg.layer_types!r}")
-    if (min(cfg.n_kv_head, cfg.head_dim, cfg.moe_intermediate_size) <= 0
-            or cfg.n_head % max(cfg.n_kv_head, 1)):
-        problems.append(
-            "n_kv_head (dividing n_head), head_dim and "
-            "moe_intermediate_size must be set")
-    if cfg.head_dim % 2:
+    if one_head_size and (min(cfg.n_kv_head, cfg.head_dim) <= 0
+                          or cfg.n_head % max(cfg.n_kv_head, 1)):
+        problems.append("n_kv_head (dividing n_head) and head_dim must be "
+                        "set")
+    if cfg.moe_intermediate_size <= 0:
+        problems.append("moe_intermediate_size must be set")
+    if one_head_size and cfg.head_dim % 2:
         problems.append("rotary positions need an even head_dim")
     if cfg.num_dense_layers and cfg.intermediate_size <= 0:
         problems.append("dense layers need intermediate_size > 0")
@@ -534,6 +554,81 @@ class Lfm2Config:
             num_dense_layers=cfg.num_dense_layers,
             intermediate_size=cfg.intermediate_size,
             moe_intermediate_size=cfg.moe_intermediate_size,
+            num_experts=cfg.num_experts,
+            num_experts_per_tok=cfg.num_experts_per_tok,
+            experts_held=(first, count), route_scale=cfg.route_scale,
+            route_norm=cfg.route_norm, rope_theta=cfg.rope_theta,
+            rms_norm_eps=cfg.rms_norm_eps, param_dtype=cfg.param_dtype,
+            compute_dtype=cfg.compute_dtype,
+            attention_impl=cfg.attention_impl, remat=cfg.remat,
+            remat_policy=cfg.remat_policy)
+
+
+@dataclass
+class DeepseekV3Config:
+    """Model-only view of the config for models.deepseek_v3.DeepseekV3."""
+
+    n_layer: int
+    n_head: int
+    n_embd: int
+    block_size: int
+    vocab_size: int
+    layer_types: tuple  # 'mla' every layer
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    num_dense_layers: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    n_shared_experts: int
+    num_experts: int
+    num_experts_per_tok: int
+    experts_held: tuple  # (first, count), count > 0
+    route_scale: float = 1.0
+    route_norm: bool = True
+    rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-5
+    param_dtype: str = "float32"
+    compute_dtype: str = "bfloat16"
+    attention_impl: str = "auto"
+    remat: bool = False
+    remat_policy: str = "save_attention"
+
+    def replace(self, **kw: Any) -> "DeepseekV3Config":
+        return dataclasses.replace(self, **kw)
+
+    @classmethod
+    def from_train_config(cls, cfg: TrainConfig,
+                          vocab_size: int) -> "DeepseekV3Config":
+        if not cfg.layer_types:
+            cfg = dataclasses.replace(
+                cfg, layer_types=",".join(["mla"] * cfg.n_layer))
+        kinds, (first, count), problems = _expert_family_keys(
+            cfg, "deepseek_v3", ("mla",), one_head_size=False)
+        if min(cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+               cfg.v_head_dim) <= 0:
+            problems.append(
+                "kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim and "
+                "v_head_dim must be set")
+        if cfg.qk_rope_head_dim % 2:
+            problems.append("rotary positions need an even qk_rope_head_dim")
+        if cfg.num_dense_layers < cfg.n_layer and cfg.n_shared_experts <= 0:
+            problems.append("expert layers need n_shared_experts > 0")
+        if problems:
+            raise ValueError(
+                "model_family='deepseek_v3': " + "; ".join(problems))
+        return cls(
+            n_layer=cfg.n_layer, n_head=cfg.n_head, n_embd=cfg.n_embd,
+            block_size=cfg.block_size, vocab_size=vocab_size,
+            layer_types=kinds, kv_lora_rank=cfg.kv_lora_rank,
+            qk_nope_head_dim=cfg.qk_nope_head_dim,
+            qk_rope_head_dim=cfg.qk_rope_head_dim,
+            v_head_dim=cfg.v_head_dim,
+            num_dense_layers=cfg.num_dense_layers,
+            intermediate_size=cfg.intermediate_size,
+            moe_intermediate_size=cfg.moe_intermediate_size,
+            n_shared_experts=cfg.n_shared_experts,
             num_experts=cfg.num_experts,
             num_experts_per_tok=cfg.num_experts_per_tok,
             experts_held=(first, count), route_scale=cfg.route_scale,

@@ -1,4 +1,7 @@
-"""Model families. ``FAMILIES`` is the one place that knows which exist:
+"""Model families: ``gpt2`` (models/gpt.py), ``afmoe`` (Arcee Trinity's
+block), ``lfm2`` (LiquidAI's LFM2 MoE block) and ``deepseek_v3`` (latent
+attention beside routed and shared experts; Moonlight is a published
+instance). ``FAMILIES`` is the one place that knows which exist:
 ``TrainConfig.model_family`` -> the family's module, imported on first use (a
 GPT-2 run imports no other family's kernels). The MODULE is the interface:
 ``Trainer`` and ``restore_for_inference`` ask it, by these module-level names,
@@ -31,6 +34,7 @@ FAMILIES = {
     "gpt2": "nanosandbox_tpu.models.gpt",
     "afmoe": "nanosandbox_tpu.models.afmoe",
     "lfm2": "nanosandbox_tpu.models.lfm2",
+    "deepseek_v3": "nanosandbox_tpu.models.deepseek_v3",
 }
 
 

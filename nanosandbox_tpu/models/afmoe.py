@@ -47,8 +47,9 @@ whole 128-row blocks), RMSNorm_q / RMSNorm_k and the rotary positions are ONE
 kernel a tensor, forward and backward (ops.attention.qk_prep: float32 in
 registers from the projection's output as it lies, no float32 copy of q or k
 in HBM); everywhere else models/experts.py's head_rms_norm and rotary, in
-XLA. The router, SwiGLU, the head norm and the routed half of the expert
-layer are models/experts.py's, shared with the other expert family.
+XLA. The router, SwiGLU, the head norm, the routed half of the expert layer
+and the shared expert are models/experts.py's, shared with the other expert
+families.
 
 Scopes (obs/opscopes.py): modules ``attn_sliding`` / ``attn_full``, ``mlp``,
 ``moe_shared``, the norms ``ln_*``, ``wte``; named scopes ``moe_route``
@@ -129,9 +130,8 @@ class Moe(nn.Module):
         cfg = self.cfg
         routed, stats = experts.routed_experts(self, m, cfg,
                                                route_eps=ROUTE_EPS)
-        shared = SwiGLU(cfg, cfg.moe_intermediate_size, name="moe_shared")(
-            m.astype(jnp.dtype(cfg.compute_dtype)))
-        return shared.astype(jnp.float32) + routed, stats
+        return experts.shared_expert(cfg, cfg.moe_intermediate_size,
+                                     m) + routed, stats
 
 
 class Block(nn.Module):

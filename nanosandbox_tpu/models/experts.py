@@ -1,7 +1,9 @@
-"""What the expert families (models/afmoe.py, models/lfm2.py) are both
-written with and neither owns: the bias-free projection and the RMSNorm of a
-float32 residual stream, the q/k head norm with its rotary positions, SwiGLU,
-the sigmoid router, the routed half of an expert layer and the loop over a
+"""What the expert families (models/afmoe.py, models/lfm2.py,
+models/deepseek_v3.py) are written with and none owns: the bias-free
+projection and the RMSNorm of a float32 residual stream, the q/k head norm
+with its rotary positions, SwiGLU, the sigmoid router, the routed half of an
+expert layer, the shared expert beside it (``shared_expert``: one SwiGLU of
+a width the family gives, every token through it) and the loop over a
 decoder's blocks that gathers the expert layers' counters. Beside
 models/common.py because it imports ops/moe.py, which a GPT-2 run never does.
 
@@ -176,6 +178,17 @@ def routed_experts(module: nn.Module, m: jax.Array, cfg: Any, *,
         # it is k row gathers a token.
         routed = checkpoint_name(routed, "moe_routed").reshape(B, T, d)
     return routed, stats
+
+
+def shared_expert(cfg: Any, width: int, m: jax.Array) -> jax.Array:
+    """The expert every token passes through, beside the routed ones: one
+    SwiGLU of ``width`` (afmoe: moe_intermediate_size; deepseek_v3:
+    n_shared_experts times it), the module ``moe_shared`` of the expert-layer
+    module whose compact call this is made from. m (B, T, d) float32 ->
+    (B, T, d) float32. Every chip of an expert-parallel job computes it
+    whole: the ranks' shares of a layer count it once."""
+    return SwiGLU(cfg, width, name="moe_shared")(
+        m.astype(jnp.dtype(cfg.compute_dtype))).astype(jnp.float32)
 
 
 def decoder_layers(block_cls, cfg: Any, mesh: Any, h: jax.Array):

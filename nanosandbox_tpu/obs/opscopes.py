@@ -5,7 +5,8 @@ traced it under (``metadata={op_name="jit(traced)/transpose(jvp(GPT))/h_3/
 mlp/c_fc/dot_general"}``), backward pass included. Flax names the modules
 (``h_3/attn/c_attn``, ``mlp``, ``ln_1``, ``ln_f``, ``wte``, ``wpe``; for
 models/afmoe.py ``attn_sliding`` / ``attn_full``, ``moe_shared``, ``ln_in``
-...; for models/lfm2.py ``conv``, ``attn_full``, ``operator_norm`` ...) and
+...; for models/lfm2.py ``conv``, ``attn_full``, ``operator_norm`` ...; for
+models/deepseek_v3.py ``attn_mla``, ``input_layernorm`` ...) and
 the trainer adds ``jax.named_scope`` where no module names the work
 (``lm_head_loss``, ``optimizer``, ``grad_norm``, ``accum``). A device trace
 names its events by instruction (``%fusion.24 = ...``), a name the compiler
@@ -41,7 +42,11 @@ PARTS = ("attn", "mlp", "ln", "embed", "lm_head_loss", "optimizer",
          # models/lfm2.py: the gated short convolution's two projections,
          # and its gates and taps (ops/short_conv.py, whatever implements
          # them); its attention is an "attn_full", its experts as above.
-         "conv", "conv_mix")
+         "conv", "conv_mix",
+         # models/deepseek_v3.py: latent attention (projections and the
+         # %attn_mla kernels), and inside it the latent's norm with the
+         # rotary positions of the 64-lane parts; its experts as above.
+         "attn_mla", "mla_prep")
 UNSCOPED = "unscoped"
 
 # Path component -> part. ``wte.attend`` is the tied head's matmul where the
@@ -65,6 +70,12 @@ _COMPONENT = {
     # ``conv``, as ``moe_experts`` inside ``moe_route``.
     "conv": "conv", "conv_mix": "conv_mix",
     "operator_norm": "ln", "ffn_norm": "ln", "embedding_norm": "ln",
+    # models/deepseek_v3.py. The named scope ``mla_prep`` lies inside the
+    # module ``attn_mla`` and holds its ``kv_a_layernorm``, which names no
+    # part of its own.
+    "attn_mla": "attn_mla", "mla_prep": "mla_prep",
+    "input_layernorm": "ln", "post_attention_layernorm": "ln",
+    "final_norm": "ln",
 }
 
 # `%fusion.24 = f32[...] fusion(...), ..., metadata={... op_name="..." ...}`;

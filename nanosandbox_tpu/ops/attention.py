@@ -32,6 +32,14 @@ _expand_stat_tile):
     attention composes, and the one for every shape the first cannot take
     (GPT-2 XL's 25 heads, D = 32, T off the 128 grid, a mesh).
 
+Beside them, on the projections' own (B, T, heads*D) layout too:
+flash_attention_gqa (grouped KV heads, an optional window: the afmoe family;
+forward and ONE backward kernel) and flash_attention_mla (latent attention:
+a query / key head of 128 content + 64 rotary lanes whose rotary key is ONE
+head that all query heads read, a value head of 128; the deepseek_v3 family;
+forward and ONE backward kernel built from the same tile functions with a
+second (q, k) pair). Each section's heading says what its kernels hold.
+
 Mosaic layout note, (B, H, T, D) entry: per-row softmax stats (L, Drow)
 leave its forward lane-REPLICATED as (..., T, 128) arrays — Mosaic
 requires the last two block dims of every operand to tile onto (8, 128)
@@ -81,6 +89,8 @@ __all__ = ["causal_attention", "causal_attention_qkv", "attention_layout",
            "flash_attention_dropout", "flash_attention_lse",
            "flash_attention_lse_dropout", "flash_attention_qkv",
            "flash_attention_gqa", "gqa_layout_supported", "gqa_route",
+           "causal_attention_mla", "flash_attention_mla",
+           "mla_layout_supported", "mla_route",
            "hash_dropout_keep_mask", "qk_prep", "qkv_layout_supported",
            "resolve_attention_impl", "resolve_gqa_bwd", "resolve_gqa_impl",
            "rotary_table"]
@@ -206,8 +216,21 @@ def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 # Pallas flash forward
 # ---------------------------------------------------------------------------
 
+def _scores(q, k, qk2=None):
+    """q k^T (bq, bk) in float32, unscaled; with ``qk2`` = (q2, k2), a second
+    pair of another width, plus q2 k2^T: ONE score from two contractions
+    summed in float32 (the latent kernels' 128 content lanes and 64 rotary
+    lanes; the rotary key is one head that every query head reads)."""
+    s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+    if qk2 is not None:
+        s = s + lax.dot_general(qk2[0], qk2[1], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+    return s
+
+
 def _fwd_tile(q, k, v, carry, *, sm_scale: float, mask=None, keep=None,
-              dropout_rate: float = 0.0):
+              dropout_rate: float = 0.0, qk2=None):
     """One (block_q, block_k) step of the online softmax for ONE head: the
     tile mathematics both forward kernels share (the (B*H, T, D) kernel
     and the (B, T, heads*D) head-group kernel).
@@ -223,9 +246,7 @@ def _fwd_tile(q, k, v, carry, *, sm_scale: float, mask=None, keep=None,
     f32 rate, ~8x slower. Scores are scaled in f32 after the dot instead
     of scaling q (same math, better bf16 numerics)."""
     acc, m, l = carry
-    s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32)  # (bq, bk)
-    s = s * sm_scale
+    s = _scores(q, k, qk2) * sm_scale                        # (bq, bk)
     if mask is not None:
         s = jnp.where(mask, s, NEG_INF)
     m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))  # (bq, 1)
@@ -524,21 +545,21 @@ def _expand_stat_tile(tile: jax.Array, row_offset, block_q: int) -> jax.Array:
 
 
 def _bwd_tile(q, k, v, do, lse, drow, *, sm_scale: float, mask=None,
-              keep=None, dropout_rate: float = 0.0):
+              keep=None, dropout_rate: float = 0.0, qk2=None):
     """One (block_q, block_k) tile of the flash backward for ONE head, up
     to the three gradient matmuls: returns (p~, ds), both (bq, bk) f32,
     with p = exp(s - L) recomputed, dp = dO V^T, ds = p (dp - Drow) and
     p~ the probabilities that multiplied v in the forward. Shared by the
-    dQ kernel, the key-parallel walk and the (B, T, heads*D) head-group
-    walk, so the three cannot drift.
+    dQ kernel, the key-parallel walk, the (B, T, heads*D) head-group walk
+    and the latent kernel's one-pass walk (``qk2``: its second (q, k) pair,
+    _scores), so they cannot drift.
 
     With dropout, p~ = keep * p / (1-r) is what multiplied v, so the mask
     (and its 1/(1-r) rescale) lands on dp too; the row term drow =
     rowsum(do*o) already equals rowsum(dp_masked * p) and needs no
     correction. The head-group walk passes 128-lane tiles with the other
     head's k and v lanes zeroed: the contractions give the same s, dp."""
-    s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32) * sm_scale
+    s = _scores(q, k, qk2) * sm_scale
     if mask is not None:
         s = jnp.where(mask, s, NEG_INF)
     p = jnp.exp(s - lse)                              # (bq, bk) f32
@@ -2094,6 +2115,294 @@ flash_attention_gqa.defvjp(_flash_gqa_fwd_rule, _flash_gqa_bwd_rule)
 
 
 # ---------------------------------------------------------------------------
+# Latent attention (MLA): a split query/key head, one rotary key for all heads
+# ---------------------------------------------------------------------------
+#
+# DeepSeek-V3's attention (models/deepseek_v3.py) scores a query against a key
+# over TWO parts: D content lanes a head ("nope": 128) and R rotary lanes
+# ("pe": 64) whose key is ONE head that all H query heads read; the value head
+# has D lanes. s = (q_nope k_nope^T + q_pe k_pe^T) / sqrt(D + R). No entry
+# above takes it: they contract q and k over one width and use it for v.
+#
+#   q_nope, k_nope, v, o   (B, T, H*D)   the projections' own layout
+#   q_pe                   (B, H, T, R)  a 64-lane block is legal only as an
+#                                        array's WHOLE minor dimension; the
+#                                        rotary pass writes q_pe anew anyway
+#   k_pe                   (B, T, R)     read where it lies, never repeated
+#
+# The kernels are the grouped-query ones at one KV head a query head, with
+# _fwd_tile / _bwd_tile's second (q, k) pair: the score tile is two
+# contractions (D lanes, R lanes) summed in float32 before the scale, then
+# ONE softmax pass; P V and dP run at D. No (B, H, T, T) array and no
+# concatenated (B, T, H*(D+R)) key exists anywhere.
+#
+#   * forward: grid (B, H, q blocks); k_nope / v arrive as whole-T blocks of
+#     head h, k_pe as the one whole-T block of the batch row.
+#   * backward, ONE pass (the design of _flash_bwd_gqa_kernel): grid (B, H,
+#     q blocks), the last in order. Every visible score tile is computed once
+#     and feeds all five gradients (eight matmuls a tile: two for the score,
+#     dP, dV, dQ_nope, dQ_pe, dK_nope, dK_pe). Resident over a (b, h): whole-T
+#     k_nope / v / dk_nope / dv of ONE head and their float32 accumulators
+#     (20 * T * D bytes in bfloat16), and the rope's k_pe and dk_pe
+#     accumulator. dk_pe is a PER-HEAD PARTIAL, (B, H, T, R) float32: head
+#     h's ds^T q_pe; XLA sums the H partials and casts once (the head axis
+#     stays 'parallel', and the sum is exact to one rounding).
+#   mla_layout_supported is the one predicate on the shapes (whole 128-lane
+#   content and value heads, whole 128-row blocks, the backward's resident
+#   blocks inside VMEM: T <= 23,040 at D = 128, R = 64 in bfloat16); what it
+#   turns away (a trainer's 8-token init batch, the CPU) runs xla_attention
+#   on the concatenated heads (mla_route).
+
+MLA_SCOPE = "attn_mla"   # names the custom calls: %attn_mla.N
+
+
+def mla_layout_supported(head_dim: int, rope_dim: int, v_dim: int, T: int,
+                         itemsize: int = 2) -> bool:
+    """Whether the latent kernels can walk these shapes: content and value
+    heads of the same whole 128-lane width, a rotary part that fits one
+    lane tile, whole 128-row blocks, and the one-pass backward's whole-T
+    blocks of ONE head inside VMEM: k_nope and v double-buffered, dk_nope and
+    dv single, their two float32 accumulators, and the rope's k_pe (double),
+    dk_pe block and accumulator (float32), each padded to 128 lanes."""
+    resident = T * (head_dim * (6 * itemsize + 8)
+                    + LANES * (2 * itemsize + 8))
+    return (head_dim % LANES == 0 and v_dim == head_dim
+            and 0 < rope_dim <= LANES and rope_dim % 8 == 0
+            and T % LANES == 0 and resident <= GQA_BWD_RESIDENT_BYTES)
+
+
+def _mla_geometry(q_nope, q_pe, k_nope, k_pe, v, n_head: int):
+    B, T, HD = q_nope.shape
+    D, R = HD // n_head, k_pe.shape[-1]
+    if (HD % n_head or q_pe.shape != (B, n_head, T, R)
+            or k_nope.shape != (B, T, HD) or k_pe.shape != (B, T, R)
+            or v.shape != (B, T, HD)
+            or not mla_layout_supported(D, R, D, T, k_nope.dtype.itemsize)):
+        raise ValueError(
+            f"flash_attention_mla needs q_nope / k_nope / v (B, T, H*D), "
+            f"q_pe (B, H, T, R), k_pe (B, T, R) with D % {LANES} == 0, "
+            f"R <= {LANES}, T % {LANES} == 0 and a T whose one-pass backward "
+            f"fits VMEM (mla_layout_supported); got q_nope {q_nope.shape}, "
+            f"q_pe {q_pe.shape}, k_nope {k_nope.shape}, k_pe {k_pe.shape}, "
+            f"v {v.shape}, H={n_head}")
+    return B, T, D, R
+
+
+def _flash_fwd_mla_kernel(q_ref, qpe_ref, k_ref, kpe_ref, v_ref, o_ref,
+                          lse_ref, *, block_q: int, block_k: int,
+                          sm_scale: float):
+    """q_ref / o_ref (1, block_q, D) and qpe_ref (1, 1, block_q, R) of head
+    h; k_ref / v_ref (1, T, D) of head h; kpe_ref (1, T, R), the batch row's
+    one rotary key; lse_ref (1, 1, 1, block_q)."""
+    qi = pl.program_id(2)
+    q, qpe = q_ref[0], qpe_ref[0, 0]
+    q_pos = qi * block_q + lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0)
+
+    def body(j, carry, *, masked: bool):
+        rows = pl.ds(j * block_k, block_k)
+        mask = None
+        if masked:
+            mask = _visible(q_pos, j * block_k + lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1), None)
+        return _fwd_tile(q, k_ref[0, rows, :], v_ref[0, rows, :], carry,
+                         sm_scale=sm_scale, mask=mask,
+                         qk2=(qpe, kpe_ref[0, rows, :]))
+
+    init = (jnp.zeros(q.shape, jnp.float32),
+            jnp.full((block_q, 1), NEG_INF, jnp.float32),
+            jnp.zeros((block_q, 1), jnp.float32))
+    acc, m, l = _walk_key_blocks(body, init, qi, block_q, block_k, None)
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+    lse_ref[0, 0] = _stat_column_to_row(m + jnp.log(l))
+
+
+def _flash_bwd_mla_kernel(q_ref, qpe_ref, k_ref, kpe_ref, v_ref, o_ref,
+                          do_ref, lse_ref, dq_ref, dqpe_ref, dk_ref,
+                          dkpe_ref, dv_ref, dk_acc, dkpe_acc, dv_acc, *,
+                          block_q: int, block_k: int, sm_scale: float):
+    """dQ_nope and dQ_pe of one (head, q block) and its share of the head's
+    dK_nope, dV and dK_pe partial: the forward's walk with _bwd_tile, every
+    visible score tile computed ONCE. Grid (B, H, q blocks), the last in
+    order. q / o / do / dq_ref (1, block_q, D); qpe / dqpe_ref
+    (1, 1, block_q, R); k / v / dk / dv_ref (1, T, D) of head h and kpe_ref
+    (1, T, R), resident over a (b, h)'s programs; dkpe_ref (1, 1, T, R)
+    float32, the head's partial; lse_ref (1, 1, T // 128, 128); the three
+    accumulators float32, zeroed at a (b, h)'s first program and written out
+    at its last."""
+    qi = pl.program_id(2)
+    num_kb = dk_acc.shape[0] // block_k
+    accs = (dk_acc, dkpe_acc, dv_acc)
+
+    def key_rows(j):
+        return pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+
+    @pl.when(qi == 0)
+    def _zero():
+        def zero(j, _):
+            for acc in accs:
+                acc[key_rows(j), :] = jnp.zeros((block_k, acc.shape[1]),
+                                                jnp.float32)
+        lax.fori_loop(0, num_kb, zero, None)
+
+    q, qpe, do = q_ref[0], qpe_ref[0, 0], do_ref[0]
+    drow = jnp.sum(do.astype(jnp.float32) * o_ref[0].astype(jnp.float32),
+                   axis=1, keepdims=True)
+    lse = _expand_stat_tile(lse_ref[0, 0], qi * (block_q // LANES), block_q)
+    q_pos = qi * block_q + lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0)
+    by_key = lambda a, b: lax.dot_general(        # a^T b: (bk, width)
+        a, b, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    by_query = lambda a, b: lax.dot_general(      # a b: (bq, width)
+        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+    def body(j, carry, *, masked: bool):
+        dq_acc, dqpe_acc = carry
+        rows = key_rows(j)
+        k, kpe, v = k_ref[0, rows, :], kpe_ref[0, rows, :], v_ref[0, rows, :]
+        mask = None
+        if masked:
+            mask = _visible(q_pos, j * block_k + lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1), None)
+        p, ds = _bwd_tile(q, k, v, do, lse, drow, sm_scale=sm_scale,
+                          mask=mask, qk2=(qpe, kpe))
+        ds = ds.astype(q.dtype)
+        dv_acc[rows, :] += by_key(p.astype(do.dtype), do)
+        dk_acc[rows, :] += by_key(ds, q)
+        dkpe_acc[rows, :] += by_key(ds, qpe)
+        return dq_acc + by_query(ds, k), dqpe_acc + by_query(ds, kpe)
+
+    dq, dqpe = _walk_key_blocks(
+        body, (jnp.zeros(q.shape, jnp.float32),
+               jnp.zeros(qpe.shape, jnp.float32)), qi, block_q, block_k, None)
+    dq_ref[0] = (dq * sm_scale).astype(dq_ref.dtype)
+    dqpe_ref[0, 0] = (dqpe * sm_scale).astype(dqpe_ref.dtype)
+
+    @pl.when(qi == pl.num_programs(2) - 1)
+    def _write():
+        def write(j, _):
+            rows = key_rows(j)
+            dk_ref[0, rows, :] = (dk_acc[rows, :] * sm_scale).astype(
+                dk_ref.dtype)
+            dkpe_ref[0, 0, rows, :] = dkpe_acc[rows, :] * sm_scale
+            dv_ref[0, rows, :] = dv_acc[rows, :].astype(dv_ref.dtype)
+        lax.fori_loop(0, num_kb, write, None)
+
+
+@functools.partial(jax.jit, static_argnames=("n_head", "interpret", "scope"))
+def _pallas_flash_fwd_mla(q_nope, q_pe, k_nope, k_pe, v, *, n_head: int,
+                          interpret: bool = False, scope: str = MLA_SCOPE):
+    """-> (o (B, T, H*D), lse (B, H, 1, T) f32). Jitted like the grouped-
+    query calls: a model's layers share one trace and one lowering; ``scope``
+    names the custom call (%<scope>.N) and its part in obs.opscopes."""
+    B, T, D, R = _mla_geometry(q_nope, q_pe, k_nope, k_pe, v, n_head)
+    block_q, block_k = _clamp_blocks(T, DEFAULT_BLOCK, DEFAULT_BLOCK)
+    q_blk = pl.BlockSpec((1, block_q, D), lambda b, h, i: (b, i, h))
+    kv_all = pl.BlockSpec((1, T, D), lambda b, h, i: (b, 0, h))
+    call = pl.pallas_call(
+        functools.partial(_flash_fwd_mla_kernel, block_q=block_q,
+                          block_k=block_k, sm_scale=(D + R) ** -0.5),
+        grid=(B, n_head, T // block_q),
+        in_specs=[q_blk,
+                  pl.BlockSpec((1, 1, block_q, R),
+                               lambda b, h, i: (b, h, i, 0)),
+                  kv_all,
+                  pl.BlockSpec((1, T, R), lambda b, h, i: (b, 0, 0)),
+                  kv_all],
+        out_specs=[
+            q_blk,
+            pl.BlockSpec((1, 1, 1, block_q), lambda b, h, i: (b, h, 0, i)),
+        ],
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct((B, n_head, 1, T), jnp.float32)],
+        compiler_params=None if interpret else _tpu_params(
+            "parallel", "parallel", "parallel"),
+        interpret=interpret,
+    )
+    with jax.named_scope(scope):
+        return call(q_nope, q_pe, k_nope, k_pe, v)
+
+
+@functools.partial(jax.jit, static_argnames=("n_head", "interpret", "scope"))
+def _pallas_flash_bwd_mla(q_nope, q_pe, k_nope, k_pe, v, o, lse, do, *,
+                          n_head: int, interpret: bool = False,
+                          scope: str = MLA_SCOPE):
+    """-> (dq_nope, dq_pe, dk_nope, dk_pe, dv) in the operands' shapes and
+    dtypes, by the one-pass kernel; dk_pe is the sum of its H float32
+    partials."""
+    B, T, D, R = _mla_geometry(q_nope, q_pe, k_nope, k_pe, v, n_head)
+    block_q, block_k = _clamp_blocks(T, GQA_BWD_BLOCK_Q, GQA_BWD_BLOCK_K)
+    once = dict(pipeline_mode=pl.Buffered(1))
+    q_blk = pl.BlockSpec((1, block_q, D), lambda b, h, i: (b, i, h))
+    qpe_blk = pl.BlockSpec((1, 1, block_q, R), lambda b, h, i: (b, h, i, 0))
+    kv_all = pl.BlockSpec((1, T, D), lambda b, h, i: (b, 0, h))
+    kv_out = pl.BlockSpec((1, T, D), lambda b, h, i: (b, 0, h), **once)
+    call = pl.pallas_call(
+        functools.partial(_flash_bwd_mla_kernel, block_q=block_q,
+                          block_k=block_k, sm_scale=(D + R) ** -0.5),
+        grid=(B, n_head, T // block_q),
+        in_specs=[q_blk, qpe_blk, kv_all,
+                  pl.BlockSpec((1, T, R), lambda b, h, i: (b, 0, 0)),
+                  kv_all, q_blk, q_blk,
+                  pl.BlockSpec((1, 1, T // LANES, LANES),
+                               lambda b, h, i: (b, h, 0, 0))],
+        out_specs=[q_blk, qpe_blk, kv_out,
+                   pl.BlockSpec((1, 1, T, R), lambda b, h, i: (b, h, 0, 0),
+                                **once),
+                   kv_out],
+        out_shape=[jax.ShapeDtypeStruct(q_nope.shape, q_nope.dtype),
+                   jax.ShapeDtypeStruct(q_pe.shape, q_pe.dtype),
+                   jax.ShapeDtypeStruct(k_nope.shape, k_nope.dtype),
+                   jax.ShapeDtypeStruct((B, n_head, T, R), jnp.float32),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((T, D), jnp.float32),
+                        pltpu.VMEM((T, R), jnp.float32),
+                        pltpu.VMEM((T, D), jnp.float32)],
+        compiler_params=None if interpret else _tpu_params(
+            "parallel", "parallel", "arbitrary"),
+        interpret=interpret,
+    )
+    stats = lse.reshape(B, n_head, T // LANES, LANES)
+    with jax.named_scope(scope):
+        dq, dqpe, dk, dkpe, dv = call(q_nope, q_pe, k_nope, k_pe, v, o, do,
+                                      stats)
+    return dq, dqpe, dk, jnp.sum(dkpe, axis=1).astype(k_pe.dtype), dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def flash_attention_mla(q_nope, q_pe, k_nope, k_pe, v, n_head: int,
+                        interpret: bool = False, scope: str = MLA_SCOPE):
+    """Causal flash attention with a split query/key head and ONE rotary key
+    for all heads: q_nope / k_nope / v (B, T, H*D), q_pe (B, H, T, R),
+    k_pe (B, T, R) -> o (B, T, H*D); head h scores query i against key
+    j <= i as (q_nope_h[i] . k_nope_h[j] + q_pe_h[i] . k_pe[j]) *
+    (D + R) ** -0.5. Shapes must satisfy mla_layout_supported."""
+    return _pallas_flash_fwd_mla(q_nope, q_pe, k_nope, k_pe, v,
+                                 n_head=n_head, interpret=interpret,
+                                 scope=scope)[0]
+
+
+def _flash_mla_fwd_rule(q_nope, q_pe, k_nope, k_pe, v, n_head, interpret,
+                        scope):
+    from jax.ad_checkpoint import checkpoint_name
+
+    o, lse = _pallas_flash_fwd_mla(q_nope, q_pe, k_nope, k_pe, v,
+                                   n_head=n_head, interpret=interpret,
+                                   scope=scope)
+    o = checkpoint_name(o, "attn_out")  # see _flash_fwd_rule
+    return o, (q_nope, q_pe, k_nope, k_pe, v, o,
+               checkpoint_name(lse, "attn_lse"))
+
+
+def _flash_mla_bwd_rule(n_head, interpret, scope, res, do):
+    return _pallas_flash_bwd_mla(*res, do, n_head=n_head,
+                                 interpret=interpret, scope=scope)
+
+
+flash_attention_mla.defvjp(_flash_mla_fwd_rule, _flash_mla_bwd_rule)
+
+
+# ---------------------------------------------------------------------------
 # The prologue of that entry: head RMSNorm, then rotary positions, in place
 # ---------------------------------------------------------------------------
 #
@@ -2459,3 +2768,44 @@ def causal_attention_gqa(q: jax.Array, k: jax.Array, v: jax.Array,
     else:
         o = xla_attention(qh, kh, vh, window=window)
     return o.transpose(0, 2, 1, 3).reshape(B, T, HD)
+
+
+def mla_route(impl: str, head_dim: int, rope_dim: int, v_dim: int, T: int,
+              itemsize: int = 2) -> str:
+    """Which entry causal_attention_mla takes at these shapes, from the
+    resolved impl and the shapes alone (no option): 'mla' (the latent
+    kernels, forward and one-pass backward: a Pallas impl and
+    mla_layout_supported) or 'xla' (xla_attention on the concatenated heads:
+    the CPU, a trainer's 8-token init batch, every other shape)."""
+    impl = resolve_attention_impl(impl)
+    if impl not in ("pallas", "pallas_interpret", "xla"):
+        raise ValueError(
+            f"latent attention has impls 'pallas', 'pallas_interpret' and "
+            f"'xla'; got {impl!r}")
+    if impl != "xla" and mla_layout_supported(head_dim, rope_dim, v_dim, T,
+                                              itemsize):
+        return "mla"
+    return "xla"
+
+
+def causal_attention_mla(q_nope: jax.Array, q_pe: jax.Array,
+                         k_nope: jax.Array, k_pe: jax.Array, v: jax.Array,
+                         n_head: int, *, impl: str = "auto",
+                         scope: str = MLA_SCOPE) -> jax.Array:
+    """Causal latent attention from q_nope / k_nope (B, T, H*D), q_pe
+    (B, T, H, R), k_pe (B, T, R) (both already rotated) and v (B, T, H*Dv)
+    to o (B, T, H*Dv), scores scaled by (D + R) ** -0.5, by mla_route: the
+    latent kernels, or xla_attention on heads concatenated to D + R with
+    the rotary key repeated. ``scope`` names the kernels' custom calls."""
+    B, T, H, R = q_pe.shape
+    D, Dv = q_nope.shape[-1] // H, v.shape[-1] // H
+    if mla_route(impl, D, R, Dv, T, k_nope.dtype.itemsize) == "mla":
+        return flash_attention_mla(
+            q_nope, q_pe.transpose(0, 2, 1, 3), k_nope, k_pe, v, H,
+            resolve_attention_impl(impl) == "pallas_interpret", scope)
+    heads = lambda x, n: x.reshape(B, T, H, n).transpose(0, 2, 1, 3)
+    q = jnp.concatenate([heads(q_nope, D), q_pe.transpose(0, 2, 1, 3)], -1)
+    k = jnp.concatenate([heads(k_nope, D), jnp.broadcast_to(
+        k_pe[:, None], (B, H, T, R))], -1)
+    o = xla_attention(q, k, heads(v, Dv), sm_scale=(D + R) ** -0.5)
+    return o.transpose(0, 2, 1, 3).reshape(B, T, H * Dv)

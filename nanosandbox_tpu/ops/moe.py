@@ -78,7 +78,9 @@ TPU_GMM_IMPL = "megablox"
 ROW_TILE = 512
 # Rows of ONE chunk of the sorted pairs, as a multiple of the expected load.
 ROWS_FACTOR = 2.0
+# megablox's (m, k, n) tiles; gmm_tiling picks between the two by the shapes.
 MEGABLOX_TILING = (512, 1024, 1024)
+MEGABLOX_TILING_NARROW = (512, 2048, 512)
 LANES = 128
 MOVE_SCOPE = "moe_rows"   # names the row mover's custom calls: %moe_rows.N
 # The row mover's walk: a program sums MOVE_STEP tokens, MOVE_TOKENS at a
@@ -447,17 +449,35 @@ def _combine_bwd(mover, res, dout):
 combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def _padded(size: int, tile: int) -> int:
+    """``size`` in whole tiles of ``tile`` (no tile larger than ``size``)."""
+    tile = min(tile, size)
+    return -(-size // tile) * tile
+
+
 def gmm_tiling(rows: int, K: int, N: int) -> tuple[int, int, int]:
     """megablox's (m, k, n) tiles for ``rows`` x (K, N) products: ONE tuple
     serves a product's forward, its dgrad (the same kernel with K and N
-    exchanged) and its wgrad. MEGABLOX_TILING, no tile larger than its
-    dimension. Measured at both cells' expert shapes (16,384 rows, 8 / 16
-    groups, three products forward and nine with the backward): at
-    2048 x 1024 it is the best of those tried (PERF.md §6, PR 29), and at
-    2048 x 1792, where its 1024 leaves a ragged 768 that the kernel masks,
-    it still is: 7.29 ms against 8.48 for tiles of 896 and 12.77 for tiles of
-    256, the only size that divides both dimensions (PERF.md §6, PR 33)."""
-    return tuple(min(t, s) for t, s in zip(MEGABLOX_TILING, (rows, K, N)))
+    exchanged) and its wgrad. ONE rule on the shapes, for every family: a
+    tile of 1024 across an expert's widths unless a tile of 512 pads one of
+    them less; then 512 across the output with the whole contraction in one
+    step; no tile larger than its dimension. Measured on a v5e, three
+    products forward and nine with the backward (PERF.md §6, PRs 29, 33, 35):
+
+      * 2048 x 1024 (16,384 rows, 16 groups): 1024 fits whole, MEGABLOX_TILING
+        the best of those tried;
+      * 2048 x 1792 (16,384 rows, 8 groups): 1792 pads to 2048 under either
+        tile, and the larger wins: 7.29 ms against 8.78 for tiles of 512 and
+        12.77 for 256, the only size dividing both widths;
+      * 2048 x 1408 (12,288 rows, 8 groups): a tile of 1024 leaves a ragged
+        384 (2048 computed for 1408) where 512 pads to 1536:
+        MEGABLOX_TILING_NARROW 5.41 ms against 6.27 (rows spread evenly) and
+        6.61 against 7.78 (skewed), (512, 512, 512) 5.86 / 6.92; a
+        contraction of 2048 in one step with 1024 across the output does not
+        fit VMEM."""
+    narrow = any(_padded(s, 512) < _padded(s, 1024) for s in (K, N))
+    tiling = MEGABLOX_TILING_NARROW if narrow else MEGABLOX_TILING
+    return tuple(min(t, s) for t, s in zip(tiling, (rows, K, N)))
 
 
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,

@@ -33,6 +33,11 @@ tests hold every later PR to what the chip accepts, at no chip time:
     buffer (32,768 rows, 8 experts, 2048 x 1792 and back) with the tiling
     ``ops.moe.gmm_tiling`` chooses there, the row mover at 16,384 tokens
     of 4 slots;
+  * the ``deepseek_v3`` family's latent attention at the Moonlight cell's
+    shape (2 x 8192 tokens, 16 heads of 128 + 64 lanes with one shared
+    rotary key, values of 128): ``flash_attention_mla``'s forward and ONE
+    backward kernel, at the cell's T and at the longest its VMEM predicate
+    lets through, and the grouped matmul at the expert width 1408;
   * all nine serving variants — ``flash_decode`` / ``flash_decode_paged``
     / ``flash_prefill_paged`` x fp / int8 / int4 — at B=8, H=12, D=64,
     the engine's default page 16 and page 32, prefill T = a page and
@@ -207,6 +212,45 @@ def test_gqa_at_head_size_64_forward_and_backward(sds):
     assert not re.search(rf"\[{B},{H},{T},{T}\]|\[{B * H},{T},{T}\]", txt)
 
 
+# Moonlight's cell: (B, T, H, content lanes, rotary lanes)
+MLA_SHAPE = (2, 8192, 16, 128, 64)
+
+
+@pytest.mark.parametrize("B, T", [MLA_SHAPE[:2], (1, 23040)],
+                         ids=["cell", "predicates-edge"])
+def test_flash_attention_mla_forward_and_backward(sds, B, T):
+    """The latent kernels: one call forward, one backward (dQ_nope, dQ_pe,
+    dK_nope, dK_pe's partials and dV from one walk of the score tiles), named
+    after their scope; no (B, H, T, T) array, no key concatenated to
+    (B, T, H * 192), at the cell's shape and at the longest sequence
+    ops.attention.mla_layout_supported lets through (whole-T blocks of one
+    head and their accumulators inside VMEM); one row more is turned away."""
+    from nanosandbox_tpu.ops.attention import (causal_attention_mla,
+                                               mla_route)
+
+    _, _, H, D, R = MLA_SHAPE
+    assert mla_route("pallas", D, R, D, T) == "mla"
+    assert mla_route("pallas", D, R, D, 23040 + 128) == "xla"
+
+    def loss(qn, qp, kn, kp, v):
+        with jax.named_scope("Model"):   # takes the jvp( ) wrappers
+            return causal_attention_mla(qn, qp, kn, kp, v, H, impl="pallas",
+                                        scope="attn_mla"
+                                        ).astype(jnp.float32).sum()
+
+    wide = sds((B, T, H * D), jnp.bfloat16)
+    txt = compiled_text(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)), wide,
+        sds((B, T, H, R), jnp.bfloat16), wide, sds((B, T, R), jnp.bfloat16),
+        wide)
+    calls = set(re.findall(r"%(attn_mla[.0-9]*) = [^\n]*custom-call\(", txt))
+    assert len(calls) == 2, calls
+    assert txt.count("custom-call(") == 2
+    assert not re.search(
+        rf"\[{B},{H},{T},{T}\]|\[{B * H},{T},{T}\]|\[{B},{T},{H * (D + R)}\]"
+        rf"|\[{B},{T},{H},{D + R}\]|\[{B},{H},{T},{D + R}\]", txt)
+
+
 def test_gated_short_conv_forward_and_backward(sds):
     """The lfm2 cell's conv mixer between its projections, (2, 8192, 3 x
     2048) bfloat16 with a (2048, 3) filter: ONE custom call a pass
@@ -254,14 +298,19 @@ def test_qk_prep_forward_and_backward(sds, heads, theta):
 
 
 @pytest.mark.parametrize("experts, K, N", [
-    (16, 2048, 1024), (8, 2048, 1792), (8, 1792, 2048)],
-    ids=["trinity-mini", "lfm2-gate-up", "lfm2-down"])
+    (16, 2048, 1024), (8, 2048, 1792), (8, 1792, 2048), (8, 2048, 1408),
+    (8, 1408, 2048)],
+    ids=["trinity-mini", "lfm2-gate-up", "lfm2-down", "moonlight-gate-up",
+         "moonlight-down"])
 def test_megablox_grouped_matmul_backward(sds, experts, K, N):
     """At each cell's buffer and expert shapes, with the tiling
-    ops.moe.gmm_tiling gives there: the one measured at both."""
+    ops.moe.gmm_tiling gives there: the one measured at each (1024 across
+    the widths; at 1408, which 512 pads less, 512 across the output and the
+    whole contraction in one step)."""
     from nanosandbox_tpu.ops.moe import gmm_tiling, grouped_matmul
 
-    assert gmm_tiling(32768, K, N) == (512, 1024, 1024)
+    assert gmm_tiling(32768, K, N) == (
+        (512, K, 512) if 1408 in (K, N) else (512, 1024, 1024))
 
     def loss(xs, w, sizes):
         return grouped_matmul(xs, w, sizes,

@@ -43,6 +43,16 @@ TINY = {
              {"attn_layout", "attn_route", "qk_prep", "conv_mix",
               "moe_row_mover", "gmm_tiling", "layer_types", "experts_held"},
              None),
+    "deepseek_v3": (dict(n_layer=3, n_head=4, n_embd=32, kv_lora_rank=24,
+                         qk_nope_head_dim=16, qk_rope_head_dim=8,
+                         v_head_dim=16, num_dense_layers=1,
+                         intermediate_size=48, moe_intermediate_size=24,
+                         n_shared_experts=2, num_experts=8,
+                         num_experts_per_tok=2, experts_held=(2, 4),
+                         route_scale=2.446),
+                    {"attn_layout", "attn_route", "mla_bwd", "moe_row_mover",
+                     "gmm_tiling", "layer_types", "experts_held"},
+                    None),
 }
 
 
@@ -183,15 +193,20 @@ def test_no_family_imports_anothers_module(name):
 
 
 def test_the_expert_families_share_one_router_swiglu_layer_and_head_norm():
-    from nanosandbox_tpu.models import afmoe, experts, lfm2
+    from nanosandbox_tpu.models import afmoe, deepseek_v3, experts, lfm2
 
-    for family in (afmoe, lfm2):
+    for family in (afmoe, lfm2, deepseek_v3):
         assert family.SwiGLU is experts.SwiGLU
-        assert family.HeadRMSNorm is experts.HeadRMSNorm
+        if family is not deepseek_v3:    # its heads carry no norm
+            assert family.HeadRMSNorm is experts.HeadRMSNorm
         assert family.experts is experts
         code = _code_of(family.__file__)
         assert "experts.routed_experts(self, " in code
         assert "def route" not in code and "class SwiGLU" not in code
+    # the shared expert is one module with a width, at both families' widths
+    for family in (afmoe, deepseek_v3):
+        code = _code_of(family.__file__)
+        assert "experts.shared_expert(" in code and "moe_shared" not in code
 
 
 def test_a_gpt2_trainer_imports_no_other_familys_kernels():
@@ -207,7 +222,7 @@ def test_a_gpt2_trainer_imports_no_other_familys_kernels():
         "              if m.startswith('nanosandbox_tpu.'))\n"
         "assert 'nanosandbox_tpu.models.gpt' in mine, mine\n"
         "other = [m for m in mine if m.endswith(('.afmoe', '.lfm2',\n"
-        "         '.experts', '.ops.moe', '.short_conv'))]\n"
+        "         '.deepseek_v3', '.experts', '.ops.moe', '.short_conv'))]\n"
         "assert not other, other\n")
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     subprocess.run([sys.executable, "-c", probe], check=True, env=env,

@@ -202,6 +202,35 @@ def test_op_parts_of_a_two_layer_lfm2_step(tiny_cfg):
     opscopes.set_provider(None)
 
 
+def test_op_parts_of_a_two_layer_deepseek_v3_step(tiny_cfg):
+    """The ``deepseek_v3`` family's step: two latent-attention layers with
+    experts; its ops fall under attn_mla (the projections and attention),
+    mla_prep (the latent's norm, the rotary positions), moe_route,
+    moe_experts and moe_shared, every matmul is somebody's, and the map
+    names no part the table lacks."""
+    trainer = Trainer(tiny_cfg.replace(
+        model_family="deepseek_v3", n_layer=2, n_head=2, n_embd=32,
+        kv_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, num_dense_layers=0, moe_intermediate_size=24,
+        n_shared_experts=2, num_experts=4, num_experts_per_tok=2,
+        experts_held=(0, 2), loss_chunk_size=16))
+    train_step, _ = trainer.compiled_steps()
+    text = train_step.lower(*trainer._step_operands()).compile().as_text()
+    parts = opscopes.op_parts(text)
+    work = _working_instructions(text)
+    labels = set(opscopes.PARTS) | {opscopes.UNSCOPED}
+    assert {parts[n] for n, _ in work} <= labels      # none unmapped
+    by_part = {p: [n for n, _ in work if parts[n] == p] for p in labels}
+    for part in ("attn_mla", "mla_prep", "moe_route", "moe_experts",
+                 "moe_shared", "ln", "embed", "lm_head_loss", "optimizer"):
+        assert by_part[part], part
+    for part in ("attn", "mlp", "attn_full", "attn_sliding", "conv"):
+        assert not by_part[part], part
+    dots = [n for n, line in work if re.search(r"\sdot\(", line)]
+    assert dots and all(parts[n] != opscopes.UNSCOPED for n in dots)
+    opscopes.set_provider(None)
+
+
 def test_lowering_for_the_map_keeps_the_live_budget_of_one(tiny_cfg):
     """step_op_parts() may trace the step once more, which is allowed for by
     name; the live loop's own budget stays one trace."""
@@ -266,6 +295,25 @@ def test_lowering_for_the_map_keeps_the_live_budget_of_one(tiny_cfg):
     ("jit(traced)/jvp(Lfm2)/h_1/operator_norm/mul", "ln"),
     ("jit(traced)/transpose(jvp(Lfm2))/h_1/ffn_norm/mul", "ln"),
     ("jit(traced)/jvp(Lfm2)/embedding_norm/reduce_sum", "ln"),
+    # deepseek_v3's latent attention: the projections and the kernels are
+    # the module's; the latent's norm and the rotary positions a part of
+    # their own inside it (the norm's own name decides nothing); its three
+    # block norms are norms; the shared expert is models/experts.py's module
+    ("jit(traced)/jvp(DeepseekV3)/h_0/attn_mla/dot_general", "attn_mla"),
+    ("jit(traced)/jvp(DeepseekV3)/h_2/attn_mla/jit(_pallas_flash_fwd_mla)/"
+     "attn_mla/pallas_call", "attn_mla"),
+    ("jit(traced)/transpose(jvp(DeepseekV3))/h_2/attn_mla/"
+     "jit(_pallas_flash_bwd_mla)/attn_mla/pallas_call", "attn_mla"),
+    ("jit(traced)/jvp(DeepseekV3)/h_1/attn_mla/mla_prep/kv_a_layernorm/mul",
+     "mla_prep"),
+    ("jit(traced)/transpose(jvp(DeepseekV3))/h_1/attn_mla/mla_prep/"
+     "dot_general", "mla_prep"),
+    ("jit(traced)/jvp(DeepseekV3)/h_1/input_layernorm/mul", "ln"),
+    ("jit(traced)/transpose(jvp(DeepseekV3))/h_1/post_attention_layernorm/"
+     "mul", "ln"),
+    ("jit(traced)/jvp(DeepseekV3)/final_norm/reduce_sum", "ln"),
+    ("jit(traced)/jvp(DeepseekV3)/h_3/moe/moe_shared/up_proj/dot_general",
+     "moe_shared"),
 ])
 def test_part_of_a_scope_path(op_name, part):
     assert opscopes.part_of(op_name) == part
