@@ -15,6 +15,7 @@ models/common.py because it imports ops/moe.py, which a GPT-2 run never does.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Any
 
 import flax.linen as nn
@@ -129,16 +130,58 @@ class SwiGLU(nn.Module):
             (jax.nn.silu(g) * u).astype(cfg.compute_dtype))
 
 
+def _hits(sel: jax.Array, num_experts: int) -> jax.Array:
+    """(N, k, E) bool: slot j of token n chose expert e. One (8, 128)
+    register a token at E 128 / k 8, made inside the fusion that reads it,
+    never an array in HBM."""
+    return sel[:, :, None] == lax.iota(sel.dtype, num_experts)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2,))
+def selected_scores(s: jax.Array, sel: jax.Array,
+                    num_experts: int) -> jax.Array:
+    """s[n, sel[n, j]] (N, k) for scores s (N, E) and ids sel (N, k) that
+    are DISTINCT in a row (``lax.top_k``'s), with neither a gather nor, in
+    the backward pass, a scatter-add: those cost by their N*k INDICES, 1.04
+    and 1.14 ms a call at N*k = 131,072, forward, in remat's replay and
+    backward (12.9 ms of Trinity-Mini's step; PERF.md §6, PR 36).
+
+    Forward: each (token, slot) has one hit, so the max over the experts of
+    ``where(hit, s, -inf)`` is that score bit for bit. A max and not a sum:
+    XLA merges a sum with the caller's sum over k into one reduce over
+    (k, E), which rounds the denominator in another order (an ulp of w,
+    read on the chip and on the CPU); no reduce merges with a max.
+    Backward: ds[n, e] = sum_j where(hit[n, j, e], dw[n, j], 0), at most
+    one term each: the scatter-add's bits. The residual is ``sel`` alone."""
+    return jnp.max(jnp.where(_hits(sel, num_experts), s[:, None, :],
+                             -jnp.inf), axis=-1)
+
+
+def _selected_scores_fwd(s, sel, num_experts):
+    return selected_scores(s, sel, num_experts), sel
+
+
+def _selected_scores_bwd(num_experts, sel, dw):
+    ds = jnp.sum(jnp.where(_hits(sel, num_experts), dw[:, :, None], 0.0),
+                 axis=1)
+    return ds, None
+
+
+selected_scores.defvjp(_selected_scores_fwd, _selected_scores_bwd)
+
+
 def route(x: jax.Array, w_router: jax.Array, bias: jax.Array, k: int, *,
           norm: bool, scale: float, eps: float):
     """(sel (N, k) int32, w (N, k) float32) for tokens x (N, d) float32:
     the k experts of the highest sigmoid score + bias, weighted by their
-    scores alone; ``norm``: divided by their sum + ``eps``; times
+    scores alone (``lax.top_k`` ranks s + bias, so its values are not the
+    weights: ``selected_scores`` reads s[sel] by a compare against the
+    expert ids); ``norm``: divided by their sum + ``eps``; times
     ``scale``."""
     s = jax.nn.sigmoid(jnp.dot(x, w_router.astype(jnp.float32),
                                precision=lax.Precision.HIGHEST))
     _, sel = lax.top_k(s + lax.stop_gradient(bias.astype(jnp.float32)), k)
-    w = jnp.take_along_axis(s, sel, axis=1)
+    w = selected_scores(s, sel, s.shape[1])
     if norm:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
     return sel.astype(jnp.int32), w * scale
