@@ -53,8 +53,10 @@ families.
 
 Scopes (obs/opscopes.py): modules ``attn_sliding`` / ``attn_full``, ``mlp``,
 ``moe_shared``, the norms ``ln_*``, ``wte``; named scopes ``moe_route``
-(router, top-k, sort, gather, combine) and, inside it, ``moe_experts`` (the
-grouped matmuls and the activation between them, ops/moe.expert_ffn);
+(router, top-k, sort, gather, combine: six stage scopes inside it, opened
+by models/experts.py and ops/moe.py, ``opscopes._STAGE``) and, inside it,
+``moe_experts`` (the grouped matmuls and the activation between them,
+ops/moe.expert_ffn);
 ``qk_prep`` inside the attention modules (its custom calls are
 ``%qk_prep.N``, apart from the flash kernels' ``%attn_sliding.N`` /
 ``%attn_full.N``; it names no part, so its time is the module's).

@@ -178,13 +178,15 @@ def route(x: jax.Array, w_router: jax.Array, bias: jax.Array, k: int, *,
     weights: ``selected_scores`` reads s[sel] by a compare against the
     expert ids); ``norm``: divided by their sum + ``eps``; times
     ``scale``."""
-    s = jax.nn.sigmoid(jnp.dot(x, w_router.astype(jnp.float32),
-                               precision=lax.Precision.HIGHEST))
-    _, sel = lax.top_k(s + lax.stop_gradient(bias.astype(jnp.float32)), k)
-    w = selected_scores(s, sel, s.shape[1])
-    if norm:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
-    return sel.astype(jnp.int32), w * scale
+    with jax.named_scope("route_router"):
+        s = jax.nn.sigmoid(jnp.dot(x, w_router.astype(jnp.float32),
+                                   precision=lax.Precision.HIGHEST))
+        _, sel = lax.top_k(s + lax.stop_gradient(bias.astype(jnp.float32)),
+                           k)
+        w = selected_scores(s, sel, s.shape[1])
+        if norm:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
+        return sel.astype(jnp.int32), w * scale
 
 
 def routed_experts(module: nn.Module, m: jax.Array, cfg: Any, *,
@@ -209,17 +211,23 @@ def routed_experts(module: nn.Module, m: jax.Array, cfg: Any, *,
     w_down = module.param("w_down", init, (count, F, d), pd)
     x = m.reshape(B * T, d)
     with jax.named_scope("moe_route"):
+        # Every op of the part lies in one of six stages (obs.opscopes:
+        # _STAGE): the router's here, the plan's, the rows' two ways and
+        # the walk's sums in ops/moe.py, forward and backward.
         sel, w = route(x, w_router, bias, cfg.num_experts_per_tok,
                        norm=cfg.route_norm, scale=cfg.route_scale,
                        eps=route_eps)
-        routed, stats = moe.routed_experts(
-            x.astype(dtype), sel, w, w_gate.astype(dtype),
-            w_up.astype(dtype), w_down.astype(dtype), first, count,
-            cfg.num_experts)
+        with jax.named_scope("route_accumulate"):
+            xs = x.astype(dtype)
+        with jax.named_scope("route_weights"):
+            held = [a.astype(dtype) for a in (w_gate, w_up, w_down)]
+        routed, stats = moe.routed_experts(xs, sel, w, *held, first, count,
+                                           cfg.num_experts)
         # Saved under remat (the families' SAVED_NAMES): as large as the
         # block's output; what follows the layer needs it, and recomputing
         # it is k row gathers a token.
-        routed = checkpoint_name(routed, "moe_routed").reshape(B, T, d)
+        with jax.named_scope("route_accumulate"):
+            routed = checkpoint_name(routed, "moe_routed").reshape(B, T, d)
     return routed, stats
 
 

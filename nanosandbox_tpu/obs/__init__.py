@@ -17,7 +17,9 @@ device readback, nothing for jaxlint to flag:
                 lm_head_loss, optimizer, grad_norm) each instruction of
                 a compiled step belongs to, from the scope path XLA
                 keeps as ``op_name``; how a device trace's op times are
-                named.
+                named. One level down for ``moe_route``: its six stages
+                (router, plan, dispatch, combine, weights, accumulate),
+                a second map out of the same lowering.
 
 Two more halves (ISSUE 10), same contract:
 

@@ -14,9 +14,14 @@ hands out anew at every change to the step; this file turns that name into
 one of a few stable labels, so that "what does the head cost a step" has an
 answer before and after a change.
 
+One part is split one level down, into *stages* (``_STAGE``, ``stage_of``,
+``op_stages``): ``moe_route``, by the scopes models/experts.py and ops/moe.py
+open inside it where each stage's work is written. A stage names no part:
+``part_of`` reads a path as it did before there were any.
+
 Stdlib only. The trainer leaves a *provider* here (``set_provider``): a
 callable that lowers its train step again, on demand, and returns
-``op_parts`` of the executable's text. It holds what a lowering needs: the
+``op_maps`` of the executable's text. It holds what a lowering needs: the
 jitted step, whose function is the trainer's own method (so the trainer
 object with its model, optimizer and mesh, none of which holds an array),
 and the step's abstract operands; no state, no loader and no batch. So the
@@ -28,7 +33,7 @@ process, as there is one ``process_tracer()``: the train step built last.
 from __future__ import annotations
 
 import re
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, Optional, Tuple
 
 # The parts, in the order a report lists them. Ops whose path names none
 # (parameters, copies the compiler added, the accumulation's own adds) are
@@ -78,6 +83,27 @@ _COMPONENT = {
     "final_norm": "ln",
 }
 
+# Scope name -> the part it splits: the stages of ``moe_route``, each a
+# ``jax.named_scope`` inside the part's own, forward and backward. The
+# stage's label is the scope's name. None of them is a key of _COMPONENT.
+#   route_router      models/experts.route: the float32 matmul, sigmoid,
+#                     top-k, selected_scores both ways, norm and scale
+#   route_plan        ops/moe.plan_pairs and every chunk_plan, in the forward
+#                     walk and in the backward's
+#   route_dispatch    ops/moe.dispatch (x[row_token] + select, and its
+#                     recompute), _dispatch_bwd (rows back to tokens)
+#   route_combine     ops/moe.combine (weighted rows to tokens), _combine_bwd
+#   route_weights     the held expert matrices' casts to the compute type and
+#                     back; their gradients' float32 sums over chunks
+#   route_accumulate  the walk's own: the sum over chunks, the counters, the
+#                     tokens' cast in and out, dx's and dw's float32 sums
+STAGES = ("route_router", "route_plan", "route_dispatch", "route_combine",
+          "route_weights", "route_accumulate")
+_STAGE = dict.fromkeys(STAGES, "moe_route")
+# An instruction of a staged part that no stage claims (the compiler's own:
+# a fusion merged across stages votes, a copy follows its operand).
+UNSTAGED = "unstaged"
+
 # `%fusion.24 = f32[...] fusion(...), ..., metadata={... op_name="..." ...}`;
 # the text of a compiled module prints the `%`, a lowered one may not.
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
@@ -111,15 +137,25 @@ def part_of(op_name: str) -> str:
     return UNSCOPED
 
 
-def op_parts(hlo_text: str) -> Dict[str, str]:
-    """{instruction name: part} for every instruction of a module's text,
-    nested computations included (a trace shows the ops of a loop's body by
-    their own names). A fusion or call whose own path names no part (its
-    root is a cast or an add in a block's own scope, say) takes the part
-    most of its computation's instructions have; an instruction the
-    compiler made, with no path at all (the CPU backend's rewritten dots),
-    the part of its first operand that has one."""
-    parts: Dict[str, str] = {}
+def stage_of(op_name: str) -> Optional[str]:
+    """The innermost component of a scope path that names a stage, where
+    ``part_of`` gives the part that stage splits; else None: under
+    ``moe_route/route_accumulate/while/body/moe_experts`` the part is
+    ``moe_experts``, which has no stages."""
+    for path in op_name.split(";"):
+        for component in reversed(path.split("/")):
+            stage = _unwrap(component)
+            if stage in _STAGE:
+                return stage if part_of(op_name) == _STAGE[stage] else None
+    return None
+
+
+def _walk(hlo_text: str):
+    """One pass over a module's text: every instruction's scope path (None
+    where it has none), nested computations included; which computation a
+    fusion, call or loop runs; each computation's instructions; the operands
+    of the instructions without a path."""
+    paths: Dict[str, Optional[str]] = {}
     calls: Dict[str, str] = {}            # instruction -> computation called
     members: Dict[str, list] = {}         # computation -> its instructions
     pathless: Dict[str, list] = {}        # instruction -> its operands
@@ -135,7 +171,7 @@ def op_parts(hlo_text: str) -> Dict[str, str]:
             continue
         name = m.group(1)
         op = _OP_NAME.search(line)
-        parts[name] = part_of(op.group(1)) if op else UNSCOPED
+        paths[name] = op.group(1) if op else None
         if op is None:
             pathless[name] = _OPERAND.findall(line[m.end():])
         if computation is not None:
@@ -143,42 +179,99 @@ def op_parts(hlo_text: str) -> Dict[str, str]:
         called = _CALLS.search(line)
         if called is not None:
             calls[name] = called.group(1)
+    return paths, calls, members, pathless
+
+
+def _labels(walk, label_of: Callable[[str], Optional[str]],
+            none: Optional[str]) -> Dict[str, Optional[str]]:
+    """{instruction: label_of(its path)}, ``none`` where the path names no
+    label. A fusion or call left with ``none`` (its root is a cast or an add
+    in a block's own scope, say) takes the label most of its computation's
+    instructions have; an instruction the compiler made, with no path at all
+    (the CPU backend's rewritten dots), that of its first operand that has
+    one."""
+    paths, calls, members, pathless = walk
+    # a step's instructions share their paths ten to one: read each once
+    of_path = {path: label_of(path) for path in set(paths.values())
+               if path is not None}
+    labels = {name: of_path.get(path, none) for name, path in paths.items()}
     for name, called in calls.items():
-        if parts[name] != UNSCOPED:
+        if labels[name] != none:
             continue
         votes: Dict[str, int] = {}
         for inner in members.get(called, ()):
-            if parts[inner] != UNSCOPED:
-                votes[parts[inner]] = votes.get(parts[inner], 0) + 1
+            if labels[inner] != none:
+                votes[labels[inner]] = votes.get(labels[inner], 0) + 1
         if votes:
-            parts[name] = max(votes, key=votes.get)
+            labels[name] = max(votes, key=votes.get)
     # Text order defines an operand before its user, so one pass carries a
-    # part along a chain of such instructions.
+    # label along a chain of such instructions.
     for name, operands in pathless.items():
-        if parts[name] == UNSCOPED:
-            parts[name] = next((parts[o] for o in operands
-                                if parts.get(o, UNSCOPED) != UNSCOPED),
-                               UNSCOPED)
-    return parts
+        if labels[name] == none:
+            labels[name] = next((labels[o] for o in operands
+                                 if labels.get(o, none) != none), none)
+    return labels
+
+
+def op_maps(hlo_text: str) -> Tuple[Dict[str, str], Dict[str, str]]:
+    """(``op_parts``, ``op_stages``) of a module's text, from one walk of
+    it."""
+    walk = _walk(hlo_text)
+    parts = _labels(walk, part_of, UNSCOPED)
+    found = _labels(walk, stage_of, None)
+    staged = set(_STAGE.values())
+    stages = {}
+    for name, part in parts.items():
+        if part in staged:       # another part's stage is none of this one's
+            stage = found[name]
+            stages[name] = stage if _STAGE.get(stage) == part else UNSTAGED
+    return parts, stages
+
+
+def op_parts(hlo_text: str) -> Dict[str, str]:
+    """{instruction name: part} for every instruction of a module's text,
+    nested computations included (a trace shows the ops of a loop's body by
+    their own names), by ``_labels``' rules for a fusion whose own path
+    names no part and for an instruction with no path."""
+    return _labels(_walk(hlo_text), part_of, UNSCOPED)
+
+
+def op_stages(hlo_text: str) -> Dict[str, str]:
+    """{instruction name: stage} for the instructions whose part has stages
+    (the keys are exactly those ``op_parts`` gives ``moe_route``), by the
+    same rules; ``UNSTAGED`` where they find none, or one of another part."""
+    return op_maps(hlo_text)[1]
 
 
 # -- the train step's map, on demand ------------------------------------------
 
-_provider: Optional[Callable[[], Dict[str, str]]] = None
-_cached: Optional[Dict[str, str]] = None
+Maps = Tuple[Dict[str, str], Dict[str, str]]
+_provider: Optional[Callable[[], Maps]] = None
+_cached: Optional[Maps] = None
 
 
-def set_provider(provider: Optional[Callable[[], Dict[str, str]]]) -> None:
-    """Replaces the one before it, and its map; None leaves none."""
+def set_provider(provider: Optional[Callable[[], Maps]]) -> None:
+    """Replaces the one before it, and its maps; None leaves none."""
     global _provider, _cached
     _provider, _cached = provider, None
 
 
-def step_parts() -> Optional[Dict[str, str]]:
-    """The map of the last train step a trainer built in this process
-    (made at the first call, then kept); None where no trainer left a
-    provider."""
+def _step_maps() -> Optional[Maps]:
     global _cached
     if _cached is None and _provider is not None:
         _cached = _provider()
     return _cached
+
+
+def step_parts() -> Optional[Dict[str, str]]:
+    """``op_parts`` of the last train step a trainer built in this process
+    (made at the first call of this or of ``step_stages``, from one
+    lowering, then kept); None where no trainer left a provider."""
+    maps = _step_maps()
+    return None if maps is None else maps[0]
+
+
+def step_stages() -> Optional[Dict[str, str]]:
+    """``op_stages`` of the same step, out of the same lowering."""
+    maps = _step_maps()
+    return None if maps is None else maps[1]

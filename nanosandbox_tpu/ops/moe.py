@@ -402,7 +402,8 @@ def dispatch(x: jax.Array, plan: dict, mover: str = "xla") -> jax.Array:
     0 past the rows held. One XLA gather of ``rows`` rows, whatever the
     mover (0.65 ms; a kernel copying the ~half that hold a pair took 0.69:
     PERF.md §6, PR 32); ``mover`` is the backward's."""
-    return jnp.where(plan["row_valid"][:, None], x[plan["row_token"]], 0)
+    with jax.named_scope("route_dispatch"):
+        return jnp.where(plan["row_valid"][:, None], x[plan["row_token"]], 0)
 
 
 def _dispatch_fwd(x, plan, mover):
@@ -410,7 +411,9 @@ def _dispatch_fwd(x, plan, mover):
 
 
 def _dispatch_bwd(mover, plan, dxs):  # the buffer has x's dtype
-    return _pairs_to_tokens(dxs, plan["dest"], None, dxs.dtype, mover), None
+    with jax.named_scope("route_dispatch"):
+        return (_pairs_to_tokens(dxs, plan["dest"], None, dxs.dtype, mover),
+                None)
 
 
 dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -421,7 +424,8 @@ def combine(y: jax.Array, w: jax.Array, plan: dict,
             mover: str = "xla") -> jax.Array:
     """out[n] = sum_j w[n, j] * y[dest[n, j]], float32 (N, d): the weighted
     sum of what a token's HELD experts gave. y (rows, d), w (N, k) f32."""
-    return _pairs_to_tokens(y, plan["dest"], w, jnp.float32, mover)
+    with jax.named_scope("route_combine"):
+        return _pairs_to_tokens(y, plan["dest"], w, jnp.float32, mover)
 
 
 def _combine_fwd(y, w, plan, mover):
@@ -432,18 +436,19 @@ def _combine_bwd(mover, res, dout):
     # The rows from tokens are XLA gathers of ``rows`` indices under every
     # mover; dw's way back to (N, k), N * k indices, is the mover's.
     y, w, plan = res
-    rows = jnp.where(plan["row_valid"][:, None],
-                     dout[plan["row_token"]], 0)          # (rows, d) f32
-    w_row = jnp.where(plan["row_valid"],
-                      w.reshape(-1)[plan["row_pair"]], 0)
-    dy = (rows * w_row[:, None]).astype(y.dtype)
-    dw_row = jnp.sum(rows * y.astype(jnp.float32), axis=1)
-    if mover == "xla" or 4 * dw_row.shape[0] > SCALAR_BYTES:
-        dw = dw_row.at[plan["dest"]].get(mode="fill", fill_value=0)
-    else:
-        dw = _pallas_row_scalars_to_pairs(
-            dw_row, plan["dest"], interpret=mover == "pallas_interpret")
-    return dy, dw.astype(w.dtype), None
+    with jax.named_scope("route_combine"):
+        rows = jnp.where(plan["row_valid"][:, None],
+                         dout[plan["row_token"]], 0)      # (rows, d) f32
+        w_row = jnp.where(plan["row_valid"],
+                          w.reshape(-1)[plan["row_pair"]], 0)
+        dy = (rows * w_row[:, None]).astype(y.dtype)
+        dw_row = jnp.sum(rows * y.astype(jnp.float32), axis=1)
+        if mover == "xla" or 4 * dw_row.shape[0] > SCALAR_BYTES:
+            dw = dw_row.at[plan["dest"]].get(mode="fill", fill_value=0)
+        else:
+            dw = _pallas_row_scalars_to_pairs(
+                dw_row, plan["dest"], interpret=mover == "pallas_interpret")
+        return dy, dw.astype(w.dtype), None
 
 
 combine.defvjp(_combine_fwd, _combine_bwd)
@@ -523,7 +528,8 @@ def expert_ffn(xs: jax.Array, w_gate: jax.Array, w_up: jax.Array,
 def _walk(x, sel, first, count, n_experts, factor):
     N, k = sel.shape
     rows, chunks = chunk_rows(N, k, n_experts, count, factor)
-    return rows, chunks, plan_pairs(sel, first, count, rows * chunks)
+    with jax.named_scope("route_plan"):
+        return rows, chunks, plan_pairs(sel, first, count, rows * chunks)
 
 
 def _chunk_out(x, w, w_gate, w_up, w_down, plan, impl):
@@ -560,18 +566,22 @@ def routed_experts(x: jax.Array, sel: jax.Array, w: jax.Array, w_gate, w_up,
     def chunk(carry, c):
         def run(carry):
             acc, covered = carry
-            plan = chunk_plan(pairs, c, rows, k)
+            with jax.named_scope("route_plan"):
+                plan = chunk_plan(pairs, c, rows, k)
             return (acc + _chunk_out(x, w, w_gate, w_up, w_down, plan, impl),
                     covered + jnp.sum(plan["group_sizes"]))
 
         return lax.cond(pairs["total"] > c * rows, run, lambda c: c,
                         carry), None
 
-    init = (jnp.zeros((x.shape[0], x.shape[1]), jnp.float32),
-            jnp.zeros((), jnp.int32))
-    (out, covered), _ = lax.scan(chunk, init, jnp.arange(chunks))
-    stats = jnp.stack([pairs["total"], pairs["max_rows"],
-                       pairs["total"] - covered]).astype(jnp.int32)
+    # The walk's own work (the sum over chunks, the counters) is the stage
+    # round it; what a chunk's plan, rows and experts cost names its own.
+    with jax.named_scope("route_accumulate"):
+        init = (jnp.zeros((x.shape[0], x.shape[1]), jnp.float32),
+                jnp.zeros((), jnp.int32))
+        (out, covered), _ = lax.scan(chunk, init, jnp.arange(chunks))
+        stats = jnp.stack([pairs["total"], pairs["max_rows"],
+                           pairs["total"] - covered]).astype(jnp.int32)
     return out, stats
 
 
@@ -588,22 +598,35 @@ def _routed_bwd(first, count, n_experts, factor, impl, res, cts):
     k = sel.shape[1]
     rows, chunks, pairs = _walk(x, sel, first, count, n_experts, factor)
     operands = (x, w, w_gate, w_up, w_down)
+    # The float32 sums over chunks, by whose they are (obs.opscopes' stages):
+    # the tokens' and the weights' are the walk's own, the expert matrices'
+    # their casts' way back.
+    stages = ("route_accumulate",) * 2 + ("route_weights",) * 3
+
+    def staged(fn, *trees):
+        out = []
+        for stage, *leaves in zip(stages, *trees):
+            with jax.named_scope(stage):
+                out.append(fn(*leaves))
+        return tuple(out)
 
     def chunk(grads, c):
         def run(grads):
-            plan = chunk_plan(pairs, c, rows, k)
+            with jax.named_scope("route_plan"):
+                plan = chunk_plan(pairs, c, rows, k)
             _, vjp = jax.vjp(
                 lambda *a: _chunk_out(*a, plan, impl), *operands)
-            return jax.tree.map(lambda g, d: g + d.astype(jnp.float32),
-                                grads, vjp(d_out))
+            return staged(lambda g, d: g + d.astype(jnp.float32),
+                          grads, vjp(d_out))
 
         return lax.cond(pairs["total"] > c * rows, run, lambda g: g,
                         grads), None
 
-    zeros = tuple(jnp.zeros(a.shape, jnp.float32) for a in operands)
-    with jax.named_scope("moe_route"):   # the accumulation is no expert's
+    zeros = staged(lambda a: jnp.zeros(a.shape, jnp.float32), operands)
+    with jax.named_scope("route_accumulate"):   # the loop is no expert's
         grads, _ = lax.scan(chunk, zeros, jnp.arange(chunks))
-    dx, dw, dg, du, dd = (g.astype(a.dtype) for g, a in zip(grads, operands))
+    dx, dw, dg, du, dd = staged(lambda g, a: g.astype(a.dtype), grads,
+                                operands)
     return dx, None, dw, dg, du, dd
 
 

@@ -178,10 +178,11 @@ def _lower_step(train_step, operands: tuple, budgets):
             budgets.register(name, budget)
 
 
-def _step_op_parts(train_step, operands: tuple, budgets) -> dict:
-    """Trainer.step_op_parts, over what a lowering needs and no more."""
+def _step_op_maps(train_step, operands: tuple, budgets) -> tuple:
+    """Trainer.step_op_parts with the stages beside it (opscopes.op_maps),
+    over what a lowering needs and no more."""
     lowered = _lower_step(train_step, operands, budgets)
-    return opscopes.op_parts(lowered.compile().as_text())
+    return opscopes.op_maps(lowered.compile().as_text())
 
 
 class Trainer:
@@ -525,10 +526,10 @@ class Trainer:
                 # (obs.opscopes.step_parts). What the provider holds is
                 # what a lowering needs, so it still answers after the
                 # trainer's owner has dropped its state and loader.
-                self._op_parts = partial(
-                    _step_op_parts, self._train_step, self._step_operands(),
+                self._op_maps = partial(
+                    _step_op_maps, self._train_step, self._step_operands(),
                     self.tracecheck)
-                opscopes.set_provider(self._op_parts)
+                opscopes.set_provider(self._op_maps)
         return self._train_step, self._eval_step
 
     def _build_steps(self) -> None:
@@ -582,7 +583,7 @@ class Trainer:
         if not self.cfg.compile:
             raise ValueError("step_op_parts requires compile=True")
         self.compiled_steps()
-        return self._op_parts()
+        return self._op_maps()[0]
 
     def memory_report(self) -> dict:
         """XLA's compile-time memory analysis of the train step — the

@@ -413,6 +413,12 @@ def test_trainer_two_steps_save_restore_same_loss(dsv3_train_cfg):
     assert {"attn_mla", "mla_prep", "mlp", "moe_route", "moe_experts",
             "moe_shared", "ln"} <= parts
     assert not {"attn", "attn_full", "attn_sliding", "conv"} & parts
+    # the stages of moe_route, out of the same lowering as the parts (under
+    # remat here: the replayed forward and the backward's second walk)
+    stages = opscopes.step_stages()
+    assert set(opscopes.STAGES) <= set(stages.values())
+    assert set(stages) == {n for n, p in opscopes.step_parts().items()
+                           if p == "moe_route"}
 
     ckpt = Checkpointer(cfg.out_dir)
     state, extra = ckpt.restore(trainer.abstract_state)

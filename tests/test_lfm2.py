@@ -402,6 +402,12 @@ def test_trainer_two_steps_save_restore_same_loss(lfm2_train_cfg):
     assert {"conv", "conv_mix", "attn_full", "moe_route",
             "moe_experts"} <= parts
     assert not {"attn", "attn_sliding", "moe_shared"} & parts
+    # the stages of moe_route, out of the same lowering as the parts (under
+    # remat here: the replayed forward and the backward's second walk)
+    stages = opscopes.step_stages()
+    assert set(opscopes.STAGES) <= set(stages.values())
+    assert set(stages) == {n for n, p in opscopes.step_parts().items()
+                           if p == "moe_route"}
 
     ckpt = Checkpointer(cfg.out_dir)
     state, extra = ckpt.restore(trainer.abstract_state)

@@ -175,6 +175,33 @@ def test_op_parts_of_the_tiny_step(tiny_cfg, loss_chunk_size):
     assert opscopes.step_parts() is None
 
 
+def _check_stages(text, parts, work):
+    """The stage map of a lowered expert step, out of the same text as
+    ``parts``: one walk gives both, the part map as ``op_parts`` gives it;
+    the six stages of ``moe_route`` are all there, nothing outside the part
+    has one, and little of the part is left unstaged."""
+    again, stages = opscopes.op_maps(text)
+    assert again == parts and stages == opscopes.op_stages(text)
+    assert set(stages) == {n for n, p in parts.items() if p == "moe_route"}
+    labels = set(opscopes.STAGES) | {opscopes.UNSTAGED}
+    by_stage = {s: [n for n, _ in work if stages.get(n) == s] for s in labels}
+    for stage in opscopes.STAGES:
+        assert by_stage[stage], stage
+    routed = [n for n, _ in work if n in stages]
+    assert len(by_stage[opscopes.UNSTAGED]) <= 0.10 * len(routed)
+    # a path's own words decide where there are any: the plan's sort, the
+    # rows' gathers and the router's top-k are never another stage's
+    for name, line in work:
+        op = opscopes._OP_NAME.search(line)
+        if name in stages and op and opscopes.stage_of(op.group(1)):
+            assert stages[name] == opscopes.stage_of(op.group(1)), name
+    # the provider hands both maps over from ONE lowering
+    calls = []
+    opscopes.set_provider(lambda: calls.append(1) or opscopes.op_maps(text))
+    assert opscopes.step_stages() == stages
+    assert opscopes.step_parts() == parts and len(calls) == 1
+
+
 def test_op_parts_of_a_two_layer_lfm2_step(tiny_cfg):
     """The ``lfm2`` family's step: a conv layer and an attention layer with
     experts; its ops fall under conv (the projections) and conv_mix (the
@@ -199,6 +226,7 @@ def test_op_parts_of_a_two_layer_lfm2_step(tiny_cfg):
         assert not by_part[part], part
     dots = [n for n, line in work if re.search(r"\sdot\(", line)]
     assert dots and all(parts[n] != opscopes.UNSCOPED for n in dots)
+    _check_stages(text, parts, work)
     opscopes.set_provider(None)
 
 
@@ -228,6 +256,7 @@ def test_op_parts_of_a_two_layer_deepseek_v3_step(tiny_cfg):
         assert not by_part[part], part
     dots = [n for n, line in work if re.search(r"\sdot\(", line)]
     assert dots and all(parts[n] != opscopes.UNSCOPED for n in dots)
+    _check_stages(text, parts, work)
     opscopes.set_provider(None)
 
 
@@ -314,9 +343,88 @@ def test_lowering_for_the_map_keeps_the_live_budget_of_one(tiny_cfg):
     ("jit(traced)/jvp(DeepseekV3)/final_norm/reduce_sum", "ln"),
     ("jit(traced)/jvp(DeepseekV3)/h_3/moe/moe_shared/up_proj/dot_general",
      "moe_shared"),
+    # the stages of moe_route (ISSUE 37) name no part: the routing scope
+    # round them decides, and the experts' own scope inside the walk
+    ("jit(traced)/jvp(Lfm2)/h_2/moe/moe_route/route_router/top_k",
+     "moe_route"),
+    ("jit(traced)/jvp(Afmoe)/h_1/moe/moe_route/route_plan/jit(argsort)/sort",
+     "moe_route"),
+    ("jit(traced)/jvp(Afmoe)/h_1/moe/moe_route/route_accumulate/while/body/"
+     "closed_call/cond/branch_1_fun/route_dispatch/gather", "moe_route"),
+    ("jit(traced)/transpose(jvp(DeepseekV3))/h_1/moe/moe_route/"
+     "route_accumulate/while/body/closed_call/cond/branch_1_fun/"
+     "transpose(jvp(route_combine))/mul", "moe_route"),
+    ("jit(traced)/transpose(jvp(Lfm2))/h_2/moe/moe_route/route_accumulate/"
+     "while/body/closed_call/cond/branch_1_fun/route_weights/add",
+     "moe_route"),
+    ("jit(traced)/jvp(Lfm2)/h_2/moe/moe_route/route_accumulate/while/body/"
+     "closed_call/cond/branch_1_fun/add", "moe_route"),
+    ("jit(traced)/jvp(Lfm2)/h_2/moe/moe_route/route_accumulate/while/body/"
+     "closed_call/cond/branch_1_fun/moe_experts/dot_general", "moe_experts"),
+    ("jit(traced)/route_router/mul", "unscoped"),
 ])
 def test_part_of_a_scope_path(op_name, part):
     assert opscopes.part_of(op_name) == part
+
+
+def test_no_stage_names_a_part():
+    assert not set(opscopes._STAGE) & set(opscopes._COMPONENT)
+    assert set(opscopes._STAGE.values()) <= set(opscopes.PARTS)
+
+
+@pytest.mark.parametrize("op_name,stage", [
+    # forward, and the backward where plain autodiff transposes it
+    ("jit(traced)/jvp(Lfm2)/h_2/moe/moe_route/route_router/dot_general",
+     "route_router"),
+    ("jit(traced)/transpose(jvp(Lfm2))/h_2/moe/moe_route/route_router/"
+     "jit(_where)/select_n", "route_router"),
+    # under remat: the replayed router, the backward's second walk
+    ("jit(traced)/transpose(jvp(DeepseekV3))/jvp(DeepseekV3)/checkpoint/"
+     "rematted_computation/h_1/moe/moe_route/route_router/top_k",
+     "route_router"),
+    ("jit(traced)/transpose(jvp(DeepseekV3))/jvp(DeepseekV3)/checkpoint/h_1/"
+     "moe/moe_route/route_plan/jit(argsort)/sort", "route_plan"),
+    # inside the walk the innermost stage decides, not the walk's own
+    ("jit(traced)/jvp(Afmoe)/h_1/moe/moe_route/route_accumulate/while/body/"
+     "closed_call/cond/branch_1_fun/route_plan/jit(clip)/max", "route_plan"),
+    ("jit(traced)/jvp(Afmoe)/h_1/moe/moe_route/route_accumulate/while/body/"
+     "closed_call/cond/branch_1_fun/add", "route_accumulate"),
+    ("jit(traced)/jvp(Afmoe)/h_1/moe/moe_route/route_accumulate/while/body/"
+     "closed_call/cond", "route_accumulate"),
+    ("jit(traced)/transpose(jvp(Afmoe))/jvp(Afmoe)/checkpoint/h_4/moe/"
+     "moe_route/route_accumulate/while/body/closed_call/cond/branch_1_fun/"
+     "jvp(route_dispatch)/gather", "route_dispatch"),
+    ("jit(traced)/transpose(jvp(Afmoe))/jvp(Afmoe)/checkpoint/h_4/moe/"
+     "moe_route/route_accumulate/while/body/closed_call/cond/branch_1_fun/"
+     "route_weights/add", "route_weights"),
+    # the row mover's kernels: dispatch's backward, combine's forward
+    ("jit(traced)/transpose(jvp(Afmoe))/jvp(Afmoe)/checkpoint/h_4/moe/"
+     "moe_route/route_accumulate/while/body/closed_call/cond/branch_1_fun/"
+     "transpose(jvp(route_dispatch))/jit(_pallas_rows_to_tokens)/moe_rows/"
+     "pallas_call", "route_dispatch"),
+    ("jit(traced)/jvp(Afmoe)/h_1/moe/moe_route/route_accumulate/while/body/"
+     "closed_call/cond/branch_1_fun/route_combine/"
+     "jit(_pallas_rows_to_tokens)/moe_rows/pallas_call", "route_combine"),
+    ("jit(traced)/transpose(jvp(Afmoe))/jvp(Afmoe)/checkpoint/h_4/moe/"
+     "moe_route/route_accumulate/while/body/closed_call/cond/branch_1_fun/"
+     "transpose(jvp(route_combine))/jit(_pallas_row_scalars_to_pairs)/"
+     "moe_rows/pallas_call", "route_combine"),
+    # the experts are a part of their own inside the walk: no stage
+    ("jit(traced)/jvp(Afmoe)/h_1/moe/moe_route/route_accumulate/while/body/"
+     "closed_call/cond/branch_1_fun/moe_experts/dot_general", None),
+    # outside moe_route a stage's name is nobody's; the part without a
+    # stage; ops the compiler merged: the first path that names each
+    ("jit(traced)/jvp(Afmoe)/h_1/moe/moe_shared/up_proj/dot_general", None),
+    ("jit(traced)/jvp(GPT)/h_0/mlp/route_router/mul", None),
+    ("jit(traced)/route_plan/sort", None),
+    ("jit(traced)/jvp(Afmoe)/h_1/moe/moe_route/mul", None),
+    ("jit(traced)/mul;jit(traced)/jvp(Lfm2)/h_2/moe/moe_route/route_plan/add",
+     "route_plan"),
+])
+def test_stage_of_a_scope_path(op_name, stage):
+    assert opscopes.stage_of(op_name) == stage
+    if stage is not None:
+        assert opscopes.part_of(op_name) == opscopes._STAGE[stage]
 
 
 def test_op_parts_votes_and_inherits():
@@ -342,6 +450,71 @@ ENTRY %main.9 (x: f32[4]) -> f32[4] {
     assert parts["fusion.8"] == "optimizer"  # its own path wins
     assert parts["x"] == "unscoped"
     assert parts["a"] == parts["b"] == "mlp"
+
+
+def test_op_stages_vote_and_inherit_inside_their_part():
+    route = "jit(traced)/jvp(Lfm2)/h_2/moe/moe_route"
+    walk = route + "/route_accumulate/while/body"
+    text = f"""
+HloModule jit_traced, is_scheduled=true
+
+%fused_computation.1 (p0: f32[4]) -> f32[4] {{
+  %p0 = f32[4]{{0}} parameter(0)
+  %a = f32[4]{{0}} add(%p0, %p0), metadata={{op_name="{walk}/route_dispatch/add"}}
+  %b = f32[4]{{0}} add(%a, %a), metadata={{op_name="{walk}/route_dispatch/mul"}}
+  ROOT %c = f32[4]{{0}} multiply(%b, %b), metadata={{op_name="{walk}/add"}}
+}}
+
+%fused_computation.2 (p1: f32[4]) -> f32[4] {{
+  %p1 = f32[4]{{0}} parameter(0)
+  ROOT %d = f32[4]{{0}} add(%p1, %p1), metadata={{op_name="{walk}/moe_experts/add"}}
+}}
+
+ENTRY %main.9 (x: f32[4]) -> f32[4] {{
+  %x = f32[4]{{0}} parameter(0), metadata={{op_name="x"}}
+  %fusion.1 = f32[4]{{0}} fusion(%x), kind=kLoop, calls=%fused_computation.1, metadata={{op_name="jit(traced)/mul"}}
+  %copy.1 = f32[4]{{0}} copy(%fusion.1)
+  %fusion.2 = f32[4]{{0}} fusion(%copy.1), kind=kLoop, calls=%fused_computation.1, metadata={{op_name="{route}/mul"}}
+  %fusion.3 = f32[4]{{0}} fusion(%fusion.2), kind=kLoop, calls=%fused_computation.1, metadata={{op_name="{route}/route_plan/add"}}
+  %fusion.4 = f32[4]{{0}} fusion(%fusion.3), kind=kLoop, calls=%fused_computation.2, metadata={{op_name="{route}/mul"}}
+  %fusion.5 = f32[4]{{0}} fusion(%fusion.4), kind=kLoop, calls=%fused_computation.2, metadata={{op_name="jit(traced)/jvp(Lfm2)/h_2/ffn_norm/route_plan/mul"}}
+  ROOT %fusion.6 = f32[4]{{0}} fusion(%fusion.5), kind=kLoop, calls=%fused_computation.2, metadata={{op_name="jit(traced)/mul"}}
+}}
+"""
+    parts, stages = opscopes.op_maps(text)
+    assert parts == opscopes.op_parts(text)
+    assert stages == opscopes.op_stages(text)
+    # no path of its own: part and stage voted by its computation
+    assert (parts["fusion.1"], stages["fusion.1"]) == (
+        "moe_route", "route_dispatch")
+    assert stages["copy.1"] == "route_dispatch"     # its operand's
+    # the part by its own path, the stage (it names none) by the vote
+    assert stages["fusion.2"] == "route_dispatch"
+    assert stages["fusion.3"] == "route_plan"        # its own path wins
+    assert stages["a"] == stages["b"] == "route_dispatch"
+    assert stages["c"] == "route_accumulate"
+    # the part's, and nothing in it names a stage of the part: unstaged
+    assert (parts["fusion.4"], stages["fusion.4"]) == ("moe_route", "unstaged")
+    # a stage's name under another part, a vote for the experts' part:
+    # neither instruction is in the stage map, nor is anything unscoped
+    assert parts["fusion.5"] == "ln" and parts["fusion.6"] == "moe_experts"
+    assert set(stages) == {"a", "b", "c", "fusion.1", "copy.1", "fusion.2",
+                           "fusion.3", "fusion.4"}
+
+
+def test_both_step_maps_come_from_one_call_of_the_provider():
+    calls = []
+    maps = ({"fusion.1": "moe_route"}, {"fusion.1": "route_plan"})
+    opscopes.set_provider(lambda: calls.append(1) or maps)
+    try:
+        assert opscopes.step_stages() == maps[1]
+        assert opscopes.step_parts() == maps[0]
+        assert opscopes.step_stages() is maps[1] and len(calls) == 1
+        opscopes.set_provider(None)
+        assert opscopes.step_parts() is None
+        assert opscopes.step_stages() is None
+    finally:
+        opscopes.set_provider(None)
 
 
 # -- compile-cache listeners ---------------------------------------------------
