@@ -231,6 +231,15 @@ def routed_experts(module: nn.Module, m: jax.Array, cfg: Any, *,
     return routed, stats
 
 
+def walk_rows(cfg: Any, n_tokens: int) -> tuple[int, int]:
+    """(rows of a chunk, chunks) of the walk over the sorted pairs that
+    ``routed_experts`` makes of ``n_tokens`` tokens under ``cfg``
+    (``ops.moe.chunk_rows`` of the numbers it hands the walk): what
+    ``moe_held`` is read against by whoever counts the chunks run."""
+    return moe.chunk_rows(n_tokens, cfg.num_experts_per_tok, cfg.num_experts,
+                          cfg.experts_held[1])
+
+
 def shared_expert(cfg: Any, width: int, m: jax.Array) -> jax.Array:
     """The expert every token passes through, beside the routed ones: one
     SwiGLU of ``width`` (afmoe: moe_intermediate_size; deepseek_v3:

@@ -17,11 +17,22 @@ does reach 2.3x of the expected load in a layer (every occurrence of a
 frequent token goes the same way; PERF.md §6, PR 29), so neither the
 expected load nor a small multiple of it is a bound. The sorted pairs are
 therefore walked in CHUNKS of ``rows`` rows (``chunk_rows``: ROWS_FACTOR
-times the expected load, in whole tiles), ``ceil(never / rows)`` of them, under a scan whose body is a ``lax.cond``: a chunk that no held pair
-reaches is not run. Memory is one chunk's; work is the chunks that hold
-pairs: a layer at the expected load runs one. ``dropped`` (pairs held less
-pairs covered by the chunks run) is 0 by construction and is counted all
-the same: the trainer's counter and the benchmark's ``fault`` read it.
+times the expected load, in whole tiles), ``ceil(never / rows)`` of them.
+THE FIRST CHUNK always runs, under no loop and no ``lax.cond``, and what it
+gives is used as it is: its combine's float32 (N, d) IS the layer's output,
+its ``jax.vjp``'s gradients, in the operands' own types, ARE the layer's
+gradients. A first chunk that holds no pair gives zeros by itself
+(``row_valid`` is all false and every group empty), so a layer that holds
+nothing needs no case of its own. The chunks after it run under a scan
+whose body is a ``lax.cond``: a chunk that no held pair reaches is not run,
+and only where a second chunk holds pairs (``total > rows``) do sums over
+chunks exist: float32, started from the first chunk's results (cast to
+float32 in the backward), added to in chunk order and cast back at the end.
+Memory is one chunk's; work is the chunks that hold pairs: a layer at the
+expected load runs one, and pays for no sum, no zero-fill and no cast.
+``dropped`` (pairs held less pairs covered by the chunks run) is 0 by
+construction and is counted all the same: the trainer's counter and the
+benchmark's ``fault`` read it.
 
 Both directions of dispatch and combine are GATHERS (custom VJPs below): a
 row knows its token (``row_token``), a (token, slot) pair knows its row
@@ -552,34 +563,51 @@ def routed_experts(x: jax.Array, sel: jax.Array, w: jax.Array, w_gate, w_up,
     module's docstring); returns (out, stats) with stats int32 (3,): pairs
     held, the fullest held expert's, pairs no chunk covered (0).
 
+    Chunk 0 runs outside the scan and under no ``cond``: with no held pair
+    at all (``total == 0``) its rows are all invalid and its groups empty,
+    so it gives zeros and covers 0 pairs, which is what makes it safe to run
+    unasked. Its output and its count of covered pairs are the carry the
+    scan over chunks 1 .. starts from: no float32 zeros, and no ``acc + out``
+    unless a second chunk holds pairs. Where one chunk covers the bound
+    (``chunks == 1``: half of the experts held, or more) there is no scan.
+
     Its own VJP: the backward walks the chunks again, recomputing each
     chunk's forward before its gradients, so that one chunk's activations
     are alive at a time however many chunks run (a scan's own transpose
-    keeps every iteration's: 19 GB at the Trinity-Mini cell). The
-    residuals are the inputs alone, so under a rematerialised block whose
-    policy saves this function's output the replayed forward is dead code:
-    the experts run twice a step (forward, backward's recompute), as any
-    rematerialised layer does."""
+    keeps every iteration's: 19 GB at the Trinity-Mini cell). Chunk 0's
+    gradients (``jax.vjp`` of its forward: dx and the expert matrices' in
+    the compute type, dw float32) are the layer's where no second chunk
+    holds pairs; otherwise ONE ``lax.cond`` runs the rest of the walk:
+    float32 sums that start from chunk 0's gradients, over chunks 1 .. in
+    order, cast back to the operands' types. The residuals are the inputs
+    alone, so under a rematerialised block whose policy saves this
+    function's output the replayed forward is dead code: the experts run
+    twice a step (forward, backward's recompute), as any rematerialised
+    layer does."""
     k = sel.shape[1]
     rows, chunks, pairs = _walk(x, sel, first, count, n_experts, factor)
 
-    def chunk(carry, c):
-        def run(carry):
-            acc, covered = carry
-            with jax.named_scope("route_plan"):
-                plan = chunk_plan(pairs, c, rows, k)
-            return (acc + _chunk_out(x, w, w_gate, w_up, w_down, plan, impl),
-                    covered + jnp.sum(plan["group_sizes"]))
+    def run(c):
+        with jax.named_scope("route_plan"):
+            plan = chunk_plan(pairs, c, rows, k)
+        return (_chunk_out(x, w, w_gate, w_up, w_down, plan, impl),
+                jnp.sum(plan["group_sizes"]))
 
-        return lax.cond(pairs["total"] > c * rows, run, lambda c: c,
+    def chunk(carry, c):
+        def add(carry):
+            out, covered = run(c)
+            return carry[0] + out, carry[1] + covered
+
+        return lax.cond(pairs["total"] > c * rows, add, lambda held: held,
                         carry), None
 
     # The walk's own work (the sum over chunks, the counters) is the stage
     # round it; what a chunk's plan, rows and experts cost names its own.
     with jax.named_scope("route_accumulate"):
-        init = (jnp.zeros((x.shape[0], x.shape[1]), jnp.float32),
-                jnp.zeros((), jnp.int32))
-        (out, covered), _ = lax.scan(chunk, init, jnp.arange(chunks))
+        carry = run(0)
+        if chunks > 1:
+            carry, _ = lax.scan(chunk, carry, jnp.arange(1, chunks))
+        out, covered = carry
         stats = jnp.stack([pairs["total"], pairs["max_rows"],
                            pairs["total"] - covered]).astype(jnp.int32)
     return out, stats
@@ -598,9 +626,9 @@ def _routed_bwd(first, count, n_experts, factor, impl, res, cts):
     k = sel.shape[1]
     rows, chunks, pairs = _walk(x, sel, first, count, n_experts, factor)
     operands = (x, w, w_gate, w_up, w_down)
-    # The float32 sums over chunks, by whose they are (obs.opscopes' stages):
-    # the tokens' and the weights' are the walk's own, the expert matrices'
-    # their casts' way back.
+    # The float32 sums over chunks (where a second chunk holds pairs), by
+    # whose they are (obs.opscopes' stages): the tokens' and the weights' are
+    # the walk's own, the expert matrices' their casts' way back.
     stages = ("route_accumulate",) * 2 + ("route_weights",) * 3
 
     def staged(fn, *trees):
@@ -610,23 +638,35 @@ def _routed_bwd(first, count, n_experts, factor, impl, res, cts):
                 out.append(fn(*leaves))
         return tuple(out)
 
-    def chunk(grads, c):
-        def run(grads):
-            with jax.named_scope("route_plan"):
-                plan = chunk_plan(pairs, c, rows, k)
-            _, vjp = jax.vjp(
-                lambda *a: _chunk_out(*a, plan, impl), *operands)
-            return staged(lambda g, d: g + d.astype(jnp.float32),
-                          grads, vjp(d_out))
+    def grads_of(c):
+        with jax.named_scope("route_plan"):
+            plan = chunk_plan(pairs, c, rows, k)
+        _, vjp = jax.vjp(lambda *a: _chunk_out(*a, plan, impl), *operands)
+        return vjp(d_out)
 
-        return lax.cond(pairs["total"] > c * rows, run, lambda g: g,
-                        grads), None
+    def rest(grads):
+        def chunk(sums, c):
+            def add(sums):
+                return staged(lambda g, d: g + d.astype(jnp.float32),
+                              sums, grads_of(c))
 
-    zeros = staged(lambda a: jnp.zeros(a.shape, jnp.float32), operands)
-    with jax.named_scope("route_accumulate"):   # the loop is no expert's
-        grads, _ = lax.scan(chunk, zeros, jnp.arange(chunks))
-    dx, dw, dg, du, dd = staged(lambda g, a: g.astype(a.dtype), grads,
-                                operands)
+            return lax.cond(pairs["total"] > c * rows, add, lambda g: g,
+                            sums), None
+
+        sums = staged(lambda d: d.astype(jnp.float32), grads)
+        sums, _ = lax.scan(chunk, sums, jnp.arange(1, chunks))
+        return staged(lambda g, d: g.astype(d.dtype), sums, grads)
+
+    with jax.named_scope("route_accumulate"):   # the walk is no expert's
+        grads = grads_of(0)
+        if chunks > 1:
+            # The barrier keeps what reads the gradients (their casts to
+            # float32, the optimizer's norm) out of the cond: XLA otherwise
+            # sinks it into both branches, and the branch that hands chunk
+            # 0's gradients through writes every one out again as float32.
+            grads = lax.optimization_barrier(lax.cond(
+                pairs["total"] > rows, rest, lambda g: g, grads))
+    dx, dw, dg, du, dd = grads
     return dx, None, dw, dg, du, dd
 
 

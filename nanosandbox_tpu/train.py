@@ -715,12 +715,23 @@ class Trainer:
         (so the step is done and this waits for nothing): an instant in the
         process tracer, rows held by layer, the fullest expert's rows and
         the held (token, slot) pairs no chunk of the sorted walk covered
-        (ops/moe.py: 0 by construction). A dropped pair is a step computed
-        wrong: said loudly, once a log step."""
+        (ops/moe.py: 0 by construction), and ``chunks_run`` by layer: the
+        chunks of that walk that held pairs, from the rows held against a
+        chunk's rows (1 at the expected load: the first chunk's results
+        used as they are, no sum over chunks). A dropped pair is a step
+        computed wrong: said loudly, once a log step."""
         if "moe_dropped" not in metrics:
             return
+        # the family whose step counts these has imported models/experts.py
+        from nanosandbox_tpu.models.experts import walk_rows
+
         got = {k: np.asarray(metrics[k]).tolist()
                for k in ("moe_held", "moe_max_rows", "moe_dropped")}
+        # the model's own config: ``experts_held`` resolved ((0, 0) = all)
+        rows, _ = walk_rows(self.model_cfg,
+                            self.cfg.batch_size * self.cfg.block_size)
+        got["chunks_run"] = [max(1, -(-held // rows))
+                             for held in got["moe_held"]]
         self.tracer.instant("moe_rows", cat="train",
                             args={"iter": iter_num, **got})
         if sum(got["moe_dropped"]) and self.is_main:

@@ -5,6 +5,7 @@ Small sizes, CPU."""
 
 import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -663,6 +664,232 @@ def test_routed_experts_with_the_row_mover_equal_the_xla_walk():
                                    atol=1e-5 * float(jnp.abs(b).max()))
 
 
+# -- the walk's first chunk, used as it is (ISSUE 38) ----------------------------
+
+WALK = {  # name: (held experts, factor, tokens that favour held expert 3)
+    "one_chunk_of_several": ((2, 4), moe.ROWS_FACTOR, 0),
+    "two_chunks": ((2, 4), 0.5, 366),
+    "three_chunks": ((2, 4), 0.5, 1024),
+    "one_chunk_statically": ((2, 12), moe.ROWS_FACTOR, 0),
+    "no_pair_held": ((16, 4), moe.ROWS_FACTOR, 0),
+}
+
+
+def _walk_case(case, dtype):
+    """A tiny layer's operands in the cell's types (tokens and matrices in
+    ``dtype``, weights float32), the chunks its walk runs, and a cotangent."""
+    N, k, E, d, F = 1024, 2, 16, 32, 24
+    (first, count), factor, skewed = WALK[case]
+    keys = jax.random.split(jax.random.key(38), 7)
+    score = jax.random.uniform(keys[1], (N, E))
+    _, sel = jax.lax.top_k(score.at[:skewed, 3].add(1.0), k)
+    x = jax.random.normal(keys[0], (N, d)).astype(dtype)
+    w = jax.random.uniform(keys[2], (N, k)) + 0.1
+    mats = tuple((0.3 * jax.random.normal(kk, shape)).astype(dtype)
+                 for kk, shape in zip(keys[3:6], [(count, d, F), (count, d, F),
+                                                  (count, F, d)]))
+    d_out = jax.random.normal(keys[6], (N, d))
+    rows, chunks = moe.chunk_rows(N, k, E, count, factor)
+    pairs = moe.plan_pairs(sel.astype(jnp.int32), first, count, rows * chunks)
+    total = int(pairs["total"])
+    ran = tuple(c for c in range(chunks) if total > c * rows)
+    return (x, sel.astype(jnp.int32), w, *mats), (first, count, E, factor), \
+        d_out, rows, chunks, ran
+
+
+def _layer_both_ways(operands, static, d_out, impl="ragged_dot"):
+    """(the jitted layer through its own VJP: (x, w, *matrices) -> (out,
+    stats, the five gradients), its arguments)."""
+    x, sel, w, *mats = operands
+
+    @jax.jit
+    def run(x, w, *mats):
+        (out, stats), vjp = jax.vjp(
+            lambda *a: moe.routed_experts(a[0], sel, a[1], *a[2:], *static,
+                                          impl), x, w, *mats)
+        return out, stats, vjp((d_out, np.zeros(3, jax.dtypes.float0)))
+    return run, (x, w, *mats)
+
+
+def _program(operands, static, d_out):
+    """(out, stats, the five gradients) of the layer through its own VJP."""
+    run, args = _layer_both_ways(operands, static, d_out)
+    return run(*args)
+
+
+def _sums_from_zeros(operands, static, d_out, rows, chunks):
+    """The walk as a sum that starts at zero, as ops/moe.py ran it before
+    ISSUE 38: float32 zeros for the output and the five gradients, a scan
+    over ALL the chunks whose body is a ``lax.cond`` that adds a chunk
+    holding pairs, every gradient cast back to its operand's type at the
+    end. (Under the same loop the CPU backend compiles a chunk's own ops as
+    it does the program's, so the comparison sees the sums alone.)"""
+    x, sel, w, *mats = operands
+    first, count, E, _ = static
+    k = sel.shape[1]
+
+    @jax.jit
+    def run(*held):
+        pairs = moe.plan_pairs(sel, first, count, rows * chunks)
+
+        def chunk(carry, c):
+            def add(carry):
+                out, covered, grads = carry
+                plan = moe.chunk_plan(pairs, c, rows, k)
+                got, vjp = jax.vjp(
+                    lambda *a: moe._chunk_out(*a, plan, "ragged_dot"), *held)
+                return (out + got, covered + jnp.sum(plan["group_sizes"]),
+                        tuple(g + d.astype(jnp.float32)
+                              for g, d in zip(grads, vjp(d_out))))
+
+            return jax.lax.cond(pairs["total"] > c * rows, add,
+                                lambda carry: carry, carry), None
+
+        zeros = (jnp.zeros(x.shape, jnp.float32), jnp.zeros((), jnp.int32),
+                 tuple(jnp.zeros(a.shape, jnp.float32) for a in held))
+        (out, covered, grads), _ = jax.lax.scan(chunk, zeros,
+                                                jnp.arange(chunks))
+        stats = jnp.stack([pairs["total"], pairs["max_rows"],
+                           pairs["total"] - covered]).astype(jnp.int32)
+        return out, stats, tuple(g.astype(a.dtype)
+                                 for g, a in zip(grads, held))
+    return run(x, w, *mats)
+
+
+def _same(got, want, bits):
+    """Equal arrays of equal types; ``bits``: every bit (a zero's sign too)."""
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        if bits:
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+        else:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_one_chunk_run_of_several_is_used_as_it_is(dtype):
+    """A balanced layer under factor 2: chunk 0 of two holds every pair.
+    The output, the counters and all five gradients are ``jax.vjp`` of
+    ``_chunk_out`` on chunk 0's plan alone, in the operands' own types, and
+    equal the sum that starts at zero (equal, not bit for bit: ``0.0 +
+    (-0.0)`` is ``+0.0``, so a zero's sign survives now where the sum lost
+    it)."""
+    operands, static, d_out, rows, chunks, ran = _walk_case(
+        "one_chunk_of_several", dtype)
+    assert chunks == 2 and ran == (0,)
+    out, stats, grads = _program(operands, static, d_out)
+    x, sel, w, *mats = operands
+    first, count, E, _ = static
+    assert 0 < int(stats[0]) <= rows and int(stats[2]) == 0
+
+    @jax.jit
+    def chunk0(x, w, *mats):
+        pairs = moe.plan_pairs(sel, first, count, rows * chunks)
+        plan = moe.chunk_plan(pairs, 0, rows, sel.shape[1])
+        got, vjp = jax.vjp(
+            lambda *a: moe._chunk_out(*a, plan, "ragged_dot"), x, w, *mats)
+        return got, vjp(d_out)
+
+    _same((out, grads), chunk0(x, w, *mats), bits=False)
+    assert [g.dtype for g in grads] == [a.dtype for a in (x, w, *mats)]
+    _same((out, stats, grads),
+          _sums_from_zeros(operands, static, d_out, rows, chunks),
+          bits=False)
+    assert float(jnp.abs(out).max()) > 0
+    assert all(float(jnp.abs(g.astype(jnp.float32)).max()) > 0 for g in grads)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["two_chunks", "three_chunks"])
+def test_chunks_past_the_first_are_summed_in_float32_in_chunk_order(case,
+                                                                    dtype):
+    """Chunks of half the expected load, and a router that sends most
+    tokens to one held expert: two and three chunks hold pairs. The float32
+    sums are those of the sum that starts at zero, chunk 0 first, every bit
+    (``0.0 + a`` is ``a`` to the bit unless ``a`` is ``-0.0``, and the sums
+    here meet none)."""
+    operands, static, d_out, rows, chunks, ran = _walk_case(case, dtype)
+    assert chunks == 4 and len(ran) == {"two_chunks": 2,
+                                        "three_chunks": 3}[case]
+    got = _program(operands, static, d_out)
+    assert int(got[1][0]) > (len(ran) - 1) * rows and int(got[1][2]) == 0
+    _same(got, _sums_from_zeros(operands, static, d_out, rows, chunks),
+          bits=True)
+
+
+def test_a_walk_of_one_chunk_is_no_loop():
+    """Where one chunk covers the bound no router can pass (``never <=
+    rows``: half of the experts held, or more), the layer is chunk 0 and
+    nothing else: its lowered text, forward and backward, holds no loop and
+    no branch."""
+    operands, static, d_out, rows, chunks, ran = _walk_case(
+        "one_chunk_statically", "float32")
+    assert chunks == 1 and ran == (0,)
+    run, args = _layer_both_ways(operands, static, d_out)
+    text = run.lower(*args).as_text()
+    assert "stablehlo.dot_general" in text or "ragged_dot" in text
+    for word in ("stablehlo.while", "stablehlo.case", "stablehlo.if"):
+        assert word not in text, word
+    _same(run(*args),
+          _sums_from_zeros(operands, static, d_out, rows, chunks),
+          bits=False)
+
+
+@pytest.mark.parametrize("factor", [moe.ROWS_FACTOR, 100.0],
+                         ids=["of_several", "statically_one"])
+def test_a_layer_that_holds_no_pair_gives_zeros(factor):
+    """Every selected expert is another chip's: chunk 0 runs all the same
+    (it is under no ``cond``), its rows are all invalid and its groups
+    empty, so the output and every gradient are zeros and the counters
+    (0, 0, 0)."""
+    operands, static, d_out, rows, chunks, ran = _walk_case(
+        "no_pair_held", "bfloat16")
+    static = (*static[:3], factor)
+    assert ran == () and (moe.chunk_rows(1024, 2, 16, 4, factor)[1] == 1) == (
+        factor == 100.0)
+    out, stats, grads = _program(operands, static, d_out)
+    assert stats.tolist() == [0, 0, 0]
+    for a in (out, *grads):
+        assert not np.asarray(a.astype(jnp.float32)).any()
+
+
+@pytest.mark.parametrize("factor", [0.5, moe.ROWS_FACTOR],
+                         ids=["two_chunks", "one_chunk"])
+def test_no_sum_over_chunks_starts_from_zeros(factor):
+    """The lowered text of a tiny layer's step, forward and VJP, under the
+    cells' kernels in the interpreter (the 'xla' mover's own ``0.0 + ...``
+    would fill (N, d) zeros of its own): no float32 array of the tokens',
+    the weights' or an expert matrix's shape is filled with zeros anywhere,
+    the branch that sums over chunks included: its sums start from the
+    first chunk's results. (The walk that started from zeros filled six.)"""
+    N, k, E, d, F, first, count = 256, 4, 16, 128, 64, 2, 12
+    keys = jax.random.split(jax.random.key(11), 6)
+    x = jax.random.normal(keys[0], (N, d)).astype(jnp.bfloat16)
+    _, sel = jax.lax.top_k(jax.random.uniform(keys[1], (N, E)), k)
+    w = jax.random.uniform(keys[2], (N, k)) + 0.1
+    mats = [(0.1 * jax.random.normal(kk, shape)).astype(jnp.bfloat16)
+            for kk, shape in zip(keys[3:], [(count, d, F), (count, d, F),
+                                            (count, F, d)])]
+    assert moe.chunk_rows(N, k, E, count, factor)[1] == (2 if factor < 1
+                                                         else 1)
+
+    run, args = _layer_both_ways(
+        (x, sel.astype(jnp.int32), w, *mats), (first, count, E, factor),
+        jnp.ones((N, d)), "megablox_interpret")
+    text = run.lower(*args).as_text()
+    shapes = "|".join("x".join(map(str, a.shape)) for a in (x, w, *mats))
+    seen = 0
+    for func in text.split("func.func")[1:]:    # a name is a function's own
+        zeros = re.findall(r"(%\S+) = stablehlo.constant dense<0\.0+e\+00> "
+                           r": tensor<f32>", func)
+        seen += len(zeros)
+        filled = re.findall(
+            rf"broadcast_in_dim (%\S+), dims = \[\] : \(tensor<f32>\) -> "
+            rf"tensor<(?:{shapes})xf32>", func)
+        assert not [c for c in filled if c in zeros]
+    assert seen                 # the pattern reads this text's constants
+
+
 def test_chunk_rows():
     assert moe.chunk_rows(16384, 8, 128, 16) == (32768, 4)
     assert moe.chunk_rows(16384, 8, 128, 16, 3.0) == (49152, 3)
@@ -700,13 +927,19 @@ def test_trainer_two_steps_save_restore_same_loss(afmoe_train_cfg):
     assert init.args["gqa_bwd"] == "xla"
     rows = [s for s in process_tracer().spans() if s.name == "moe_rows"][-1]
     assert rows.args["moe_dropped"] == [0, 0] and len(rows.args["moe_held"]) == 2
+    assert rows.args["chunks_run"] == [1, 1]    # one chunk covers the bound
     parts = set(opscopes.step_parts().values())
     assert {"attn_sliding", "attn_full", "moe_route", "moe_experts",
             "moe_shared"} <= parts and "attn" not in parts
     # the stages of moe_route, out of the same lowering as the parts (under
-    # remat here: the replayed forward and the backward's second walk)
+    # remat here: the replayed forward and the backward's second walk); in
+    # float32 the held matrices need no cast and this walk is one chunk, with
+    # no sum over chunks: ``route_weights`` has nothing to own (in bfloat16
+    # it has: tests/test_train_tracing.py)
     stages = opscopes.step_stages()
-    assert set(opscopes.STAGES) <= set(stages.values())
+    # exactly the other five: a stage that loses its scope, ``route_weights``
+    # gaining work in float32 or an unstaged instruction is noticed here
+    assert set(stages.values()) == set(opscopes.STAGES) - {"route_weights"}
     assert set(stages) == {n for n, p in opscopes.step_parts().items()
                            if p == "moe_route"}
 

@@ -409,14 +409,20 @@ def test_trainer_two_steps_save_restore_same_loss(dsv3_train_cfg):
     rows = [s for s in process_tracer().spans() if s.name == "moe_rows"][-1]
     assert rows.args["moe_dropped"] == [0, 0, 0]
     assert len(rows.args["moe_held"]) == 3
+    assert rows.args["chunks_run"] == [1, 1, 1]    # one chunk covers the bound
     parts = set(opscopes.step_parts().values())
     assert {"attn_mla", "mla_prep", "mlp", "moe_route", "moe_experts",
             "moe_shared", "ln"} <= parts
     assert not {"attn", "attn_full", "attn_sliding", "conv"} & parts
     # the stages of moe_route, out of the same lowering as the parts (under
-    # remat here: the replayed forward and the backward's second walk)
+    # remat here: the replayed forward and the backward's second walk); in
+    # float32 the held matrices need no cast and this walk is one chunk, with
+    # no sum over chunks: ``route_weights`` has nothing to own (in bfloat16
+    # it has: tests/test_train_tracing.py)
     stages = opscopes.step_stages()
-    assert set(opscopes.STAGES) <= set(stages.values())
+    # exactly the other five: a stage that loses its scope, ``route_weights``
+    # gaining work in float32 or an unstaged instruction is noticed here
+    assert set(stages.values()) == set(opscopes.STAGES) - {"route_weights"}
     assert set(stages) == {n for n, p in opscopes.step_parts().items()
                            if p == "moe_route"}
 

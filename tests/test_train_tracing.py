@@ -175,11 +175,24 @@ def test_op_parts_of_the_tiny_step(tiny_cfg, loss_chunk_size):
     assert opscopes.step_parts() is None
 
 
-def _check_stages(text, parts, work):
+# The tiny expert steps walk 1024 tokens with top-2 of ``num_experts``, three
+# held: of 4 experts one chunk covers the bound (ops.moe.chunk_rows: no scan
+# at all), of 8 the walk has a second chunk, under its scan and conds. They
+# compute in bfloat16, as the cells do: in float32 the held matrices need no
+# cast, and a walk of one chunk leaves ``route_weights`` nothing to own.
+WALKS = pytest.mark.parametrize("num_experts", [4, 8],
+                                ids=["one-chunk-walk", "two-chunk-walk"])
+
+
+def _check_stages(text, parts, work, cfg):
     """The stage map of a lowered expert step, out of the same text as
     ``parts``: one walk gives both, the part map as ``op_parts`` gives it;
     the six stages of ``moe_route`` are all there, nothing outside the part
-    has one, and little of the part is left unstaged."""
+    has one, and NO instruction of the part is left unstaged: the walk's
+    first chunk lies outside the scan, under the stages that name its code,
+    like the chunks inside it."""
+    from nanosandbox_tpu.ops import moe
+
     again, stages = opscopes.op_maps(text)
     assert again == parts and stages == opscopes.op_stages(text)
     assert set(stages) == {n for n, p in parts.items() if p == "moe_route"}
@@ -187,14 +200,21 @@ def _check_stages(text, parts, work):
     by_stage = {s: [n for n, _ in work if stages.get(n) == s] for s in labels}
     for stage in opscopes.STAGES:
         assert by_stage[stage], stage
-    routed = [n for n, _ in work if n in stages]
-    assert len(by_stage[opscopes.UNSTAGED]) <= 0.10 * len(routed)
+    assert not by_stage[opscopes.UNSTAGED]
+    assert opscopes.UNSTAGED not in stages.values()
     # a path's own words decide where there are any: the plan's sort, the
     # rows' gathers and the router's top-k are never another stage's
     for name, line in work:
         op = opscopes._OP_NAME.search(line)
         if name in stages and op and opscopes.stage_of(op.group(1)):
             assert stages[name] == opscopes.stage_of(op.group(1)), name
+    _, chunks = moe.chunk_rows(cfg.batch_size * cfg.block_size,
+                               cfg.num_experts_per_tok, cfg.num_experts,
+                               cfg.experts_held[1])
+    assert chunks == {4: 1, 8: 2}[cfg.num_experts]
+    walked = [n for n, line in work if n in stages and re.search(
+        r"route_accumulate/(while|cond)", line)]
+    assert bool(walked) == (chunks > 1)
     # the provider hands both maps over from ONE lowering
     calls = []
     opscopes.set_provider(lambda: calls.append(1) or opscopes.op_maps(text))
@@ -202,16 +222,117 @@ def _check_stages(text, parts, work):
     assert opscopes.step_parts() == parts and len(calls) == 1
 
 
-def test_op_parts_of_a_two_layer_lfm2_step(tiny_cfg):
+@WALKS
+def test_op_stages_of_a_two_layer_afmoe_step(tiny_cfg, num_experts):
+    """The ``afmoe`` family's step, a window layer and a full one, both with
+    experts: what ``_check_stages`` holds the stage map of ``moe_route`` to,
+    with a walk of one chunk and of two."""
+    cfg = tiny_cfg.replace(
+        model_family="afmoe", n_layer=2, n_head=2, n_kv_head=1, head_dim=16,
+        n_embd=32, layer_types="sliding,full", sliding_window=16,
+        num_dense_layers=0, intermediate_size=48, moe_intermediate_size=24,
+        num_experts=num_experts, num_experts_per_tok=2, experts_held=(0, 3),
+        batch_size=16, loss_chunk_size=16, compute_dtype="bfloat16")
+    trainer = Trainer(cfg)
+    train_step, _ = trainer.compiled_steps()
+    text = train_step.lower(*trainer._step_operands()).compile().as_text()
+    parts = opscopes.op_parts(text)
+    work = _working_instructions(text)
+    by_part = {p for n, _ in work for p in [parts[n]]}
+    assert {"attn_sliding", "attn_full", "moe_route", "moe_experts",
+            "moe_shared"} <= by_part
+    _check_stages(text, parts, work, cfg)
+    opscopes.set_provider(None)
+
+
+def test_moe_rows_instant_counts_the_chunks_run(tiny_cfg):
+    """``Trainer._count_expert_rows`` adds ``chunks_run`` by layer to the
+    ``moe_rows`` instant: the rows held against a chunk's rows, at least 1
+    (the first chunk runs whatever it holds). Host arithmetic on numbers
+    the step's metrics carry already."""
+    from nanosandbox_tpu.ops import moe
+
+    cfg = tiny_cfg.replace(
+        model_family="lfm2", n_layer=2, n_head=2, n_kv_head=1, head_dim=16,
+        n_embd=32, layer_types="conv,full", num_dense_layers=0,
+        moe_intermediate_size=24, num_experts=8, num_experts_per_tok=2,
+        experts_held=(0, 2), loss_chunk_size=16)
+    trainer = Trainer(cfg)
+    rows, chunks = moe.chunk_rows(cfg.batch_size * cfg.block_size, 2, 8, 2)
+    assert (rows, chunks) == (512, 2)
+    tracer = process_tracer()
+    tracer.clear()
+    held = [0, 1, rows, rows + 1, 2 * rows]
+    trainer._count_expert_rows(
+        {"moe_held": np.array(held), "moe_max_rows": np.array(held),
+         "moe_dropped": np.zeros(5, int)}, 7)
+    (instant,) = _named(tracer.spans(), "moe_rows")
+    assert instant.args["iter"] == 7 and instant.args["moe_held"] == held
+    assert instant.args["chunks_run"] == [1, 1, 1, 2, 2] == [
+        max(1, -(-h // rows)) for h in held]
+    # a step without expert layers leaves no such instant
+    trainer._count_expert_rows({"loss": 1.0}, 8)
+    assert len(_named(tracer.spans(), "moe_rows")) == 1
+
+
+FAMILY_KEYS = {
+    "afmoe": dict(n_head=2, n_kv_head=1, head_dim=16, intermediate_size=48,
+                  layer_types="sliding,full", sliding_window=16),
+    "lfm2": dict(n_head=2, n_kv_head=1, head_dim=16,
+                 layer_types="conv,full"),
+    "deepseek_v3": dict(n_head=2, kv_lora_rank=24, qk_nope_head_dim=16,
+                        qk_rope_head_dim=8, v_head_dim=16,
+                        n_shared_experts=2),
+}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILY_KEYS))
+def test_run_counts_the_chunks_with_experts_held_left_unset(tiny_cfg, family):
+    """``experts_held`` left at its default (0, 0) means ALL experts are
+    held: the family's model config resolves it, and ``chunks_run`` is read
+    against the chunk of the walk THAT config gives (``experts.walk_rows``),
+    not against the unresolved pair (a chunk of 0 rows). A two-step run()
+    with a log read-back every step goes through ``_count_expert_rows``."""
+    from nanosandbox_tpu.models import experts
+
+    cfg = tiny_cfg.replace(
+        model_family=family, n_layer=2, n_embd=32, num_dense_layers=0,
+        moe_intermediate_size=24, num_experts=4, num_experts_per_tok=2,
+        loss_chunk_size=16, max_iters=2, lr_decay_iters=2, log_interval=1,
+        **FAMILY_KEYS[family])
+    assert cfg.experts_held == (0, 0)
+    tracer = process_tracer()
+    tracer.clear()
+    trainer = Trainer(cfg)
+    assert trainer.model_cfg.experts_held == (0, 4)
+    assert trainer.run()["iter_num"] == 2
+    rows, chunks = experts.walk_rows(trainer.model_cfg,
+                                     cfg.batch_size * cfg.block_size)
+    assert rows > 0 and chunks == 1
+    instants = _named(tracer.spans(), "moe_rows")
+    assert len(instants) >= 2
+    for instant in instants:
+        held = instant.args["moe_held"]
+        # every token's k pairs are held, and one chunk covers them
+        assert held == [cfg.batch_size * cfg.block_size * 2] * 2
+        assert instant.args["chunks_run"] == [1, 1] == [
+            max(1, -(-h // rows)) for h in held]
+        assert instant.args["moe_dropped"] == [0, 0]
+
+
+@WALKS
+def test_op_parts_of_a_two_layer_lfm2_step(tiny_cfg, num_experts):
     """The ``lfm2`` family's step: a conv layer and an attention layer with
     experts; its ops fall under conv (the projections) and conv_mix (the
     gates and taps), attn_full, moe_route and moe_experts, every matmul is
     somebody's, and the map names no part the table lacks."""
-    trainer = Trainer(tiny_cfg.replace(
+    cfg = tiny_cfg.replace(
         model_family="lfm2", n_layer=2, n_head=2, n_kv_head=1, head_dim=16,
         n_embd=32, layer_types="conv,full", num_dense_layers=0,
-        moe_intermediate_size=24, num_experts=4, num_experts_per_tok=2,
-        experts_held=(0, 2), loss_chunk_size=16))
+        moe_intermediate_size=24, num_experts=num_experts,
+        num_experts_per_tok=2, experts_held=(0, 3), batch_size=16,
+        loss_chunk_size=16, compute_dtype="bfloat16")
+    trainer = Trainer(cfg)
     train_step, _ = trainer.compiled_steps()
     text = train_step.lower(*trainer._step_operands()).compile().as_text()
     parts = opscopes.op_parts(text)
@@ -226,22 +347,25 @@ def test_op_parts_of_a_two_layer_lfm2_step(tiny_cfg):
         assert not by_part[part], part
     dots = [n for n, line in work if re.search(r"\sdot\(", line)]
     assert dots and all(parts[n] != opscopes.UNSCOPED for n in dots)
-    _check_stages(text, parts, work)
+    _check_stages(text, parts, work, cfg)
     opscopes.set_provider(None)
 
 
-def test_op_parts_of_a_two_layer_deepseek_v3_step(tiny_cfg):
+@WALKS
+def test_op_parts_of_a_two_layer_deepseek_v3_step(tiny_cfg, num_experts):
     """The ``deepseek_v3`` family's step: two latent-attention layers with
     experts; its ops fall under attn_mla (the projections and attention),
     mla_prep (the latent's norm, the rotary positions), moe_route,
     moe_experts and moe_shared, every matmul is somebody's, and the map
     names no part the table lacks."""
-    trainer = Trainer(tiny_cfg.replace(
+    cfg = tiny_cfg.replace(
         model_family="deepseek_v3", n_layer=2, n_head=2, n_embd=32,
         kv_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8,
         v_head_dim=16, num_dense_layers=0, moe_intermediate_size=24,
-        n_shared_experts=2, num_experts=4, num_experts_per_tok=2,
-        experts_held=(0, 2), loss_chunk_size=16))
+        n_shared_experts=2, num_experts=num_experts, num_experts_per_tok=2,
+        experts_held=(0, 3), batch_size=16, loss_chunk_size=16,
+        compute_dtype="bfloat16")
+    trainer = Trainer(cfg)
     train_step, _ = trainer.compiled_steps()
     text = train_step.lower(*trainer._step_operands()).compile().as_text()
     parts = opscopes.op_parts(text)
@@ -256,7 +380,7 @@ def test_op_parts_of_a_two_layer_deepseek_v3_step(tiny_cfg):
         assert not by_part[part], part
     dots = [n for n, line in work if re.search(r"\sdot\(", line)]
     assert dots and all(parts[n] != opscopes.UNSCOPED for n in dots)
-    _check_stages(text, parts, work)
+    _check_stages(text, parts, work, cfg)
     opscopes.set_provider(None)
 
 
