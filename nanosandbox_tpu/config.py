@@ -53,8 +53,10 @@ class TrainConfig:
     # -- model family. 'gpt2' (models/gpt.py) reads the keys above; 'afmoe'
     #    (models/afmoe.py: Arcee Trinity's block, HF model_type afmoe),
     #    'lfm2' (models/lfm2.py: LiquidAI's LFM2 MoE block, HF model_type
-    #    lfm2_moe) and 'deepseek_v3' (models/deepseek_v3.py: latent attention
-    #    beside routed and shared experts, HF model_type deepseek_v3) read
+    #    lfm2_moe), 'deepseek_v3' (models/deepseek_v3.py: latent attention
+    #    beside routed and shared experts, HF model_type deepseek_v3) and
+    #    'ouro' (models/ouro.py: one stack of layers run total_ut_steps times
+    #    a step, an exit after every pass, HF model_type ouro) read
     #    n_layer, n_head, n_embd, block_size, vocab_size above and the keys
     #    below, named as the published config.json names them (each family's
     #    model-config class further down says which). --
@@ -99,6 +101,10 @@ class TrainConfig:
     n_shared_experts: int = 0  # the shared expert is this many experts wide
     n_group: int = 1
     topk_group: int = 1
+    # ouro's loop: the whole stack of n_layer layers (and the final norm) is
+    # run this many times a step, with an exit (head + exit gate) after each
+    # pass; the loss is over the exits (models/ouro.py).
+    total_ut_steps: int = 4
 
     # -- optimizer / schedule (nanoGPT contract: cosine decay, AdamW, clip) --
     learning_rate: float = 6e-4
@@ -403,7 +409,7 @@ class GPTConfig:
         )
 
 
-MODEL_FAMILIES = ("gpt2", "afmoe", "lfm2", "deepseek_v3")
+MODEL_FAMILIES = ("gpt2", "afmoe", "lfm2", "deepseek_v3", "ouro")
 
 
 def _expert_family_keys(cfg: TrainConfig, family: str, kinds_allowed: tuple,
@@ -633,6 +639,58 @@ class DeepseekV3Config:
             num_experts_per_tok=cfg.num_experts_per_tok,
             experts_held=(first, count), route_scale=cfg.route_scale,
             route_norm=cfg.route_norm, rope_theta=cfg.rope_theta,
+            rms_norm_eps=cfg.rms_norm_eps, param_dtype=cfg.param_dtype,
+            compute_dtype=cfg.compute_dtype,
+            attention_impl=cfg.attention_impl, remat=cfg.remat,
+            remat_policy=cfg.remat_policy)
+
+
+@dataclass
+class OuroConfig:
+    """Model-only view of the config for models.ouro.Ouro."""
+
+    n_layer: int  # layers held: ONE pass of the stack
+    n_head: int
+    n_kv_head: int
+    head_dim: int
+    n_embd: int
+    block_size: int
+    vocab_size: int
+    intermediate_size: int
+    total_ut_steps: int  # passes of the stack a step, an exit after each
+    rope_theta: float = 1000000.0
+    rms_norm_eps: float = 1e-6
+    param_dtype: str = "float32"
+    compute_dtype: str = "bfloat16"
+    attention_impl: str = "auto"
+    remat: bool = False
+    remat_policy: str = "save_attention"
+
+    def replace(self, **kw: Any) -> "OuroConfig":
+        return dataclasses.replace(self, **kw)
+
+    @classmethod
+    def from_train_config(cls, cfg: TrainConfig,
+                          vocab_size: int) -> "OuroConfig":
+        n_kv_head = cfg.n_kv_head or cfg.n_head
+        head_dim = cfg.head_dim or cfg.n_embd // max(cfg.n_head, 1)
+        problems = []
+        if cfg.n_head % n_kv_head or head_dim % 2:
+            problems.append("n_kv_head must divide n_head, and rotary "
+                            "positions need an even head_dim")
+        if cfg.intermediate_size <= 0:
+            problems.append("intermediate_size must be set")
+        if cfg.total_ut_steps < 1:
+            problems.append("total_ut_steps must be at least 1")
+        if cfg.dropout or cfg.bias:
+            problems.append("the ouro block has no dropout and no biases")
+        if problems:
+            raise ValueError("model_family='ouro': " + "; ".join(problems))
+        return cls(
+            n_layer=cfg.n_layer, n_head=cfg.n_head, n_kv_head=n_kv_head,
+            head_dim=head_dim, n_embd=cfg.n_embd, block_size=cfg.block_size,
+            vocab_size=vocab_size, intermediate_size=cfg.intermediate_size,
+            total_ut_steps=cfg.total_ut_steps, rope_theta=cfg.rope_theta,
             rms_norm_eps=cfg.rms_norm_eps, param_dtype=cfg.param_dtype,
             compute_dtype=cfg.compute_dtype,
             attention_impl=cfg.attention_impl, remat=cfg.remat,

@@ -1,7 +1,8 @@
 """Model families: ``gpt2`` (models/gpt.py), ``afmoe`` (Arcee Trinity's
-block), ``lfm2`` (LiquidAI's LFM2 MoE block) and ``deepseek_v3`` (latent
+block), ``lfm2`` (LiquidAI's LFM2 MoE block), ``deepseek_v3`` (latent
 attention beside routed and shared experts; Moonlight is a published
-instance). ``FAMILIES`` is the one place that knows which exist:
+instance) and ``ouro`` (one stack of layers run several times a step, an
+exit after every pass). ``FAMILIES`` is the one place that knows which exist:
 ``TrainConfig.model_family`` -> the family's module, imported on first use (a
 GPT-2 run imports no other family's kernels). The MODULE is the interface:
 ``Trainer`` and ``restore_for_inference`` ask it, by these module-level names,
@@ -19,6 +20,11 @@ what they would otherwise ask by the family's name:
     inference                  None, or what inference misses for the family
     pretrained(cfg, dataset_meta)   (cfg, params) where ``init_from`` can
                                name weights; a family without it refuses them
+    exit_loss(nll, aux)        (loss, aux) where the family makes its loss of
+                               SEVERAL hidden states (``apply`` gives them
+                               stacked, one an exit): their per-token cross
+                               entropies (exits, B, T) in; a family without
+                               it is one hidden state through one head
 
 A new family is its module (and kernels), one line here, its ``TrainConfig``
 keys and model-config class (config.py), its rows in ``opscopes._COMPONENT``.
@@ -35,6 +41,7 @@ FAMILIES = {
     "afmoe": "nanosandbox_tpu.models.afmoe",
     "lfm2": "nanosandbox_tpu.models.lfm2",
     "deepseek_v3": "nanosandbox_tpu.models.deepseek_v3",
+    "ouro": "nanosandbox_tpu.models.ouro",
 }
 
 

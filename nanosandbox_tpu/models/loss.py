@@ -1,7 +1,8 @@
 """The head's losses, for every model family: mean next-token cross entropy
 over full logits, and the head matmul fused into it chunk by chunk (alone, or
-per shard under sequence parallelism). ``Trainer._loss_fn`` picks by
-``loss_chunk_size`` and the mesh."""
+per shard under sequence parallelism, or per token for a family that makes
+its own loss of several exits). ``Trainer._loss_fn`` picks by
+``loss_chunk_size``, the mesh and the family."""
 
 from __future__ import annotations
 
@@ -64,6 +65,38 @@ def chunked_cross_entropy_loss(hidden: jax.Array, embedding: jax.Array,
     return tot / jnp.maximum(cnt, 1)
 
 
+def _chunks(hidden, targets, chunk_size: int):
+    """(hidden (n, B, cs, C), targets (n, B, cs)): the sequence cut into n
+    chunks of the largest cs <= chunk_size that divides it, for a scan."""
+    B, T, C = hidden.shape
+    cs = min(chunk_size, T)
+    while T % cs:
+        cs -= 1  # largest divisor <= chunk_size; worst case 1
+    n = T // cs
+    return (hidden.reshape(B, n, cs, C).transpose(1, 0, 2, 3),
+            targets.reshape(B, n, cs).transpose(1, 0, 2))
+
+
+def _chunk_nll(h_c, y_c, emb, dtype, ignore_index: int):
+    """(nll (B, cs), valid (B, cs)) of one chunk: its head matmul and cross
+    entropy, the body both scans below checkpoint."""
+    from jax import lax
+
+    logits = lax.dot_general(
+        h_c.astype(dtype), emb,
+        (((2,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)  # (B, cs, V)
+    valid = y_c != ignore_index
+    safe = jnp.where(valid, y_c, 0)
+    # logsumexp form, same as cross_entropy_loss: the (B, cs, V)
+    # log-prob tensor never materializes (here it would also be
+    # recomputed by the checkpoint during backward, doubling the
+    # waste).
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    tgt = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0]
+    return lse - tgt, valid
+
+
 def _chunked_nll_sums(hidden, embedding, targets, *, chunk_size: int,
                       compute_dtype: str, ignore_index: int = -1):
     """(sum of nll, count of valid targets) via the chunked scan — the
@@ -71,32 +104,13 @@ def _chunked_nll_sums(hidden, embedding, targets, *, chunk_size: int,
     sequence-parallel psum variant below."""
     from jax import lax
 
-    B, T, C = hidden.shape
-    cs = min(chunk_size, T)
-    while T % cs:
-        cs -= 1  # largest divisor <= chunk_size; worst case 1
-    n = T // cs
     dtype = jnp.dtype(compute_dtype)
-    h = hidden.reshape(B, n, cs, C).transpose(1, 0, 2, 3)
-    y = targets.reshape(B, n, cs).transpose(1, 0, 2)
+    h, y = _chunks(hidden, targets, chunk_size)
     emb = embedding.astype(dtype)
 
     @jax.checkpoint
     def body(carry, xy):
-        h_c, y_c = xy
-        logits = lax.dot_general(
-            h_c.astype(dtype), emb,
-            (((2,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)  # (B, cs, V)
-        valid = y_c != ignore_index
-        safe = jnp.where(valid, y_c, 0)
-        # logsumexp form, same as cross_entropy_loss: the (B, cs, V)
-        # log-prob tensor never materializes (here it would also be
-        # recomputed by the checkpoint during backward, doubling the
-        # waste).
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        tgt = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0]
-        nll = lse - tgt
+        nll, valid = _chunk_nll(*xy, emb, dtype, ignore_index)
         tot, cnt = carry
         return (tot + jnp.where(valid, nll, 0.0).sum()[None],
                 cnt + valid.sum()[None]), None
@@ -110,6 +124,30 @@ def _chunked_nll_sums(hidden, embedding, targets, *, chunk_size: int,
         body, (jnp.zeros((1,), jnp.float32), jnp.zeros((1,), jnp.int32)),
         (h, y))
     return tot[0], cnt[0]
+
+
+def chunked_token_nll(hidden: jax.Array, embedding: jax.Array,
+                      targets: jax.Array, *, chunk_size: int,
+                      compute_dtype: str = "bfloat16",
+                      ignore_index: int = -1) -> jax.Array:
+    """The head + cross entropy of ``chunked_cross_entropy_loss``, the same
+    chunks and checkpointed body, kept per token: (B, T) float32 nll (0 at
+    ``ignore_index``) and no mean, for a family that weights its tokens'
+    losses itself (models/ouro.py's ``exit_loss``)."""
+    from jax import lax
+
+    B, T, _ = hidden.shape
+    dtype = jnp.dtype(compute_dtype)
+    h, y = _chunks(hidden, targets, chunk_size)
+    emb = embedding.astype(dtype)
+
+    @jax.checkpoint
+    def body(carry, xy):
+        nll, valid = _chunk_nll(*xy, emb, dtype, ignore_index)
+        return carry, jnp.where(valid, nll, 0.0)
+
+    _, nll = lax.scan(body, None, (h, y))      # (n, B, cs)
+    return nll.transpose(1, 0, 2).reshape(B, T)
 
 
 def sharded_chunked_cross_entropy_loss(hidden: jax.Array,

@@ -6,9 +6,10 @@ mlp/c_fc/dot_general"}``), backward pass included. Flax names the modules
 (``h_3/attn/c_attn``, ``mlp``, ``ln_1``, ``ln_f``, ``wte``, ``wpe``; for
 models/afmoe.py ``attn_sliding`` / ``attn_full``, ``moe_shared``, ``ln_in``
 ...; for models/lfm2.py ``conv``, ``attn_full``, ``operator_norm`` ...; for
-models/deepseek_v3.py ``attn_mla``, ``input_layernorm`` ...) and
-the trainer adds ``jax.named_scope`` where no module names the work
-(``lm_head_loss``, ``optimizer``, ``grad_norm``, ``accum``). A device trace
+models/deepseek_v3.py ``attn_mla``, ``input_layernorm`` ...; for
+models/ouro.py ``exit_gate``) and the trainer adds ``jax.named_scope``
+where no module names the work (``lm_head_loss``, ``exits``,
+``optimizer``, ``grad_norm``, ``accum``). A device trace
 names its events by instruction (``%fusion.24 = ...``), a name the compiler
 hands out anew at every change to the step; this file turns that name into
 one of a few stable labels, so that "what does the head cost a step" has an
@@ -51,7 +52,11 @@ PARTS = ("attn", "mlp", "ln", "embed", "lm_head_loss", "optimizer",
          # models/deepseek_v3.py: latent attention (projections and the
          # %attn_mla kernels), and inside it the latent's norm with the
          # rotary positions of the 64-lane parts; its experts as above.
-         "attn_mla", "mla_prep")
+         "attn_mla", "mla_prep",
+         # models/ouro.py: the exits of its looped stack (the heads with
+         # their cross entropies, the exit gate, the expected loss and the
+         # entropy); its attention is an "attn_full".
+         "exits")
 UNSCOPED = "unscoped"
 
 # Path component -> part. ``wte.attend`` is the tied head's matmul where the
@@ -81,6 +86,9 @@ _COMPONENT = {
     "attn_mla": "attn_mla", "mla_prep": "mla_prep",
     "input_layernorm": "ln", "post_attention_layernorm": "ln",
     "final_norm": "ln",
+    # models/ouro.py. The trainer's named scope ``exits`` and the model's
+    # gate module; the norms are afmoe's names.
+    "exits": "exits", "exit_gate": "exits",
 }
 
 # Scope name -> the part it splits: the stages of ``moe_route``, each a

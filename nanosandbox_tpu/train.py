@@ -416,11 +416,14 @@ class Trainer:
     def _loss_fn(self, params, x, y, rng):
         """(loss, aux): aux is what the family's ``apply`` reports of a
         step beside its loss ({} for a dense model; expert layers' rows
-        held, fullest expert and dropped slots where there are any)."""
+        held, fullest expert and dropped slots where there are any; the
+        exits' mean probabilities and cross entropies where the family
+        makes its loss of several)."""
         import jax
+        import jax.numpy as jnp
 
         from nanosandbox_tpu.models.loss import (
-            chunked_cross_entropy_loss, cross_entropy_loss,
+            chunked_cross_entropy_loss, chunked_token_nll, cross_entropy_loss,
             sharded_chunked_cross_entropy_loss)
 
         deterministic = self.cfg.dropout == 0.0 or rng is None
@@ -428,6 +431,21 @@ class Trainer:
             self.family.apply, self.model, params, x,
             deterministic=deterministic,
             rngs=None if deterministic else {"dropout": rng})
+        # A family that makes its loss of several exits (models/__init__.py:
+        # ``exit_loss``): each exit's state through the head, per token, then
+        # the family's own weighting, all under the scope ``exits``. The
+        # family's ``check`` keeps the sequence axis off; a chunk size of 0
+        # is one chunk of the whole sequence.
+        exit_loss = getattr(self.family, "exit_loss", None)
+        if exit_loss is not None:
+            hidden, aux = apply(return_hidden=True)     # (exits, B, T, C)
+            head = self.family.head(params)
+            with jax.named_scope("exits"):
+                nll = jnp.stack([chunked_token_nll(
+                    h, head, y, compute_dtype=self.cfg.compute_dtype,
+                    chunk_size=self.loss_chunk_size or self.cfg.block_size)
+                    for h in hidden])
+                return exit_loss(nll, aux)
         # The head matmul and the cross entropy are no flax module's, so
         # they get a scope of their own (obs.opscopes reads it back from
         # the compiled step): round them alone, not round this whole
@@ -740,6 +758,19 @@ class Trainer:
                   f"{got['moe_held']}): ops/moe.py's chunks no longer "
                   "cover its own bound", file=sys.stderr)
 
+    def note_exits(self, metrics: dict, iter_num: int) -> None:
+        """The exits' numbers of a step whose loss has been read, where the
+        family makes its loss of several (``exit_loss``): an instant
+        ``<family module>_exits`` in the process tracer with each pass's mean
+        exit probability and each exit's mean cross entropy."""
+        if "exit_p" not in metrics:
+            return
+        name = self.family.__name__.rsplit(".", 1)[-1] + "_exits"
+        self.tracer.instant(name, cat="train", args={
+            "iter": iter_num,
+            **{k: np.asarray(metrics[k]).tolist()
+               for k in ("exit_p", "exit_nll")}})
+
     # -- evaluation (nanoGPT estimate_loss) ----------------------------------
 
     def estimate_loss(self, state, eval_iters: int | None = None) -> dict:
@@ -1025,6 +1056,7 @@ class Trainer:
                     # train_iter's return value, so no suppression here.)
                     grad_norm = float(metrics["grad_norm"])
                     self._count_expert_rows(metrics, iter_num)
+                    self.note_exits(metrics, iter_num)
                     lr = (float(self.lr_schedule(iter_num))
                           if callable(self.lr_schedule)
                           else self.lr_schedule)

@@ -53,6 +53,25 @@ TINY = {
                     {"attn_layout", "attn_route", "mla_bwd", "moe_row_mover",
                      "gmm_tiling", "layer_types", "experts_held"},
                     None),
+    "ouro": (dict(n_layer=2, n_head=4, n_kv_head=4, head_dim=16, n_embd=64,
+                  intermediate_size=96, total_ut_steps=4),
+             {"attn_layout", "attn_route", "gqa_bwd", "loops", "layers_held",
+              "remat_policy"},
+             None),
+}
+# sha256 of each family's train step as `built` lowers it for the compiler
+# (its HLO text without metadata: scope paths and source lines are not the
+# program), at PR 38's tree (1614ae0). PR 39 put `ouro` beside them, gave
+# Trainer._loss_fn a branch for a family with `exit_loss` and models/loss.py
+# a per-token head: the four steps are what they were, op for op. What the
+# compiler receives, not what it returns: the same text compiles to the same
+# program on any host, where the CPU's own passes may differ by machine.
+STEP_SHA256 = {
+    "gpt2": "d09f607a08ceb2dc34899bbeb2bb6c714fdc3eb50457033b81736b29c4986067",
+    "afmoe": "cc6dede9a0095121916826a52063dd92cddaa618c90c1692ba3b8a648c609b3f",
+    "lfm2": "50ae1f1f769ff459850a277cfd21443fb6ee62a1860e5552d8b6fcf23ee38053",
+    "deepseek_v3":
+        "8f1dab78531ca3c83a43fbe39908ece0b8f7c9b5e7cefd0002d04bb2878a9942",
 }
 
 
@@ -114,18 +133,53 @@ def test_flops_per_iter_is_the_parents(built):
 
 
 def test_head_and_apply_have_the_shapes_the_loss_takes(built):
+    """One hidden state (B, T, C) for the head, or, where the family makes
+    its loss of several exits (``exit_loss``), one an exit (exits, B, T, C)
+    with the exits' float32 logits in the aux; the counters are int32."""
     _, trainer, _ = built
     family, m = trainer.family, trainer.model_cfg
     params = jax.eval_shape(trainer._init_state, jax.random.key(0))["params"]
     assert family.head(params).shape == (m.vocab_size, m.n_embd)
     x = jax.ShapeDtypeStruct((8, 16), jnp.int32)  # a row a device
+    exits = hasattr(family, "exit_loss")
     for hidden, last in ((True, m.n_embd), (False, m.vocab_size)):
         out, aux = jax.eval_shape(
             lambda p, x: family.apply(trainer.model, p, x, deterministic=True,
                                       return_hidden=hidden), params, x)
-        assert out.shape == (8, 16, last)
+        lead = (m.total_ut_steps,) if exits and hidden else ()
+        assert out.shape == (*lead, 8, 16, last)
         assert isinstance(aux, dict)
-        assert all(a.dtype == jnp.int32 for a in aux.values())
+        assert all(a.dtype == jnp.int32 for k, a in aux.items()
+                   if k != "exit_logits")
+        if exits:
+            assert aux["exit_logits"].shape == (m.total_ut_steps, 8, 16)
+
+
+def _step_hlo(trainer) -> str:
+    """The train step's HLO as lowered for the compiler, without metadata."""
+    from nanosandbox_tpu.train import _lower_step
+
+    step, _ = trainer.compiled_steps()
+    lowered = _lower_step(step, trainer._step_operands(), trainer.tracecheck)
+    return re.sub(r", metadata=\{[^}]*\}", "", lowered.as_text(dialect="hlo"))
+
+
+def test_the_other_families_steps_are_what_they_were(built):
+    """The four families that came before ``ouro`` lower to PR 38's step;
+    ``ouro``'s step has its own part, ``exits``, beside its attention and
+    SwiGLU (obs.opscopes reads it)."""
+    import hashlib
+
+    from nanosandbox_tpu.obs import opscopes
+
+    name, trainer, _ = built
+    if name in STEP_SHA256:
+        got = hashlib.sha256(_step_hlo(trainer).encode()).hexdigest()
+        assert got == STEP_SHA256[name]
+        return
+    parts = set(trainer.step_op_parts().values())
+    assert {"exits", "attn_full", "mlp", "ln", "embed"} <= parts
+    assert "lm_head_loss" not in parts and "exits" in opscopes.PARTS
 
 
 def test_inference_is_refused_with_the_familys_own_sentence(tmp_path):
@@ -222,7 +276,8 @@ def test_a_gpt2_trainer_imports_no_other_familys_kernels():
         "              if m.startswith('nanosandbox_tpu.'))\n"
         "assert 'nanosandbox_tpu.models.gpt' in mine, mine\n"
         "other = [m for m in mine if m.endswith(('.afmoe', '.lfm2',\n"
-        "         '.deepseek_v3', '.experts', '.ops.moe', '.short_conv'))]\n"
+        "         '.deepseek_v3', '.ouro', '.experts', '.ops.moe',\n"
+        "         '.short_conv'))]\n"
         "assert not other, other\n")
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     subprocess.run([sys.executable, "-c", probe], check=True, env=env,
