@@ -42,9 +42,13 @@ float32 accumulation; the residual stream, the norms, rotary positions and
 the exit gate (a float32 matmul at full precision) in float32.
 
 Attention is ops.attention.causal_attention_gqa on the projections'
-(B, T, heads * D) layout (G = H here: a group of one), with rotary positions
-by models/experts.rotary (XLA; no q/k norm, so no ``qk_prep``). Under remat
-(``save_attention``) a block keeps the kernels' output and logsumexp.
+(B, T, heads * D) layout (G = H here: a group of one). Its rotary positions
+take the form the grouped-query kernels take (ops.attention.resolve_gqa_impl):
+beside the kernels, ops.attention.qk_rotary, the attention prologue's kernel
+without its norm (there is no q/k norm), one pass over the projection's
+output each way (``%qk_prep.N``); on the XLA path models/experts.rotary, in
+float32. Under remat (``save_attention``) a block keeps the kernels' output
+and logsumexp.
 
 Scopes (obs/opscopes.py): module ``attn_full`` (projections, rotary and the
 flash kernels, ``%attn_full.N``), ``mlp``, the norms ``ln_*`` (``ln_f`` is
@@ -67,7 +71,8 @@ from nanosandbox_tpu.models.common import (_dense_init, constrain_acts,
                                            remat_block)
 from nanosandbox_tpu.models.experts import SwiGLU, dense, rms_norm, rotary
 from nanosandbox_tpu.ops.attention import (causal_attention_gqa,
-                                           gqa_route, resolve_gqa_bwd)
+                                           gqa_route, qk_rotary,
+                                           resolve_gqa_bwd, resolve_gqa_impl)
 
 # The weight of the exit distribution's entropy in the loss (beta): the
 # LoopLM paper's stage-one value; config.json does not state it (assumed).
@@ -88,8 +93,12 @@ class Attention(nn.Module):
         B, T, _ = a.shape
         H, G, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
         dtype = jnp.dtype(cfg.compute_dtype)
+        impl = resolve_gqa_impl(cfg.attention_impl, D, T)
 
         def rotated(x, heads):
+            if impl != "xla":
+                return qk_rotary(x, heads, cfg.rope_theta,
+                                 impl == "pallas_interpret")
             x = x.reshape(B, T, heads, D).astype(jnp.float32)
             return rotary(x, cfg.rope_theta).reshape(B, T, heads * D).astype(
                 dtype)
@@ -235,11 +244,14 @@ def build(cfg: OuroConfig, mesh: Any):
     """(the model, what ``trainer_init`` records of it)."""
     # What a full batch's attention resolves to, as the model will at trace
     # time: 'btc-gqa' (the grouped-query kernels on the projections' own
-    # layout, a group of one) or 'xla', and their backward.
+    # layout, a group of one) or 'xla', and their backward; the rotary
+    # positions' form beside them ('pallas': qk_rotary).
     route = gqa_route(cfg.attention_impl, cfg.head_dim, cfg.block_size)
     return Ouro(cfg, mesh=mesh), {
         "attn_layout": "btc-gqa" if route == "btc-gqa" else "bhtd",
         "attn_route": route,
+        "qk_prep": resolve_gqa_impl(cfg.attention_impl, cfg.head_dim,
+                                    cfg.block_size),
         "gqa_bwd": resolve_gqa_bwd(cfg.attention_impl, cfg.head_dim,
                                    cfg.block_size,
                                    jnp.dtype(cfg.compute_dtype).itemsize),

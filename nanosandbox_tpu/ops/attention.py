@@ -91,9 +91,9 @@ __all__ = ["causal_attention", "causal_attention_qkv", "attention_layout",
            "flash_attention_gqa", "gqa_layout_supported", "gqa_route",
            "causal_attention_mla", "flash_attention_mla",
            "mla_layout_supported", "mla_route",
-           "hash_dropout_keep_mask", "qk_prep", "qkv_layout_supported",
-           "resolve_attention_impl", "resolve_gqa_bwd", "resolve_gqa_impl",
-           "rotary_table"]
+           "hash_dropout_keep_mask", "qk_prep", "qk_rotary",
+           "qkv_layout_supported", "resolve_attention_impl",
+           "resolve_gqa_bwd", "resolve_gqa_impl", "rotary_table"]
 
 
 # ---------------------------------------------------------------------------
@@ -2417,6 +2417,9 @@ flash_attention_mla.defvjp(_flash_mla_fwd_rule, _flash_mla_bwd_rule)
 # the mean of squares a lane reduce and rotate-half a lane roll by D / 2.
 # The backward is one pass as well: it recomputes the norm from the saved
 # input, and leaves the scale's gradient as one (1, D) partial sum a program.
+# A model with no q/k norm (models/ouro.py) takes the rotation alone
+# (qk_rotary): the forward kernel with no scale, and as its backward the same
+# kernel turning the other way, since a rotation's transpose is its inverse.
 
 QK_PREP_SCOPE = "qk_prep"   # names the custom calls: %qk_prep.N
 # A program's block, the largest of each that divides T and the heads. On a
@@ -2437,25 +2440,30 @@ def rotary_table(T: int, D: int, theta: float):
             jnp.concatenate([jnp.sin(angle)] * 2, -1))
 
 
-def _rotate_half_sin(T: int, D: int, theta: float):
+def _rotate_half_sin(T: int, D: int, theta: float, inverse: bool = False):
     """(cos, sin with the first half negated): rotate_half(y) * sin is
-    roll(y, D / 2) * that, the sign moved onto the table."""
+    roll(y, D / 2) * that, the sign moved onto the table. ``inverse``: the
+    table of the opposite angles (sin negated as well)."""
     cos, sin = rotary_table(T, D, theta)
     sign = jnp.where(jnp.arange(D) < D // 2, -1.0, 1.0)
-    return cos, sin * sign
+    return cos, sin * (-sign if inverse else sign)
 
 
-def _qk_prep_fwd_kernel(x_ref, scale_ref, *refs, heads: int, D: int,
-                        eps: float):
-    """x_ref / o_ref (1, rows, heads * D); scale_ref (1, D) float32; with
-    positions, cos_ref / sin_ref (rows, D) before o_ref."""
+def _qk_prep_fwd_kernel(x_ref, *refs, heads: int, D: int,
+                        eps: float | None):
+    """x_ref / o_ref (1, rows, heads * D); where eps is given (the norm),
+    scale_ref (1, D) float32 next; with positions, cos_ref / sin_ref
+    (rows, D) before o_ref."""
+    if eps is not None:
+        scale_ref, *refs = refs
+        scale = scale_ref[...]
     *table, o_ref = refs
-    scale = scale_ref[...]
     for h in range(heads):
         lanes = slice(h * D, (h + 1) * D)
-        x = x_ref[0, :, lanes].astype(jnp.float32)
-        mean_sq = jnp.sum(x * x, axis=1, keepdims=True) * (1.0 / D)
-        y = x * lax.rsqrt(mean_sq + eps) * scale
+        y = x = x_ref[0, :, lanes].astype(jnp.float32)
+        if eps is not None:
+            mean_sq = jnp.sum(x * x, axis=1, keepdims=True) * (1.0 / D)
+            y = x * lax.rsqrt(mean_sq + eps) * scale
         if table:
             cos_ref, sin_ref = table
             y = y * cos_ref[...] + pltpu.roll(y, D // 2, 1) * sin_ref[...]
@@ -2487,35 +2495,40 @@ def _qk_prep_bwd_kernel(x_ref, scale_ref, dz_ref, *refs, heads: int, D: int,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "n_head", "eps", "theta", "interpret"))
-def _pallas_qk_prep(x, scale, dz=None, *, n_head: int, eps: float, theta,
-                    interpret: bool = False):
+    "n_head", "eps", "theta", "interpret", "inverse"))
+def _pallas_qk_prep(x, scale, dz=None, *, n_head: int, eps: float | None,
+                    theta, interpret: bool = False, inverse: bool = False):
     """The forward (-> z like x) or, given the cotangent dz of z, the
-    backward (-> dx like x, dscale like scale). Grid (B, row blocks, head
+    backward (-> dx like x, dscale like scale). With no scale (and no eps)
+    the forward alone, the rotation without the norm; ``inverse`` turns it
+    by the opposite angles, which is its backward. Grid (B, row blocks, head
     groups), the head groups innermost so that a row block's table is
     fetched once. Jitted like the kernel calls round it: one trace and one
-    lowering a (shape, pass, positions or not) variant, whatever the number
-    of layers."""
+    lowering a (shape, pass, norm or not, positions or not) variant,
+    whatever the number of layers."""
     B, T, HD = x.shape
     D = HD // n_head
-    if HD != n_head * D or scale.shape != (D,) or not gqa_layout_supported(
-            D, T):
+    if HD != n_head * D or (scale is not None and scale.shape != (D,)) or (
+            not gqa_layout_supported(D, T)):
         raise ValueError(
-            f"qk_prep needs x (B, T, heads*D) and scale (D,) with "
-            f"D % {LANES} == 0 and T % {LANES} == 0; got x {x.shape}, "
-            f"scale {scale.shape}, heads={n_head}")
+            f"qk_prep needs x (B, T, heads*D), and scale (D,) where it "
+            f"normalises, with D % {LANES} == 0 and T % {LANES} == 0; got x "
+            f"{x.shape}, scale {None if scale is None else scale.shape}, "
+            f"heads={n_head}")
     backward = dz is not None
     rows = next(r for r in PREP_BLOCK_ROWS if T % r == 0)
     heads = next(h for h in PREP_BLOCK_HEADS if n_head % h == 0)
     grid = (B, T // rows, n_head // heads)
     blk = pl.BlockSpec((1, rows, heads * D), lambda b, i, g: (b, i, g))
-    operands = [x, scale.astype(jnp.float32).reshape(1, D)]
-    in_specs = [blk, pl.BlockSpec((1, D), lambda b, i, g: (0, 0))]
+    operands, in_specs = [x], [blk]
+    if scale is not None:
+        operands.append(scale.astype(jnp.float32).reshape(1, D))
+        in_specs.append(pl.BlockSpec((1, D), lambda b, i, g: (0, 0)))
     if backward:
         operands.append(dz)
         in_specs.append(blk)
     if theta is not None:
-        operands += _rotate_half_sin(T, D, theta)
+        operands += _rotate_half_sin(T, D, theta, inverse)
         in_specs += [pl.BlockSpec((rows, D), lambda b, i, g: (i, 0))] * 2
     out_specs, out_shape = [blk], [jax.ShapeDtypeStruct(x.shape, x.dtype)]
     if backward:
@@ -2525,7 +2538,7 @@ def _pallas_qk_prep(x, scale, dz=None, *, n_head: int, eps: float, theta,
     call = pl.pallas_call(
         functools.partial(
             _qk_prep_bwd_kernel if backward else _qk_prep_fwd_kernel,
-            heads=heads, D=D, eps=eps),
+            heads=heads, D=D, eps=None if scale is None else eps),
         grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape,
         compiler_params=None if interpret else _tpu_params(
@@ -2565,6 +2578,30 @@ def _qk_prep_bwd_rule(n_head, eps, theta, interpret, res, dz):
 
 
 qk_prep.defvjp(_qk_prep_fwd_rule, _qk_prep_bwd_rule)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
+def qk_rotary(x, n_head: int, theta: float, interpret: bool = False):
+    """qk_prep without the norm, for a model whose q and k have none: x
+    (B, T, heads*D) -> the same shape and dtype, every head's D lanes turned
+    by rotate-half rotary positions 0..T-1 (``x * cos + [-x2, x1] * sin``),
+    in float32 from x as it lies. The rotation is linear and orthogonal, so
+    the backward is the same one-pass kernel by the opposite angles and
+    keeps nothing. Shapes must satisfy gqa_layout_supported."""
+    return _pallas_qk_prep(x, None, n_head=n_head, eps=None, theta=theta,
+                           interpret=interpret)
+
+
+def _qk_rotary_fwd_rule(x, n_head, theta, interpret):
+    return qk_rotary(x, n_head, theta, interpret), None
+
+
+def _qk_rotary_bwd_rule(n_head, theta, interpret, _, dz):
+    return (_pallas_qk_prep(dz, None, n_head=n_head, eps=None, theta=theta,
+                            interpret=interpret, inverse=True),)
+
+
+qk_rotary.defvjp(_qk_rotary_fwd_rule, _qk_rotary_bwd_rule)
 
 
 def hash_dropout_keep_mask(seed, B: int, H: int, Tq: int, Tk: int, *,

@@ -25,7 +25,9 @@ tests hold every later PR to what the chip accepts, at no chip time:
     backward);
   * ``qk_prep``, the one-pass head RMSNorm + rotary positions in front of
     those kernels, forward and backward, at the cell's q (2, 8192, 4096) /
-    32 heads and k (2, 8192, 512) / 4 heads, with and without positions;
+    32 heads and k (2, 8192, 512) / 4 heads, with and without positions,
+    its kernels' text pinned; and its rotary-only form ``qk_rotary`` at the
+    Ouro cell's q and k, (2, 8192, 2048) / 16 heads;
   * the ``lfm2`` family's shapes through the same entries: grouped-query
     attention at head size 64 (2 x 8192 tokens, 32 query heads on 8 KV
     heads of 64: ``causal_attention_gqa``'s 'bhtd-rep' route, the
@@ -67,7 +69,7 @@ from nanosandbox_tpu.ops.attention import (flash_attention,
                                            flash_attention_dropout,
                                            flash_attention_gqa,
                                            flash_attention_qkv, qk_prep,
-                                           resolve_gqa_bwd)
+                                           qk_rotary, resolve_gqa_bwd)
 
 B, H, D, L = 8, 12, 64, 1024          # serving widths (GPT-2 124M heads)
 TRAIN_SHAPE = (16, 12, 1024, 64)       # the 124M train step's q/k/v
@@ -289,6 +291,86 @@ def test_qk_prep_forward_and_backward(sds, heads, theta):
     # then the forward
     for fn in (jax.grad(loss, argnums=(0, 1)),
                lambda x, scale: qk_prep(x, scale, heads, 1e-5, theta)):
+        txt = compiled_text(fn, *args)
+        assert len(re.findall(r"%qk_prep[.0-9]* = [^\n]*custom-call\(",
+                              txt)) == 1
+        assert txt.count("custom-call(") == 1
+        assert not MOVES_AN_ACTIVATION.search(txt)
+        assert not re.search(rf"f32\[{B},{T},", txt)
+
+
+def _kernel_texts(lowered_text: str) -> list:
+    """The Mosaic modules of a lowering's tpu_custom_calls, as text without
+    source locations: what the chip's compiler receives of each kernel."""
+    import base64
+    import json
+
+    from jax._src.interpreters import mlir
+    from jaxlib.mlir import ir
+
+    out = []
+    for m in re.finditer(r'@tpu_custom_call\([^)]*\) \{backend_config = '
+                         r'"((?:[^"\\]|\\.)*)"', lowered_text):
+        config = json.loads(m.group(1).replace("\\22", '"'))
+        ctx = mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        module = ir.Module.parse(
+            base64.b64decode(config["custom_call_config"]["body"]),
+            context=ctx)
+        out.append(module.operation.get_asm(enable_debug_info=False))
+    return out
+
+
+# sha256 of the normed prologue's kernel text (_kernel_texts), forward and
+# backward, at the Trinity-Mini cell's q / k with and without positions, as
+# the tree before the rotary-only form (0e57a95) lowered them.
+QK_PREP_KERNEL_SHA256 = {
+    (32, 10000.0): (
+        "1ad580d9bed090b60604c44dac779ece7c0fc8bc11254dff6dad5d6f7a97500a",
+        "8e7ab3b12a0da79250ee2b057bb15954739adf9b808248c170fced0f6ff64936"),
+    (32, None): (
+        "007d4dadf557655886f1207d5adadf37ff3786909bdaa3c60713a83b70c6989e",
+        "65bc3e70b9ddcf7cd2feb858b785fd04ddabe3d38d3f5716c14c4ec5f3479314"),
+    (4, 10000.0): (
+        "b71a85e866e12dd82f3747a96cf22e0eacb54efd3a6175cf0f346b1613dfedc6",
+        "59d99d79682a4b2ee1b832cb93cebb5771694b2d40dd6ae3c023539beb34fd78"),
+    (4, None): (
+        "45b6315252fccc083b3540bcdb6df1978a5b2dab66315bf46eb12f805ef4e885",
+        "26cb60d4636e84d165ae42f73d457f03d3d7763b3c2260168d9a998d5129ac65"),
+}
+
+
+@pytest.mark.parametrize("theta", [10000.0, None], ids=["rotary", "none"])
+@pytest.mark.parametrize("heads", [32, 4], ids=["q-32-heads", "k-4-heads"])
+def test_qk_prep_normed_kernels_are_what_they_were(sds, heads, theta):
+    """The normed prologue (Trinity-Mini's) lowers to the same kernel text,
+    forward and backward, since the rotary-only form shares its forward
+    kernel."""
+    import hashlib
+
+    B, T, _, _, D = GQA_SHAPE
+    fwd = lambda x, scale: qk_prep(x, scale, heads, 1e-5, theta)
+    bwd = jax.grad(lambda x, scale: fwd(x, scale).astype(jnp.float32).sum(),
+                   argnums=(0, 1))
+    args = (sds((B, T, heads * D), jnp.bfloat16), sds((D,), jnp.float32))
+    got = []
+    for fn in (fwd, bwd):
+        (text,) = _kernel_texts(jax.jit(fn).lower(*args).as_text())
+        got.append(hashlib.sha256(text.encode()).hexdigest())
+    assert tuple(got) == QK_PREP_KERNEL_SHA256[(heads, theta)]
+
+
+def test_qk_rotary_forward_and_backward(sds):
+    """The rotary-only form at the Ouro cell's q and k, (2, 8192, 2048) / 16
+    heads: one custom call a pass (%qk_prep.N; the backward is the forward
+    kernel by the opposite angles), no other pass over the activation and
+    no float32 array of activation size."""
+    B, T, heads, D = 2, 8192, 16, 128
+    rotate = lambda x: qk_rotary(x, heads, 1e6)
+    x = sds((B, T, heads * D), jnp.bfloat16)
+    # the backward from a cotangent (it reads nothing of x), then the forward
+    for fn, args in ((lambda x, dz: jax.vjp(rotate, x)[1](dz), (x, x)),
+                     (rotate, (x,))):
         txt = compiled_text(fn, *args)
         assert len(re.findall(r"%qk_prep[.0-9]* = [^\n]*custom-call\(",
                               txt)) == 1

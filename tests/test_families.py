@@ -55,8 +55,8 @@ TINY = {
                     None),
     "ouro": (dict(n_layer=2, n_head=4, n_kv_head=4, head_dim=16, n_embd=64,
                   intermediate_size=96, total_ut_steps=4),
-             {"attn_layout", "attn_route", "gqa_bwd", "loops", "layers_held",
-              "remat_policy"},
+             {"attn_layout", "attn_route", "qk_prep", "gqa_bwd", "loops",
+              "layers_held", "remat_policy"},
              None),
 }
 # sha256 of each family's train step as `built` lowers it for the compiler

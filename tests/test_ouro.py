@@ -15,7 +15,7 @@ import pytest
 from chipbench import flops_ouro, weights_ouro
 from chipbench.reference import ouro as ref
 from nanosandbox_tpu.config import OuroConfig, TrainConfig
-from nanosandbox_tpu.models import ouro
+from nanosandbox_tpu.models import experts, ouro
 from nanosandbox_tpu.ops import attention as A
 
 SIZES = {
@@ -62,13 +62,43 @@ def program_loss_and_grad(trainer, params, x, y):
         lambda p: trainer._loss_fn(p, x, y, None), has_aux=True))(params)
 
 
-@pytest.fixture(scope="module")
-def both(trainer, seeded):
-    params, x, y = seeded
+def _both(trainer, params, x, y, sizes):
     with jax.default_matmul_precision("highest"):
         mine = program_loss_and_grad(trainer, params, x, y)
-        theirs = jax.jit(lambda p: ref.loss_and_grad(p, x, y, SIZES))(params)
+        theirs = jax.jit(lambda p: ref.loss_and_grad(p, x, y, sizes))(params)
     return mine, theirs
+
+
+@pytest.fixture(scope="module")
+def both(trainer, seeded):
+    return _both(trainer, *seeded, SIZES)
+
+
+# Heads of 128 lanes and T 128: what the grouped-query kernels and the rotary
+# kernel (ops.attention.qk_rotary) walk, here in the interpreter.
+KERNEL_SIZES = {**SIZES, "n_head": 2, "n_kv_head": 2, "head_dim": 128,
+                "block_size": 128}
+
+
+@pytest.fixture(scope="module")
+def both_kernels(char_dataset, tmp_path_factory):
+    """``both`` with attention_impl 'pallas_interpret' at KERNEL_SIZES: the
+    looped stack's attention through the grouped-query kernels, its rotary
+    positions through qk_rotary, against the same float32 reference."""
+    from nanosandbox_tpu.train import Trainer
+
+    trainer = Trainer(train_cfg(
+        out_dir=str(tmp_path_factory.mktemp("ouro_kernels") / "out"),
+        data_dir=char_dataset, dataset="shakespeare_char", batch_size=8,
+        loss_chunk_size=32, tensorboard=False, seed=0,
+        attention_impl="pallas_interpret",
+        **{k: KERNEL_SIZES[k] for k in ("n_head", "n_kv_head", "head_dim",
+                                        "block_size")}, **OPT))
+    assert trainer.qk_prep == "pallas_interpret"
+    params = weights_ouro.make_params(KERNEL_SIZES, weights_ouro.seed_key(5))
+    x = jax.random.randint(jax.random.key(1), (2, 129), 0,
+                           KERNEL_SIZES["vocab_size"])
+    return _both(trainer, params, x[:, :-1], x[:, 1:], KERNEL_SIZES)
 
 
 flat = weights_ouro.flatten
@@ -84,9 +114,14 @@ def test_weights_file_has_the_programs_layout(trainer, seeded):
         SIZES)
 
 
-def test_loss_and_every_gradient_leaf_equal_the_reference(both):
-    (loss, aux), grads = both[0]
-    ref_loss, ref_grads = both[1]
+@pytest.mark.parametrize("fixture", ["both", "both_kernels"],
+                         ids=["xla", "kernels"])
+def test_loss_and_every_gradient_leaf_equal_the_reference(fixture, request):
+    """The XLA path at heads of 16, and the kernels' path (the grouped-query
+    kernels and qk_rotary in the interpreter) at heads of 128."""
+    mine, theirs = request.getfixturevalue(fixture)
+    (loss, aux), grads = mine
+    ref_loss, ref_grads = theirs
     np.testing.assert_allclose(loss, ref_loss, rtol=1e-5)
     got, want = flat(grads), flat(ref_grads)
     assert set(got) == set(want)
@@ -231,6 +266,70 @@ def test_grouped_query_kernels_at_one_kv_head_a_query_head():
         np.testing.assert_allclose(a, b, atol=2e-4, rtol=1e-3)
 
 
+def _xla_rotary(x, heads, theta):
+    B, T, HD = x.shape
+    y = experts.rotary(x.reshape(B, T, heads, HD // heads).astype(
+        jnp.float32), theta)
+    return y.reshape(B, T, HD).astype(x.dtype)
+
+
+@pytest.mark.parametrize("T", [128, 384])
+@pytest.mark.parametrize("heads", [16, 4])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_rotary_kernel_equals_the_xla_rotary(dtype, heads, T):
+    """ops.attention.qk_rotary in interpret mode against models/experts.rotary
+    (the XLA path: the permutation product at full precision): output and
+    input gradient, float32 to rounding, bfloat16 to one bfloat16 step of
+    the largest value (both round the same float32 numbers)."""
+    D, theta = 128, SIZES["rope_theta"]
+    ks = jax.random.split(jax.random.key(heads + T), 2)
+    x = (2.0 * jax.random.normal(ks[0], (2, T, heads * D))).astype(dtype)
+    w = jax.random.normal(ks[1], (2, T, heads * D)).astype(dtype)
+
+    def run(rotate):
+        def loss(x):
+            z = rotate(x)
+            return jnp.sum((z * w).astype(jnp.float32)), z
+        return jax.value_and_grad(loss, has_aux=True)(x)
+
+    with jax.default_matmul_precision("highest"):
+        (_, z_x), dx_x = run(lambda x: _xla_rotary(x, heads, theta))
+    (_, z_p), dx_p = run(lambda x: A.qk_rotary(x, heads, theta, True))
+    assert z_p.dtype == dx_p.dtype == x.dtype
+    f32 = lambda a: np.asarray(a, np.float32)
+    step = 2.0 ** -8 if dtype == "bfloat16" else 2e-6
+    for got, want in ((z_p, z_x), (dx_p, dx_x)):
+        assert np.abs(f32(got) - f32(want)).max() <= step * np.abs(
+            f32(want)).max()
+
+
+def test_the_rotary_kernel_keeps_nothing_for_its_backward(capsys):
+    """A rotation's transpose is its inverse: the custom VJP's forward hands
+    its backward no residual, and differentiating it saves no array (the
+    normed prologue, qk_prep, keeps its input and scale)."""
+    x = jnp.ones((1, 128, 2 * 128))
+    _, res = A._qk_rotary_fwd_rule(x, 2, 1e6, True)
+    assert jax.tree.leaves(res) == []
+    from jax.ad_checkpoint import print_saved_residuals
+
+    print_saved_residuals(lambda x: A.qk_rotary(x, 2, 1e6, True), x)
+    assert capsys.readouterr().out == ""
+    print_saved_residuals(
+        lambda x, s: A.qk_prep(x, s, 2, 1e-5, 1e6, True), x, jnp.ones(128))
+    assert "f32[1,128,256] from the argument x" in capsys.readouterr().out
+
+
+def test_the_rotary_kernel_refuses_shapes_it_cannot_walk():
+    with pytest.raises(ValueError, match="D % 128"):
+        A.qk_rotary(jnp.zeros((1, 128, 4 * 64)), 4, 1e6, True)
+    with pytest.raises(ValueError, match="T % 128"):
+        A.qk_rotary(jnp.zeros((1, 8, 4 * 128)), 4, 1e6, True)
+    with pytest.raises(ValueError, match="heads=3"):
+        A.qk_rotary(jnp.zeros((1, 128, 4 * 128)), 3, 1e6, True)
+    # and the model takes the XLA rotary wherever the kernels cannot go
+    assert A.resolve_gqa_impl("pallas_interpret", 16, 128) == "xla"
+
+
 def test_the_cli_trains_two_steps_and_leaves_the_exits_instants(
         char_dataset, tmp_path):
     """``python -m nanosandbox_tpu.train configs/train_ouro_2_6b_pp8.py``,
@@ -260,7 +359,8 @@ def test_the_cli_trains_two_steps_and_leaves_the_exits_instants(
     spans = process_tracer().spans()
     init = [s for s in spans if s.name == "trainer_init"][-1]
     assert {"loops": 4, "layers_held": 2, "remat_policy": "full",
-            "attn_route": "xla", "attn_layout": "bhtd"}.items() <= \
+            "attn_route": "xla", "attn_layout": "bhtd",
+            "qk_prep": "xla"}.items() <= \
         init.args.items()
     exits = [s for s in spans if s.name == "ouro_exits"]
     assert [s.args["iter"] for s in exits] == [0, 1]
