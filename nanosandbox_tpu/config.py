@@ -56,7 +56,9 @@ class TrainConfig:
     #    lfm2_moe), 'deepseek_v3' (models/deepseek_v3.py: latent attention
     #    beside routed and shared experts, HF model_type deepseek_v3) and
     #    'ouro' (models/ouro.py: one stack of layers run total_ut_steps times
-    #    a step, an exit after every pass, HF model_type ouro) read
+    #    a step, an exit after every pass, HF model_type ouro) and 'granite'
+    #    (models/granite.py: Mamba-2 state-space layers beside grouped-query
+    #    attention, HF model_type granitemoehybrid) read
     #    n_layer, n_head, n_embd, block_size, vocab_size above and the keys
     #    below, named as the published config.json names them (each family's
     #    model-config class further down says which). --
@@ -68,7 +70,8 @@ class TrainConfig:
     # layers see everything and carry no positions. lfm2: 'conv' | 'full':
     # a gated short convolution of `conv_L_cache` taps, or full causal
     # attention with rotary positions. deepseek_v3: 'mla' every layer (left
-    # empty, that is what it means).
+    # empty, that is what it means). granite: 'mamba' | 'attention' (no
+    # positions).
     layer_types: str = ""
     conv_L_cache: int = 3
     sliding_window: int = 0
@@ -105,6 +108,22 @@ class TrainConfig:
     # run this many times a step, with an exit (head + exit gate) after each
     # pass; the loss is over the exits (models/ouro.py).
     total_ut_steps: int = 4
+    # granite's Mamba-2 layers (layer_types 'mamba'; models/granite.py):
+    # mamba_n_heads heads of mamba_d_head lanes, a state of mamba_d_state a
+    # head, B and C in mamba_n_groups groups, a causal conv of mamba_d_conv
+    # taps, the scan in chunks of mamba_chunk_size tokens. Its muP
+    # multipliers: the embedding, each residual branch, attention's score
+    # (in place of head_dim ** -0.5) and the logits' divisor.
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
 
     # -- optimizer / schedule (nanoGPT contract: cosine decay, AdamW, clip) --
     learning_rate: float = 6e-4
@@ -409,7 +428,7 @@ class GPTConfig:
         )
 
 
-MODEL_FAMILIES = ("gpt2", "afmoe", "lfm2", "deepseek_v3", "ouro")
+MODEL_FAMILIES = ("gpt2", "afmoe", "lfm2", "deepseek_v3", "ouro", "granite")
 
 
 def _expert_family_keys(cfg: TrainConfig, family: str, kinds_allowed: tuple,
@@ -693,6 +712,92 @@ class OuroConfig:
             total_ut_steps=cfg.total_ut_steps, rope_theta=cfg.rope_theta,
             rms_norm_eps=cfg.rms_norm_eps, param_dtype=cfg.param_dtype,
             compute_dtype=cfg.compute_dtype,
+            attention_impl=cfg.attention_impl, remat=cfg.remat,
+            remat_policy=cfg.remat_policy)
+
+
+@dataclass
+class GraniteConfig:
+    """Model-only view of the config for models.granite.Granite."""
+
+    n_layer: int
+    n_head: int
+    n_kv_head: int
+    head_dim: int
+    n_embd: int
+    block_size: int
+    vocab_size: int
+    layer_types: tuple  # 'mamba' | 'attention' a layer
+    intermediate_size: int  # the shared MLP's width
+    mamba_n_heads: int
+    mamba_d_head: int
+    mamba_d_state: int
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
+    rms_norm_eps: float = 1e-5
+    param_dtype: str = "float32"
+    compute_dtype: str = "bfloat16"
+    attention_impl: str = "auto"
+    remat: bool = False
+    remat_policy: str = "save_attention"
+
+    def replace(self, **kw: Any) -> "GraniteConfig":
+        return dataclasses.replace(self, **kw)
+
+    @classmethod
+    def from_train_config(cls, cfg: TrainConfig,
+                          vocab_size: int) -> "GraniteConfig":
+        kinds = tuple(k.strip() for k in cfg.layer_types.split(",")
+                      if k.strip())
+        n_kv_head = cfg.n_kv_head or cfg.n_head
+        head_dim = cfg.head_dim or cfg.n_embd // max(cfg.n_head, 1)
+        problems = []
+        if len(kinds) != cfg.n_layer or set(kinds) - {"mamba", "attention"}:
+            problems.append(
+                f"layer_types needs {cfg.n_layer} entries of 'mamba' | "
+                f"'attention', got {cfg.layer_types!r}")
+        if cfg.n_head % n_kv_head:
+            problems.append("n_kv_head must divide n_head")
+        if cfg.intermediate_size <= 0:
+            problems.append("intermediate_size must be set")
+        if (min(cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state,
+                cfg.mamba_n_groups, cfg.mamba_d_conv,
+                cfg.mamba_chunk_size) <= 0
+                or cfg.mamba_n_heads % cfg.mamba_n_groups):
+            problems.append(
+                "mamba_n_heads, mamba_d_head, mamba_d_state, mamba_d_conv "
+                "and mamba_chunk_size must be set, and mamba_n_groups must "
+                "divide mamba_n_heads")
+        if min(cfg.attention_multiplier, cfg.embedding_multiplier,
+               cfg.residual_multiplier, cfg.logits_scaling) <= 0:
+            problems.append("embedding_multiplier, residual_multiplier, "
+                            "attention_multiplier and logits_scaling must be "
+                            "set (> 0)")
+        if cfg.dropout or cfg.bias:
+            problems.append("the granite block has no dropout and no biases "
+                            "but the conv's")
+        if problems:
+            raise ValueError("model_family='granite': " + "; ".join(problems))
+        return cls(
+            n_layer=cfg.n_layer, n_head=cfg.n_head, n_kv_head=n_kv_head,
+            head_dim=head_dim, n_embd=cfg.n_embd, block_size=cfg.block_size,
+            vocab_size=vocab_size, layer_types=kinds,
+            intermediate_size=cfg.intermediate_size,
+            mamba_n_heads=cfg.mamba_n_heads, mamba_d_head=cfg.mamba_d_head,
+            mamba_d_state=cfg.mamba_d_state,
+            mamba_n_groups=cfg.mamba_n_groups,
+            mamba_d_conv=cfg.mamba_d_conv,
+            mamba_chunk_size=cfg.mamba_chunk_size,
+            embedding_multiplier=cfg.embedding_multiplier,
+            residual_multiplier=cfg.residual_multiplier,
+            attention_multiplier=cfg.attention_multiplier,
+            logits_scaling=cfg.logits_scaling, rms_norm_eps=cfg.rms_norm_eps,
+            param_dtype=cfg.param_dtype, compute_dtype=cfg.compute_dtype,
             attention_impl=cfg.attention_impl, remat=cfg.remat,
             remat_policy=cfg.remat_policy)
 

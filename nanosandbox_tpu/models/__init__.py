@@ -1,8 +1,9 @@
 """Model families: ``gpt2`` (models/gpt.py), ``afmoe`` (Arcee Trinity's
 block), ``lfm2`` (LiquidAI's LFM2 MoE block), ``deepseek_v3`` (latent
 attention beside routed and shared experts; Moonlight is a published
-instance) and ``ouro`` (one stack of layers run several times a step, an
-exit after every pass). ``FAMILIES`` is the one place that knows which exist:
+instance), ``ouro`` (one stack of layers run several times a step, an
+exit after every pass) and ``granite`` (Mamba-2 state-space layers beside
+grouped-query attention). ``FAMILIES`` is the one place that knows which exist:
 ``TrainConfig.model_family`` -> the family's module, imported on first use (a
 GPT-2 run imports no other family's kernels). The MODULE is the interface:
 ``Trainer`` and ``restore_for_inference`` ask it, by these module-level names,
@@ -25,6 +26,10 @@ what they would otherwise ask by the family's name:
                                stacked, one an exit): their per-token cross
                                entropies (exits, B, T) in; a family without
                                it is one hidden state through one head
+    INSTANTS                   {instant name: keys of the step's aux
+                               (``apply``'s, or ``exit_loss``'s)} where the
+                               family has counters ``Trainer`` leaves in its
+                               tracer at log steps
 
 A new family is its module (and kernels), one line here, its ``TrainConfig``
 keys and model-config class (config.py), its rows in ``opscopes._COMPONENT``.
@@ -42,6 +47,7 @@ FAMILIES = {
     "lfm2": "nanosandbox_tpu.models.lfm2",
     "deepseek_v3": "nanosandbox_tpu.models.deepseek_v3",
     "ouro": "nanosandbox_tpu.models.ouro",
+    "granite": "nanosandbox_tpu.models.granite",
 }
 
 

@@ -80,6 +80,8 @@ EXIT_ENTROPY_WEIGHT = 0.1
 # What a block under remat keeps: the attention kernels' output and
 # logsumexp (ops/attention.py).
 SAVED_NAMES = ("attn_out", "attn_lse")
+# The counters Trainer leaves as an instant at log steps: {name: aux keys}.
+INSTANTS = {"ouro_exits": ("exit_p", "exit_nll")}
 
 
 class Attention(nn.Module):

@@ -7,8 +7,8 @@ mlp/c_fc/dot_general"}``), backward pass included. Flax names the modules
 models/afmoe.py ``attn_sliding`` / ``attn_full``, ``moe_shared``, ``ln_in``
 ...; for models/lfm2.py ``conv``, ``attn_full``, ``operator_norm`` ...; for
 models/deepseek_v3.py ``attn_mla``, ``input_layernorm`` ...; for
-models/ouro.py ``exit_gate``) and the trainer adds ``jax.named_scope``
-where no module names the work (``lm_head_loss``, ``exits``,
+models/ouro.py ``exit_gate``; for models/granite.py ``mamba``) and the trainer adds ``jax.named_scope``
+where no module names the work (``lm_head_loss``, ``exits``, ``ssd``,
 ``optimizer``, ``grad_norm``, ``accum``). A device trace
 names its events by instruction (``%fusion.24 = ...``), a name the compiler
 hands out anew at every change to the step; this file turns that name into
@@ -56,7 +56,11 @@ PARTS = ("attn", "mlp", "ln", "embed", "lm_head_loss", "optimizer",
          # models/ouro.py: the exits of its looped stack (the heads with
          # their cross entropies, the exit gate, the expected loss and the
          # entropy); its attention is an "attn_full".
-         "exits")
+         "exits",
+         # models/granite.py: a Mamba-2 layer's projections, taps, dt and
+         # gated norm, and inside it the scan (ops/ssd.py, whatever
+         # implements it); its attention is an "attn_full".
+         "mamba", "ssd")
 UNSCOPED = "unscoped"
 
 # Path component -> part. ``wte.attend`` is the tied head's matmul where the
@@ -89,6 +93,10 @@ _COMPONENT = {
     # models/ouro.py. The trainer's named scope ``exits`` and the model's
     # gate module; the norms are afmoe's names.
     "exits": "exits", "exit_gate": "exits",
+    # models/granite.py. The named scope ``ssd`` lies inside the module
+    # ``mamba``, whose gated norm (``norm``) names no part of its own; the
+    # norms are deepseek_v3's names.
+    "mamba": "mamba", "ssd": "ssd",
 }
 
 # Scope name -> the part it splits: the stages of ``moe_route``, each a

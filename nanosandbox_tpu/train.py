@@ -758,18 +758,19 @@ class Trainer:
                   f"{got['moe_held']}): ops/moe.py's chunks no longer "
                   "cover its own bound", file=sys.stderr)
 
-    def note_exits(self, metrics: dict, iter_num: int) -> None:
-        """The exits' numbers of a step whose loss has been read, where the
-        family makes its loss of several (``exit_loss``): an instant
-        ``<family module>_exits`` in the process tracer with each pass's mean
-        exit probability and each exit's mean cross entropy."""
-        if "exit_p" not in metrics:
-            return
-        name = self.family.__name__.rsplit(".", 1)[-1] + "_exits"
-        self.tracer.instant(name, cat="train", args={
-            "iter": iter_num,
-            **{k: np.asarray(metrics[k]).tolist()
-               for k in ("exit_p", "exit_nll")}})
+    def note_instants(self, metrics: dict, iter_num: int) -> None:
+        """The family's counters of a step whose loss has been read: for
+        each instant its module names (``INSTANTS``: {name: keys of the
+        step's aux}), one in the process tracer with those keys' values,
+        where the step reported them all."""
+        for name, keys in getattr(self.family, "INSTANTS", {}).items():
+            if all(k in metrics for k in keys):
+                self.tracer.instant(name, cat="train", args={
+                    "iter": iter_num,
+                    **{k: np.asarray(metrics[k]).tolist() for k in keys}})
+
+    # The name chipbench/runners/train_ouro.py calls (Ouro's exits).
+    note_exits = note_instants
 
     # -- evaluation (nanoGPT estimate_loss) ----------------------------------
 
@@ -1056,7 +1057,7 @@ class Trainer:
                     # train_iter's return value, so no suppression here.)
                     grad_norm = float(metrics["grad_norm"])
                     self._count_expert_rows(metrics, iter_num)
-                    self.note_exits(metrics, iter_num)
+                    self.note_instants(metrics, iter_num)
                     lr = (float(self.lr_schedule(iter_num))
                           if callable(self.lr_schedule)
                           else self.lr_schedule)

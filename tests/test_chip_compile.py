@@ -40,6 +40,9 @@ tests hold every later PR to what the chip accepts, at no chip time:
     rotary key, values of 128): ``flash_attention_mla``'s forward and ONE
     backward kernel, at the cell's T and at the longest its VMEM predicate
     lets through, and the grouped matmul at the expert width 1408;
+  * the ``granite`` family's Mamba-2 scan (``ops/ssd.py``, XLA, all
+    chunks at once) at the cell's (1, 8192) tokens, 64 heads of 64,
+    d_state 128, forward and backward, its temporaries under 1 GB;
   * all nine serving variants — ``flash_decode`` / ``flash_decode_paged``
     / ``flash_prefill_paged`` x fp / int8 / int4 — at B=8, H=12, D=64,
     the engine's default page 16 and page 32, prefill T = a page and
@@ -273,6 +276,27 @@ def test_gated_short_conv_forward_and_backward(sds):
         assert len(re.findall(r"%conv_mix[.0-9]* = [^\n]*custom-call\(",
                               txt)) == 1
         assert not re.search(rf"f32\[{B},{T},", txt)
+
+
+@pytest.mark.parametrize("pass_", ["forward", "backward"])
+def test_ssd_scan_forward_and_backward(sds, pass_):
+    """The granite cell's Mamba-2 scan (ops/ssd.py, all chunks at once) at
+    (1, 8192) tokens, 64 heads of 64, d_state 128, one group, chunk 256:
+    it compiles for the chip, and its temporaries stay under 1 GB (0.47 GB
+    forward, 0.42 GB forward + backward when written), where the decay
+    blocks of all 32 chunks, (32, 64, 256, 256), would be 537 MB each in
+    float32 if the compiler wrote them out."""
+    from nanosandbox_tpu.ops.ssd import ssd
+
+    T, H, P, N = 8192, 64, 64, 128
+    args = (sds((1, T, H * P), jnp.float32), sds((1, T, H), jnp.float32),
+            sds((H,), jnp.float32), sds((1, T, N), jnp.float32),
+            sds((1, T, N), jnp.float32), sds((H,), jnp.float32))
+    forward = lambda *a: ssd(*a, chunk=256)[0]
+    fn = (forward if pass_ == "forward" else jax.grad(
+        lambda *a: jnp.sum(forward(*a) * a[0]), argnums=range(6)))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
 
 
 @pytest.mark.parametrize("theta", [10000.0, None], ids=["rotary", "none"])

@@ -58,21 +58,36 @@ TINY = {
              {"attn_layout", "attn_route", "qk_prep", "gqa_bwd", "loops",
               "layers_held", "remat_policy"},
              None),
+    "granite": (dict(n_layer=3, n_head=4, n_kv_head=2, head_dim=16,
+                     n_embd=64, layer_types="mamba,attention,mamba",
+                     intermediate_size=96, mamba_n_heads=4, mamba_d_head=16,
+                     mamba_d_state=16, mamba_chunk_size=16,
+                     embedding_multiplier=12.0, residual_multiplier=0.22,
+                     attention_multiplier=0.015625, logits_scaling=8.0),
+                {"attn_layout", "attn_route", "ssd_impl", "ssd_chunk",
+                 "layer_types", "remat_policy"},
+                None),
 }
 # sha256 of each family's train step as `built` lowers it for the compiler
 # (its HLO text without metadata: scope paths and source lines are not the
 # program), at PR 38's tree (1614ae0). PR 39 put `ouro` beside them, gave
 # Trainer._loss_fn a branch for a family with `exit_loss` and models/loss.py
-# a per-token head: the four steps are what they were, op for op. What the
-# compiler receives, not what it returns: the same text compiles to the same
-# program on any host, where the CPU's own passes may differ by machine.
+# a per-token head: the four steps are what they were, op for op. `ouro`'s
+# is pinned as of 8cab71d, and `granite` came beside the five, which a new
+# family leaves as they are. What the compiler receives,
+# not what it returns: the same text compiles to the same program on any
+# host, where the CPU's own passes may differ by machine.
 STEP_SHA256 = {
     "gpt2": "d09f607a08ceb2dc34899bbeb2bb6c714fdc3eb50457033b81736b29c4986067",
     "afmoe": "cc6dede9a0095121916826a52063dd92cddaa618c90c1692ba3b8a648c609b3f",
     "lfm2": "50ae1f1f769ff459850a277cfd21443fb6ee62a1860e5552d8b6fcf23ee38053",
     "deepseek_v3":
         "8f1dab78531ca3c83a43fbe39908ece0b8f7c9b5e7cefd0002d04bb2878a9942",
+    "ouro": "7d82eb737a79366e7adc7eae8ca216c5cad1319d28881e09279113f2d9193dfc",
 }
+# The parts a family's step has beside the ones every family has, where its
+# step is not pinned above.
+OWN_PARTS = {"granite": {"mamba", "ssd", "attn_full", "mlp"}}
 
 
 def test_the_table_and_the_config_name_the_same_families():
@@ -135,7 +150,9 @@ def test_flops_per_iter_is_the_parents(built):
 def test_head_and_apply_have_the_shapes_the_loss_takes(built):
     """One hidden state (B, T, C) for the head, or, where the family makes
     its loss of several exits (``exit_loss``), one an exit (exits, B, T, C)
-    with the exits' float32 logits in the aux; the counters are int32."""
+    with the exits' float32 logits in the aux; the counters are int32, but
+    for those the family leaves as instants (``INSTANTS``), which may be
+    float32."""
     _, trainer, _ = built
     family, m = trainer.family, trainer.model_cfg
     params = jax.eval_shape(trainer._init_state, jax.random.key(0))["params"]
@@ -149,8 +166,12 @@ def test_head_and_apply_have_the_shapes_the_loss_takes(built):
         lead = (m.total_ut_steps,) if exits and hidden else ()
         assert out.shape == (*lead, 8, 16, last)
         assert isinstance(aux, dict)
+        measured = {k for keys in getattr(family, "INSTANTS", {}).values()
+                    for k in keys}
         assert all(a.dtype == jnp.int32 for k, a in aux.items()
-                   if k != "exit_logits")
+                   if k != "exit_logits" and k not in measured)
+        assert all(aux[k].dtype == jnp.float32 for k in measured
+                   if k in aux)
         if exits:
             assert aux["exit_logits"].shape == (m.total_ut_steps, 8, 16)
 
@@ -165,9 +186,9 @@ def _step_hlo(trainer) -> str:
 
 
 def test_the_other_families_steps_are_what_they_were(built):
-    """The four families that came before ``ouro`` lower to PR 38's step;
-    ``ouro``'s step has its own part, ``exits``, beside its attention and
-    SwiGLU (obs.opscopes reads it)."""
+    """The five families that came before ``granite`` lower to the steps
+    pinned above; ``granite``'s step has its own parts, ``mamba`` and
+    ``ssd``, beside its attention and MLP (obs.opscopes reads them)."""
     import hashlib
 
     from nanosandbox_tpu.obs import opscopes
@@ -178,8 +199,8 @@ def test_the_other_families_steps_are_what_they_were(built):
         assert got == STEP_SHA256[name]
         return
     parts = set(trainer.step_op_parts().values())
-    assert {"exits", "attn_full", "mlp", "ln", "embed"} <= parts
-    assert "lm_head_loss" not in parts and "exits" in opscopes.PARTS
+    assert OWN_PARTS[name] | {"ln", "embed", "lm_head_loss"} <= parts
+    assert OWN_PARTS[name] <= set(opscopes.PARTS)
 
 
 def test_inference_is_refused_with_the_familys_own_sentence(tmp_path):
@@ -276,8 +297,8 @@ def test_a_gpt2_trainer_imports_no_other_familys_kernels():
         "              if m.startswith('nanosandbox_tpu.'))\n"
         "assert 'nanosandbox_tpu.models.gpt' in mine, mine\n"
         "other = [m for m in mine if m.endswith(('.afmoe', '.lfm2',\n"
-        "         '.deepseek_v3', '.ouro', '.experts', '.ops.moe',\n"
-        "         '.short_conv'))]\n"
+        "         '.deepseek_v3', '.ouro', '.granite', '.experts',\n"
+        "         '.ops.moe', '.short_conv', '.ssd'))]\n"
         "assert not other, other\n")
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     subprocess.run([sys.executable, "-c", probe], check=True, env=env,
