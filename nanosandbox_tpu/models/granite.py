@@ -23,7 +23,10 @@ mamba_n_groups; K = mamba_d_conv):
         xBC = silu(causal_conv_K(xBC) + b_conv)  depth-wise, taps t-K+1..t
         x, B, C = xBC split inner | Gm N | Gm N
         dt = softplus(dt + dt_bias)             no clamp;  A = -exp(A_log)
-        y = SSD(x, dt, A, B, C, D)              ops/ssd.py, in chunks
+        y = SSD(x, dt, A, B, C, D)              ops/ssd.py, in chunks: one
+                                                Pallas kernel each way where
+                                                ssd.resolve_ssd_impl says so,
+                                                the XLA form elsewhere
         y = RMSNorm_inner(y * silu(z)) * w_norm  the gate before the norm,
                                                 over inner / Gm lanes a group
         out = y W_out
@@ -52,8 +55,9 @@ instant ``granite_ssd`` at log steps (``INSTANTS``).
 
 Scopes (obs/opscopes.py): modules ``mamba`` (the projections, taps, dt and
 the gated norm) and, inside it, the named scope ``ssd`` (the scan, forward
-and backward); ``attn_full`` (projections and the flash kernels,
-``%attn_full.N``); ``mlp``; the norms ``input_layernorm``,
+and backward; the kernels' custom calls ``%ssd.N``); ``attn_full``
+(projections and the flash kernels, ``%attn_full.N``); ``mlp``; the norms
+``input_layernorm``,
 ``post_attention_layernorm``, ``final_norm``; ``wte``.
 """
 
@@ -149,11 +153,14 @@ class Mamba(nn.Module):
         dt_bias = self.param("dt_bias", _dt_bias_init, (H,), pd)
         A_log = self.param("A_log", _a_log_init, (H,), pd)
         D = self.param("D", nn.initializers.ones, (H,), pd)
+        impl = ssd.resolve_ssd_impl(cfg.attention_impl, a.shape[1],
+                                    cfg.mamba_chunk_size, P, N, heads=H,
+                                    groups=G)
         y, stats = ssd.ssd(x, jax.nn.softplus(dt.astype(jnp.float32)
                                               + dt_bias),
                            -jnp.exp(A_log.astype(jnp.float32)), B, C, D,
                            chunk=cfg.mamba_chunk_size, groups=G,
-                           dtype=jnp.dtype(cfg.compute_dtype))
+                           dtype=jnp.dtype(cfg.compute_dtype), impl=impl)
         y = GatedRMSNorm(cfg.rms_norm_eps, G, cfg.param_dtype, name="norm")(
             y, z)
         out = dense(cfg, cfg.n_embd, "out_proj")(y.astype(cfg.compute_dtype))
@@ -276,13 +283,17 @@ def check(cfg, pretrained: bool) -> None:
 
 def build(cfg: GraniteConfig, mesh: Any):
     """(the model, what ``trainer_init`` records of it)."""
-    # What a full batch's attention resolves to, as the model will at trace
-    # time (ops.attention.gqa_route); the scan has one implementation.
+    # What a full batch's attention and scan resolve to, as the model will
+    # at trace time (ops.attention.gqa_route, ops.ssd.resolve_ssd_impl).
     route = gqa_route(cfg.attention_impl, cfg.head_dim, cfg.block_size)
+    scan = ssd.resolve_ssd_impl(
+        cfg.attention_impl, cfg.block_size, cfg.mamba_chunk_size,
+        cfg.mamba_d_head, cfg.mamba_d_state, heads=cfg.mamba_n_heads,
+        groups=cfg.mamba_n_groups)
     return Granite(cfg, mesh=mesh), {
         "attn_layout": "bhtd" if route == "xla" else route,
         "attn_route": route,
-        "ssd_impl": "xla", "ssd_chunk": cfg.mamba_chunk_size,
+        "ssd_impl": scan, "ssd_chunk": cfg.mamba_chunk_size,
         "layer_types": ",".join(cfg.layer_types),
         "remat_policy": cfg.remat_policy if cfg.remat else "none"}
 

@@ -40,9 +40,11 @@ tests hold every later PR to what the chip accepts, at no chip time:
     rotary key, values of 128): ``flash_attention_mla``'s forward and ONE
     backward kernel, at the cell's T and at the longest its VMEM predicate
     lets through, and the grouped matmul at the expert width 1408;
-  * the ``granite`` family's Mamba-2 scan (``ops/ssd.py``, XLA, all
-    chunks at once) at the cell's (1, 8192) tokens, 64 heads of 64,
-    d_state 128, forward and backward, its temporaries under 1 GB;
+  * the ``granite`` family's Mamba-2 scan (``ops/ssd.py``) at the cell's
+    (1, 8192) tokens, 64 heads of 64, d_state 128, forward and backward:
+    the XLA form, its temporaries under 1 GB, and the kernels (``%ssd.N``,
+    one call a pass, no (..., 256, 256) array beside them), also inside the
+    family's loss and gradient at the cell's widths;
   * all nine serving variants — ``flash_decode`` / ``flash_decode_paged``
     / ``flash_prefill_paged`` x fp / int8 / int4 — at B=8, H=12, D=64,
     the engine's default page 16 and page 32, prefill T = a page and
@@ -278,25 +280,89 @@ def test_gated_short_conv_forward_and_backward(sds):
         assert not re.search(rf"f32\[{B},{T},", txt)
 
 
-@pytest.mark.parametrize("pass_", ["forward", "backward"])
-def test_ssd_scan_forward_and_backward(sds, pass_):
-    """The granite cell's Mamba-2 scan (ops/ssd.py, all chunks at once) at
-    (1, 8192) tokens, 64 heads of 64, d_state 128, one group, chunk 256:
-    it compiles for the chip, and its temporaries stay under 1 GB (0.47 GB
-    forward, 0.42 GB forward + backward when written), where the decay
-    blocks of all 32 chunks, (32, 64, 256, 256), would be 537 MB each in
-    float32 if the compiler wrote them out."""
-    from nanosandbox_tpu.ops.ssd import ssd
+# the granite cell's scan: (1, T) tokens, H heads of P, d_state N, chunk L
+SSD_SHAPE = (8192, 64, 64, 128, 256)
+# an instruction's results, `%name = (f32[...]..., f32[...]...) custom-call(`
+SSD_CALL = re.compile(r"%(ssd[.0-9]*) = \(?([^\n]*?)\)? custom-call\(")
 
-    T, H, P, N = 8192, 64, 64, 128
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("pass_", ["forward", "backward"])
+def test_ssd_scan_forward_and_backward(sds, pass_, impl):
+    """The granite cell's Mamba-2 scan (ops/ssd.py) at (1, 8192) tokens, 64
+    heads of 64, d_state 128, one group, chunk 256, forward and forward +
+    backward. The XLA form (all chunks at once) compiles for the chip with
+    its temporaries under 1 GB (0.47 GB forward, 0.42 GB forward + backward
+    when written), where the decay blocks of all 32 chunks, (32, 64, 256,
+    256), would be 537 MB each in float32 if the compiler wrote them out.
+    The kernels: one custom call forward, one more backward, each filed
+    under the part ``ssd``, no (..., 256, 256) array outside them, and
+    temporaries under the XLA form's."""
+    from nanosandbox_tpu.obs import opscopes
+    from nanosandbox_tpu.ops.ssd import resolve_ssd_impl, ssd
+
+    T, H, P, N, L = SSD_SHAPE
+    if impl == "pallas":
+        assert resolve_ssd_impl("pallas", T, L, P, N, heads=H,
+                                groups=1) == "pallas"
     args = (sds((1, T, H * P), jnp.float32), sds((1, T, H), jnp.float32),
             sds((H,), jnp.float32), sds((1, T, N), jnp.float32),
             sds((1, T, N), jnp.float32), sds((H,), jnp.float32))
-    forward = lambda *a: ssd(*a, chunk=256)[0]
+    forward = lambda *a: ssd(*a, chunk=L, impl=impl)[0]
     fn = (forward if pass_ == "forward" else jax.grad(
         lambda *a: jnp.sum(forward(*a) * a[0]), argnums=range(6)))
     compiled = jax.jit(fn).lower(*args).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1e9
+    if impl == "xla":
+        return
+    assert temp < 0.42e9
+    txt = compiled.as_text()
+    calls = [name for name, _ in SSD_CALL.findall(txt)]
+    assert len(calls) == {"forward": 1, "backward": 2}[pass_], calls
+    parts = opscopes.op_parts(txt)
+    assert all(parts[name] == "ssd" for name in calls)
+    assert not re.search(rf"\[[0-9,]*{L},{L}\]", txt)
+
+
+def test_the_granite_step_runs_the_scan_kernels(sds):
+    """The family's loss and gradient at the cell's widths and T (three
+    layers: mamba, attention, mamba; ``save_attention`` remat; a toy
+    vocabulary): per Mamba layer one forward kernel call, remat's replay of
+    it and one backward call, every one filed under ``ssd``."""
+    from nanosandbox_tpu.config import GraniteConfig, TrainConfig
+    from nanosandbox_tpu.models import granite
+    from nanosandbox_tpu.obs import opscopes
+
+    T, H, P, N, L = SSD_SHAPE
+    tc = TrainConfig(
+        model_family="granite", n_layer=3, n_head=32, n_kv_head=8,
+        head_dim=64, n_embd=2048, intermediate_size=8192, block_size=T,
+        layer_types="mamba,attention,mamba", mamba_n_heads=H,
+        mamba_d_head=P, mamba_d_state=N, mamba_n_groups=1, mamba_d_conv=4,
+        mamba_chunk_size=L, embedding_multiplier=12.0,
+        residual_multiplier=0.22, attention_multiplier=0.015625,
+        logits_scaling=8.0, rms_norm_eps=1e-5, compute_dtype="bfloat16",
+        attention_impl="pallas", remat=True, remat_policy="save_attention")
+    model, recorded = granite.build(GraniteConfig.from_train_config(tc, 512),
+                                    None)
+    assert recorded["ssd_impl"] == "pallas"
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.zeros((1, T), jnp.int32))["params"])
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype), params)
+
+    def loss(p, idx):
+        h, _ = model.apply({"params": p}, idx, return_hidden=True)
+        return jnp.mean(jnp.square(h))
+
+    txt = compiled_text(jax.grad(loss), params, sds((1, T), jnp.int32))
+    calls = SSD_CALL.findall(txt)
+    # results: the forward's y, the largest |S| and the chunks' states; the
+    # backward's dx, dB, dC and the per-position rows
+    outputs = sorted(results.count("f32[") for _, results in calls)
+    assert outputs == [3] * 4 + [4] * 2, calls
+    parts = opscopes.op_parts(txt)
+    assert all(parts[name] == "ssd" for name, _ in calls)
 
 
 @pytest.mark.parametrize("theta", [10000.0, None], ids=["rotary", "none"])

@@ -14,10 +14,13 @@ rtol 2e-4 and 1e-5 of their largest magnitude, its gradients to 1e-4 of each
 one's largest magnitude, 5e-4 where dt is large (at dt A of -45 a token, cs
 reaches -720 inside a chunk of 16, and A's gradient, a sum over every
 token, reads up to 2e-4 of its largest off there); the model's loss to rtol 1e-5 and each gradient leaf to
-rtol 2e-4 / atol 1e-6, as the other families' tests hold theirs.
+rtol 2e-4 / atol 1e-6, as the other families' tests hold theirs. The scan's
+kernels run in the interpreter under the same tolerances.
 """
 
 import dataclasses
+import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +64,7 @@ def train_cfg(**kw) -> TrainConfig:
 
 # -- the scan against the token recurrence -------------------------------------
 
+IMPLS = ["xla", "pallas_interpret"]
 SCAN = {"mamba_n_heads": 4, "mamba_d_head": 8, "mamba_d_state": 16,
         "mamba_n_groups": 2, "mamba_chunk_size": 16}
 
@@ -78,9 +82,10 @@ def _scan_inputs(T, dt_shift, seed=0):
     return x, dt, A, B, C, D
 
 
-def _chunked(*a):
-    return ssd_op.ssd(*a, chunk=SCAN["mamba_chunk_size"],
-                      groups=SCAN["mamba_n_groups"], dtype=jnp.float32)[0]
+def _chunked(*a, impl="xla", groups=SCAN["mamba_n_groups"],
+             dtype=jnp.float32):
+    return ssd_op.ssd(*a, chunk=SCAN["mamba_chunk_size"], groups=groups,
+                      dtype=dtype, impl=impl)[0]
 
 
 def _token_by_token(x, dt, A, B, C, D):
@@ -88,24 +93,28 @@ def _token_by_token(x, dt, A, B, C, D):
     return ref.recurrence(x, dt, A, B, C, D, SCAN)
 
 
+@pytest.mark.parametrize("impl", IMPLS)
 @pytest.mark.parametrize("dt_shift", [-8.0, 0.0, 3.0],
                          ids=["dt_near_0", "dt_mid", "dt_large"])
 @pytest.mark.parametrize("T", [16, 40, 48], ids=["1_chunk", "2.5_chunks",
                                                  "3_chunks"])
-def test_the_chunked_scan_is_the_token_recurrence(T, dt_shift):
+def test_the_chunked_scan_is_the_token_recurrence(T, dt_shift, impl):
     """Values and the gradient of every input, at one whole chunk, two and a
     half (padded inside the op) and three, with dt near 0 (the state barely
     moves: exp(dt A) ~ 1), in the middle, and large (it forgets within a few
-    tokens)."""
+    tokens); the XLA form and the kernels in the interpreter (blocks of 8
+    positions in the chunk of 16, so that the blocks below the diagonal
+    run)."""
     args = _scan_inputs(T, dt_shift)
     w = jax.random.normal(jax.random.key(9), (2, T, 32))
+    scan = functools.partial(_chunked, impl=impl)
     both = lambda f: jax.jit(jax.value_and_grad(
         lambda *a: jnp.sum(f(*a) * w), argnums=range(6)))
     with jax.default_matmul_precision("highest"):
-        got, want = jax.jit(_chunked)(*args), jax.jit(_token_by_token)(*args)
+        got, want = jax.jit(scan)(*args), jax.jit(_token_by_token)(*args)
         np.testing.assert_allclose(got, want, rtol=2e-4,
                                    atol=1e-5 * float(jnp.max(jnp.abs(want))))
-        (_, g), (_, r) = both(_chunked)(*args), both(_token_by_token)(*args)
+        (_, g), (_, r) = both(scan)(*args), both(_token_by_token)(*args)
     for name, a, b in zip("x dt A B C D".split(), g, r):
         scale = float(jnp.max(jnp.abs(b)))
         off = 5e-4 if dt_shift > 0 else 1e-4
@@ -113,15 +122,17 @@ def test_the_chunked_scan_is_the_token_recurrence(T, dt_shift):
                                    err_msg=name)
 
 
-def test_the_scans_counters_and_its_scope():
+@pytest.mark.parametrize("impl", IMPLS)
+def test_the_scans_counters_and_its_scope(impl):
     """``ssd_decay`` is the mean of exp(sum of dt A over a chunk), which the
     state keeps of itself across one chunk; ``ssd_state_max`` the largest
     |S| at a chunk's end, here read off the recurrence; no gradient reaches
-    them; every op lies under the scope ``ssd``."""
+    them; every op lies under the scope ``ssd``, forward and through
+    ``jax.grad``."""
     x, dt, A, B, C, D = _scan_inputs(48, -2.0)
     L = SCAN["mamba_chunk_size"]
     _, stats = ssd_op.ssd(x, dt, A, B, C, D, chunk=L, groups=2,
-                          dtype=jnp.float32)
+                          dtype=jnp.float32, impl=impl)
     decay = jnp.exp((dt * A).reshape(2, 3, L, 4).sum(axis=2))
     np.testing.assert_allclose(stats["ssd_decay"], decay.mean(), rtol=1e-5)
     # the state at each chunk's end, by the recurrence
@@ -137,18 +148,97 @@ def test_the_scans_counters_and_its_scope():
             ends.append(np.abs(S).max())
     np.testing.assert_allclose(stats["ssd_state_max"], max(ends), rtol=1e-4)
     counted = jax.grad(lambda x: sum(ssd_op.ssd(
-        x, *_scan_inputs(48, -2.0)[1:], chunk=L, groups=2)[1].values()))
+        x, *_scan_inputs(48, -2.0)[1:], chunk=L, groups=2,
+        impl=impl)[1].values()))
     assert not np.any(counted(_scan_inputs(48, -2.0)[0]))
     # forward and backward under the scope (obs.opscopes reads the paths)
     from nanosandbox_tpu.obs import opscopes
 
-    text = jax.jit(jax.grad(lambda *a: jnp.sum(ssd_op.ssd(
-        *a, chunk=L, groups=2)[0]), argnums=range(6))).lower(
-        *_scan_inputs(48, -2.0)).compile().as_text()
+    scan = lambda *a: ssd_op.ssd(*a, chunk=L, groups=2, impl=impl)[0]
+    grad = jax.jit(jax.grad(lambda *a: jnp.sum(scan(*a)), argnums=range(6)))
+    text = grad.lower(*_scan_inputs(48, -2.0)).compile().as_text()
     paths = opscopes._OP_NAME.findall(text)
     assert any(p.startswith("jit(<lambda>)/transpose(")
                and opscopes.part_of(p) == "ssd" for p in paths)
     assert "ssd" in opscopes.op_parts(text).values()
+    # every op the scan writes, forward and backward, names the scope: the
+    # lowered program's op names (not its file and function locations; the
+    # interpreter's loop over a kernel's grid names its body afresh: on the
+    # chip that body is one custom call, tests/test_chip_compile.py)
+    args = _scan_inputs(48, -2.0)
+    pullback = jax.jit(lambda a, ct: jax.vjp(scan, *a)[1](ct))
+    for lowered in (jax.jit(scan).lower(*args),
+                    pullback.lower(args, jnp.ones((2, 48, 32)))):
+        names = [n for n in re.findall(r'loc\("([^"]*)"',
+                                       lowered.as_text(debug_info=True))
+                 if "/" in n and not n.startswith(("/", "while/"))]
+        assert names and all(opscopes.part_of(n) == "ssd" for n in names), {
+            n for n in names if opscopes.part_of(n) != "ssd"}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("groups", [1, 2])
+def test_the_kernels_are_the_xla_form(groups, dtype):
+    """The kernels (in the interpreter) against the XLA form at 2.5 chunks:
+    y, both counters and the gradient of every input. In float32 they are
+    one map summed in another order. With bfloat16 products both are held
+    to the float32 form: the kernels' largest error within 2.5 times the
+    XLA form's own (on the CPU the XLA form keeps its cotangents in float32
+    where the kernels round them for the products, as XLA does on the
+    chip)."""
+    args = _scan_inputs(40, 0.0)
+    w = jax.random.normal(jax.random.key(9), (2, 40, 32))
+
+    def run(impl, dt):
+        def loss(*a):
+            y, stats = ssd_op.ssd(*a, chunk=SCAN["mamba_chunk_size"],
+                                  groups=groups, dtype=jnp.dtype(dt),
+                                  impl=impl)
+            return jnp.sum(y * w), (y, stats)
+        (_, (y, stats)), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=range(6), has_aux=True))(*args)
+        return [y, *grads], stats
+
+    with jax.default_matmul_precision("highest"):
+        want, want_stats = run("xla", "float32")
+        got, stats = run("pallas_interpret", dtype)
+        xla, xla_stats = run("xla", dtype)
+    for k in want_stats:
+        np.testing.assert_allclose(stats[k], xla_stats[k], rtol=1e-5,
+                                   err_msg=k)
+    for name, a, b, c in zip("y x dt A B C D".split(), got, want, xla):
+        scale = float(jnp.max(jnp.abs(b)))
+        if dtype == "float32":
+            np.testing.assert_allclose(a, b, rtol=2e-4, atol=1e-5 * scale,
+                                       err_msg=name)
+        else:
+            own = float(jnp.max(jnp.abs(c - b)))
+            assert float(jnp.max(jnp.abs(a - b))) <= max(
+                2.5 * own, 1e-6 * scale), name
+
+
+@pytest.mark.parametrize("case, want", [
+    (dict(), "pallas"),
+    (dict(attention_impl="xla"), "xla"),
+    (dict(attention_impl="auto"), "xla"),          # the CPU backend
+    (dict(T=128), "xla"),                          # shorter than a chunk
+    (dict(chunk=192), "xla"),
+    (dict(d_state=64), "xla"),
+    (dict(head_dim=48, heads=4), "xla"),           # no whole 128-lane tile
+    (dict(attention_impl="pallas_interpret"), "pallas_interpret"),
+])
+def test_resolve_ssd_impl_gives_the_kernels_where_the_blocks_tile(case,
+                                                                  want):
+    shape = dict(attention_impl="pallas", T=8192, chunk=256, head_dim=64,
+                 d_state=128, heads=64, groups=1)
+    shape.update(case)
+    impl = shape.pop("attention_impl")
+    assert ssd_op.resolve_ssd_impl(impl, shape.pop("T"), shape.pop("chunk"),
+                                   shape.pop("head_dim"), shape.pop("d_state"),
+                                   **shape) == want
+    # the cell's: a block of 8 heads of 64 lanes, taken two at a time
+    assert ssd_op.head_block(64, 1, 64) == 8
+    assert ssd_op.lane_group(8, 64, 256) == 2
 
 
 def test_forward_flops_per_token_is_the_hand_count():
@@ -325,7 +415,12 @@ def test_the_cli_trains_two_steps_and_leaves_the_scans_instants(
     assert np.isfinite(out["final_loss"])
     spans = process_tracer().spans()
     init = [s for s in spans if s.name == "trainer_init"][-1]
-    assert {"ssd_impl": "xla", "ssd_chunk": 16,
+    resolved = ssd_op.resolve_ssd_impl(
+        "auto", SIZES["block_size"], SIZES["mamba_chunk_size"],
+        SIZES["mamba_d_head"], SIZES["mamba_d_state"],
+        heads=SIZES["mamba_n_heads"], groups=SIZES["mamba_n_groups"])
+    assert resolved == "xla"                         # the CPU backend
+    assert {"ssd_impl": resolved, "ssd_chunk": 16,
             "remat_policy": "save_attention",
             "attn_route": "xla", "layer_types": "mamba,attention,mamba"
             }.items() <= init.args.items()
