@@ -21,7 +21,11 @@ mamba_n_groups; K = mamba_d_conv):
     Mixer, 'mamba' layers:
         [z | xBC | dt] = a W_in                 inner | inner + 2 Gm N | Hm
         xBC = silu(causal_conv_K(xBC) + b_conv)  depth-wise, taps t-K+1..t
-        x, B, C = xBC split inner | Gm N | Gm N
+        x, B, C = xBC split inner | Gm N | Gm N  ops/mamba_conv.py: one Pallas
+                                                pass each way reading xBC in
+                                                W_in's output where
+                                                resolve_mamba_conv_impl says
+                                                so, the XLA form elsewhere
         dt = softplus(dt + dt_bias)             no clamp;  A = -exp(A_log)
         y = SSD(x, dt, A, B, C, D)              ops/ssd.py, in chunks: one
                                                 Pallas kernel each way where
@@ -54,8 +58,10 @@ a chunk boundary (``ssd_state_max``); ``Trainer`` leaves them as the
 instant ``granite_ssd`` at log steps (``INSTANTS``).
 
 Scopes (obs/opscopes.py): modules ``mamba`` (the projections, taps, dt and
-the gated norm) and, inside it, the named scope ``ssd`` (the scan, forward
-and backward; the kernels' custom calls ``%ssd.N``); ``attn_full``
+the gated norm; the conv's named scope ``mamba_conv``, its kernels' custom
+calls ``%mamba_conv.N``, names no part and is filed here) and, inside it,
+the named scope ``ssd`` (the scan, forward and backward; the kernels'
+custom calls ``%ssd.N``); ``attn_full``
 (projections and the flash kernels, ``%attn_full.N``); ``mlp``; the norms
 ``input_layernorm``,
 ``post_attention_layernorm``, ``final_norm``; ``wte``.
@@ -76,7 +82,8 @@ from nanosandbox_tpu.models.common import (_dense_init, constrain_acts,
 from nanosandbox_tpu.models.experts import SwiGLU, dense, rms_norm
 from nanosandbox_tpu.ops import ssd
 from nanosandbox_tpu.ops.attention import causal_attention_gqa, gqa_route
-from nanosandbox_tpu.ops.short_conv import causal_taps
+from nanosandbox_tpu.ops.mamba_conv import (mamba_conv,
+                                            resolve_mamba_conv_impl)
 
 # What a block under remat keeps: the attention kernels' output and
 # logsumexp (ops/attention.py).
@@ -142,14 +149,16 @@ class Mamba(nn.Module):
         G, N = cfg.mamba_n_groups, cfg.mamba_d_state
         inner, pd = H * P, jnp.dtype(cfg.param_dtype)
         width = inner + 2 * G * N                 # what the taps mix
-        z, xbc, dt = jnp.split(dense(cfg, inner + width + H, "in_proj")(a),
-                               [inner, inner + width], axis=-1)
+        proj = dense(cfg, inner + width + H, "in_proj")(a)
+        z, dt = proj[..., :inner], proj[..., inner + width:]
         taps = self.param("conv_weight", _taps_init,
                           (width, cfg.mamba_d_conv), pd)
         bias = self.param("conv_bias", nn.initializers.zeros, (width,), pd)
-        xbc = jax.nn.silu(causal_taps(xbc.astype(jnp.float32),
-                                      taps.astype(jnp.float32)) + bias)
-        x, B, C = jnp.split(xbc, [inner, inner + G * N], axis=-1)
+        x, B, C = mamba_conv(
+            proj, taps, bias, start=inner, inner=inner,
+            impl=resolve_mamba_conv_impl(cfg.attention_impl, a.shape[1],
+                                         inner, inner, width,
+                                         cfg.mamba_d_conv))
         dt_bias = self.param("dt_bias", _dt_bias_init, (H,), pd)
         A_log = self.param("A_log", _a_log_init, (H,), pd)
         D = self.param("D", nn.initializers.ones, (H,), pd)
@@ -290,9 +299,13 @@ def build(cfg: GraniteConfig, mesh: Any):
         cfg.attention_impl, cfg.block_size, cfg.mamba_chunk_size,
         cfg.mamba_d_head, cfg.mamba_d_state, heads=cfg.mamba_n_heads,
         groups=cfg.mamba_n_groups)
+    inner = cfg.mamba_n_heads * cfg.mamba_d_head
+    conv = resolve_mamba_conv_impl(
+        cfg.attention_impl, cfg.block_size, inner, inner,
+        inner + 2 * cfg.mamba_n_groups * cfg.mamba_d_state, cfg.mamba_d_conv)
     return Granite(cfg, mesh=mesh), {
         "attn_layout": "bhtd" if route == "xla" else route,
-        "attn_route": route,
+        "attn_route": route, "conv_impl": conv,
         "ssd_impl": scan, "ssd_chunk": cfg.mamba_chunk_size,
         "layer_types": ",".join(cfg.layer_types),
         "remat_policy": cfg.remat_policy if cfg.remat else "none"}

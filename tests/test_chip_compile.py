@@ -44,7 +44,8 @@ tests hold every later PR to what the chip accepts, at no chip time:
     (1, 8192) tokens, 64 heads of 64, d_state 128, forward and backward:
     the XLA form, its temporaries under 1 GB, and the kernels (``%ssd.N``,
     one call a pass, no (..., 256, 256) array beside them), also inside the
-    family's loss and gradient at the cell's widths;
+    family's loss and gradient at the cell's widths, where the conv's
+    kernels (``ops/mamba_conv.py``, ``%mamba_conv.N``) hand it x, B and C;
   * all nine serving variants — ``flash_decode`` / ``flash_decode_paged``
     / ``flash_prefill_paged`` x fp / int8 / int4 — at B=8, H=12, D=64,
     the engine's default page 16 and page 32, prefill T = a page and
@@ -325,11 +326,25 @@ def test_ssd_scan_forward_and_backward(sds, pass_, impl):
     assert not re.search(rf"\[[0-9,]*{L},{L}\]", txt)
 
 
+def _producer(txt: str, name: str) -> str:
+    """The instruction that makes ``name``'s value, through the
+    get-tuple-elements, bitcasts and memory-space copies on its way."""
+    ops = dict(re.findall(
+        r"^\s*(?:ROOT )?%(\S+) = [^\n]*?\b(?:get-tuple-element|bitcast|"
+        r"copy-done|copy-start)\(%([^,)\s]+)", txt, re.M))
+    while name in ops:
+        name = ops[name]
+    return name
+
+
 def test_the_granite_step_runs_the_scan_kernels(sds):
     """The family's loss and gradient at the cell's widths and T (three
     layers: mamba, attention, mamba; ``save_attention`` remat; a toy
-    vocabulary): per Mamba layer one forward kernel call, remat's replay of
-    it and one backward call, every one filed under ``ssd``."""
+    vocabulary): per Mamba layer one forward kernel call of the scan,
+    remat's replay of it and one backward call, every one filed under
+    ``ssd``; and as many of the conv's kernels (ops/mamba_conv.py), filed
+    under ``mamba``, whose outputs are the scan calls' x, B and C as they
+    are, with no xBC-wide float32 array left in the step."""
     from nanosandbox_tpu.config import GraniteConfig, TrainConfig
     from nanosandbox_tpu.models import granite
     from nanosandbox_tpu.obs import opscopes
@@ -346,7 +361,7 @@ def test_the_granite_step_runs_the_scan_kernels(sds):
         attention_impl="pallas", remat=True, remat_policy="save_attention")
     model, recorded = granite.build(GraniteConfig.from_train_config(tc, 512),
                                     None)
-    assert recorded["ssd_impl"] == "pallas"
+    assert recorded["ssd_impl"] == recorded["conv_impl"] == "pallas"
     params = jax.eval_shape(lambda: model.init(
         jax.random.key(0), jnp.zeros((1, T), jnp.int32))["params"])
     params = jax.tree.map(lambda a: sds(a.shape, a.dtype), params)
@@ -363,6 +378,22 @@ def test_the_granite_step_runs_the_scan_kernels(sds):
     assert outputs == [3] * 4 + [4] * 2, calls
     parts = opscopes.op_parts(txt)
     assert all(parts[name] == "ssd" for name, _ in calls)
+    # the conv: a forward, its replay and a backward a Mamba layer
+    convs = dict(re.findall(r"%(mamba_conv[.0-9]*) = \(([^\n]*?)\) "
+                            r"custom-call\(", txt))
+    # results: the forward's x, B and C; the backward's dxBC (bfloat16)
+    # and the per-program sums of the taps' and bias's gradients
+    assert sorted(results.count("bf16[") for results in convs.values()
+                  ) == [0] * 4 + [1] * 2, convs
+    assert all(parts[name] == "mamba" for name in convs)
+    assert f"f32[1,{T},{H * P + 2 * N}]" not in txt
+    for name, _ in calls:
+        operands = re.search(rf"%{re.escape(name)} = [^\n]*?custom-call\("
+                             r"([^)]*)\)", txt).group(1)
+        operands = re.findall(r"%([^,\s]+)", operands)
+        # x, B and C: the scan's operands 0, 3 and 4, both ways
+        assert {_producer(txt, operands[i]) for i in (0, 3, 4)} <= set(
+            convs), (name, operands)
 
 
 @pytest.mark.parametrize("theta", [10000.0, None], ids=["rotary", "none"])

@@ -65,7 +65,7 @@ TINY = {
                      embedding_multiplier=12.0, residual_multiplier=0.22,
                      attention_multiplier=0.015625, logits_scaling=8.0),
                 {"attn_layout", "attn_route", "ssd_impl", "ssd_chunk",
-                 "layer_types", "remat_policy"},
+                 "conv_impl", "layer_types", "remat_policy"},
                 None),
 }
 # sha256 of each family's train step as `built` lowers it for the compiler

@@ -32,6 +32,7 @@ from chipbench import flops_granite, weights_granite
 from chipbench.reference import granite as ref
 from nanosandbox_tpu.config import GraniteConfig, TrainConfig
 from nanosandbox_tpu.models import granite
+from nanosandbox_tpu.ops import mamba_conv as conv_op
 from nanosandbox_tpu.ops import ssd as ssd_op
 
 SIZES = {
@@ -241,6 +242,76 @@ def test_resolve_ssd_impl_gives_the_kernels_where_the_blocks_tile(case,
     assert ssd_op.lane_group(8, 64, 256) == 2
 
 
+# -- the conv between in_proj and the scan ------------------------------------
+
+@pytest.mark.parametrize("dtype, inner", [("float32", 256), ("bfloat16", 128)])
+def test_the_conv_kernels_are_the_xla_form(dtype, inner):
+    """The conv's kernels (ops/mamba_conv.py, in the interpreter) against its
+    XLA form: x, B and C, and the gradients of in_proj's output, the taps
+    and the bias under fixed cotangents. Two rows of 768 tokens in three
+    row blocks of 256, so the taps reach across block edges and are masked
+    at position 0; GN 64, so a channel block of B and C is one 128-lane
+    tile, and x one or two more. xBC lies between z (``inner`` lanes) and
+    dt (4 lanes), where in_proj leaves it. Both sides are float32 inside:
+    the outputs within a few float32 units of rounding, the taps' and
+    bias's gradients (sums over 1,536 rows in another order) within 1e-5 of
+    their largest, dxBC within one unit of rounding of the input's dtype."""
+    T, GN, L = 768, 64, 4
+    width = inner + 2 * GN
+    k = jax.random.split(jax.random.key(7), 4)
+    proj = jax.random.normal(k[0], (2, T, inner + width + 4)).astype(dtype)
+    w = jax.random.uniform(k[1], (width, L), minval=-0.5, maxval=0.5)
+    bias = 0.1 * jax.random.normal(k[2], (width,))
+    cts = [jax.random.normal(c, (2, T, n)) for c, n in zip(
+        jax.random.split(k[3], 3), (inner, GN, GN))]
+    assert conv_op.resolve_mamba_conv_impl(
+        "pallas_interpret", T, inner, inner, width, L) == "pallas_interpret"
+
+    def run(impl):
+        def loss(*args):
+            out = conv_op.mamba_conv(*args, start=inner, inner=inner,
+                                     impl=impl)
+            return sum(jnp.sum(o * c) for o, c in zip(out, cts)), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(proj, w, bias)
+        return [*out, *grads]
+
+    got, want = run("pallas_interpret"), run("xla")
+    for name, a, b in zip("x B C dproj dw dbias".split(), got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        scale = float(np.abs(b).max())
+        if name == "dproj":
+            atol = float(jnp.finfo(dtype).eps) * scale
+        elif name in ("dw", "dbias"):
+            atol = 1e-5 * scale
+        else:
+            atol = 4 * float(jnp.finfo(jnp.float32).eps) * scale
+        np.testing.assert_allclose(a, b, rtol=0, atol=atol, err_msg=name)
+    # z's and dt's columns get no gradient from the conv
+    assert not got[3][..., :inner].any() and not got[3][..., -4:].any()
+
+
+@pytest.mark.parametrize("case, want", [
+    (dict(), "pallas"),
+    (dict(attention_impl="xla"), "xla"),
+    (dict(attention_impl="auto"), "xla"),          # the CPU backend
+    (dict(T=128), "xla"),                          # under a row block
+    (dict(T=8000), "xla"),                         # no whole row blocks
+    (dict(inner=4000, width=4256), "xla"),         # x in no whole blocks
+    (dict(width=4096 + 192), "xla"),               # B | C no whole tile
+    (dict(start=4160), "xla"),                     # xBC off a block edge
+    (dict(taps=8), "xla"),                         # gradients past a tile
+    (dict(attention_impl="pallas_interpret"), "pallas_interpret"),
+])
+def test_resolve_mamba_conv_impl_gives_the_kernels_where_the_blocks_tile(
+        case, want):
+    shape = dict(attention_impl="pallas", T=8192, start=4096, inner=4096,
+                 width=4352, taps=4)               # the granite cell's
+    shape.update(case)
+    assert conv_op.resolve_mamba_conv_impl(**shape) == want
+
+
 def test_forward_flops_per_token_is_the_hand_count():
     # chunk 256, 64 heads of 64, d_state 128, one group
     got = ssd_op.forward_flops_per_token(256, 64, 64, 128, 1)
@@ -420,7 +491,9 @@ def test_the_cli_trains_two_steps_and_leaves_the_scans_instants(
         SIZES["mamba_d_head"], SIZES["mamba_d_state"],
         heads=SIZES["mamba_n_heads"], groups=SIZES["mamba_n_groups"])
     assert resolved == "xla"                         # the CPU backend
-    assert {"ssd_impl": resolved, "ssd_chunk": 16,
+    assert conv_op.resolve_mamba_conv_impl(
+        "auto", SIZES["block_size"], 64, 64, 96, 4) == "xla"
+    assert {"ssd_impl": resolved, "ssd_chunk": 16, "conv_impl": "xla",
             "remat_policy": "save_attention",
             "attn_route": "xla", "layer_types": "mamba,attention,mamba"
             }.items() <= init.args.items()
