@@ -1,6 +1,6 @@
 # The REGULARIZED GPT-2-regime run: identical to
 # train_gpt2_124m_englishprose_bpe.py except dropout 0.1, which runs
-# INSIDE the Pallas flash kernels (r4: flash_attention_dropout — the r3
+# INSIDE the Pallas flash kernels (r4: flash_attention's dropout — the r3
 # convergence runs fell to the ~10%-MFU XLA fallback whenever dropout was
 # on). Two things this artifact demonstrates at once:
 #   1. the in-kernel dropout path sustaining a real 124M training run on

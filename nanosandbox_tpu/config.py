@@ -155,15 +155,10 @@ class TrainConfig:
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"  # MXU-native
     attention_impl: str = "auto"  # 'auto' | 'pallas' | 'xla' | 'ring'
-    # Flash-backward softmax-stat operand layout: 'replicated' broadcasts
-    # per-row stats across the 128-lane minor dim (always lowers);
-    # 'compact' stores them dense as (Tp/128, 128) rows and expands tiles
-    # in-register — ~128x less stat HBM traffic (ops/attention.py).
-    # Default compact: measured faster at the 124M bench shape once the
-    # r5 backward-kernel changes removed the other overheads (110.8k vs
-    # 108.9k tok/s; July 2026, earlier tree, not re-measured), and
-    # strictly less memory; tests/test_chip_compile.py compiles both
-    # layouts for v5e.
+    # The flash backward reads its softmax statistics one way: compact,
+    # as (Tp/128, 128) rows expanded in-register (ops/attention.py). The
+    # key stays only because the benchmark's files still name it; no
+    # other value is accepted (__post_init__).
     attention_stat_layout: str = "compact"
     remat: bool = False  # jax.checkpoint each block (HBM <-> FLOPs trade)
     # What remat saves: 'save_attention' keeps each block's attention
@@ -229,6 +224,11 @@ class TrainConfig:
             self.lr_decay_iters = self.max_iters
         # A checkpoint's JSON hands the pair back as a list.
         self.experts_held = tuple(int(v) for v in self.experts_held)
+        if self.attention_stat_layout != "compact":
+            raise ValueError(
+                f"attention_stat_layout={self.attention_stat_layout!r}: "
+                "'compact' is the flash backward's only statistic layout; "
+                "the key goes once the benchmark's files stop naming it")
         if self.profile_steps:  # fail fast, before any resources exist
             self.profile_range()
 
@@ -393,7 +393,6 @@ class GPTConfig:
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
     attention_impl: str = "auto"
-    attention_stat_layout: str = "compact"
     ring_layout: str = "zigzag"
     ring_block_impl: str = "auto"
     remat: bool = False
@@ -420,7 +419,6 @@ class GPTConfig:
             param_dtype=cfg.param_dtype,
             compute_dtype=cfg.compute_dtype,
             attention_impl=cfg.attention_impl,
-            attention_stat_layout=cfg.attention_stat_layout,
             ring_layout=cfg.ring_layout,
             ring_block_impl=cfg.ring_block_impl,
             remat=cfg.remat,

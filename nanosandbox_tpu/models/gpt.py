@@ -49,7 +49,7 @@ def attn_layout(cfg: GPTConfig, mesh: Any, T: int) -> str:
     resolves to no Pallas impl of its own, so it keeps (B, H, T, D)."""
     return attention_layout(
         cfg.n_head, cfg.n_embd // cfg.n_head, T, impl=cfg.attention_impl,
-        stat_layout=cfg.attention_stat_layout, mesh=mesh)
+        mesh=mesh)
 
 
 class CausalSelfAttention(nn.Module):
@@ -459,7 +459,6 @@ class CausalSelfAttention(nn.Module):
             y = ring_attention_sharded(
                 q, k, v, mesh=mesh, layout=cfg.ring_layout,
                 block_impl=cfg.ring_block_impl,
-                stat_layout=cfg.attention_stat_layout,
                 dropout_rate=ring_rate, dropout_seed=dropout_seed)
         else:
             attn_rng = None
@@ -498,14 +497,12 @@ class CausalSelfAttention(nn.Module):
                 y = ring_attention_sharded(
                     q, k, v, mesh=mesh, layout="contiguous",
                     block_impl=cfg.attention_impl,
-                    stat_layout=cfg.attention_stat_layout,
                     dropout_rate=rate, dropout_seed=seed)
             else:
                 y = causal_attention(
                     q, k, v, impl=cfg.attention_impl,
                     dropout_rate=0.0 if deterministic else cfg.dropout,
-                    dropout_rng=attn_rng,
-                    stat_layout=cfg.attention_stat_layout)
+                    dropout_rng=attn_rng)
         if layout == "bhtd":
             y = y.transpose(0, 2, 1, 3).reshape(B, T, C)
         if cache is not None and tp_mesh is not None:

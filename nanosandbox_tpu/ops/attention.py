@@ -7,12 +7,12 @@ equivalent is a Pallas kernel compiled by Mosaic: the forward pass is an
 online-softmax (flash) kernel that never materializes the (T, T) score
 matrix in HBM, tiled to the MXU (128-lane blocks, f32 accumulation).
 
-The backward pass is two Pallas kernels under jax.custom_vjp sharing the
-forward's per-row logsumexp L and the precomputed row term
-Drow = rowsum(dO * O): one computes dQ (parallel over query blocks), the
-other dK/dV (parallel over key blocks); both recompute P = exp(S - L)
-block-by-block instead of saving the (T, T) probability matrix, and both
-skip fully-masked blocks at the causal frontier.
+The backward pass is one Pallas kernel under jax.custom_vjp reading the
+forward's per-row logsumexp L and recomputing the row term
+Drow = rowsum(dO * O): parallel over key blocks, it accumulates dQ, dK and
+dV from one walk of the score tiles, recomputes P = exp(S - L)
+block-by-block instead of saving the (T, T) probability matrix, and skips
+fully-masked blocks at the causal frontier.
 
 Two HBM interfaces, one set of tile functions (_fwd_tile, _bwd_tile,
 _expand_stat_tile):
@@ -25,12 +25,13 @@ _expand_stat_tile):
     surrounds the kernels. Taken by training / eval on one device when
     the heads tile the lanes (attention_layout decides, from shapes and
     mesh); see the section heading further down.
-  * flash_attention / flash_attention_dropout / flash_attention_lse*:
-    q, k, v are (B, H, T, D), flattened to (B*H, T, D). D (head_dim) is
-    padded to a multiple of 128 lanes (64 runs unpadded) and T to a
-    multiple of the 128-row block inside the Pallas path. The entry ring
-    attention composes, and the one for every shape the first cannot take
-    (GPT-2 XL's 25 heads, D = 32, T off the 128 grid, a mesh).
+  * flash_attention: q, k, v are (B, H, T, D), flattened to (B*H, T, D);
+    ONE custom_vjp, with optional in-kernel dropout and an optional
+    logsumexp output. D (head_dim) is padded to a multiple of 128 lanes
+    (64 runs unpadded) and T to a multiple of the 128-row block inside the
+    Pallas path. The entry ring attention composes, and the one for every
+    shape the first cannot take (GPT-2 XL's 25 heads, D = 32, T off the
+    128 grid, a mesh).
 
 Beside them, on the projections' own (B, T, heads*D) layout too:
 flash_attention_gqa (grouped KV heads, an optional window: the afmoe family;
@@ -40,14 +41,13 @@ head that all query heads read, a value head of 128; the deepseek_v3 family;
 forward and ONE backward kernel built from the same tile functions with a
 second (q, k) pair). Each section's heading says what its kernels hold.
 
-Mosaic layout note, (B, H, T, D) entry: per-row softmax stats (L, Drow)
-leave its forward lane-REPLICATED as (..., T, 128) arrays — Mosaic
-requires the last two block dims of every operand to tile onto (8, 128)
-sublane x lane registers, so a (block_q, 1) column block cannot lower;
-broadcasting each row stat across the 128-lane minor dim (the layout
-jax's own pallas.ops.tpu.flash_attention uses) makes every BlockSpec
-legal at the cost of a 128x blowup in HBM. The (B, T, 3C) entry turns the
-column into a lane-dense (1, block_q) row in-register instead
+Mosaic layout note, (B, H, T, D) entry: the per-row logsumexp leaves
+its forward lane-REPLICATED as a (..., T, 128) array — Mosaic requires
+the last two block dims of every operand to tile onto (8, 128) sublane x
+lane registers, so a (block_q, 1) column block cannot lower. Only its
+first lane is kept as the residual, and the backward reads it compact,
+as (T // 128, 128) rows (_expand_stat_tile). The (B, T, 3C) entry turns
+the column into a lane-dense (1, block_q) row in-register instead
 (_stat_column_to_row) and never writes the replicated form.
 """
 
@@ -70,9 +70,8 @@ def _tpu_params(*semantics: str):
     vmem_limit_bytes raises Mosaic's default (~16 MB) VMEM budget check to
     100 MB of the chip's 128: the backward kernels stream q/do/o as
     full-T blocks, whose footprint scales with sequence length — at the
-    default budget the backward stops COMPILING between T=8192 and 16384
-    (and the 'replicated' stat layout already fails at 8192 with 12
-    heads). The limit is a constraint check, not an allocation: small
+    default budget the backward stops COMPILING between T=8192 and
+    16384. The limit is a constraint check, not an allocation: small
     kernels are unaffected (124M bench measured identical), and with it
     the single-shard envelope extends through T=32768 (r5, v5e)."""
     return pltpu.CompilerParams(dimension_semantics=semantics,
@@ -86,9 +85,8 @@ LANES = 128  # minor-dim register width; row stats are replicated across it
 
 __all__ = ["causal_attention", "causal_attention_qkv", "attention_layout",
            "causal_attention_gqa", "xla_attention", "flash_attention",
-           "flash_attention_dropout", "flash_attention_lse",
-           "flash_attention_lse_dropout", "flash_attention_qkv",
-           "flash_attention_gqa", "gqa_layout_supported", "gqa_route",
+           "flash_attention_qkv", "flash_attention_gqa",
+           "gqa_layout_supported", "gqa_route",
            "causal_attention_mla", "flash_attention_mla",
            "mla_layout_supported", "mla_route",
            "hash_dropout_keep_mask", "qk_prep", "qk_rotary",
@@ -101,13 +99,13 @@ __all__ = ["causal_attention", "causal_attention_qkv", "attention_layout",
 # ---------------------------------------------------------------------------
 #
 # Attention-probability dropout needs the SAME keep-mask in the forward and
-# both backward kernels (they recompute P block-by-block instead of saving
+# the backward kernels (they recompute P block-by-block instead of saving
 # it). pltpu.prng_* can't provide that — reseeding per tile would work on
 # hardware but the interpreter returns zero bits, so the CPU test tier
 # could never exercise the masked math. Instead the mask is a pure
 # counter-based hash (murmur3's fmix32 finalizer) over the GLOBAL
 # (q_pos, k_pos) element index, keyed by a per-call seed mixed with the
-# batch*head grid index: any (fwd, bwd-dq, bwd-dkv) kernel visiting the
+# batch*head grid index: any (forward, backward) kernel visiting the
 # same score element derives the same bit from plain uint32 VPU ops, in
 # compiled and interpret mode alike. ~6 integer ops per element, noise
 # against the two MXU matmuls that touch the same tile.
@@ -336,14 +334,6 @@ DEFAULT_BLOCK = 512  # measured on v5e: 512x512 runs ~2.3-3x faster than
 # 128x128 (fewer grid programs; the MXU pipeline amortizes over bigger
 # score tiles) while a 512x512 f32 score tile is only 1 MiB of VMEM.
 
-# The dKV kernel's own best blocking differs from dQ's: it is parallel
-# over KEY blocks with an inner loop over q blocks, so a WIDE block_k
-# (fewer grid programs, each amortizing the q/do/o streams) wins — r5
-# microbench on v5e at (16, 12, 1024, 64): dkv 512x1024 = 1.26 ms vs
-# 512x512 = 1.37 ms, and the combined fwd+bwd layer drops ~25% once the
-# two backward kernels stop sharing one compromise blocking.
-DKV_BLOCK_K = 1024
-
 
 def _clamp_blocks(T: int, block_q: int, block_k: int) -> tuple[int, int]:
     """Pick per-call block sizes: the largest value <= the requested block
@@ -489,28 +479,17 @@ def _pallas_flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # Pallas flash backward
 # ---------------------------------------------------------------------------
 #
-# Stat-operand layouts (--attention_stat_layout):
-#   'replicated' (default): per-row stats broadcast across the 128-lane
-#     minor dim, (B*H, Tp, LANES) f32 — every BlockSpec trivially legal,
-#     but the backward streams ~128x more stat bytes from HBM than the
-#     information content (~400 MB/layer/step at the 124M bench shape).
-#   'compact': the (Tp,) stat vector reshaped to (Tp//LANES, LANES) rows —
-#     dense in HBM (the minor dim carries REAL data, so XLA's (8, 128)
-#     tiling pads nothing). The catch: inside the kernel the (rows, LANES)
-#     tile must become a (block_q, 1) column, a cross-lane -> sublane
-#     relayout Mosaic cannot express as a plain reshape. _expand_stat_tile
-#     does it with a tiny selection matmul + masked rowsum — ops that
-#     always lower (MXU + VPU), no relayout primitive needed.
-#
-# Standard flash-attention backward split into two kernels sharing the
-# forward's per-row logsumexp L and the precomputed row term
-# Drow = rowsum(dO * O):
+# ONE key-parallel pass gives all three gradients from the forward's
+# per-row logsumexp L and the row term Drow = rowsum(dO * O):
 #   dQ_i  = sm_scale * sum_j dS_ij @ K_j
 #   dK_j  = sm_scale * sum_i dS_ij^T @ Q_i
 #   dV_j  = sum_i P_ij^T @ dO_i
 # with P = exp(S*scale - L) recomputed per block (never materialized at
 # (T, T)), dP = dO @ V^T, dS = P * (dP - Drow). The causal frontier skips
 # fully-masked blocks, halving the work the XLA-recompute backward did.
+# L reaches the kernel compact: the (Tp,) vector as (Tp//LANES, LANES)
+# rows, dense in HBM, each tile expanded to its (block_q, 1) column
+# in-register by _expand_stat_tile.
 
 def _expand_stat_tile(tile: jax.Array, row_offset, block_q: int) -> jax.Array:
     """FULL compact stat tile (R, LANES) -> the (block_q, 1) column for
@@ -572,108 +551,26 @@ def _bwd_tile(q, k, v, do, lse, drow, *, sm_scale: float, mask=None,
     return p_v, p * (dp - drow)
 
 
-def _flash_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
-                         lse_ref, dq_ref, *, block_q: int, block_k: int,
-                         sm_scale: float, causal: bool, has_dlse: bool,
-                         dropout_rate: float = 0.0,
-                         stat_layout: str = "replicated",
-                         local_heads: int = 1, hash_heads: int = 1,
-                         hash_seq_len: int = 0):
-    qi = pl.program_id(1)
-    if dropout_rate > 0.0:
-        mix = _dropout_tile_seed(seed_ref, pl.program_id(0),
-                                 local_heads, hash_heads)
-        q_off = seed_ref[3].astype(jnp.int32)
-        k_off = seed_ref[4].astype(jnp.int32)
-    q = q_ref[0]                                     # (bq, D) storage dtype
-    do = do_ref[0]
-    # The row term Drow = rowsum(dO * O) is computed HERE from the o
-    # block instead of arriving as a precomputed lane-replicated f32
-    # operand: that operand cost an XLA prepass plus ~350 MB/layer/step
-    # of HBM traffic at the 124M bench shape, vs a few VPU ops on data
-    # the kernel touches anyway. (bq, 1) column vectors are fine
-    # in-register; only memory-ref blocks must tile to (8, 128).
-    drow = jnp.sum(do.astype(jnp.float32) * o_ref[0].astype(jnp.float32),
-                   axis=1, keepdims=True)            # (bq, 1) f32
-    if stat_layout == "compact":
-        # lse_ref block: (1, S, Tp//LANES, LANES) full dense rows; the
-        # expansion matmul selects this q block's slice.
-        row0 = qi * (block_q // LANES)
-        lse = _expand_stat_tile(lse_ref[0, 0], row0, block_q)
-        if has_dlse:
-            # Fold the lse cotangent into the row term
-            # (ds = p * (dp - (drow - dlse))).
-            drow = drow - _expand_stat_tile(lse_ref[0, 1], row0, block_q)
-    else:
-        if has_dlse:
-            # lse_ref carries [lse | dlse] stacked on the minor dim.
-            drow = drow - lse_ref[0][:, LANES:LANES + 1]
-        lse = lse_ref[0][:, :1]                      # (bq, 1) f32
-    seq_len = k_ref.shape[1]
-    if causal:
-        num_kb_inner, num_kb = _causal_kb_range(qi, block_q, block_k)
-    else:
-        num_kb = seq_len // block_k
-        num_kb_inner = num_kb
-    q_pos = qi * block_q + lax.broadcasted_iota(jnp.int32,
-                                                (block_q, block_k), 0)
-
-    def body(j, dq_acc, *, masked: bool):
-        k = k_ref[0, pl.ds(j * block_k, block_k), :]
-        v = v_ref[0, pl.ds(j * block_k, block_k), :]
-        mask = keep = None
-        if masked:
-            k_pos = j * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            mask = q_pos >= k_pos
-        if dropout_rate > 0.0:
-            keep = _dropout_keep(mix, q_off + qi * block_q,
-                                 k_off + j * block_k,
-                                 (block_q, block_k), hash_seq_len,
-                                 dropout_rate)
-        _, ds = _bwd_tile(q, k, v, do, lse, drow, sm_scale=sm_scale,
-                          mask=mask, keep=keep, dropout_rate=dropout_rate)
-        return dq_acc + lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    dq = lax.fori_loop(0, num_kb_inner, functools.partial(body, masked=False),
-                       jnp.zeros((block_q, q.shape[1]), jnp.float32))
-    dq = lax.fori_loop(num_kb_inner, num_kb,
-                       functools.partial(body, masked=True), dq)
-    dq_ref[0] = (dq * sm_scale).astype(dq_ref.dtype)
-
-
 def _flash_bwd_tiles_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
-                            lse_ref, *out_refs, block_q: int, block_k: int,
-                            sm_scale: float, causal: bool, has_dlse: bool,
-                            with_dq: bool, dropout_rate: float = 0.0,
-                            stat_layout: str = "replicated",
+                            lse_ref, dq_ref, dk_ref, dv_ref, *, block_q: int,
+                            block_k: int, sm_scale: float, causal: bool,
+                            has_dlse: bool, dropout_rate: float = 0.0,
                             local_heads: int = 1, hash_heads: int = 1,
                             hash_seq_len: int = 0):
-    """The key-parallel backward walk, shared by BOTH backward strategies.
+    """The one-pass backward: dQ, dK and dV from one walk of the tiles.
 
     Grid (batch*head, key blocks); inner loop over the causal q-block
     range, computing per tile: p = exp(s - L), dv += p~^T dO,
-    dp = dO V^T, ds = p (dp - Drow), dk += ds^T Q.
-
-    with_dq=False: out_refs = (dk_ref, dv_ref) — the split strategy's
-    dKV kernel (a separate q-parallel kernel computes dQ).
-    with_dq=True: out_refs = (dq_ref, dk_ref, dv_ref) — the FUSED
-    one-pass strategy: the same ds additionally accumulates dq += ds K
-    into an f32 output block that stays RESIDENT in VMEM across the
-    (sequential, 'arbitrary'-semantics) key grid dimension and flushes
-    once per batch*head. The split backward recomputes s/exp/dp twice
-    (once per kernel); fused computes each causal tile once and feeds
-    all three gradients — r5 measured 124M bench 147 -> 141.6 ms. Cost:
+    dp = dO V^T, ds = p (dp - Drow), dk += ds^T Q, dq += ds K. dq
+    accumulates into an f32 output block that stays RESIDENT in VMEM
+    across the (sequential, 'arbitrary'-semantics) key grid dimension and
+    flushes once per batch*head, so each causal tile's s, exp and dp are
+    computed once for all three gradients — r5 measured 124M bench 147 ->
+    141.6 ms against a q-parallel dQ kernel beside a dK/dV walk. Cost:
     a (Tp, D) f32 VMEM accumulator (256 KB at the 124M shape); dq is
     scaled by sm_scale and cast OUTSIDE the kernel (XLA fuses both into
     the unpad copy).
     """
-    if with_dq:
-        dq_ref, dk_ref, dv_ref = out_refs
-    else:
-        dk_ref, dv_ref = out_refs
     ki = pl.program_id(1)
     if dropout_rate > 0.0:
         mix = _dropout_tile_seed(seed_ref, pl.program_id(0),
@@ -696,31 +593,34 @@ def _flash_bwd_tiles_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
     k_pos = ki * block_k + lax.broadcasted_iota(jnp.int32,
                                                 (block_q, block_k), 1)
 
-    if with_dq:
-        # The dq accumulator is revisited across ki: zero on first visit.
-        @pl.when(ki == 0)
-        def _zero_dq():
-            dq_ref[0] = jnp.zeros_like(dq_ref[0])
+    # The dq accumulator is revisited across ki: zero on first visit.
+    @pl.when(ki == 0)
+    def _zero_dq():
+        dq_ref[0] = jnp.zeros_like(dq_ref[0])
 
     def body(i, carry, *, masked: bool):
         dk_acc, dv_acc = carry
         q = q_ref[0, pl.ds(i * block_q, block_q), :]
         do = do_ref[0, pl.ds(i * block_q, block_q), :]
-        # Drow recomputed in-kernel from o (see _flash_bwd_dq_kernel).
+        # The row term Drow = rowsum(dO * O) is computed HERE from the o
+        # block instead of arriving as a precomputed lane-replicated f32
+        # operand: that operand cost an XLA prepass plus ~350
+        # MB/layer/step of HBM traffic at the 124M bench shape, vs a few
+        # VPU ops on data the kernel touches anyway. (bq, 1) column
+        # vectors are fine in-register; only memory-ref blocks must tile
+        # to (8, 128).
         drow = jnp.sum(
             do.astype(jnp.float32)
             * o_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32),
             axis=1, keepdims=True)                    # (bq, 1) f32
-        if stat_layout == "compact":
-            row0 = i * (block_q // LANES)
-            lse = _expand_stat_tile(lse_ref[0, 0], row0, block_q)
-            if has_dlse:
-                drow = drow - _expand_stat_tile(lse_ref[0, 1], row0, block_q)
-        else:
-            stats = lse_ref[0, pl.ds(i * block_q, block_q), :]
-            if has_dlse:
-                drow = drow - stats[:, LANES:LANES + 1]
-            lse = stats[:, :1]                        # (bq, 1) f32
+        # lse_ref block: (1, S, Tp//LANES, LANES) full dense rows; the
+        # expansion matmul selects this q block's slice. A dlse
+        # cotangent folds into the row term (ds = p * (dp - (drow -
+        # dlse))).
+        row0 = i * (block_q // LANES)
+        lse = _expand_stat_tile(lse_ref[0, 0], row0, block_q)
+        if has_dlse:
+            drow = drow - _expand_stat_tile(lse_ref[0, 1], row0, block_q)
         mask = keep = None
         if masked:
             q_pos = i * block_q + lax.broadcasted_iota(
@@ -740,12 +640,11 @@ def _flash_bwd_tiles_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
         dk_acc = dk_acc + lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)       # (bk, D)
-        if with_dq:
-            dq_blk = dq_ref[0, pl.ds(i * block_q, block_q), :]
-            dq_ref[0, pl.ds(i * block_q, block_q), :] = (
-                dq_blk + lax.dot_general(
-                    ds, k, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32))  # f32 accum
+        dq_blk = dq_ref[0, pl.ds(i * block_q, block_q), :]
+        dq_ref[0, pl.ds(i * block_q, block_q), :] = (
+            dq_blk + lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))  # f32 accum
         return dk_acc, dv_acc
 
     D = k.shape[1]
@@ -763,50 +662,30 @@ def _flash_bwd_tiles_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-# Backward strategy: 'fused' (one pass, dq resident — the r5 default) or
-# 'split' (q-parallel dQ kernel + key-parallel dKV walk). Both strategies
-# share _flash_bwd_tiles_kernel for the dk/dv math, so they cannot drift
-# there; tests/test_attention.py pins fused-vs-split gradient parity so
-# the split path stays exercised. NOT an automatic fallback: nothing
-# degrades fused -> split (or pallas -> XLA) on a compile error.
-BWD_IMPL = "fused"
-
-
 def _pallas_flash_bwd(q, k, v, o, lse, do, *, causal: bool, sm_scale: float,
                       block_q: int = DEFAULT_BLOCK,
                       block_k: int = DEFAULT_BLOCK,
                       interpret: bool = False, dlse=None,
                       dropout_rate: float = 0.0, seed=None,
-                      stat_layout: str = "replicated",
                       hash_heads: int | None = None,
                       hash_seq_len: int | None = None):
     """lse arrives compact and T-padded from the forward: (B*H, Tp, 1) f32.
+    The kernel reads it as (Tp//LANES, LANES) rows.
 
-    stat_layout picks the HBM operand the kernels read it through:
-    'replicated' broadcasts both row stats across the 128-lane minor dim
-    (transiently, here); 'compact' reshapes the dense vector to
-    (Tp//LANES, LANES) rows and the kernels expand tiles in-register
-    (_expand_stat_tile) — ~128x less stat traffic.
-
-    dlse (optional, (B, H, T) f32): cotangent of the logsumexp output for
-    callers of flash_attention_lse. Since d lse / d s = p, the extra term
-    folds into the existing row stat: ds = p * (dp - (drow - dlse)).
+    dlse (optional, (B, H, T) f32): cotangent of the logsumexp output of
+    flash_attention(..., with_lse=True). Since d lse / d s = p, the extra
+    term folds into the existing row stat: ds = p * (dp - (drow - dlse)).
     dV has no lse dependence (dv = p^T do only)."""
-    if stat_layout not in ("replicated", "compact"):
-        raise ValueError(f"unknown attention stat_layout: {stat_layout!r} "
-                         "(expected 'replicated' or 'compact')")
     block_q, block_k = _clamp_blocks(q.shape[2], block_q, block_k)
-    # The dKV kernel gets its own (wider) key blocking — see DKV_BLOCK_K.
-    dkv_block_k = _clamp_blocks(q.shape[2], block_q,
-                                max(block_k, DKV_BLOCK_K))[1]
+    # Both blocks divide the 128-padded T (_clamp_blocks), so every
+    # operand pads to it, as in the forward.
     qf, kf, vf, (B, H, T, D, Tp, Dp, pad_T, pad_D) = _pad_qkv(
-        q, k, v, block_q, max(block_k, dkv_block_k), causal)
+        q, k, v, block_q, block_k, causal)
     dof = _pad_qkv(do, do, do, block_q, block_k, causal)[0]
     of = _pad_qkv(o, o, o, block_q, block_k, causal)[0]
-    # Drow is NOT built here — both kernels recompute it in-register from
-    # (do, o), which they read anyway. When the caller supplies a dlse
-    # cotangent (flash_attention_lse), it rides along in the same stats
-    # operand so the kernels keep a single stats ref.
+    # Drow is NOT built here — the kernel recomputes it in-register from
+    # (do, o), which it reads anyway. A dlse cotangent rides along in the
+    # same stats operand so the kernel keeps a single stats ref.
     has_dlse = dlse is not None
     dlsef = None
     if has_dlse:
@@ -814,30 +693,14 @@ def _pallas_flash_bwd(q, k, v, o, lse, do, *, causal: bool, sm_scale: float,
         if pad_T:
             d = jnp.pad(d, [(0, 0), (0, 0), (0, pad_T)])
         dlsef = d.reshape(B * H, Tp, 1)
-    if stat_layout == "compact":
-        # (B*H, S, Tp//LANES, LANES): dense rows, S in {1, 2} stacks
-        # [lse, dlse?] on a dedicated dim so one contiguous block serves
-        # each q-block's slice of both stats.
-        parts = [lse[..., 0].reshape(B * H, Tp // LANES, LANES)]
-        if has_dlse:
-            parts.append(dlsef[..., 0].reshape(B * H, Tp // LANES, LANES))
-        statsf = jnp.stack(parts, axis=1)
-        S = len(parts)
-        # Both kernels take the FULL (tiny: Tp*4 bytes/bh) stats block —
-        # Mosaic requires the last two block dims be 8/128-divisible OR
-        # equal to the array dims, and block_q//LANES rows is neither.
-        full_stats = pl.BlockSpec((1, S, Tp // LANES, LANES),
-                                  lambda b, i: (b, 0, 0, 0))
-        dq_stats_spec = dkv_stats_spec = full_stats
-    else:
-        statsf = jnp.broadcast_to(lse, (B * H, Tp, LANES))
-        if has_dlse:
-            statsf = jnp.concatenate(
-                [statsf, jnp.broadcast_to(dlsef, (B * H, Tp, LANES))],
-                axis=-1)
-        W = statsf.shape[-1]  # LANES or 2*LANES
-        dq_stats_spec = pl.BlockSpec((1, block_q, W), lambda b, i: (b, i, 0))
-        dkv_stats_spec = pl.BlockSpec((1, Tp, W), lambda b, j: (b, 0, 0))
+    # (B*H, S, Tp//LANES, LANES): dense rows, S in {1, 2} stacks
+    # [lse, dlse?] on a dedicated dim so one contiguous block serves each
+    # q-block's slice of both stats.
+    parts = [lse[..., 0].reshape(B * H, Tp // LANES, LANES)]
+    if has_dlse:
+        parts.append(dlsef[..., 0].reshape(B * H, Tp // LANES, LANES))
+    statsf = jnp.stack(parts, axis=1)
+    S = len(parts)
 
     hash_heads = hash_heads if hash_heads is not None else H
     hash_seq_len = hash_seq_len if hash_seq_len is not None else Tp
@@ -845,301 +708,79 @@ def _pallas_flash_bwd(q, k, v, o, lse, do, *, causal: bool, sm_scale: float,
     seed_arg = _dropout_seed_arg(seed, dropout_rate)
 
     unpad = lambda g: g.reshape(B, H, Tp, Dp)[:, :, :T, :D]
-    if BWD_IMPL == "fused":
-        # One pass over the causal tiles computing all three grads; dq is
-        # an f32 accumulator block resident across the (sequential) key
-        # grid dimension, scaled+cast outside (XLA fuses both into the
-        # unpad copy). dkv_stats_spec already serves the per-q-block
-        # stats reads this kernel does.
-        grid_f = (B * H, Tp // block_k)
-        dq, dk, dv = pl.pallas_call(
-            functools.partial(_flash_bwd_tiles_kernel, block_q=block_q,
-                              block_k=block_k, sm_scale=sm_scale,
-                              causal=causal, has_dlse=has_dlse,
-                              with_dq=True,
-                              dropout_rate=dropout_rate,
-                              stat_layout=stat_layout, local_heads=H,
-                              hash_heads=hash_heads,
-                              hash_seq_len=hash_seq_len),
-            grid=grid_f,
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, Tp, Dp), lambda b, j: (b, 0, 0)),
-                pl.BlockSpec((1, block_k, Dp), lambda b, j: (b, j, 0)),
-                pl.BlockSpec((1, block_k, Dp), lambda b, j: (b, j, 0)),
-                pl.BlockSpec((1, Tp, Dp), lambda b, j: (b, 0, 0)),
-                pl.BlockSpec((1, Tp, Dp), lambda b, j: (b, 0, 0)),
-                dkv_stats_spec,
-            ],
-            out_specs=[
-                pl.BlockSpec((1, Tp, Dp), lambda b, j: (b, 0, 0)),
-                pl.BlockSpec((1, block_k, Dp), lambda b, j: (b, j, 0)),
-                pl.BlockSpec((1, block_k, Dp), lambda b, j: (b, j, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((B * H, Tp, Dp), jnp.float32),
-                jax.ShapeDtypeStruct((B * H, Tp, Dp), k.dtype),
-                jax.ShapeDtypeStruct((B * H, Tp, Dp), v.dtype),
-            ],
-            # The key grid dim is 'arbitrary' (sequential): the resident
-            # dq block's read-modify-write across ki requires it.
-            compiler_params=None if interpret else _tpu_params(
-                "parallel", "arbitrary"),
-            interpret=interpret,
-        )(seed_arg, qf, kf, vf, of, dof, statsf)
-        return (unpad(dq * sm_scale).astype(q.dtype),
-                unpad(dk).astype(k.dtype), unpad(dv).astype(v.dtype))
-
-    grid_q = (B * H, Tp // block_q)
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, block_q=block_q,
-                          block_k=block_k, sm_scale=sm_scale, causal=causal,
-                          has_dlse=has_dlse, dropout_rate=dropout_rate,
-                          stat_layout=stat_layout, local_heads=H,
-                          hash_heads=hash_heads, hash_seq_len=hash_seq_len),
-        grid=grid_q,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Tp, Dp), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Tp, Dp), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
-            dq_stats_spec,
-        ],
-        out_specs=pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
-        # Grads leave the kernel already in the input dtype: the f32
-        # accumulators are rounded on the register->VMEM write, which
-        # halves the grad HBM writes AND deletes the XLA cast pass that a
-        # f32 out_shape forced afterwards (r5 microbench: the three
-        # (B*H, Tp, 128-padded) f32 grad tensors cost ~1 ms/layer in
-        # write+cast traffic at the 124M bench shape).
-        out_shape=jax.ShapeDtypeStruct((B * H, Tp, Dp), q.dtype),
-        compiler_params=None if interpret else _tpu_params(
-            "parallel", "parallel"),
-        interpret=interpret,
-    )(seed_arg, qf, kf, vf, of, dof, statsf)
-
-    grid_k = (B * H, Tp // dkv_block_k)
-    dk, dv = pl.pallas_call(
+    dq, dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_tiles_kernel, block_q=block_q,
-                          block_k=dkv_block_k, sm_scale=sm_scale,
+                          block_k=block_k, sm_scale=sm_scale,
                           causal=causal, has_dlse=has_dlse,
-                          with_dq=False,
-                          dropout_rate=dropout_rate,
-                          stat_layout=stat_layout, local_heads=H,
-                          hash_heads=hash_heads, hash_seq_len=hash_seq_len),
-        grid=grid_k,
+                          dropout_rate=dropout_rate, local_heads=H,
+                          hash_heads=hash_heads,
+                          hash_seq_len=hash_seq_len),
+        grid=(B * H, Tp // block_k),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, Tp, Dp), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, dkv_block_k, Dp), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, dkv_block_k, Dp), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, Dp), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, Dp), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, Tp, Dp), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, Tp, Dp), lambda b, j: (b, 0, 0)),
-            dkv_stats_spec,
+            # The FULL (tiny: Tp*4 bytes/bh) stats block: Mosaic requires
+            # the last two block dims be 8/128-divisible OR equal to the
+            # array dims, and block_q//LANES rows is neither.
+            pl.BlockSpec((1, S, Tp // LANES, LANES),
+                         lambda b, j: (b, 0, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, dkv_block_k, Dp), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, dkv_block_k, Dp), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, Tp, Dp), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, block_k, Dp), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, Dp), lambda b, j: (b, j, 0)),
         ],
         out_shape=[
+            jax.ShapeDtypeStruct((B * H, Tp, Dp), jnp.float32),
             jax.ShapeDtypeStruct((B * H, Tp, Dp), k.dtype),
             jax.ShapeDtypeStruct((B * H, Tp, Dp), v.dtype),
         ],
+        # The key grid dim is 'arbitrary' (sequential): the resident dq
+        # block's read-modify-write across ki requires it.
         compiler_params=None if interpret else _tpu_params(
-            "parallel", "parallel"),
+            "parallel", "arbitrary"),
         interpret=interpret,
     )(seed_arg, qf, kf, vf, of, dof, statsf)
-
-    return (unpad(dq).astype(q.dtype), unpad(dk).astype(k.dtype),
-            unpad(dv).astype(v.dtype))
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention(q, k, v, causal: bool = True, sm_scale: float | None = None,
-                    interpret: bool = False, stat_layout: str = "replicated"):
-    """Flash attention: Pallas forward AND backward (both causal-aware).
-
-    stat_layout ('replicated' | 'compact') picks the backward's softmax-
-    stat operand layout; forward math is identical either way."""
-    if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
-    out, _ = _pallas_flash_fwd(q, k, v, causal=causal, sm_scale=sm_scale,
-                               interpret=interpret)
-    return out
-
-
-def _flash_fwd_rule(q, k, v, causal, sm_scale, interpret,
-                    stat_layout="replicated"):
-    from jax.ad_checkpoint import checkpoint_name
-
-    if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
-    o, lse = _pallas_flash_fwd(q, k, v, causal=causal, sm_scale=sm_scale,
-                               interpret=interpret)
-    # Store the residual COMPACT (B*H, Tp, 1): the lane-replicated
-    # (..., 128) form would be the largest per-layer activation held
-    # across the whole backward (128x a (B, H, T) vector); the backward
-    # re-broadcasts it transiently right before its pallas_call.
-    #
-    # checkpoint_name tags make these residuals SAVEABLE under
-    # remat_policy='save_attention' (models/gpt.py): a jax.checkpoint
-    # region discards custom_vjp residuals by default, which would
-    # re-run this whole forward kernel during the backward — tagging
-    # o and lse (q/k/v recompute from the block input via one cheap
-    # dense matmul) is what actually elides the O(T^2) recompute.
-    o = checkpoint_name(o, "attn_out")
-    return o, (q, k, v, o, checkpoint_name(lse[..., :1], "attn_lse"))
-
-
-def _flash_bwd_rule(causal, sm_scale, interpret, stat_layout, res, do):
-    q, k, v, o, lse = res
-    if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
-    return _pallas_flash_bwd(q, k, v, o, lse, do, causal=causal,
-                             sm_scale=sm_scale, interpret=interpret,
-                             stat_layout=stat_layout)
-
-
-flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def flash_attention_dropout(q, k, v, seed, causal: bool = True,
-                            sm_scale: float | None = None,
-                            dropout_rate: float = 0.0,
-                            interpret: bool = False,
-                            stat_layout: str = "replicated"):
-    """Flash attention with attention-probability dropout IN the kernels.
-
-    Semantically o = dropout(softmax(s)) @ v — identical regularization to
-    xla_attention's dropout path (nanoGPT's attn_dropout, the reference's
-    exercised ``--dropout`` key, ipynb:74-77) but at flash-kernel speed:
-    round 3's convergence runs fell to the ~10%-MFU XLA fallback solely
-    because dropout wasn't expressible here (r3 VERDICT weak #1).
-
-    seed: (1,) uint32 array. The keep-mask is a counter-based hash of the
-    global element position keyed by (seed, batch*head), so the forward
-    and both backward kernels reconstruct the same mask without ever
-    materializing it; the same (seed, shapes) pair always yields the same
-    mask, making the op a pure function of its inputs (remat-safe).
-    """
-    if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
-    out, _ = _pallas_flash_fwd(q, k, v, causal=causal, sm_scale=sm_scale,
-                               interpret=interpret,
-                               dropout_rate=dropout_rate, seed=seed)
-    return out
-
-
-def _flash_dropout_fwd_rule(q, k, v, seed, causal, sm_scale, dropout_rate,
-                            interpret, stat_layout="replicated"):
-    from jax.ad_checkpoint import checkpoint_name
-
-    if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
-    o, lse = _pallas_flash_fwd(q, k, v, causal=causal, sm_scale=sm_scale,
-                               interpret=interpret,
-                               dropout_rate=dropout_rate, seed=seed)
-    o = checkpoint_name(o, "attn_out")  # see _flash_fwd_rule
-    return o, (q, k, v, o, checkpoint_name(lse[..., :1], "attn_lse"), seed)
-
-
-def _flash_dropout_bwd_rule(causal, sm_scale, dropout_rate, interpret,
-                            stat_layout, res, do):
-    q, k, v, o, lse, seed = res
-    if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
-    dq, dk, dv = _pallas_flash_bwd(q, k, v, o, lse, do, causal=causal,
-                                   sm_scale=sm_scale, interpret=interpret,
-                                   dropout_rate=dropout_rate, seed=seed,
-                                   stat_layout=stat_layout)
-    return dq, dk, dv, None
-
-
-flash_attention_dropout.defvjp(_flash_dropout_fwd_rule,
-                               _flash_dropout_bwd_rule)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention_lse(q, k, v, causal: bool = True,
-                        sm_scale: float | None = None,
-                        interpret: bool = False,
-                        stat_layout: str = "replicated"):
-    """Flash attention that ALSO returns the per-row logsumexp.
-
-    Returns (out (B, H, T, D), lse (B, H, T) f32) where
-    lse = log sum_k exp(s_k * sm_scale). This is the block primitive ring
-    attention composes: per-chunk (out_j, lse_j) pairs merge exactly via
-    out = sum_j exp(lse_j - logsumexp_j lse_j) * out_j, so the ring can
-    run the real Mosaic kernel per block instead of materializing
-    (Tc, Tc) score tensors (round-2 VERDICT weak #1). Differentiable in
-    both outputs: the lse cotangent folds into the backward's row stat
-    (see _pallas_flash_bwd).
-    """
-    if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
-    out, lse = _pallas_flash_fwd(q, k, v, causal=causal, sm_scale=sm_scale,
-                                 interpret=interpret)
-    return out, _compact_lse(lse, q.shape)
-
-
-def _compact_lse(lse, qshape):
-    """(B*H, Tp, LANES) lane-replicated -> (B, H, T) compact."""
-    B, H, T, _ = qshape
-    return lse[:, :T, 0].reshape(B, H, T)
-
-
-def _flash_lse_fwd_rule(q, k, v, causal, sm_scale, interpret,
-                        stat_layout="replicated"):
-    from jax.ad_checkpoint import checkpoint_name
-
-    if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
-    o, lse = _pallas_flash_fwd(q, k, v, causal=causal, sm_scale=sm_scale,
-                               interpret=interpret)
-    o = checkpoint_name(o, "attn_out")  # see _flash_fwd_rule
-    return ((o, _compact_lse(lse, q.shape)),
-            (q, k, v, o, checkpoint_name(lse[..., :1], "attn_lse")))
-
-
-def _flash_lse_bwd_rule(causal, sm_scale, interpret, stat_layout, res, cts):
-    q, k, v, o, lse = res
-    do, dlse = cts
-    if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
-    return _pallas_flash_bwd(q, k, v, o, lse, do, causal=causal,
-                             sm_scale=sm_scale, interpret=interpret,
-                             dlse=dlse, stat_layout=stat_layout)
-
-
-flash_attention_lse.defvjp(_flash_lse_fwd_rule, _flash_lse_bwd_rule)
+    return (unpad(dq * sm_scale).astype(q.dtype),
+            unpad(dk).astype(k.dtype), unpad(dv).astype(v.dtype))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
-def flash_attention_lse_dropout(q, k, v, seed, causal: bool = True,
-                                sm_scale: float | None = None,
-                                dropout_rate: float = 0.0,
-                                interpret: bool = False,
-                                stat_layout: str = "replicated",
-                                hash_heads: int | None = None,
-                                hash_seq_len: int | None = None):
-    """flash_attention_lse + in-kernel dropout keyed on GLOBAL coordinates
-    — the block primitive regularized ring attention composes.
+def flash_attention(q, k, v, seed=None, causal: bool = True,
+                    sm_scale: float | None = None, dropout_rate: float = 0.0,
+                    with_lse: bool = False, interpret: bool = False,
+                    hash_heads: int | None = None,
+                    hash_seq_len: int | None = None):
+    """Flash attention over (B, H, T, D): a Pallas forward and a one-pass
+    Pallas backward, both causal-aware.
 
-    seed: (SEED_WORDS,) uint32 [seed, b_off, h_off, q_off, k_off] (or a
-    (1,) seed for the degenerate unsharded case). hash_heads /
-    hash_seq_len are the GLOBAL head count and sequence length the mask
-    hash is keyed over, so every ring step (and the dq/dkv backward
-    kernels recomputing P) reconstructs the same mask for the same global
-    score element regardless of which shard computes it.
+    Returns o (B, H, T, D), or (o, lse (B, H, T) f32) with ``with_lse``,
+    where lse = log sum_k exp(s_k * sm_scale). That pair is the block
+    primitive ring attention composes: per-chunk (out_j, lse_j) pairs
+    merge exactly via out = sum_j exp(lse_j - logsumexp_j lse_j) * out_j,
+    so the ring runs the real Mosaic kernel per block instead of
+    materializing (Tc, Tc) score tensors. Differentiable in both outputs:
+    the lse cotangent folds into the backward's row stat (see
+    _pallas_flash_bwd).
 
-    The returned lse is the logsumexp of the UNMASKED scores (dropout
-    applies to normalized probabilities; the normalizer is mask-free), so
-    ring merging of (out_j, lse_j) pairs over dropout blocks is exact:
-    the masked probabilities are rescaled by the same global normalizer
-    the unmasked merge computes.
+    dropout_rate > 0: attention-probability dropout IN the kernels,
+    o = dropout(softmax(s)) @ v — xla_attention's dropout (nanoGPT's
+    attn_dropout, the reference's ``--dropout``, ipynb:74-77) at
+    flash-kernel speed. ``seed`` is then a (1,) uint32 array, or the
+    (SEED_WORDS,) vector [seed, b_off, h_off, q_off, k_off] of a ring
+    block; None without dropout. The keep-mask is a counter-based hash of
+    the GLOBAL element position keyed by (seed, batch*head), so the
+    forward and the backward rebuild the same mask without materializing
+    it, and the same (seed, shapes) always give the same mask (a pure
+    function of its inputs: remat-safe). hash_heads / hash_seq_len are the
+    global head count and sequence length the hash is keyed over (ring
+    callers); default the local heads and the padded length. The lse is
+    that of the UNMASKED scores (dropout applies to normalized
+    probabilities), so ring merging over dropout blocks is exact too.
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
@@ -1148,13 +789,17 @@ def flash_attention_lse_dropout(q, k, v, seed, causal: bool = True,
                                  dropout_rate=dropout_rate, seed=seed,
                                  hash_heads=hash_heads,
                                  hash_seq_len=hash_seq_len)
-    return out, _compact_lse(lse, q.shape)
+    return (out, _compact_lse(lse, q.shape)) if with_lse else out
 
 
-def _flash_lse_dropout_fwd_rule(q, k, v, seed, causal, sm_scale,
-                                dropout_rate, interpret,
-                                stat_layout="replicated",
-                                hash_heads=None, hash_seq_len=None):
+def _compact_lse(lse, qshape):
+    """(B*H, Tp, LANES) lane-replicated -> (B, H, T) compact."""
+    B, H, T, _ = qshape
+    return lse[:, :T, 0].reshape(B, H, T)
+
+
+def _flash_fwd_rule(q, k, v, seed, causal, sm_scale, dropout_rate, with_lse,
+                    interpret, hash_heads, hash_seq_len):
     from jax.ad_checkpoint import checkpoint_name
 
     if sm_scale is None:
@@ -1164,29 +809,36 @@ def _flash_lse_dropout_fwd_rule(q, k, v, seed, causal, sm_scale,
                                dropout_rate=dropout_rate, seed=seed,
                                hash_heads=hash_heads,
                                hash_seq_len=hash_seq_len)
-    o = checkpoint_name(o, "attn_out")  # see _flash_fwd_rule
-    return ((o, _compact_lse(lse, q.shape)),
-            (q, k, v, o, checkpoint_name(lse[..., :1], "attn_lse"), seed))
+    # Store the residual COMPACT (B*H, Tp, 1): the lane-replicated
+    # (..., 128) form would be the largest per-layer activation held
+    # across the whole backward (128x a (B, H, T) vector).
+    #
+    # checkpoint_name tags make these residuals SAVEABLE under
+    # remat_policy='save_attention' (models/gpt.py): a jax.checkpoint
+    # region discards custom_vjp residuals by default, which would
+    # re-run this whole forward kernel during the backward — tagging
+    # o and lse (q/k/v recompute from the block input via one cheap
+    # dense matmul) is what actually elides the O(T^2) recompute.
+    o = checkpoint_name(o, "attn_out")
+    out = (o, _compact_lse(lse, q.shape)) if with_lse else o
+    return out, (q, k, v, o, checkpoint_name(lse[..., :1], "attn_lse"), seed)
 
 
-def _flash_lse_dropout_bwd_rule(causal, sm_scale, dropout_rate, interpret,
-                                stat_layout, hash_heads, hash_seq_len,
-                                res, cts):
+def _flash_bwd_rule(causal, sm_scale, dropout_rate, with_lse, interpret,
+                    hash_heads, hash_seq_len, res, cts):
     q, k, v, o, lse, seed = res
-    do, dlse = cts
+    do, dlse = cts if with_lse else (cts, None)
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     dq, dk, dv = _pallas_flash_bwd(q, k, v, o, lse, do, causal=causal,
                                    sm_scale=sm_scale, interpret=interpret,
                                    dlse=dlse, dropout_rate=dropout_rate,
-                                   seed=seed, stat_layout=stat_layout,
-                                   hash_heads=hash_heads,
+                                   seed=seed, hash_heads=hash_heads,
                                    hash_seq_len=hash_seq_len)
     return dq, dk, dv, None
 
 
-flash_attention_lse_dropout.defvjp(_flash_lse_dropout_fwd_rule,
-                                   _flash_lse_dropout_bwd_rule)
+flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 # ---------------------------------------------------------------------------
@@ -1409,8 +1061,8 @@ def _flash_bwd_qkv_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
                           sems, *, block_q: int, block_k: int,
                           sm_scale: float, n_head: int, head_dim: int,
                           dropout_rate: float = 0.0):
-    """The fused one-pass backward (see _flash_bwd_tiles_kernel,
-    with_dq=True) for one (batch row, head group, key block).
+    """The one-pass backward (see _flash_bwd_tiles_kernel) for one (batch
+    row, head group, key block).
 
     q_ref / o_ref / do_ref (1, T, W), k_ref / v_ref (1, block_k, W),
     lse_ref (1, group, T // 128, 128); dqkv_ref is the whole (B, T, 3C)
@@ -1606,7 +1258,7 @@ def flash_attention_qkv(qkv, seed, n_head: int, dropout_rate: float = 0.0,
 
     seed: (1,) uint32 for in-kernel attention dropout (None when
     dropout_rate == 0); the keep-mask is hash_dropout_keep_mask's, bit for
-    bit, as in flash_attention_dropout. Shapes must satisfy
+    bit, as in flash_attention. Shapes must satisfy
     qkv_layout_supported; everything else goes through causal_attention.
     """
     return _pallas_flash_fwd_qkv(qkv, n_head, interpret=interpret,
@@ -2652,20 +2304,17 @@ def resolve_attention_impl(impl: str) -> str:
 
 
 def attention_layout(n_head: int, head_dim: int, T: int, *,
-                     impl: str = "auto", stat_layout: str = "compact",
-                     mesh=None) -> str:
+                     impl: str = "auto", mesh=None) -> str:
     """Which HBM interface the training / eval attention of these shapes
     takes: 'btc' (flash_attention_qkv: the kernels read qkv (B, T, 3C) and
     write o (B, T, C), no layout copy) or 'bhtd' (causal_attention over
     (B, H, T, D) after the transposes). Decided once, at trace time, from
     what the code can observe: the resolved impl, the shapes and the bound
-    mesh. 'btc' needs this file's Pallas kernels, the compact statistic
-    layout (the only one the new kernels have), head groups that tile the
+    mesh. 'btc' needs this file's Pallas kernels, head groups that tile the
     128 lanes (qkv_layout_supported: not GPT-2 XL's 25 heads) and one
     device: on a mesh the kernels run inside ring_attention_sharded's
     shard_map shell, which composes the (B, H, T, D) entry."""
     if (resolve_attention_impl(impl) in ("pallas", "pallas_interpret")
-            and stat_layout == "compact"
             and (mesh is None or mesh.size == 1)
             and qkv_layout_supported(n_head, head_dim, T)):
         return "btc"
@@ -2695,41 +2344,32 @@ def causal_attention_qkv(qkv: jax.Array, n_head: int, *, impl: str = "auto",
 def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                      impl: str = "auto", sm_scale: float | None = None,
                      dropout_rate: float = 0.0,
-                     dropout_rng: jax.Array | None = None,
-                     stat_layout: str = "replicated") -> jax.Array:
+                     dropout_rng: jax.Array | None = None) -> jax.Array:
     """Causal attention over (B, H, T, D) tensors.
 
     impl: 'auto' (Pallas on a tpu backend — a compile error propagates —
     and XLA on any other; see resolve_attention_impl), 'pallas',
     'pallas_interpret' (for CPU tests), or 'xla'.
 
-    stat_layout ('replicated' | 'compact'): the flash backward's softmax-
-    stat operand layout (--attention_stat_layout); ignored by the xla
-    path.
-
-    Attention-probability dropout runs INSIDE the flash kernels
-    (flash_attention_dropout) for the pallas impls.
+    Attention-probability dropout runs INSIDE the flash kernels for the
+    pallas impls, its seed drawn from dropout_rng.
     The pallas and XLA paths draw different (equally valid) masks from the
     same rng — identical regularization statistics, different bits.
     """
     impl = resolve_attention_impl(impl)
-    if dropout_rate > 0.0 and dropout_rng is not None:
-        if impl in ("pallas", "pallas_interpret"):
-            seed = jax.random.bits(dropout_rng, (1,), jnp.uint32)
-            return flash_attention_dropout(q, k, v, seed, True, sm_scale,
-                                           float(dropout_rate),
-                                           impl == "pallas_interpret",
-                                           stat_layout)
+    if impl == "xla":
         return xla_attention(q, k, v, causal=True, sm_scale=sm_scale,
                              dropout_rate=dropout_rate,
                              dropout_rng=dropout_rng)
-    if impl == "xla":
-        return xla_attention(q, k, v, causal=True, sm_scale=sm_scale)
-    if impl == "pallas":
-        return flash_attention(q, k, v, True, sm_scale, False, stat_layout)
-    if impl == "pallas_interpret":
-        return flash_attention(q, k, v, True, sm_scale, True, stat_layout)
-    raise ValueError(f"unknown attention impl: {impl!r}")
+    if impl not in ("pallas", "pallas_interpret"):
+        raise ValueError(f"unknown attention impl: {impl!r}")
+    seed = None
+    if dropout_rate > 0.0 and dropout_rng is not None:
+        seed = jax.random.bits(dropout_rng, (1,), jnp.uint32)
+    return flash_attention(
+        q, k, v, seed, True, sm_scale,
+        float(dropout_rate) if seed is not None else 0.0, False,
+        impl == "pallas_interpret")
 
 
 def resolve_gqa_impl(impl: str, head_dim: int, T: int) -> str:
@@ -2801,7 +2441,7 @@ def causal_attention_gqa(q: jax.Array, k: jax.Array, v: jax.Array,
     vh = jnp.repeat(heads(v, n_kv_head), rep, axis=1)
     if route == "bhtd-rep":
         with jax.named_scope(scope):
-            o = flash_attention(qh, kh, vh, True, None, interpret, "compact")
+            o = flash_attention(qh, kh, vh, interpret=interpret)
     else:
         o = xla_attention(qh, kh, vh, window=window)
     return o.transpose(0, 2, 1, 3).reshape(B, T, HD)

@@ -90,7 +90,6 @@ def _xla_block(q, k, v, mask, sm_scale, keep=None, rate: float = 0.0):
 
 
 def _make_block_fn(block_impl: str, sm_scale: float,
-                   stat_layout: str = "replicated",
                    dropout_rate: float = 0.0,
                    hash_heads: int | None = None,
                    hash_seq_len: int | None = None):
@@ -119,19 +118,15 @@ def _make_block_fn(block_impl: str, sm_scale: float,
             return _xla_block(q, k, v, mask, sm_scale, keep, dropout_rate)
         return block
     if block_impl in ("pallas", "pallas_interpret"):
-        from nanosandbox_tpu.ops.attention import (flash_attention_lse,
-                                                   flash_attention_lse_dropout)
+        from nanosandbox_tpu.ops.attention import flash_attention
 
         interpret = block_impl == "pallas_interpret"
 
         def block(q, k, v, diag, seed):
-            if dropout_rate > 0.0:
-                out, lse = flash_attention_lse_dropout(
-                    q, k, v, seed, diag, sm_scale, dropout_rate,
-                    interpret, stat_layout, hash_heads, hash_seq_len)
-            else:
-                out, lse = flash_attention_lse(q, k, v, diag, sm_scale,
-                                               interpret, stat_layout)
+            out, lse = flash_attention(
+                q, k, v, seed if dropout_rate > 0.0 else None, diag,
+                sm_scale, dropout_rate, True, interpret, hash_heads,
+                hash_seq_len)
             return out.astype(jnp.float32), lse
         return block
     raise ValueError(f"unknown ring block impl: {block_impl!r}")
@@ -177,7 +172,6 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    axis_name: str, axis_size: int, causal: bool = True,
                    sm_scale: Optional[float] = None,
                    block_impl: str = "xla",
-                   stat_layout: str = "replicated",
                    dropout_rate: float = 0.0,
                    hash_heads: int | None = None,
                    hash_seq_len: int | None = None,
@@ -194,8 +188,8 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         sm_scale = q.shape[-1] ** -0.5
     my = lax.axis_index(axis_name)
     Tc = q.shape[2]
-    block = _make_block_fn(block_impl, sm_scale, stat_layout,
-                           dropout_rate, hash_heads, hash_seq_len)
+    block = _make_block_fn(block_impl, sm_scale, dropout_rate, hash_heads,
+                           hash_seq_len)
     b_off, h_off = _shard_offsets(q, dropout_rate, data_size, fsdp_size,
                                   model_size)
     q_off = my.astype(jnp.uint32) * jnp.uint32(Tc)
@@ -266,7 +260,6 @@ def zigzag_ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                           axis_name: str, axis_size: int,
                           sm_scale: Optional[float] = None,
                           block_impl: str = "xla",
-                          stat_layout: str = "replicated",
                           dropout_rate: float = 0.0,
                           hash_heads: int | None = None,
                           hash_seq_len: int | None = None,
@@ -284,8 +277,8 @@ def zigzag_ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     h = T2 // 2
     cp = axis_size
     my = lax.axis_index(axis_name)
-    block = _make_block_fn(block_impl, sm_scale, stat_layout,
-                           dropout_rate, hash_heads, hash_seq_len)
+    block = _make_block_fn(block_impl, sm_scale, dropout_rate, hash_heads,
+                           hash_seq_len)
     b_off, h_off = _shard_offsets(q, dropout_rate, data_size, fsdp_size,
                                   model_size)
     hh = jnp.uint32(h)
@@ -354,13 +347,13 @@ def zigzag_permutation(T: int, cp: int):
 @functools.lru_cache(maxsize=8)
 def _sharded_fn(mesh, causal: bool, sm_scale: float, seq_axis: str,
                 zigzag: bool = False, block_impl: str = "xla",
-                stat_layout: str = "replicated", dropout_rate: float = 0.0,
+                dropout_rate: float = 0.0,
                 hash_heads: int | None = None,
                 hash_seq_len: int | None = None):
     spec = P(("data", "fsdp"), "model", seq_axis, None)
     common = dict(axis_name=seq_axis, axis_size=mesh.shape[seq_axis],
                   sm_scale=sm_scale, block_impl=block_impl,
-                  stat_layout=stat_layout, dropout_rate=dropout_rate,
+                  dropout_rate=dropout_rate,
                   hash_heads=hash_heads, hash_seq_len=hash_seq_len,
                   data_size=mesh.shape["data"],
                   fsdp_size=mesh.shape["fsdp"],
@@ -416,7 +409,6 @@ def ring_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            seq_axis: str = "seq",
                            layout: str = "zigzag",
                            block_impl: str = "auto",
-                           stat_layout: str = "replicated",
                            dropout_rate: float = 0.0,
                            dropout_seed: Optional[jax.Array] = None
                            ) -> jax.Array:
@@ -437,7 +429,6 @@ def ring_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array, *,
     block_impl selects the per-chunk math: 'auto' runs the Pallas flash
     kernel inside the ring when available (scores stay in VMEM — the
     long-context configs need this), degrading to the XLA einsum block.
-    stat_layout is forwarded to the flash backward (round-4 ADVICE #3).
 
     dropout_rate/dropout_seed: attention-probability dropout via the
     global-position hash mask; seed is a (1,) uint32 per-step value
@@ -464,8 +455,8 @@ def ring_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array, *,
     seed = (jnp.zeros((1,), jnp.uint32) if dropout_seed is None
             else jnp.asarray(dropout_seed, jnp.uint32).reshape((1,)))
     hash_heads = q.shape[1]  # global head count (sharded over 'model')
-    fn_args = dict(stat_layout=stat_layout, dropout_rate=float(dropout_rate),
-                   hash_heads=hash_heads, hash_seq_len=T)
+    fn_args = dict(dropout_rate=float(dropout_rate), hash_heads=hash_heads,
+                   hash_seq_len=T)
     if not use_zigzag:
         return _sharded_fn(mesh, causal, float(sm_scale), seq_axis,
                            block_impl=impl, **fn_args)(q, k, v, seed)

@@ -20,7 +20,7 @@ def test_flash_matches_xla(T, D):
     rng = np.random.default_rng(0)
     q, k, v = rand_qkv(rng, T=T, D=D)
     ref = xla_attention(q, k, v, causal=True)
-    out = flash_attention(q, k, v, True, None, True)  # interpret mode
+    out = flash_attention(q, k, v, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -30,7 +30,7 @@ def test_flash_gradients_match():
     q, k, v = rand_qkv(rng, T=64, D=32)
 
     def loss_flash(q, k, v):
-        return flash_attention(q, k, v, True, None, True).sum()
+        return flash_attention(q, k, v, interpret=True).sum()
 
     def loss_ref(q, k, v):
         return xla_attention(q, k, v, causal=True).sum()
@@ -53,10 +53,10 @@ def test_dispatch_auto_on_cpu_uses_xla():
 def test_causal_masking():
     rng = np.random.default_rng(3)
     q, k, v = rand_qkv(rng, B=1, H=1, T=64, D=32)
-    out1 = flash_attention(q, k, v, True, None, True)
+    out1 = flash_attention(q, k, v, interpret=True)
     k2 = k.at[:, :, 40:, :].set(0.0)
     v2 = v.at[:, :, 40:, :].set(0.0)
-    out2 = flash_attention(q, k2, v2, True, None, True)
+    out2 = flash_attention(q, k2, v2, interpret=True)
     # Positions < 40 never see keys >= 40, so they are identical.
     np.testing.assert_allclose(np.asarray(out1[:, :, :40]),
                                np.asarray(out2[:, :, :40]), atol=1e-5)
@@ -66,7 +66,7 @@ def test_bf16_inputs():
     rng = np.random.default_rng(4)
     q, k, v = rand_qkv(rng, T=128, D=64, dtype=jnp.bfloat16)
     ref = xla_attention(q, k, v, causal=True)
-    out = flash_attention(q, k, v, True, None, True)
+    out = flash_attention(q, k, v, interpret=True)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=3e-2)
 
@@ -108,36 +108,42 @@ def test_flash_matches_xla_non_divisor_T(T):
     rng = np.random.default_rng(7)
     q, k, v = rand_qkv(rng, B=1, H=2, T=T, D=32)
     ref = xla_attention(q, k, v, causal=True)
-    out = flash_attention(q, k, v, True, None, True)
+    out = flash_attention(q, k, v, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
 
-# -- flash_attention_lse: the ring's block primitive ----------------------
+# -- with_lse: the ring's block primitive ---------------------------------
 
-def _lse_reference(q, k, v, causal=True):
-    """(out, lse) via plain XLA ops."""
+def _lse_reference(q, k, v, causal=True, seed=None, rate=0.0):
+    """(out, lse) via plain XLA ops; with a seed, the probabilities are
+    dropped by hash_dropout_keep_mask, the kernels' own mask, and lse
+    stays that of the unmasked scores."""
+    from nanosandbox_tpu.ops.attention import hash_dropout_keep_mask
+
+    B, H, T, _ = q.shape
     sm_scale = q.shape[-1] ** -0.5
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32) * sm_scale,
                    k.astype(jnp.float32))
     if causal:
-        T = q.shape[2]
         mask = jnp.tril(jnp.ones((T, T), dtype=bool))
         s = jnp.where(mask[None, None], s, -1e30)
     lse = jax.scipy.special.logsumexp(s, axis=-1)
-    out = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1),
-                     v.astype(jnp.float32))
+    p = jax.nn.softmax(s, -1)
+    if seed is not None:
+        keep = hash_dropout_keep_mask(seed, B, H, T, T, rate=rate)
+        p = jnp.where(keep, p / (1.0 - rate), 0.0)
+    out = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
     return out, lse
 
 
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_lse_matches_reference(causal):
-    from nanosandbox_tpu.ops.attention import flash_attention_lse
-
     rng = np.random.default_rng(11)
     mk = lambda: jnp.asarray(rng.normal(size=(1, 2, 256, 32)), jnp.float32)
     q, k, v = mk(), mk(), mk()
-    out, lse = flash_attention_lse(q, k, v, causal, None, True)  # interpret
+    out, lse = flash_attention(q, k, v, None, causal, with_lse=True,
+                               interpret=True)
     ref_out, ref_lse = _lse_reference(q, k, v, causal)
     assert lse.shape == (1, 2, 256)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
@@ -146,7 +152,7 @@ def test_flash_lse_matches_reference(causal):
                                atol=2e-5, rtol=2e-5)
 
 
-# -- flash_attention_dropout: in-kernel attention-prob dropout ------------
+# -- dropout_rate > 0: in-kernel attention-prob dropout --------------------
 
 def _reference_keep_mask(seed: int, bh: int, T: int, rate: float) -> np.ndarray:
     """Rebuild the kernel's counter-hash mask with the SAME shared helpers
@@ -178,18 +184,16 @@ def _reference_dropout_attention(q, k, v, seed: int, rate: float):
 
 
 def test_flash_dropout_rate0_is_exact_flash():
-    from nanosandbox_tpu.ops.attention import flash_attention_dropout
-
     rng = np.random.default_rng(20)
     q, k, v = rand_qkv(rng, T=128, D=64)
     seed = jnp.array([77], jnp.uint32)
-    base = flash_attention(q, k, v, True, None, True)
-    out = flash_attention_dropout(q, k, v, seed, True, None, 0.0, True)
+    base = flash_attention(q, k, v, interpret=True)
+    out = flash_attention(q, k, v, seed, interpret=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(base))
     gb = jax.grad(lambda q, k, v: flash_attention(
-        q, k, v, True, None, True).sum(), argnums=(0, 1, 2))(q, k, v)
-    gd = jax.grad(lambda q, k, v: flash_attention_dropout(
-        q, k, v, seed, True, None, 0.0, True).sum(), argnums=(0, 1, 2))(q, k, v)
+        q, k, v, interpret=True).sum(), argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, seed, interpret=True).sum(), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gb, gd):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -199,8 +203,6 @@ def test_flash_dropout_matches_masked_reference(rate):
     """Forward AND all three grads against a plain-jnp reference using the
     identical positional mask — proves fwd and both bwd kernels agree on
     every mask bit (the whole correctness risk of recomputed-mask dropout)."""
-    from nanosandbox_tpu.ops.attention import flash_attention_dropout
-
     rng = np.random.default_rng(21)
     q, k, v = rand_qkv(rng, B=2, H=2, T=256, D=64)
     seed_val = 12345
@@ -208,13 +210,13 @@ def test_flash_dropout_matches_masked_reference(rate):
     w = jnp.asarray(rng.normal(size=(64,)), jnp.float32)
 
     ref = _reference_dropout_attention(q, k, v, seed_val, rate)
-    out = flash_attention_dropout(q, k, v, seed, True, None, rate, True)
+    out = flash_attention(q, k, v, seed, dropout_rate=rate, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
     def loss_got(q, k, v):
-        return (flash_attention_dropout(q, k, v, seed, True, None, rate,
-                                        True) * w).sum()
+        return (flash_attention(q, k, v, seed, dropout_rate=rate,
+                                interpret=True) * w).sum()
 
     def loss_ref(q, k, v):
         return (_reference_dropout_attention(q, k, v, seed_val, rate) * w).sum()
@@ -229,8 +231,6 @@ def test_flash_dropout_matches_masked_reference(rate):
 def test_flash_dropout_keep_rate_and_determinism():
     """Statistical contract: drop fraction ~ Binomial(rate), masks differ
     across seeds, identical across calls with the same seed."""
-    from nanosandbox_tpu.ops.attention import flash_attention_dropout
-
     rate = 0.2
     B, H, T = 1, 2, 128
     # v = identity: each output row IS that query's dropped-prob row, so
@@ -238,12 +238,10 @@ def test_flash_dropout_keep_rate_and_determinism():
     q = jnp.zeros((B, H, T, T), jnp.float32)  # uniform scores
     k = jnp.zeros((B, H, T, T), jnp.float32)
     v = jnp.broadcast_to(jnp.eye(T, dtype=jnp.float32), (B, H, T, T))
-    out1 = flash_attention_dropout(q, k, v, jnp.array([5], jnp.uint32),
-                                   True, None, rate, True)
-    out2 = flash_attention_dropout(q, k, v, jnp.array([5], jnp.uint32),
-                                   True, None, rate, True)
-    out3 = flash_attention_dropout(q, k, v, jnp.array([6], jnp.uint32),
-                                   True, None, rate, True)
+    attend = lambda seed: flash_attention(
+        q, k, v, jnp.array([seed], jnp.uint32), dropout_rate=rate,
+        interpret=True)
+    out1, out2, out3 = attend(5), attend(5), attend(6)
     np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
     assert not np.array_equal(np.asarray(out1), np.asarray(out3))
     tril = np.tril(np.ones((T, T), bool))
@@ -264,18 +262,17 @@ def test_causal_attention_dropout_dispatches_to_pallas_kernel():
     silently fall back to XLA as rounds 1-3 did): kernel masks are a pure
     function of (seed, positions), so two calls with the SAME rng must
     agree — the XLA path consumes the rng differently."""
-    from nanosandbox_tpu.ops.attention import flash_attention_dropout
-
     rng = np.random.default_rng(22)
     q, k, v = rand_qkv(rng, T=128, D=64)
     key = jax.random.PRNGKey(3)
     out = causal_attention(q, k, v, impl="pallas_interpret",
                            dropout_rate=0.25, dropout_rng=key)
     seed = jax.random.bits(key, (1,), jnp.uint32)
-    direct = flash_attention_dropout(q, k, v, seed, True, None, 0.25, True)
+    direct = flash_attention(q, k, v, seed, dropout_rate=0.25,
+                             interpret=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(direct))
     # and the mean over many cells is ~ the no-dropout output (unbiased)
-    base = flash_attention(q, k, v, True, None, True)
+    base = flash_attention(q, k, v, interpret=True)
     assert float(jnp.abs(out.mean() - base.mean())) < 0.05
 
 
@@ -283,15 +280,13 @@ def test_flash_lse_gradients_including_dlse():
     """A loss that consumes BOTH outputs exercises the dlse fold-in
     (ds = p * (dp - (drow - dlse))) — exactly what the ring's
     logsumexp-weighted merge does in its backward."""
-    from nanosandbox_tpu.ops.attention import flash_attention_lse
-
     rng = np.random.default_rng(12)
     mk = lambda: jnp.asarray(rng.normal(size=(1, 2, 256, 32)), jnp.float32)
     q, k, v = mk(), mk(), mk()
     w = jnp.asarray(rng.normal(size=(1, 2, 256)), jnp.float32)
 
     def loss_flash(q, k, v):
-        out, lse = flash_attention_lse(q, k, v, True, None, True)
+        out, lse = flash_attention(q, k, v, with_lse=True, interpret=True)
         return (out ** 2).sum() + (lse * w).sum()
 
     def loss_ref(q, k, v):
@@ -306,165 +301,80 @@ def test_flash_lse_gradients_including_dlse():
 
 
 # ---------------------------------------------------------------------------
-# Compact backward-stat layout (--attention_stat_layout=compact)
+# The one backward: compact statistics, one pass, against XLA
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("T,D", [(128, 64), (256, 32), (640, 64)])
-def test_compact_stat_layout_gradients_match_replicated(T, D):
-    """'compact' must be a pure layout change: gradients bit-comparable to
-    the replicated path at every shape class (single stat row, multiple
-    rows, non-block-multiple T that exercises padding)."""
+def _assert_grads_close(got, want, tol):
+    for a, b, name in zip(got, want, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=tol,
+                                   rtol=tol, err_msg=f"d{name} diverged")
+
+
+@pytest.mark.parametrize("T,D", [(128, 64), (256, 32), (640, 64), (256, 64),
+                                 (200, 64), (96, 32)])
+def test_one_pass_backward_matches_xla(T, D):
+    """dQ, dK and dV from the one key-parallel pass, reading the logsumexp
+    as (T // 128, 128) rows, against XLA's: one stat row, several, blocks
+    clamped to 128 (T = 640), and T off the 128 grid (200 and 96 pad to
+    256 and 128; D = 32 pads to 128 lanes)."""
     rng = np.random.default_rng(21)
     q, k, v = rand_qkv(rng, T=T, D=D)
 
-    def loss(layout):
-        def f(q, k, v):
-            return (flash_attention(q, k, v, True, None, True, layout)
-                    ** 2).sum()
-        return f
+    def grads(attend):
+        return jax.grad(lambda q, k, v: (attend(q, k, v) ** 2).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
 
-    gr = jax.grad(loss("replicated"), argnums=(0, 1, 2))(q, k, v)
-    gc = jax.grad(loss("compact"), argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(gr, gc):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=1e-6, rtol=1e-6)
+    _assert_grads_close(
+        grads(lambda q, k, v: flash_attention(q, k, v, interpret=True)),
+        grads(lambda q, k, v: xla_attention(q, k, v, causal=True)), 2e-4)
 
 
-def test_compact_stat_layout_matches_xla_gradients():
-    rng = np.random.default_rng(22)
-    q, k, v = rand_qkv(rng, T=256, D=64)
-
-    def loss_flash(q, k, v):
-        return flash_attention(q, k, v, True, None, True, "compact").sum()
-
-    def loss_ref(q, k, v):
-        return xla_attention(q, k, v, causal=True).sum()
-
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(gf, gr):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=2e-4, rtol=2e-4)
-
-
-def test_compact_stat_layout_dropout_gradients_match_replicated():
-    """The same keep-mask is positional, so dropout gradients must also be
-    layout-invariant."""
-    from nanosandbox_tpu.ops.attention import flash_attention_dropout
-
+@pytest.mark.parametrize("T,D,dropout,with_lse", [
+    (256, 32, True, False), (640, 32, False, True), (256, 64, True, True),
+    (200, 64, True, True)])
+def test_one_pass_backward_matches_reference_dropout_dlse(T, D, dropout,
+                                                          with_lse):
+    """The other branches of the one backward against the dense reference:
+    the positional keep-mask (hash_dropout_keep_mask's), a dlse cotangent
+    riding beside the logsumexp in the stats operand (two stacked rows a
+    head), and both at once (the ring block's surface), on and off the
+    block grid."""
     rng = np.random.default_rng(23)
-    q, k, v = rand_qkv(rng, T=256, D=32)
-    seed = jnp.asarray([1234], jnp.uint32)
+    q, k, v = rand_qkv(rng, B=1, H=2, T=T, D=D)
+    seed = jnp.asarray([1234], jnp.uint32) if dropout else None
+    w = jnp.asarray(rng.normal(size=(1, 2, T)), jnp.float32)
 
-    def loss(layout):
-        def f(q, k, v):
-            return (flash_attention_dropout(q, k, v, seed, True, None, 0.2,
-                                            True, layout) ** 2).sum()
-        return f
-
-    gr = jax.grad(loss("replicated"), argnums=(0, 1, 2))(q, k, v)
-    gc = jax.grad(loss("compact"), argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(gr, gc):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=1e-6, rtol=1e-6)
-
-
-def test_compact_stat_layout_dlse_gradients_match_replicated():
-    """flash_attention_lse's dlse cotangent rides in the stacked stats
-    operand — the S=2 compact path."""
-    from nanosandbox_tpu.ops.attention import flash_attention_lse
-
-    rng = np.random.default_rng(24)
-    mk = lambda: jnp.asarray(rng.normal(size=(1, 2, 256, 32)), jnp.float32)
-    q, k, v = mk(), mk(), mk()
-    w = jnp.asarray(rng.normal(size=(1, 2, 256)), jnp.float32)
-
-    def loss(layout):
-        def f(q, k, v):
-            out, lse = flash_attention_lse(q, k, v, True, None, True, layout)
-            return (out ** 2).sum() + (lse * w).sum()
-        return f
-
-    gr = jax.grad(loss("replicated"), argnums=(0, 1, 2))(q, k, v)
-    gc = jax.grad(loss("compact"), argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(gr, gc):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=1e-6, rtol=1e-6)
-
-
-def test_stat_layout_rejects_unknown():
-    rng = np.random.default_rng(25)
-    q, k, v = rand_qkv(rng, T=128, D=32)
-    with pytest.raises(ValueError, match="stat_layout"):
-        jax.grad(lambda q: flash_attention(q, k, v, True, None, True,
-                                           "bogus").sum())(q)
-
-
-def test_fused_and_split_backward_agree():
-    """The two backward strategies (BWD_IMPL 'fused' default / 'split'
-    reference) must produce the same gradients — this is what keeps the
-    split path exercised and the fused path honest. dk/dv share the same
-    kernel body (bit-identical); dq differs only by f32 accumulation
-    order."""
-    from nanosandbox_tpu.ops import attention as A
-
-    rng = np.random.default_rng(99)
-    B, H, T, D = 2, 3, 256, 64
-    q, k, v = (jnp.asarray(rng.normal(size=(B, H, T, D)), jnp.float32)
-               for _ in range(3))
-
-    def grads():
+    def grads(attend):
         def loss(q, k, v):
-            return (A.flash_attention(q, k, v, True, None, True)
-                    .astype(jnp.float32) ** 2).sum()
+            out, lse = attend(q, k, v)
+            return (out ** 2).sum() + ((lse * w).sum() if with_lse else 0.0)
         return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
-    orig = A.BWD_IMPL
-    try:
-        A.BWD_IMPL = "fused"
-        gf = grads()
-        A.BWD_IMPL = "split"
-        gs = grads()
-    finally:
-        A.BWD_IMPL = orig
-    for a, b, name in zip(gf, gs, "qkv"):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=1e-4, rtol=1e-4,
-                                   err_msg=f"d{name} diverged")
+    def flash(q, k, v):
+        got = flash_attention(q, k, v, seed,
+                              dropout_rate=0.2 if dropout else 0.0,
+                              with_lse=with_lse, interpret=True)
+        return got if with_lse else (got, None)
+
+    _assert_grads_close(
+        grads(flash),
+        grads(lambda q, k, v: _lse_reference(q, k, v, seed=seed, rate=0.2)),
+        5e-4 if with_lse else 2e-4)
 
 
-def test_fused_and_split_backward_agree_dropout_dlse():
-    """Same parity through the heavier path: dropout active AND an lse
-    cotangent (the ring-block surface) — every branch of the shared tile
-    body plus the dq extension."""
-    from nanosandbox_tpu.ops import attention as A
+def test_train_config_has_one_statistic_layout():
+    """attention_stat_layout stays a TrainConfig key only because the
+    benchmark's files name it: 'compact' is its one value, from a
+    constructor, a --key=value flag or a checkpoint's saved config."""
+    from nanosandbox_tpu.config import TrainConfig, load_config
 
-    rng = np.random.default_rng(100)
-    B, H, T, D = 1, 2, 256, 64
-    q, k, v = (jnp.asarray(rng.normal(size=(B, H, T, D)), jnp.float32)
-               for _ in range(3))
-    seed = jnp.asarray([3], jnp.uint32)
-
-    def grads():
-        def loss(q, k, v):
-            out, lse = A.flash_attention_lse_dropout(
-                q, k, v, seed, True, None, 0.2, True)
-            return ((out.astype(jnp.float32) ** 2).sum()
-                    + (lse ** 2).sum())
-        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-
-    orig = A.BWD_IMPL
-    try:
-        A.BWD_IMPL = "fused"
-        gf = grads()
-        A.BWD_IMPL = "split"
-        gs = grads()
-    finally:
-        A.BWD_IMPL = orig
-    for a, b, name in zip(gf, gs, "qkv"):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=1e-4, rtol=1e-4,
-                                   err_msg=f"d{name} diverged")
+    assert TrainConfig().attention_stat_layout == "compact"
+    with pytest.raises(ValueError, match="only statistic layout"):
+        TrainConfig(attention_stat_layout="replicated")
+    with pytest.raises(ValueError, match="only statistic layout"):
+        load_config(["--attention_stat_layout=replicated"])
+    assert load_config(["--attention_stat_layout=compact"]
+                       ).attention_stat_layout == "compact"
 
 
 # -- the (B, T, heads*D) entry: kernels on the model's own layout ----------
@@ -509,8 +419,7 @@ def test_qkv_entry_dropout_mask_and_gradients():
     bit for bit (read off the forward: uniform scores, v one-hot over one
     64-key block at a time), and output and gradients equal the
     (B, H, T, D) entry's for the same seed."""
-    from nanosandbox_tpu.ops.attention import (flash_attention_dropout,
-                                               flash_attention_qkv,
+    from nanosandbox_tpu.ops.attention import (flash_attention_qkv,
                                                hash_dropout_keep_mask)
 
     B, H, D, T, rate = 2, 2, 64, 256, 0.1
@@ -536,8 +445,8 @@ def test_qkv_entry_dropout_mask_and_gradients():
 
     def old(x):
         q, k, v = (_heads_major(t, H) for t in jnp.split(x, 3, axis=-1))
-        o = flash_attention_dropout(q, k, v, seed, True, None, rate, True,
-                                    "compact")
+        o = flash_attention(q, k, v, seed, dropout_rate=rate,
+                            interpret=True)
         return o.transpose(0, 2, 1, 3).reshape(B, T, H * D)
 
     new = lambda x: flash_attention_qkv(x, seed, H, rate, True)
@@ -606,29 +515,37 @@ def _kernel_operand_ranks(closed):
                    for v in e.invars if v.aval.size > 8})
 
 
+def _keep_bhtd(monkeypatch):
+    """Send every shape to the (B, H, T, D) entry: the control that the
+    (B, T, heads*D) entry is what changes a program."""
+    from nanosandbox_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "qkv_layout_supported", lambda *a: False)
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
-def test_new_path_has_no_activation_sized_transpose(grad):
+def test_new_path_has_no_activation_sized_transpose(grad, monkeypatch):
     """CausalSelfAttention on the (B, T, heads*D) entry, forward and
     jax.grad: no transpose of an activation-sized array in the jaxpr (a
-    weight gradient's (3C, C) -> (C, 3C) is smaller and allowed). The
-    'replicated' stat layout, which keeps the (B, H, T, D) entry, is the
-    control that the detector sees the old path's transposes."""
+    weight gradient's (3C, C) -> (C, 3C) is smaller and allowed). The same
+    module kept on the (B, H, T, D) entry is the control that the detector
+    sees the old path's transposes."""
     T, C = 256, 128
     new, B = _attn_module_jaxprs(2, C, T, grad=grad)
     assert _kernel_operand_ranks(new)[:1] == [3]           # (B, T, 3C)
     assert _activation_transposes(new, B * T * C) == []
-    old, _ = _attn_module_jaxprs(2, C, T, grad=grad,
-                                 attention_stat_layout="replicated")
+    _keep_bhtd(monkeypatch)
+    old, _ = _attn_module_jaxprs(2, C, T, grad=grad)
     assert len(_activation_transposes(old, B * T * C)) >= 4
 
 
 @pytest.mark.parametrize("case", ["25x64", "12x32", "model2", "cache",
-                                  "replicated", "12x64"])
+                                  "data2", "12x64"])
 def test_layout_dispatch(case):
     """What lands on the old (B, H, T, D) entry: head counts that do not
     tile 128 lanes (GPT-2 XL's 25 x 64; 12 x 32), a bound mesh with
-    model = 2, a cache, the lane-replicated stat layout. 12 x 64 on no
-    mesh is the control that takes the new entry."""
+    model = 2 or data = 2, a cache. 12 x 64 on no mesh is the control that
+    takes the new entry."""
     from nanosandbox_tpu.config import GPTConfig
     from nanosandbox_tpu.models.gpt import attn_layout
     from nanosandbox_tpu.ops.attention import attention_layout
@@ -636,16 +553,16 @@ def test_layout_dispatch(case):
     T = 256
     H, D = {"25x64": (25, 64), "12x32": (12, 32)}.get(case, (12, 64))
     mesh = None
-    if case == "model2":
+    if case in ("model2", "data2"):
         from nanosandbox_tpu.parallel.mesh import make_mesh
 
-        mesh = make_mesh(mesh_dp=1, mesh_tp=2, devices=jax.devices()[:2])
-    kw = ({"attention_stat_layout": "replicated"}
-          if case == "replicated" else {})
+        tp = 2 if case == "model2" else 1
+        mesh = make_mesh(mesh_dp=2 // tp, mesh_tp=tp,
+                         devices=jax.devices()[:2])
     closed, B = _attn_module_jaxprs(H, H * D, T, mesh=mesh,
-                                    cached=case == "cache", **kw)
+                                    cached=case == "cache")
     cfg = GPTConfig(n_layer=1, n_head=H, n_embd=H * D, block_size=T,
-                    vocab_size=64, attention_impl="pallas_interpret", **kw)
+                    vocab_size=64, attention_impl="pallas_interpret")
     want = "btc" if case == "12x64" else "bhtd"
     if case != "cache":      # a cache is the module's own branch
         assert attn_layout(cfg, mesh, T) == want
@@ -668,10 +585,10 @@ def test_layout_dispatch(case):
 
 
 @pytest.mark.parametrize("dropout", [0.0, 0.1])
-def test_gpt_loss_and_gradients_equal_across_layouts(dropout):
+def test_gpt_loss_and_gradients_equal_across_layouts(dropout, monkeypatch):
     """The whole model, both HBM interfaces: same parameters, same batch,
     same dropout rng -> the same loss and the same gradient for every
-    parameter ('replicated' keeps the (B, H, T, D) entry; the keep-masks
+    parameter (the control keeps the (B, H, T, D) entry; the keep-masks
     agree because both key them on the global (b, h, q, k))."""
     from nanosandbox_tpu.config import GPTConfig
     from nanosandbox_tpu.models.gpt import GPT, attn_layout
@@ -681,11 +598,10 @@ def test_gpt_loss_and_gradients_equal_across_layouts(dropout):
     idx = jnp.asarray(rng.integers(0, 64, size=(2, T)), jnp.int32)
     tgt = jnp.asarray(rng.integers(0, 64, size=(2, T)), jnp.int32)
 
-    def loss_and_grads(stat_layout, want):
+    def loss_and_grads(want):
         cfg = GPTConfig(n_layer=2, n_head=2, n_embd=128, block_size=T,
                         vocab_size=64, dropout=dropout, bias=False,
                         attention_impl="pallas_interpret",
-                        attention_stat_layout=stat_layout,
                         compute_dtype="float32")
         assert attn_layout(cfg, None, T) == want
         model = GPT(cfg)
@@ -700,8 +616,9 @@ def test_gpt_loss_and_gradients_equal_across_layouts(dropout):
 
         return jax.value_and_grad(loss)(params)
 
-    l_new, g_new = loss_and_grads("compact", "btc")
-    l_old, g_old = loss_and_grads("replicated", "bhtd")
+    l_new, g_new = loss_and_grads("btc")
+    _keep_bhtd(monkeypatch)
+    l_old, g_old = loss_and_grads("bhtd")
     np.testing.assert_allclose(float(l_new), float(l_old), rtol=1e-6)
     for a, b in zip(jax.tree.leaves(g_new), jax.tree.leaves(g_old)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),

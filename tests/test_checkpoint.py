@@ -23,6 +23,28 @@ def test_roundtrip(tiny_cfg):
     ckpt.close()
 
 
+def test_restores_a_config_that_names_the_statistic_layout(tiny_cfg):
+    """A checkpoint whose saved config holds attention_stat_layout
+    'compact', as every saved config does, restores for inference: the key
+    is still a TrainConfig field and 'compact' its one value."""
+    from nanosandbox_tpu.train import restore_for_inference
+
+    trainer = Trainer(tiny_cfg)
+    state = trainer.init_state()
+    config = tiny_cfg.to_dict()
+    assert config["attention_stat_layout"] == "compact"
+    ckpt = Checkpointer(tiny_cfg.out_dir, keep=2)
+    ckpt.save(3, state, {"iter_num": 3, "config": config}, wait=True)
+    ckpt.close()
+    trainer2, restored, step = restore_for_inference(tiny_cfg.out_dir,
+                                                     device="cpu")
+    assert step == 3
+    assert trainer2.cfg.attention_stat_layout == "compact"
+    for a, b in zip(jax.tree.leaves(state["params"]),
+                    jax.tree.leaves(restored["params"])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_keep_limit(tiny_cfg):
     trainer = Trainer(tiny_cfg)
     state = trainer.init_state()

@@ -6,9 +6,10 @@ what the vector unit can do to an int8. The TPU compiler is installed
 here and compiles for a chip that is described, not attached — so these
 tests hold every later PR to what the chip accepts, at no chip time:
 
-  * the training kernel ``flash_attention``, forward and backward in
-    both stat layouts (and the in-kernel dropout variant), at
-    (16, 12, 1024, 64) bf16;
+  * the training kernel ``flash_attention``, forward and backward, with
+    and without in-kernel dropout, at (16, 12, 1024, 64) bf16, its
+    kernels' text pinned, and its 'bhtd-rep' route at the lfm2 and
+    granite cells' shapes pinned whole;
   * ``flash_attention_qkv``, the same kernels on the model's own
     (B, T, 3C) layout, forward and backward, with and without dropout,
     at both benchmark cells' shapes: (16, 1024, 2304) (12 heads) and
@@ -72,7 +73,6 @@ from jax.sharding import SingleDeviceSharding
 
 from nanosandbox_tpu.ops import flash_decode as fd
 from nanosandbox_tpu.ops.attention import (flash_attention,
-                                           flash_attention_dropout,
                                            flash_attention_gqa,
                                            flash_attention_qkv, qk_prep,
                                            qk_rotary, resolve_gqa_bwd)
@@ -122,27 +122,21 @@ def compiled_text(fn, *args) -> str:
 def test_flash_attention_forward(sds):
     x = sds(TRAIN_SHAPE, jnp.bfloat16)
     txt = compiled_text(
-        lambda q, k, v: flash_attention(q, k, v, True, None, False), x, x, x)
+        lambda q, k, v: flash_attention(q, k, v), x, x, x)
     assert "tpu_custom_call" in txt
 
 
 @pytest.mark.parametrize("dropout", [False, True], ids=["plain", "dropout"])
-@pytest.mark.parametrize("stat_layout", ["replicated", "compact"])
-def test_flash_attention_backward(sds, stat_layout, dropout):
+def test_flash_attention_backward(sds, dropout):
     x = sds(TRAIN_SHAPE, jnp.bfloat16)
-    if dropout:
-        def loss(q, k, v, seed):
-            return flash_attention_dropout(
-                q, k, v, seed, True, None, 0.1, False,
-                stat_layout).astype(jnp.float32).sum()
-        args = (x, x, x, sds((1,), jnp.uint32))
-    else:
-        def loss(q, k, v):
-            return flash_attention(
-                q, k, v, True, None, False,
-                stat_layout).astype(jnp.float32).sum()
-        args = (x, x, x)
-    txt = compiled_text(jax.grad(loss, argnums=(0, 1, 2)), *args)
+
+    def loss(q, k, v, seed):
+        return flash_attention(
+            q, k, v, seed if dropout else None,
+            dropout_rate=0.1 if dropout else 0.0).astype(jnp.float32).sum()
+
+    txt = compiled_text(jax.grad(loss, argnums=(0, 1, 2)), x, x, x,
+                        sds((1,), jnp.uint32))
     assert "tpu_custom_call" in txt
 
 
@@ -479,6 +473,121 @@ def test_qk_prep_normed_kernels_are_what_they_were(sds, heads, theta):
         (text,) = _kernel_texts(jax.jit(fn).lower(*args).as_text())
         got.append(hashlib.sha256(text.encode()).hexdigest())
     assert tuple(got) == QK_PREP_KERNEL_SHA256[(heads, theta)]
+
+
+def _sha256(text: str) -> str:
+    import hashlib
+
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+def _wrapper_text(lowered) -> str:
+    """A lowering's HLO without what names a source line: every op's
+    metadata, and the custom calls' backend_config (the kernels' bodies,
+    which _kernel_texts reads without their locations)."""
+    hlo = re.sub(r", metadata=\{[^}]*\}", "", lowered.as_text(dialect="hlo"))
+    return re.sub(r", backend_config=.*$", "", hlo, flags=re.M)
+
+
+def _kernel_hashes(lowered) -> tuple:
+    return tuple(_sha256(t) for t in _kernel_texts(lowered.as_text()))
+
+
+# The (B, H, T, D) entry's programs as b2443c7 lowered them, before its four
+# custom_vjps became one, its backward lost the replicated statistic layout
+# and the split pair. sha256 of causal_attention_gqa's 'bhtd-rep' route,
+# jax.grad at 32 query heads on 8 KV heads of 64 and T = 8192, the lfm2
+# cell's B = 2 and the granite cell's B = 1: the wrapper (_wrapper_text),
+# then the forward's and the backward's kernel text.
+BHTD_REP_SHA256 = {
+    2: (
+        "8732f2d2517e50ef40496e06702ad8e66923d92db5a94f956ad65ffd70e79dc8",
+        "01217c456d0b8c28458799275d83f42c81ba89579a3c200eedf0b8534ddef3cb",
+        "c439ebddfe05ba99854949f8db88e04a724a6597397dffcc19e566112972de47",
+    ),
+    1: (
+        "2f14b41d20270d142588131912ccee222958505f2ad28ceec62784402f1d38cf",
+        "26eb32a99a5e0cf688488f810fb2342c13bd2dc9ae69dedef17c635ee40f24e4",
+        "8af00eeffbf8476d2232a47f2ad466f41bfd9e79683e8482182141d2d9ab7a24",
+    ),
+}
+
+
+@pytest.mark.parametrize("B", [2, 1], ids=["lfm2", "granite"])
+def test_bhtd_rep_route_is_what_it_was(sds, B):
+    from nanosandbox_tpu.ops.attention import causal_attention_gqa
+
+    T, H, G, D = 8192, 32, 8, 64
+
+    def loss(q, k, v):
+        with jax.named_scope("Model"):
+            return causal_attention_gqa(q, k, v, H, G, impl="pallas",
+                                        scope="attn_full"
+                                        ).astype(jnp.float32).sum()
+
+    kv = sds((B, T, G * D), jnp.bfloat16)
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        sds((B, T, H * D), jnp.bfloat16), kv, kv)
+    assert (_sha256(_wrapper_text(lowered)),
+            *_kernel_hashes(lowered)) == BHTD_REP_SHA256[B]
+
+
+# flash_attention's kernel text at TRAIN_SHAPE, as b2443c7 lowered it: the
+# forward's kernel, and the forward's and the backward's under jax.grad,
+# without and with dropout (rate 0.1).
+FLASH_KERNEL_SHA256 = {
+    ("forward", False): (
+        "bb6ad4371c2f3f1c0b92f13b5dcdf3ab658a40179d74fa242f07e0229ac067dd",
+    ),
+    ("forward", True): (
+        "a8389a4e25834c7864b00dbf6f4500a3829b23b89103bd402fcaca2103c9f685",
+    ),
+    ("backward", False): (
+        "bb6ad4371c2f3f1c0b92f13b5dcdf3ab658a40179d74fa242f07e0229ac067dd",
+        "3fcb90a2aa9b8f8898c5b027a97ff8a01a596efb07c0f027a2cc8ac351efed4f",
+    ),
+    ("backward", True): (
+        "a8389a4e25834c7864b00dbf6f4500a3829b23b89103bd402fcaca2103c9f685",
+        "c023e08fe49c655d4d2b911a7dedc5b1cef842099b035481332bceccb91967ac",
+    ),
+}
+
+
+@pytest.mark.parametrize("dropout", [False, True], ids=["plain", "dropout"])
+@pytest.mark.parametrize("pass_", ["forward", "backward"])
+def test_flash_attention_kernels_are_what_they_were(sds, pass_, dropout):
+    def attend(q, k, v, seed):
+        return flash_attention(q, k, v, seed if dropout else None,
+                               dropout_rate=0.1 if dropout else 0.0)
+
+    fn = attend if pass_ == "forward" else jax.grad(
+        lambda *a: attend(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2))
+    x = sds(TRAIN_SHAPE, jnp.bfloat16)
+    assert _kernel_hashes(jax.jit(fn).lower(
+        x, x, x, sds((1,), jnp.uint32))) == FLASH_KERNEL_SHA256[
+            (pass_, dropout)]
+
+
+# flash_attention_qkv's kernel text at the 124M cell's (16, 1024, 2304), as
+# b2443c7 lowered it: its tiles are the (B, H, T, D) entry's.
+QKV_KERNEL_SHA256 = {
+    "forward": (
+        "1308f2c50cf430c47e4968223e01f7328b958ed51e1112a7ada3e3fea690ea2b",
+    ),
+    "backward": (
+        "1308f2c50cf430c47e4968223e01f7328b958ed51e1112a7ada3e3fea690ea2b",
+        "d900c196aa3dba53308ab3ea7a870682f7a611087c0b5837f13ae5358d2b4268",
+    ),
+}
+
+
+@pytest.mark.parametrize("pass_", ["forward", "backward"])
+def test_flash_attention_qkv_kernels_are_what_they_were(sds, pass_):
+    attend, args = _qkv_call("124m", False, sds)
+    fn = attend if pass_ == "forward" else jax.grad(
+        lambda qkv, seed: attend(qkv, seed).astype(jnp.float32).sum())
+    assert _kernel_hashes(jax.jit(fn).lower(*args)) == QKV_KERNEL_SHA256[
+        pass_]
 
 
 def test_qk_rotary_forward_and_backward(sds):

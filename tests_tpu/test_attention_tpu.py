@@ -53,8 +53,7 @@ def test_auto_is_the_compiled_kernel():
 def test_flash_fwd_matches_xla_compiled(T, D, dtype):
     rng = np.random.default_rng(0)
     q, k, v = rand_qkv(rng, T=T, D=D, dtype=dtype)
-    out = jax.jit(lambda q, k, v: flash_attention(
-        q, k, v, True, None, False))(q, k, v)
+    out = jax.jit(lambda q, k, v: flash_attention(q, k, v))(q, k, v)
     ref = xla_attention(q, k, v, causal=True)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32), atol=1e-2)
@@ -66,8 +65,7 @@ def test_flash_grads_match_xla_compiled(dtype):
     q, k, v = rand_qkv(rng, dtype=dtype)
 
     def loss_flash(q, k, v):
-        return flash_attention(q, k, v, True, None, False).astype(
-            jnp.float32).mean()
+        return flash_attention(q, k, v).astype(jnp.float32).mean()
 
     def loss_ref(q, k, v):
         return xla_attention(q, k, v, causal=True).astype(jnp.float32).mean()
@@ -89,17 +87,15 @@ def test_auto_dispatch_selects_pallas_on_tpu():
         np.asarray(out, np.float32), np.asarray(ref, np.float32), atol=5e-2)
 
 
-# -- round 3: flash_attention_lse + ring flash blocks + remat policy ------
+# -- round 3: with_lse + ring flash blocks + remat policy -----------------
 
 def test_flash_lse_compiles_and_matches(T=1024):
     """The ring's block primitive must lower on real Mosaic (interpret
     mode cannot see BlockSpec/layout regressions)."""
-    from nanosandbox_tpu.ops.attention import flash_attention_lse
-
     rng = np.random.default_rng(4)
     q, k, v = rand_qkv(rng, B=1, H=2, T=T, D=64)
-    out, lse = jax.jit(lambda q, k, v: flash_attention_lse(
-        q, k, v, True, None, False))(q, k, v)
+    out, lse = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, with_lse=True))(q, k, v)
     s = (np.asarray(q, np.float32) * (64 ** -0.5)) @ np.asarray(
         k, np.float32).transpose(0, 1, 3, 2)
     s = np.where(np.tril(np.ones((T, T), bool))[None, None], s, -1e30)
@@ -151,18 +147,16 @@ def test_remat_save_attention_compiles_on_tpu():
 
 
 def test_flash_lse_gradients_compile_with_dlse_on_tpu():
-    """The has_dlse backward is its own Mosaic program (W=2*LANES stacked
-    stats operand, lane-offset column reads) — compile and check it on
-    real hardware, not just interpret mode. A loss consuming BOTH outputs
+    """The has_dlse backward is its own Mosaic program (a second stats row
+    stacked beside the logsumexp's) — compile and check it on real
+    hardware, not just interpret mode. A loss consuming BOTH outputs
     forces a nonzero dlse cotangent through the kernels."""
-    from nanosandbox_tpu.ops.attention import flash_attention_lse
-
     rng = np.random.default_rng(6)
     q, k, v = rand_qkv(rng, B=1, H=2, T=1024, D=64, dtype=jnp.float32)
     w = jnp.asarray(rng.normal(size=(1, 2, 1024)), jnp.float32)
 
     def loss_flash(q, k, v):
-        out, lse = flash_attention_lse(q, k, v, True, None, False)
+        out, lse = flash_attention(q, k, v, with_lse=True)
         return (out.astype(jnp.float32) ** 2).sum() + (lse * w).sum()
 
     def loss_ref(q, k, v):
@@ -182,24 +176,27 @@ def test_flash_lse_gradients_compile_with_dlse_on_tpu():
         assert np.abs(a32 - b32).max() / scale < 1e-2
 
 
-def test_compact_stat_layout_bitwise_on_hardware():
-    """--attention_stat_layout=compact must be a PURE layout change on the
-    real chip: the HIGHEST-precision selection matmul in _expand_stat_tile
-    makes the expanded lse bit-identical to the replicated operand, so
-    gradients match bitwise (not just within tolerance). Catches any
-    Mosaic lowering drift in the expansion path that interpret mode
-    cannot see."""
-    rng = np.random.default_rng(31)
-    q, k, v = rand_qkv(rng)
-
-    def grads(layout):
+def _grads_match_xla(q, k, v):
+    """The one backward's gradients of sum(o**2) against XLA's; the
+    HIGHEST-precision selection matmul in _expand_stat_tile keeps the
+    expanded logsumexp exact, so any Mosaic lowering drift in the
+    expansion shows as an O(1) error."""
+    def grads(attend):
         def loss(q, k, v):
-            return (flash_attention(q, k, v, True, None, False, layout)
-                    .astype(jnp.float32) ** 2).sum()
+            return (attend(q, k, v).astype(jnp.float32) ** 2).sum()
         return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
 
-    for a, b in zip(grads("replicated"), grads("compact")):
-        assert bool(jnp.array_equal(a, b)), "compact layout changed gradients"
+    for a, b in zip(grads(flash_attention),
+                    grads(lambda q, k, v: xla_attention(q, k, v,
+                                                        causal=True))):
+        a32, b32 = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        scale = max(np.abs(b32).max(), 1e-8)
+        assert np.abs(a32 - b32).max() / scale < 1e-2
+
+
+def test_compact_stats_grads_match_xla_on_hardware():
+    """The compact statistics' expansion, compiled, at the default shape."""
+    _grads_match_xla(*rand_qkv(np.random.default_rng(31), dtype=jnp.float32))
 
 
 def test_kv_cached_decode_matches_full_forward_on_hardware():
@@ -252,12 +249,11 @@ def test_kv_cached_decode_matches_full_forward_on_hardware():
 
 
 def test_dropout_kernel_matches_masked_dense_reference_on_hardware():
-    """The in-kernel dropout mask, COMPILED: flash_attention_dropout's
+    """The in-kernel dropout mask, COMPILED: flash_attention's dropout
     output must equal a dense softmax masked with hash_dropout_keep_mask
     (the same hash the kernel inlines), proving the Mosaic-lowered mask
     derivation matches the jnp derivation bit-for-bit on hardware."""
-    from nanosandbox_tpu.ops.attention import (flash_attention_dropout,
-                                               hash_dropout_keep_mask)
+    from nanosandbox_tpu.ops.attention import hash_dropout_keep_mask
 
     rng = np.random.default_rng(41)
     B, H, T, D = 2, 4, 512, 64
@@ -265,8 +261,8 @@ def test_dropout_kernel_matches_masked_dense_reference_on_hardware():
     seed = jnp.asarray([991], jnp.uint32)
     rate = 0.2
 
-    out = jax.jit(lambda q, k, v: flash_attention_dropout(
-        q, k, v, seed, True, None, rate, False))(q, k, v)
+    out = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, seed, dropout_rate=rate))(q, k, v)
 
     sm = D ** -0.5
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32) * sm,
@@ -287,8 +283,7 @@ def test_dropout_mask_determinism_fwd_vs_bwd_on_hardware():
     on hardware, fwd+bwd with the same seed must be exactly reproducible
     call-to-call, and the gradients must match jax.grad of the dense
     masked reference (same mask => same math => same grads within bf16)."""
-    from nanosandbox_tpu.ops.attention import (flash_attention_dropout,
-                                               hash_dropout_keep_mask)
+    from nanosandbox_tpu.ops.attention import hash_dropout_keep_mask
 
     rng = np.random.default_rng(42)
     B, H, T, D = 2, 4, 512, 64
@@ -297,8 +292,8 @@ def test_dropout_mask_determinism_fwd_vs_bwd_on_hardware():
     rate = 0.15
 
     def loss(q, k, v):
-        return (flash_attention_dropout(q, k, v, seed, True, None, rate,
-                                        False).astype(jnp.float32) ** 2).sum()
+        return (flash_attention(q, k, v, seed, dropout_rate=rate).astype(
+            jnp.float32) ** 2).sum()
 
     g1 = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
     g2 = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
@@ -335,16 +330,13 @@ def test_dropout_rbg_seed_path_on_hardware():
     """The production dropout configs run rng_impl=rbg: deriving the
     kernel seed via jax.random.bits from an rbg key must compile and be
     deterministic per key on the hardware RNG path."""
-    from nanosandbox_tpu.ops.attention import flash_attention_dropout
-
     rng = np.random.default_rng(43)
     q, k, v = rand_qkv(rng, T=512)
 
     @jax.jit
     def run(key, q, k, v):
         seed = jax.random.bits(key, (1,), jnp.uint32)
-        return flash_attention_dropout(q, k, v, seed, True, None, 0.1,
-                                       False)
+        return flash_attention(q, k, v, seed, dropout_rate=0.1)
 
     k1 = jax.random.key(7, impl="rbg")
     o1 = run(k1, q, k, v)
@@ -355,45 +347,30 @@ def test_dropout_rbg_seed_path_on_hardware():
 
 
 def test_lse_dropout_ring_block_on_hardware():
-    """flash_attention_lse_dropout (the regularized ring block) compiles
-    and matches flash_attention_dropout's output; its lse equals the
-    UNMASKED flash_attention_lse's (dropout must not perturb the
-    normalizer the ring merge relies on)."""
-    from nanosandbox_tpu.ops.attention import (flash_attention_dropout,
-                                               flash_attention_lse,
-                                               flash_attention_lse_dropout)
-
+    """Dropout with the logsumexp (the regularized ring block) compiles
+    and gives the output of dropout without it; its lse equals the
+    UNMASKED one (dropout must not perturb the normalizer the ring merge
+    relies on)."""
     rng = np.random.default_rng(44)
     q, k, v = rand_qkv(rng, T=512)
     seed = jnp.asarray([17], jnp.uint32)
 
-    out_d, lse_d = jax.jit(lambda q, k, v: flash_attention_lse_dropout(
-        q, k, v, seed, True, None, 0.2, False))(q, k, v)
-    out_ref = jax.jit(lambda q, k, v: flash_attention_dropout(
-        q, k, v, seed, True, None, 0.2, False))(q, k, v)
-    _, lse_ref = jax.jit(lambda q, k, v: flash_attention_lse(
-        q, k, v, True, None, False))(q, k, v)
+    out_d, lse_d = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, seed, dropout_rate=0.2, with_lse=True))(q, k, v)
+    out_ref = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, seed, dropout_rate=0.2))(q, k, v)
+    _, lse_ref = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, with_lse=True))(q, k, v)
     assert bool(jnp.array_equal(out_d, out_ref))
     np.testing.assert_allclose(np.asarray(lse_d), np.asarray(lse_ref),
                                atol=1e-5)
 
 
-def test_compact_stat_layout_grads_long_context_on_hardware():
+def test_compact_stats_grads_long_context_on_hardware():
     """The compact expansion at T=8192 (the long-context shape, where the
-    stat tile is (64, 128) per q-block slice): grads must stay bitwise
-    equal to the replicated layout under real Mosaic lowering."""
-    rng = np.random.default_rng(45)
-    q, k, v = rand_qkv(rng, B=1, H=2, T=8192, D=64)
-
-    def grads(layout):
-        def loss(q, k, v):
-            return (flash_attention(q, k, v, True, None, False, layout)
-                    .astype(jnp.float32) ** 2).sum()
-        return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
-
-    for a, b in zip(grads("replicated"), grads("compact")):
-        assert bool(jnp.array_equal(a, b)), (
-            "compact layout changed gradients at T=8192")
+    stat tile is (64, 128) per q-block slice), compiled."""
+    _grads_match_xla(*rand_qkv(np.random.default_rng(45), B=1, H=2, T=8192,
+                               D=64, dtype=jnp.float32))
 
 
 def test_ring_dropout_single_device_degenerate_on_hardware():
@@ -401,7 +378,6 @@ def test_ring_dropout_single_device_degenerate_on_hardware():
     ring (one local Mosaic flash-dropout block) must match the non-ring
     kernel exactly — proving the regularized ring path lowers on
     hardware. (Multi-device sp parity is CPU-tier; one chip here.)"""
-    from nanosandbox_tpu.ops.attention import flash_attention_dropout
     from nanosandbox_tpu.ops.ring_attention import ring_attention_sharded
     from nanosandbox_tpu.parallel.mesh import make_mesh
 
@@ -411,8 +387,8 @@ def test_ring_dropout_single_device_degenerate_on_hardware():
     mesh = make_mesh(mesh_dp=1, devices=jax.devices()[:1])
     out = jax.jit(lambda q, k, v: ring_attention_sharded(
         q, k, v, mesh=mesh, dropout_rate=0.2, dropout_seed=seed))(q, k, v)
-    ref = jax.jit(lambda q, k, v: flash_attention_dropout(
-        q, k, v, seed, True, None, 0.2, False))(q, k, v)
+    ref = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, seed, dropout_rate=0.2))(q, k, v)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=2e-2)
 
@@ -427,8 +403,7 @@ def test_flash_bwd_compiles_at_long_context(T):
     x = jax.ShapeDtypeStruct((1, 12, T, 64), jnp.bfloat16)
 
     def loss(q):
-        return flash_attention(q, q, q, True, None, False,
-                               "compact").astype(jnp.float32).sum()
+        return flash_attention(q, q, q).astype(jnp.float32).sum()
 
     jax.jit(jax.grad(loss)).lower(x).compile()
 
@@ -475,8 +450,7 @@ def test_qkv_entry_dropout_equals_bhtd_entry_compiled(H, D):
     """Same seed, same keep-mask (hash_dropout_keep_mask's, keyed on the
     global (b, h, q, k)): output and gradients of the head-group kernels
     equal the (B, H, T, D) entry's on the chip."""
-    from nanosandbox_tpu.ops.attention import (flash_attention_dropout,
-                                               flash_attention_qkv)
+    from nanosandbox_tpu.ops.attention import flash_attention_qkv
 
     B, T, rate = 2, 1024, 0.1
     rng = np.random.default_rng(31)
@@ -486,8 +460,7 @@ def test_qkv_entry_dropout_equals_bhtd_entry_compiled(H, D):
 
     def old(x):
         q, k, v = (_heads_major(t, H) for t in jnp.split(x, 3, axis=-1))
-        o = flash_attention_dropout(q, k, v, seed, True, None, rate, False,
-                                    "compact")
+        o = flash_attention(q, k, v, seed, dropout_rate=rate)
         return o.transpose(0, 2, 1, 3).reshape(B, T, H * D)
 
     new = lambda x: flash_attention_qkv(x, seed, H, rate, False)
